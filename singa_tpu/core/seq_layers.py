@@ -26,8 +26,9 @@ from ..ops.attention import (attention_reference, expand_kv_heads,
                              flash_attention, rope)
 from .layers import Layer, LayerError, register_layer
 
-# (layer name, seq_len, head_dim) triples that already warned about
-# the dense-fallback path
+# keys of the fallbacks already reported once: (layer name, seq_len,
+# head_dim) for dense attention, (layer name, "head", shapes...) for
+# the chunked LM head
 _flash_fallback_warned: set = set()
 
 
@@ -451,12 +452,18 @@ class LMHeadLossLayer(Layer, _HeadProjection):
         self.flops_shape = (b, s, e, p.vocab_size)   # for utils.flops
         self.out_shape = (2,)
 
-    def _use_fused(self, h2, w, is_vE) -> bool:
+    def _use_fused(self, h2, w, is_vE, ctx) -> bool:
         """Whether the fused Pallas forward applies: tied (V, E)
-        layout, top-1 metric, kernel-legal shapes, real TPU."""
-        from ..ops.attention import _on_tpu
+        layout, top-1 metric, kernel-legal shapes, no mesh.  The
+        platform does not enter the choice (off-TPU the same kernel
+        runs interpreted, as the flash kernels do), so CPU tests walk
+        the branch the chip takes.  Under a mesh the chunked XLA head
+        stays: GSPMD cannot partition a Pallas custom call, so the
+        fused head on mesh-sharded operands would gather them and run
+        whole on every chip, while the chunked head's dots partition
+        like any other matmul."""
         from ..ops.head_loss import eligible
-        return (self.topk == 1 and is_vE and _on_tpu()
+        return (self.topk == 1 and is_vE and ctx.mesh is None
                 and eligible(h2, w))
 
     @staticmethod
@@ -497,10 +504,19 @@ class LMHeadLossLayer(Layer, _HeadProjection):
         # fused Pallas forward (one pass over vocab blocks, logits
         # VMEM-only — ops/head_loss.py) for tied heads at kernel-legal
         # shapes; the chunked XLA path covers everything else
-        if self._use_fused(h2, w, is_vE):
+        if self._use_fused(h2, w, is_vE, ctx):
             loss, prec = fused_lm_xent(h2, w, l2, self.scale,
                                        self.chunk)
             return {"loss": loss, "precision": prec}
+        key = (self.cfg.name, "head") + tuple(h2.shape) + tuple(w.shape)
+        if key not in _flash_fallback_warned:
+            _flash_fallback_warned.add(key)
+            import sys
+            print(f"note: LM head {self.cfg.name!r} (tokens={h2.shape[0]}, "
+                  f"weight={tuple(w.shape)}) runs the chunked XLA head — "
+                  f"the fused kernel needs a tied (V, E) weight, topk=1, "
+                  f"no mesh, tokens % 512 == 0, V % 2048 == 0 and "
+                  f"E % 128 == 0", file=sys.stderr)
         loss, prec = chunked_lm_xent(
             h2, w, l2, chunk_size=self.chunk, topk=self.topk,
             scale=self.scale, w_is_vE=is_vE)

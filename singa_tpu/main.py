@@ -301,6 +301,7 @@ def serve_main(argv) -> int:
 
         import jax
 
+        _log_device(log)
         from .serve import InferenceEngine, InferenceServer, ServeSpec
         spec = (ServeSpec.parse(args.serve_spec) if args.serve_spec
                 else ServeSpec())
@@ -732,7 +733,19 @@ def _pipeline_smoke(ctl, net, args, log) -> int:
     return 0 if ok else 1
 
 
+def _log_device(log) -> None:
+    """Name what this run executes on, in its own log: a run that fell
+    back to the CPU must not read like one that used the chip."""
+    from .utils.flops import device_info
+    d = device_info()
+    log(f"device: platform={d['platform']} kind={d['kind']!r} "
+        f"count={d['count']} jax={d['jax']} jaxlib={d['jaxlib']} "
+        f"libtpu={d['libtpu']}")
+
+
 def main(argv=None) -> int:
+    from .utils import compile_cache
+    compile_cache.enable()
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] == "serve":
         return serve_main(argv[1:])
@@ -789,6 +802,7 @@ def _run(args) -> int:
     # more than one device is visible (ClusterProto topology → Mesh,
     # the reference's Cluster singleton role, cluster.h:20-121).
     import jax
+    _log_device(log)
     mesh = None
     if cluster is not None and len(jax.devices()) > 1:
         from .parallel import mesh_from_cluster

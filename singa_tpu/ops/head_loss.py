@@ -23,13 +23,17 @@ transposed copy of the table ever materializes.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+#: Stable kernel name, as ops/attention.py KERNEL_NAMES.
+KERNEL_NAME = "singa_lm_head_stats"
 
 
 def _fwd_kernel(h_ref, w_ref, lbl_ref, lse_ref, ll_ref, hit_ref,
@@ -102,6 +106,7 @@ def _head_stats_pallas(h, w_vE, labels, bn: int, bv: int,
            pltpu.VMEM((bn, 1), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
+        name=KERNEL_NAME,
     )(h, w_vE, lbl2)
     return lse[:, 0], ll[:, 0], hit[:, 0]
 
@@ -117,15 +122,20 @@ def eligible(h, w_vE, bn: int = 512, bv: int = 2048) -> bool:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def fused_lm_xent(h, w_vE, labels, scale: float = 1.0,
                   chunk_size: int = 4096, bn: int = 512, bv: int = 2048,
-                  interpret: bool = False):
+                  interpret: Optional[bool] = None):
     """(loss, precision) for an LM head with (V, E) weight — fused
     Pallas forward, chunked XLA backward.  Top-1 precision only (the
-    kernel tracks argmax; topk>1 callers use chunked_lm_xent)."""
+    kernel tracks argmax; topk>1 callers use chunked_lm_xent).
+    `interpret` None = interpreted off-TPU, compiled on it (the flash
+    kernels' convention)."""
     return _fused_fwd(h, w_vE, labels, scale, chunk_size, bn, bv,
                       interpret)[0]
 
 
 def _fused_fwd(h, w_vE, labels, scale, chunk_size, bn, bv, interpret):
+    if interpret is None:
+        from .attention import _on_tpu
+        interpret = not _on_tpu()
     n = h.shape[0]
     lse, ll, hit = _head_stats_pallas(h, w_vE, labels, bn, bv, interpret)
     loss = scale * jnp.sum(lse - ll) / n

@@ -24,20 +24,8 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-    LEGACY_SHARD_MAP = False   # see parallel/sequence.py
-except ImportError:  # pre-0.4.35 jax: experimental namespace, and the
-    # replication-check kwarg is still called check_rep there
-    from jax.experimental.shard_map import shard_map as _shard_map
-    LEGACY_SHARD_MAP = True
-
-    def shard_map(f, **kw):
-        # call sites pass check_vma=False; keep the legacy check_rep
-        # rewrite OFF too (sequence.py has the numerics rationale)
-        kw["check_rep"] = bool(kw.pop("check_vma", False))
-        return _shard_map(f, **kw)
 
 
 def stack_stage_params(per_stage_params: Sequence[Any]) -> Any:

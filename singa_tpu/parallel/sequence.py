@@ -21,26 +21,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-    #: True when running on the pre-0.4.35 experimental shard_map.
-    #: The legacy tracer's check_rep/rewrite machinery is known to
-    #: drift ring-attention numerics slightly (PR 10); parity tests
-    #: consult this flag to xfail rather than assert-fail there.
-    LEGACY_SHARD_MAP = False
-except ImportError:  # pre-0.4.35 jax: experimental namespace, and the
-    # replication-check kwarg is still called check_rep there
-    from jax.experimental.shard_map import shard_map as _shard_map
-    LEGACY_SHARD_MAP = True
-
-    def shard_map(f, **kw):
-        # every call site here passes check_vma=False; map it to
-        # check_rep=False (the old default of True turned the
-        # replication CHECK into a rewrite pass that perturbed the
-        # ring collectives' numerics — the PR 10 drift)
-        kw["check_rep"] = bool(kw.pop("check_vma", False))
-        return _shard_map(f, **kw)
 
 from ..ops.attention import (NEG_INF, attention_reference,
                              chunk_attention_blockwise, flash_chunk,

@@ -696,10 +696,15 @@ class InferenceEngine:
 
         return fn
 
+    @property
+    def serve_dtype(self):
+        """The dtype the compiled programs compute and cache in: the
+        params' own (every program reads it off the params tree)."""
+        return jax.tree_util.tree_leaves(self._params)[0].dtype
+
     def _pools_spec(self):
-        dtype = jax.tree_util.tree_leaves(self._params)[0].dtype
         pools = init_pools(self.net, self.spec.cb_pool_blocks,
-                           self.spec.cb_block_len, dtype)
+                           self.spec.cb_block_len, self.serve_dtype)
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), pools)
 

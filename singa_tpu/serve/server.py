@@ -142,7 +142,8 @@ class InferenceServer:
                  if self.engine.spec.cb_on
                  else f"buckets {self.engine.spec.buckets}")
         self.log(f"serve: warmed {n} program(s) for {shape}, serving "
-                 f"checkpoint step {self.engine.params_step}")
+                 f"checkpoint step {self.engine.params_step} as "
+                 f"{self.engine.serve_dtype}")
         self.batcher.start()
         if self.scheduler is not None:
             self.scheduler.start()

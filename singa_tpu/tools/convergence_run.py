@@ -123,6 +123,8 @@ def run(conf: str, target: float, max_steps: int, out: str,
 
 
 def main():
+    from ..utils import compile_cache
+    compile_cache.enable()
     repo = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     ap = argparse.ArgumentParser()

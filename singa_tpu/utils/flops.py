@@ -20,25 +20,53 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-# bf16 MXU peak FLOP/s per jax Device, keyed by device_kind.  v2/v3
-# expose one device per core (chip peaks are 45/123 TFLOP/s over 2
-# cores); v4+ expose one device per chip.
+# bf16 MXU peak FLOP/s per jax Device, keyed by the `device_kind`
+# string JAX reports.  The strings are the ones the installed JAX
+# itself matches on (jax/_src/pallas/mosaic/tpu_info.py); "TPU v5 lite"
+# is what the v5e this repo runs on reports.  Peaks are Google Cloud's
+# published per-chip numbers (v4+ expose one device per chip).
 PEAK_FLOPS: Dict[str, float] = {
-    "TPU v2": 22.5e12, "TPU v3": 61.5e12,
-    "TPU v4": 275e12, "TPU v4 lite": 137e12,
-    "TPU v5 lite": 197e12, "TPU v5e": 197e12, "TPU v5": 197e12,
-    "TPU v5p": 459e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12, "TPU v5e": 197e12,
+    "TPU v5": 459e12, "TPU v5p": 459e12,
     "TPU v6 lite": 918e12, "TPU v6e": 918e12,
 }
 
 
 def peak_flops(device=None) -> Optional[float]:
-    """Per-chip bf16 peak for `device` (default: jax.devices()[0]);
-    None when unknown (e.g. the CPU test platform)."""
+    """Per-chip bf16 peak for `device` (default: jax.devices()[0]).
+    None on the CPU platform, where no utilization is defined.  An
+    accelerator whose kind is not in the table raises: a utilization
+    computed against a guessed peak, or silently dropped as None, is
+    worse than no number."""
     if device is None:
         import jax
         device = jax.devices()[0]
-    return PEAK_FLOPS.get(getattr(device, "device_kind", ""))
+    kind = getattr(device, "device_kind", "")
+    if kind in PEAK_FLOPS:
+        return PEAK_FLOPS[kind]
+    if getattr(device, "platform", "") == "cpu":
+        return None
+    raise ValueError(
+        f"no peak FLOP/s on record for device kind {kind!r}; add it to "
+        f"singa_tpu.utils.flops.PEAK_FLOPS with its source")
+
+
+def device_info() -> Dict[str, Any]:
+    """What a run executes on, as JAX reports it, and the installation:
+    the record every entry point logs or prints beside its results."""
+    import jax
+    import jaxlib
+    from importlib.metadata import PackageNotFoundError, version
+    try:
+        libtpu = version("libtpu")
+    except PackageNotFoundError:
+        libtpu = "none"
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices),
+            "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": libtpu}
 
 
 def cost_metrics(compiled) -> Dict[str, float]:
@@ -50,13 +78,11 @@ def cost_metrics(compiled) -> Dict[str, float]:
 
     Returns {} when the backend reports nothing; otherwise a dict with
     whatever of `flops` / `bytes accessed` / `utilization` keys the
-    cost model provides (older jax wraps the dict in a list)."""
+    cost model provides."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 — diagnostics, never a failure
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return {}
     return {k: float(v) for k, v in ca.items()

@@ -2,8 +2,8 @@
 
 Policy: ModelProto `scoped_vmem` (auto|on|off), overridden by the
 SINGA_TPU_SCOPED_VMEM env var.  `auto` applies the raised budget only
-to conv stacks whose widest conv has >= 96 filters — the documented
-workaround for the LeNet-scale compile hang.
+to conv stacks whose widest conv has >= 96 filters (smaller nets gain
+nothing from it).
 """
 
 import pytest
@@ -89,3 +89,23 @@ def test_attention_family_gets_modest_budget(monkeypatch):
     cfg2.scoped_vmem = "on"
     assert _opts(cfg2, shapes,
                  monkeypatch) == Trainer.TPU_ATTN_COMPILER_OPTIONS
+
+
+def test_on_tpu_is_the_default_backend(monkeypatch):
+    import jax
+    assert attention._on_tpu() is False            # pytest runs on cpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention._on_tpu() is True
+
+
+def test_on_tpu_propagates_a_backend_error(monkeypatch):
+    """A chip that cannot be reached must fail the run, not select the
+    interpreter (and drop the compiler options) behind its back."""
+    import jax
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        attention._on_tpu()

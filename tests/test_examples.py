@@ -3,6 +3,7 @@ the examples ARE the integration suite, as in the reference (SURVEY §4).
 """
 
 import glob
+import os
 import subprocess
 import sys
 
@@ -17,6 +18,7 @@ from singa_tpu.parallel import mesh_from_cluster
 
 LM_CONF = "examples/transformer/lm.conf"
 CLUSTER_CONF = "examples/transformer/cluster.conf"
+REF = "/root/reference/examples/mnist"
 
 
 def test_lm_conf_loads_and_matches_builder_idiom():
@@ -128,19 +130,26 @@ def test_discovery_peeks_a_real_shard(tmp_path):
     assert shapes[data.name]["pixel"] == (3, 40, 40)
 
 
-def test_shipped_example_confs_match_zoo_and_reference():
+def test_shipped_example_confs_match_zoo():
     """examples/{mnist,cifar10,imagenet}/*.conf are generated from the
     model zoo (tools/export_examples); they must load back equal to the
-    zoo configs, and the mnist pair must describe the same nets as the
-    reference's hand-written mlp.conf/conv.conf."""
+    zoo configs."""
     from singa_tpu.models import vision
     from singa_tpu.tools.export_examples import EXAMPLES
 
     for rel, build in EXAMPLES.items():
         assert load_model_config(f"examples/{rel}") == build(), rel
+    assert vision.mlp_mnist() == load_model_config(
+        "examples/mnist/mlp.conf")
 
+
+@pytest.mark.skipif(not os.path.exists(f"{REF}/conv.conf"),
+                    reason="reference tree not mounted at /root/reference")
+def test_shipped_mnist_confs_match_reference():
+    """The shipped mnist pair must describe the same nets as the
+    reference's hand-written mlp.conf/conv.conf."""
     ours = load_model_config("examples/mnist/conv.conf")
-    ref = load_model_config("/root/reference/examples/mnist/conv.conf")
+    ref = load_model_config(f"{REF}/conv.conf")
     # data source differs by design (kShardData here vs the reference's
     # phase-excluded kLMDBData pair); the neuron-layer graph must match.
     skip = {"kShardData", "kLMDBData"}
@@ -151,7 +160,7 @@ def test_shipped_example_confs_match_zoo_and_reference():
     assert ours.updater.base_learning_rate == ref.updater.base_learning_rate
 
     mlp_ours = load_model_config("examples/mnist/mlp.conf")
-    mlp_ref = load_model_config("/root/reference/examples/mnist/mlp.conf")
+    mlp_ref = load_model_config(f"{REF}/mlp.conf")
     assert ([(l.type,
               l.inner_product_param.num_output if l.inner_product_param
               else None) for l in mlp_ours.neuralnet.layer
@@ -160,7 +169,6 @@ def test_shipped_example_confs_match_zoo_and_reference():
                  l.inner_product_param.num_output if l.inner_product_param
                  else None) for l in mlp_ref.neuralnet.layer
                 if l.type not in skip])
-    assert vision.mlp_mnist() == mlp_ours
 
 
 def test_viz_dot_and_log_plot(tmp_path):
@@ -171,7 +179,7 @@ def test_viz_dot_and_log_plot(tmp_path):
     from singa_tpu.tools.viz import (json_to_dot, parse_training_log,
                                      plot_training_log)
 
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config("examples/mnist/conv.conf")
     net = build_net(cfg, "kTrain", {"data": {"pixel": (28, 28),
                                              "label": ()}}, batchsize=2)
     dot = json_to_dot(net.to_json())

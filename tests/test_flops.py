@@ -5,6 +5,8 @@ a matmul/conv-dominated net the two must agree to within the share of
 elementwise work XLA additionally counts.
 """
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -18,7 +20,9 @@ MNIST_SHAPES = {"data": {"pixel": (28, 28), "label": ()}}
 
 
 def _lenet_net(bs=64):
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "examples/mnist/conv.conf"))
     return build_net(cfg, "kTrain", MNIST_SHAPES, batchsize=bs)
 
 
@@ -68,7 +72,19 @@ def test_mfu_and_peak_lookup():
     # 197e12 flops done in 2s on a 197e12-peak chip → 50% MFU
     assert mfu(197e12, 2.0, FakeDev()) == pytest.approx(0.5)
 
-    class Unknown:
+    class Cpu:
         device_kind = "cpu"
-    assert peak_flops(Unknown()) is None
-    assert mfu(1e9, 1.0, Unknown()) is None
+        platform = "cpu"
+    assert peak_flops(Cpu()) is None
+    assert mfu(1e9, 1.0, Cpu()) is None
+    assert peak_flops() is None          # the test platform itself
+
+    class Unknown:
+        device_kind = "TPU v99"
+        platform = "tpu"
+    # an accelerator with no peak on record is an error, never a
+    # silently absent (or guessed) utilization
+    with pytest.raises(ValueError, match="TPU v99"):
+        peak_flops(Unknown())
+    with pytest.raises(ValueError, match="TPU v99"):
+        mfu(1e9, 1.0, Unknown())

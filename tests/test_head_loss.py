@@ -93,10 +93,14 @@ def test_label_logit_exact():
     np.testing.assert_allclose(float(loss_f), float(loss_d), rtol=1e-5)
 
 
-def test_layer_gating(monkeypatch):
+def test_layer_gating():
     """The LMHeadLoss layer selects the fused kernel exactly when the
-    head is tied, top-1, kernel-legal, and on a real TPU."""
-    import singa_tpu.ops.attention as attention
+    head is tied, top-1, kernel-legal and unsharded — whatever the
+    platform (off-TPU the kernel runs interpreted)."""
+    import types
+
+    from jax.sharding import Mesh
+
     from singa_tpu.core.net import build_net
     from singa_tpu.models.transformer import transformer_lm
 
@@ -108,15 +112,50 @@ def test_layer_gating(monkeypatch):
     layer = net.layers["loss"]
     h2 = jnp.zeros((4 * 128, 128), jnp.bfloat16)      # N=512, E=128
     w = jnp.zeros((2048, 128), jnp.bfloat16)          # (V, E)
+    ctx = types.SimpleNamespace(mesh=None)
 
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    assert layer._use_fused(h2, w, True)
-    assert not layer._use_fused(h2, w, False)          # untied (E,V)
+    assert layer._use_fused(h2, w, True, ctx)
+    assert not layer._use_fused(h2, w, False, ctx)     # untied (E,V)
     layer.topk = 5
-    assert not layer._use_fused(h2, w, True)           # top-k > 1
+    assert not layer._use_fused(h2, w, True, ctx)      # top-k > 1
     layer.topk = 1
     # shape-illegal: N not a multiple of the token block
-    assert not layer._use_fused(h2[:100], w, True)
-    # off-TPU: always the chunked XLA path
-    monkeypatch.setattr(attention, "_on_tpu", lambda: False)
-    assert not layer._use_fused(h2, w, True)
+    assert not layer._use_fused(h2[:100], w, True, ctx)
+    # under a mesh GSPMD cannot partition the custom call: chunked head
+    meshed = types.SimpleNamespace(
+        mesh=Mesh(np.array(jax.devices()[:2]), ("data",)))
+    assert not layer._use_fused(h2, w, True, meshed)
+
+
+def test_layer_walks_the_fused_branch_off_tpu():
+    """Through the layer, on CPU: kernel-legal shapes take the fused
+    (interpreted) head and match the chunked head the same net takes
+    when the kernel is not legal for it."""
+    from singa_tpu.core.net import build_net
+    from singa_tpu.models.transformer import (synthetic_token_batches,
+                                              transformer_lm)
+    from singa_tpu.ops import head_loss
+
+    cfg = transformer_lm(vocab_size=2048, num_layers=1, embed_dim=128,
+                         num_heads=2, head_dim=64, seq_len=128,
+                         batchsize=4)
+    net = build_net(cfg, "kTrain", {"data": {"input": (128,),
+                                             "target": (128,)}})
+    params = net.init_params(jax.random.PRNGKey(0))
+    batch = next(synthetic_token_batches(4, 128, 2048))
+    calls = []
+    real = head_loss._head_stats_pallas
+
+    def spy(h, w, labels, bn, bv, interpret):
+        calls.append(interpret)
+        return real(h, w, labels, bn, bv, interpret)
+
+    head_loss._head_stats_pallas = spy
+    try:
+        fused, _, _ = net.apply(params, batch, train=False)
+    finally:
+        head_loss._head_stats_pallas = real
+    assert calls == [True]              # fused kernel, interpreted
+    net.layers["loss"].topk = 2         # not kernel-legal -> chunked
+    chunked, _, _ = net.apply(params, batch, train=False)
+    np.testing.assert_allclose(float(fused), float(chunked), rtol=1e-5)

@@ -1,5 +1,7 @@
 """Model zoo + checkpoint/resume + RBM tests."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,9 @@ from singa_tpu.utils.checkpoint import CheckpointManager
 
 CIFAR_SHAPES = {"data": {"pixel": (3, 32, 32), "label": ()}}
 MNIST_SHAPES = {"data": {"pixel": (28, 28), "label": ()}}
+# the repo's shipped copies of the reference's mnist configs
+MNIST = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "examples", "mnist")
 
 
 def _cifar_batch(bs, seed=0):
@@ -59,7 +64,7 @@ def test_programmatic_lenet_matches_conf_lenet():
     from singa_tpu.core import build_net
     a = build_net(lenet_mnist(batchsize=4), "kTrain", MNIST_SHAPES)
     b = build_net(load_model_config(
-        "/root/reference/examples/mnist/conv.conf"), "kTrain",
+        f"{MNIST}/conv.conf"), "kTrain",
         MNIST_SHAPES, batchsize=4)
     for k in ("conv1", "pool1", "conv2", "pool2", "ip1", "ip2"):
         assert a.shapes[k] == b.shapes[k]

@@ -1,5 +1,7 @@
 """NeuralNet builder tests: reference configs → compiled train steps."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,9 @@ from singa_tpu.core import build_net, Trainer
 from singa_tpu.core.graph import Graph, GraphError
 
 MNIST_SHAPES = {"data": {"pixel": (28, 28), "label": ()}}
+# the repo's shipped copies of the reference's mnist configs
+MNIST = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "examples", "mnist")
 
 
 def _mnist_batch(bs, rng, size=28, nclass=10):
@@ -33,7 +38,7 @@ def test_graph_topo_and_cycle():
 
 
 def test_build_mlp_from_reference_conf():
-    cfg = load_model_config("/root/reference/examples/mnist/mlp.conf")
+    cfg = load_model_config(f"{MNIST}/mlp.conf")
     net = build_net(cfg, "kTrain", MNIST_SHAPES, batchsize=8)
     # phase filtering: only one data layer remains
     assert [n for n in net.topo if n == "data"] == ["data"]
@@ -55,7 +60,7 @@ def test_build_mlp_from_reference_conf():
 
 
 def test_build_lenet_from_reference_conf():
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     net = build_net(cfg, "kTrain", MNIST_SHAPES, batchsize=4)
     # NHWC runtime layout (same geometry as the reference's NCHW shapes)
     assert net.shapes["conv1"] == (4, 24, 24, 20)
@@ -74,7 +79,7 @@ def test_build_lenet_from_reference_conf():
 
 
 def test_test_phase_net_shares_params():
-    cfg = load_model_config("/root/reference/examples/mnist/mlp.conf")
+    cfg = load_model_config(f"{MNIST}/mlp.conf")
     train_net = build_net(cfg, "kTrain", MNIST_SHAPES, batchsize=8)
     test_net = build_net(cfg, "kTest", MNIST_SHAPES, batchsize=8)
     # same param specs → same pytree works for both (ShareWeights parity)
@@ -87,7 +92,7 @@ def test_test_phase_net_shares_params():
 
 def test_trainer_loss_decreases_on_fixed_batch():
     """End-to-end smoke: jitted train step memorizes one batch."""
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     cfg.train_steps = 30
     cfg.test_frequency = 0
     cfg.display_frequency = 0
@@ -246,7 +251,7 @@ def test_fused_relu_lrn_net_matches_unfused():
 
 
 def test_debug_info_and_json():
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     net = build_net(cfg, "kTrain", MNIST_SHAPES, batchsize=2)
     params = net.init_params(jax.random.PRNGKey(0))
     rng = np.random.default_rng(6)
@@ -260,7 +265,7 @@ def test_debug_info_and_json():
 def test_train_steps_scan_matches_per_step_calls():
     """trainer.train_steps (one lax.scan program) must reproduce n
     individual train_step calls exactly — same params, same metrics."""
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     cfg.display_frequency = 0
     trainer = Trainer(cfg, MNIST_SHAPES, donate=False)
     params, opt_state = trainer.init(seed=0)
@@ -301,7 +306,7 @@ def test_train_steps_scan_matches_per_step_calls():
 def test_run_scan_chunk_matches_per_step_run():
     """run(scan_chunk=N) must produce the same params, display logs, and
     test history as the per-step loop, with cadence at the same steps."""
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     cfg.train_steps = 11
     cfg.display_frequency = 3
     cfg.test_frequency = 5
@@ -344,7 +349,7 @@ def test_preemption_signal_checkpoints_and_resumes(tmp_path):
     import os
     import signal
 
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     cfg.train_steps = 50
     cfg.test_frequency = 0
     cfg.display_frequency = 0

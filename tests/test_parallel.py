@@ -20,6 +20,9 @@ from singa_tpu.parallel import (batch_shardings, make_mesh,
                                 mesh_from_cluster, param_shardings)
 
 MNIST_SHAPES = {"data": {"pixel": (28, 28), "label": ()}}
+# the repo's shipped copies of the reference's mnist configs
+MNIST = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "examples", "mnist")
 
 
 def _batch(bs, seed=0):
@@ -78,7 +81,7 @@ def test_dp_sharded_step_matches_single_device():
     """The sharded train step must produce the same numbers as the
     unsharded one — GSPMD inserts the gradient psum (the reference's
     in-process allreduce, param_manager.cc:166-187)."""
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     cfg.train_steps = 3
     for layer in cfg.neuralnet.layer:
         if layer.data_param:
@@ -108,7 +111,7 @@ def test_dp_sharded_step_matches_single_device():
 
 
 def test_tp_weight_sharding_from_partition_dim():
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     trainer = Trainer(cfg, MNIST_SHAPES, donate=False)
     mesh = make_mesh(model=2)
     shardings = param_shardings(mesh, trainer.train_net)
@@ -121,7 +124,7 @@ def test_tp_weight_sharding_from_partition_dim():
 
 
 def test_tp_sharded_step_matches_single_device():
-    cfg = load_model_config("/root/reference/examples/mnist/conv.conf")
+    cfg = load_model_config(f"{MNIST}/conv.conf")
     for layer in cfg.neuralnet.layer:
         if layer.data_param:
             layer.data_param.batchsize = 8

@@ -199,8 +199,8 @@ class _CompiledGuard:
     attempt to re-lower (i.e. recompile) trips the test."""
 
     def cost_analysis(self):
-        return [{"flops": 123.0, "bytes accessed": 456.0,
-                 "not_numeric": "x"}]
+        return {"flops": 123.0, "bytes accessed": 456.0,
+                "not_numeric": "x"}
 
     def lower(self, *a, **k):       # pragma: no cover — the property
         raise AssertionError("CostWatch triggered a recompile")

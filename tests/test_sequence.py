@@ -14,22 +14,9 @@ from singa_tpu.ops.moe import moe_ffn
 from singa_tpu.parallel import (make_mesh, param_shardings, pipeline_apply,
                                 ring_attention, seq_batch_shardings,
                                 stack_stage_params, ulysses_attention)
-from singa_tpu.parallel.sequence import LEGACY_SHARD_MAP
 
 RNG = np.random.default_rng(0)
 SEQ_SHAPES = {"data": {"input": (128,), "target": (128,)}}
-
-# Ring-attention PARITY (not structure) is asserted only on modern jax:
-# the pre-0.4.35 experimental shard_map's check_rep rewrite perturbs
-# the ring collectives' numerics slightly (the drift noted in PR 10).
-# strict=False because the tightened shim (check_rep defaulted off)
-# may well restore parity on some legacy versions — an xpass is fine.
-ring_parity = pytest.mark.xfail(
-    LEGACY_SHARD_MAP,
-    reason="pre-0.4.35 jax: experimental shard_map's check_rep "
-           "rewrite drifts ring-attention numerics (PR 10 known "
-           "issue); parity is asserted on modern jax only",
-    strict=False)
 
 
 def _qkv(b=2, h=8, s=256, d=32):
@@ -57,7 +44,6 @@ def test_flash_attention_grads():
                                    rtol=1e-4, atol=1e-5)
 
 
-@ring_parity
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_reference(causal):
     q, k, v = _qkv()
@@ -68,7 +54,6 @@ def test_ring_attention_matches_reference(causal):
                                rtol=1e-4, atol=1e-5)
 
 
-@ring_parity
 def test_ring_attention_grad():
     q, k, v = _qkv(1, 4, 128, 16)
     mesh = make_mesh(seq=8)
@@ -169,7 +154,6 @@ def test_transformer_trains_and_beats_unigram():
     assert losses[-1] < np.log(vocab) - 0.1, losses[::10]
 
 
-@ring_parity
 def test_transformer_sharded_step_matches_local():
     """dp×tp×sp mesh with ring attention + MoE == single-device numerics."""
     mesh = make_mesh(data=2, model=2, seq=2)
@@ -487,7 +471,6 @@ def test_attention_layer_gqa_packed_matches_strided():
                                    rtol=1e-3, atol=1e-5, err_msg=k)
 
 
-@ring_parity
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_flash_and_blockwise_paths_agree(causal):
     """Both ring local-step implementations — the Pallas flash unrolled
@@ -625,7 +608,6 @@ def _gqa_ref(q, k, v, causal):
                                expand_kv_heads(v, q.shape[1]), causal)
 
 
-@ring_parity
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_gqa_unexpanded_kv(causal):
     """Ring accepts (B, Hkv, S, D) k/v directly: forward parity vs the

@@ -86,6 +86,8 @@ def measure(cfg, batch_size, iters=10, reps=3, fwd_only=False):
 
 
 def main():
+    from singa_tpu.utils import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=8192)
     ap.add_argument("--fwd", action="store_true")

@@ -72,6 +72,8 @@ def measure(seq_len, batch, iters, reps, bq, bk, split=False,
 
 
 def main():
+    from singa_tpu.utils import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=4096)
     ap.add_argument("--batch", type=int, default=0)

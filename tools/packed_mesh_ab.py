@@ -58,6 +58,8 @@ def measure(seq_len, batch, iters, reps, kv_heads, use_mesh):
 
 
 def main():
+    from singa_tpu.utils import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=1024)
     ap.add_argument("--batch", type=int, default=32)

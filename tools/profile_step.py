@@ -111,6 +111,8 @@ def parse(outdir, iters, top, attr=None):
 
 
 def main():
+    from singa_tpu.utils import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="alexnet",
                     choices=["alexnet", "transformer"])
