@@ -1,0 +1,6 @@
+"""Gap between tokens above the knee: recorded, not judged."""
+from benchmark.layer_metrics._common import sample_p95
+
+
+def read(facts):
+    return sample_p95(facts, "itl_ms")
