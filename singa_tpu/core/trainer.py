@@ -67,12 +67,10 @@ class TimerInfo:
     is active, so wait+stage+train can exceed wall time there — see
     docs/PERFORMANCE.md), `train` (dispatch + device sync).  The
     device-side fwd/bwd/update split the reference timed around each
-    phase call is one fused XLA program here, so it comes from a
-    one-shot profiler trace (Trainer.profile_phases) and rides along as
-    `phase_shares`."""
+    phase call is one fused XLA program here: there is no boundary to
+    time."""
     times: Dict[str, float] = field(default_factory=dict)
     steps: int = 0
-    phase_shares: Optional[Dict[str, float]] = None
 
     def add(self, phase: str, seconds: float) -> None:
         self.times[phase] = self.times.get(phase, 0.0) + seconds
@@ -82,18 +80,7 @@ class TimerInfo:
         parts = [f"{k}: {v / max(self.steps, 1) * 1e3:.2f}ms "
                  f"({100 * v / total:.0f}%)"
                  for k, v in self.times.items()]
-        out = "Time per step — " + ", ".join(parts)
-        if self.phase_shares:
-            shares = dict(self.phase_shares)
-            cov = shares.pop("coverage", None)
-            out += " [device: " + ", ".join(
-                f"{k} {100 * v:.0f}%" for k, v in shares.items())
-            if cov is not None:
-                # fusion blur can swallow a phase (classify_phase);
-                # the coverage qualifier keeps "update 0%" honest
-                out += f" — {100 * cov:.0f}% of device time attributed"
-            out += "]"
-        return out
+        return "Time per step — " + ", ".join(parts)
 
     def reset(self) -> None:
         self.times.clear()
@@ -485,8 +472,8 @@ class Trainer:
                       rng, nsteps: int, stacked: bool = False):
         """The AOT-compiled fused-scan executable for this geometry,
         compiled at most once and cached.  Every consumer of the
-        compiled program — `profile_phases` (HLO text + traced runs),
-        the convergence tool's pre-timing warmup, CostWatch harvesting
+        compiled program — the convergence tool's pre-timing warmup,
+        CostWatch harvesting
         — goes through here, so diagnostics never re-lower+recompile a
         program the trainer already owns.  Call the returned
         executable with the five traced args only (statics are baked
@@ -510,42 +497,6 @@ class Trainer:
         perf.harvest("train_scan", got)
         self._aot_cache[key] = got
         return got
-
-    def profile_phases(self, params, opt_state, batch, step: int = 0,
-                       rng=None, iters: int = 2,
-                       outdir: Optional[str] = None) -> Dict[str, float]:
-        """Measure the device-side fwd/bwd/update split of the train
-        step (worker.h:91-114's tForward_/tBackward_/tSyncParam_ report)
-        and pin it on `self.timer` for every subsequent TimerInfo line.
-
-        One-shot cost: an AOT lower+compile of the scan step (for the
-        HLO metadata) plus a short traced run.  Training state is not
-        consumed — donated buffers are fed copies."""
-        import tempfile
-
-        from ..utils import profiler
-
-        rng = rng if rng is not None else jax.random.PRNGKey(0)
-        outdir = outdir or tempfile.mkdtemp(prefix="singa_phase_prof_")
-        # ONE compile serves both the HLO text and the traced runs —
-        # executing through the cached AOT object (traced args only;
-        # the statics are baked in) instead of re-dispatching the jit
-        compiled = self.compiled_scan(params, opt_state, batch, step,
-                                      rng, iters)
-        txt = compiled.as_text()
-        # the scan may donate params/opt_state — hand it copies
-        cp = jax.tree_util.tree_map(jnp.copy, params)
-        co = jax.tree_util.tree_map(jnp.copy, opt_state)
-        p, _, _ = compiled(cp, co, batch, step, rng)
-        profiler.hard_sync(p)   # execution path warm before the trace
-        with profiler.trace(outdir):
-            cp = jax.tree_util.tree_map(jnp.copy, params)
-            co = jax.tree_util.tree_map(jnp.copy, opt_state)
-            p, _, _ = compiled(cp, co, batch, step, rng)
-            profiler.hard_sync(p)
-        shares = profiler.phase_shares(outdir, txt)
-        self.timer.phase_shares = shares
-        return shares
 
     # -- init --------------------------------------------------------------
     def init(self, seed: int = 0):
@@ -846,20 +797,6 @@ class Trainer:
                         for h in hooks:
                             self._call_hook(h, s, m)
                     if self.display_now(s):
-                        if (self.timer.phase_shares is None
-                                and (getattr(self, "phase_profile", False)
-                                     or os.environ.get(
-                                         "SINGA_TPU_PHASE_PROFILE") == "1")):
-                            # one-shot device fwd/bwd/update attribution;
-                            # never let a profiler hiccup kill training
-                            try:
-                                self.profile_phases(
-                                    params, opt_state, last_dbg[0],
-                                    step=s, rng=rng)
-                            except Exception as e:  # pragma: no cover
-                                self.timer.phase_shares = {}
-                                self.log(f"warning: phase profile "
-                                         f"failed: {e}")
                         self.log(f"step-{s}: {self.perf.to_string()}")
                         self.log(self.timer.to_string())
                         self.perf.reset()

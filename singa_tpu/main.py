@@ -109,10 +109,6 @@ def make_argparser() -> argparse.ArgumentParser:
                     dest="feeder_depth", default=0,
                     help="staged chunks the feeder may run ahead "
                          "(0 = SINGA_TPU_FEEDER_DEPTH or 2)")
-    ap.add_argument("--phase_profile", action="store_true",
-                    help="measure the device fwd/bwd/update split once "
-                         "(profiler trace) and report it at every "
-                         "display interval (worker.h:91-114 parity)")
     _add_obs_flags(ap)
     return ap
 
@@ -834,7 +830,6 @@ def _run(args) -> int:
                       n_micro=(cluster.pipeline_microbatches
                                if cluster else 0),
                       ngroups=ngroups, health=health)
-    trainer.phase_profile = args.phase_profile
     # additive metric collectors (no-op without --obs on): the per-phase
     # timer and the health-verdict tallies feed the periodic dump
     reg = obs.registry()

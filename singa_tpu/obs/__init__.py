@@ -8,10 +8,13 @@ The reference's only telemetry was the per-phase timer report
 ROADMAP's remaining items (fleet router health, canary promotion,
 pipeline mode) consume.  Four rules:
 
-  1. **~zero cost off.**  `obs.span(...)` / `obs.emit_event(...)` are
-     one module-global read when no session is active — the same
-     discipline as `faults.maybe_fault`.  Instrumented hot paths pay
-     nothing until `--obs on`.
+  1. **one flag read off.**  `obs.span(...)` is a profiler annotation
+     (`trace.device_span`) whenever a profiler is running, so the
+     program's spans lie in any device trace, in the same file as the
+     device's ops, with no switch; with no session and no profiler it
+     is the shared null span.  Attributes that cost something to
+     compute are gated by `obs.tracing()` at the call site.
+     `obs.emit_event(...)` is one module-global read when off.
   2. **telemetry never kills work.**  Every record/write path consults
      the `obs.emit` fault site and swallows ALL failures into drop
      counters (`tests/test_obs.py` proves a faulted emit still
@@ -49,11 +52,11 @@ from .flightrec import FlightRecorder
 from .log import EventLog, Logger, MetricsDumper
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       Sample, parse_prometheus)
-from .trace import NULL_HANDLE, NULL_SPAN, Tracer
+from .trace import Tracer, device_span, profiling
 
 __all__ = [
     "ObsSpec", "Observability", "TailSampler", "enable", "disable",
-    "active", "session", "span", "current_corr", "trace_context",
+    "active", "session", "span", "tracing", "current_corr", "trace_context",
     "trace_dump", "emit_event", "sample_trace", "get_logger",
     "registry", "Tracer", "FlightRecorder", "MetricsRegistry",
     "Counter", "Gauge", "Histogram", "Sample", "EventLog", "Logger",
@@ -266,19 +269,30 @@ class session:
         return False
 
 
-# -- the instrumented-site API (hot-path: one global read when off) ---------
+# -- the instrumented-site API (hot path: one annotation when off) ----------
 
 def span(name: str, corr: Optional[str] = None,
          trace: Optional[str] = None, parent: Optional[int] = None,
          **attrs):
-    """Open a trace span, or the shared null span when off.
-    `trace`/`parent` anchor under a remote or cross-thread parent
-    (the receive side of an `X-Trace-Id`/`X-Parent-Span` hop)."""
+    """Open a span.  While a profiler runs it is a profiler annotation
+    (`device_span`) carrying the scalar `attrs`, so it lies in any
+    device trace; with a session on it is also recorded by the tracer,
+    and only then does the body see a live handle (`NULL_HANDLE`
+    otherwise).  `trace`/`parent` anchor under a remote or
+    cross-thread parent (the receive side of an
+    `X-Trace-Id`/`X-Parent-Span` hop)."""
     o = _ACTIVE
     if o is None:
-        return NULL_SPAN
+        return device_span(name, attrs, corr)
     return o.tracer.span(name, corr=corr, trace=trace, parent=parent,
                          **attrs)
+
+
+def tracing() -> bool:
+    """Whether anything records spans now: a session, or a running
+    profiler.  For a call site whose span attributes cost something to
+    compute (a reduction over the slots); cheap ones are just passed."""
+    return _ACTIVE is not None or profiling()
 
 
 def current_corr() -> Optional[str]:

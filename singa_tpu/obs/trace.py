@@ -1,12 +1,17 @@
-"""Span tracer: thread-safe, ~zero-cost-when-off context-manager
-spans exporting Chrome trace-event JSON.
+"""Span tracer: thread-safe context-manager spans on two clocks — the
+profiler's (every span is a `jax.profiler.TraceAnnotation`, so it lies
+in any device trace anyone takes, beside the device's ops) and, while
+an observability session is on, this tracer's own buffer exporting
+Chrome trace-event JSON.
 
 Design targets (docs/OBSERVABILITY.md):
 
-  * **~zero cost off** — instrumented code calls `obs.span(name)`,
-    which is one module-global read plus returning a shared null
-    context manager when no observability session is active (the same
-    discipline as `faults.maybe_fault`).
+  * **one flag read off** — with no session, `obs.span(name)` is one
+    module-global read plus the profiler's own "is one running" flag:
+    while none is, the shared null span; while one is, a
+    `TraceAnnotation` whose body sees the shared `NULL_HANDLE` (no
+    handle, no id, no lock).  Scalar attributes ride in the annotation
+    and come back as the xplane event's `stats`.
   * **parenting** — each thread keeps a span stack; a new span's
     parent is the innermost open span on the SAME thread, recorded as
     `args.parent_id`.  Remote and cross-thread parents are explicit:
@@ -29,8 +34,8 @@ Design targets (docs/OBSERVABILITY.md):
 Export format: `{"traceEvents": [...], "displayTimeUnit": "ms"}` with
 `ph: "X"` complete events (ts/dur in microseconds) plus `ph: "M"`
 thread-name and process-name metadata — the same trace-event schema
-`utils/profiler.parse_trace_ops` consumes from device traces, so both
-files load side by side in Perfetto / chrome://tracing.  The dict
+the profiler's own `trace.json.gz` uses, so both files load side by
+side in Perfetto / chrome://tracing.  The dict
 additionally carries `process`, `pid`, and `wall_origin_s` top-level
 keys (legal extras in the Chrome schema): `wall_origin_s` is the
 wall-clock instant of this tracer's ts=0, which is what lets
@@ -105,18 +110,88 @@ class NullSpan:
 NULL_SPAN = NullSpan()
 
 
+# -- the profiler's clock -----------------------------------------------------
+
+_DEVICE_SPAN = None       # built on first use: `jax` is imported lazily
+
+
+class _NoProfiler:
+    """Stands in for the annotation class where jax cannot be imported:
+    never enabled, so never built."""
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
+def _build_device_span():
+    """The annotation class: a `TraceAnnotation` whose body sees
+    `NULL_HANDLE`.  Without jax no profiler can run, so `obs` stays
+    importable (and its spans null) there."""
+    global _DEVICE_SPAN
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:  # noqa: BLE001 — no jax: spans are no-ops
+        _DEVICE_SPAN = _NoProfiler
+        return _DEVICE_SPAN
+    enter = TraceAnnotation.__enter__
+
+    class DeviceSpan(TraceAnnotation):
+        __slots__ = ()
+
+        def __enter__(self) -> _NullHandle:
+            enter(self)
+            return NULL_HANDLE
+
+    _DEVICE_SPAN = DeviceSpan
+    return DeviceSpan
+
+
+def _scalars(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """What an annotation can carry: numbers, booleans and strings
+    (`#` and `,` delimit the profiler's own encoding of them)."""
+    return {k: (v.replace("#", "_").replace(",", ";")
+                if isinstance(v, str) else v)
+            for k, v in attrs.items()
+            if isinstance(v, (int, float, str, bool))}
+
+
+def profiling() -> bool:
+    """Whether a profiler is running (a flag read)."""
+    return (_DEVICE_SPAN or _build_device_span()).is_enabled()
+
+
+def device_span(name: str, attrs: Dict[str, Any],
+                corr: Optional[str] = None):
+    """A span on the profiler's clock only: an annotation carrying the
+    scalar `attrs` (and `corr`) while a profiler is running, the shared
+    null span otherwise (an annotation made then would stay a no-op
+    for its whole life, so nothing is lost and nothing is built)."""
+    cls = _DEVICE_SPAN or _build_device_span()
+    if not cls.is_enabled():
+        return NULL_SPAN
+    if corr is not None:
+        attrs = {**attrs, "corr": corr}
+    return cls(name, **_scalars(attrs))
+
+
 class _SpanCtx:
     """One live span.  Class-based (not @contextmanager) to keep the
     on-path overhead at a couple of attribute stores; exceptions in
-    the body propagate untouched — the span still records."""
+    the body propagate untouched — the span still records.  The span
+    is also on the profiler's clock (`device_span`), like every span
+    taken with no session."""
 
-    __slots__ = ("_tracer", "_handle")
+    __slots__ = ("_tracer", "_handle", "_device")
 
     def __init__(self, tracer: "Tracer", handle: SpanHandle):
         self._tracer = tracer
         self._handle = handle
+        self._device = device_span(handle.name, handle.attrs,
+                                   handle.corr)
 
     def __enter__(self) -> SpanHandle:
+        self._device.__enter__()
         self._tracer._push(self._handle)
         return self._handle
 
@@ -127,6 +202,7 @@ class _SpanCtx:
         if exc_type is not None:
             h.attrs.setdefault("error", exc_type.__name__)
         self._tracer._record(h, dur)
+        self._device.__exit__(exc_type, exc, tb)
         return False
 
 

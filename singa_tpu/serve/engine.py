@@ -667,7 +667,9 @@ class InferenceEngine:
         temperature, top_k, top_p = (float(spec.temperature),
                                      int(spec.top_k), float(spec.top_p))
 
-        def fn(params, pools, tokens, plen, row, key):
+        # the function's name is the program's in a device trace
+        # (`jit_cb_prefill`)
+        def cb_prefill(params, pools, tokens, plen, row, key):
             dtype = jax.tree_util.tree_leaves(params)[0].dtype
             cache = init_cache(net, 1, p_len, dtype)
             logits, cache = forward_cached(net, params, tokens, cache, 0)
@@ -676,7 +678,7 @@ class InferenceEngine:
             tok0 = _sample(last, key, temperature, top_k, top_p)[0]
             return tok0, scatter_prefill(pools, cache, row)
 
-        return fn
+        return cb_prefill
 
     def _build_cb_decode(self):
         """ONE compiled decode step at fixed slot count S: every
@@ -688,13 +690,13 @@ class InferenceEngine:
         temperature, top_k, top_p = (float(spec.temperature),
                                      int(spec.top_k), float(spec.top_p))
 
-        def fn(params, pools, tokens, ntoks, tables, key):
+        def cb_decode(params, pools, tokens, ntoks, tables, key):
             logits, pools = forward_paged(net, params, tokens[None],
                                           pools, tables, ntoks)
             nxt = _sample(logits[0], key, temperature, top_k, top_p)
             return nxt, pools
 
-        return fn
+        return cb_decode
 
     @property
     def serve_dtype(self):
@@ -797,12 +799,15 @@ class InferenceEngine:
         self._maybe_stall()
         compiled = self._compile_cb("decode")
         t0 = time.perf_counter()
-        nxt, pools = compiled(params, pools,
-                              jnp.asarray(tokens, jnp.int32),
-                              jnp.asarray(ntoks, jnp.int32),
-                              jnp.asarray(tables, jnp.int32),
-                              self._next_key())
-        nxt = np.asarray(nxt)
+        with obs.span("engine.cb_decode"):
+            with obs.span("engine.upload"):
+                args = (jnp.asarray(tokens, jnp.int32),
+                        jnp.asarray(ntoks, jnp.int32),
+                        jnp.asarray(tables, jnp.int32), self._next_key())
+            with obs.span("engine.dispatch"):
+                nxt, pools = compiled(params, pools, *args)
+            with obs.span("engine.fetch"):
+                nxt = np.asarray(nxt)
         perf.observe_step("cb_decode", time.perf_counter() - t0)
         return nxt, pools
 

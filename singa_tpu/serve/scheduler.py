@@ -467,24 +467,39 @@ class ContinuousScheduler:
             self._iterate()
 
     def _iterate(self) -> None:
-        """One scheduler step: expire, admit, decode, account."""
-        # ONE params read covers this step's prefills AND decode — the
-        # per-step no-tear guarantee (see module docstring)
-        params = self.engine.params
-        step_no = self.engine.params_step
-        now = time.monotonic()
-        self._expire_pending(now)
-        try:
-            self._admit_pending(params, step_no)
-            if self._active.any():
-                self._decode_step(params, step_no)
-        except Exception as e:  # noqa: BLE001 — fail step, keep serving
-            self._fail_step(e)
-            return
-        if self.kv is not None:
-            self.stats.observe_cb_step(int(self._active.sum()),
-                                       self.kv.blocks_in_use)
-            self.stats.gauge("cb_blocks_in_use", self.kv.blocks_in_use)
+        """One scheduler step: expire, admit, decode, account.  The
+        step, its admissions and its decode are spans
+        (docs/OBSERVABILITY.md, the per-token path): what the host does
+        between two decode programs is read off a device trace by
+        these names."""
+        attrs = ({"active": int(self._active.sum()),
+                  "pending": len(self._pending)}
+                 if obs.tracing() else {})
+        with obs.span("scheduler.step", **attrs):
+            # ONE params read covers this step's prefills AND decode —
+            # the per-step no-tear guarantee (see module docstring)
+            params = self.engine.params
+            step_no = self.engine.params_step
+            self._expire_pending(time.monotonic())
+            try:
+                # an unlocked peek: a submit that lands just after it
+                # is admitted by the next step, as it always was
+                if self._pending and not self._active.all():
+                    with obs.span("scheduler.admit_pending") as sp:
+                        admitted = self._admit_pending(params, step_no)
+                        sp.set(admitted=admitted)     # a session's tracer only
+                    if admitted:
+                        self.stats.count("cb_admit_steps")
+                active = int(self._active.sum())
+                if active:
+                    self._decode_step(params, step_no, active)
+            except Exception as e:  # noqa: BLE001 — fail step, keep serving
+                self._fail_step(e)
+                return
+            if self.kv is not None:
+                self.stats.observe_cb_step(int(self._active.sum()),
+                                           self.kv.blocks_in_use)
+                self.stats.gauge("cb_blocks_in_use", self.kv.blocks_in_use)
 
     def _expire_pending(self, now: float) -> None:
         with self._cv:
@@ -511,8 +526,9 @@ class ContinuousScheduler:
                 f"deadline passed after {now - r.t_submit:.3f}s in "
                 f"queue"))
 
-    def _admit_pending(self, params, step_no: int) -> None:
-        """Admit the queue head while a slot AND its blocks are free.
+    def _admit_pending(self, params, step_no: int) -> int:
+        """Admit the queue head while a slot AND its blocks are free;
+        returns how many requests were admitted (prefilled).
         FIFO with one tenancy carve-out: a head blocked ONLY by its
         own tenant's slot/KV quota is stepped over (its quota is its
         own blast radius — it must not wedge the other tenants), but
@@ -520,11 +536,12 @@ class ContinuousScheduler:
         still holds everything behind it, preserving the
         no-starvation guarantee for long prompts."""
         spec = self.spec
+        admitted = 0
         while True:
             free = np.flatnonzero(~self._active)
             with self._cv:
                 if not self._pending or free.size == 0:
-                    return
+                    return admitted
                 # per-tenant occupancy among the ACTIVE slots (slot
                 # count + conservative block reservations), once per
                 # admission round
@@ -541,7 +558,7 @@ class ContinuousScheduler:
                     if not self.kv.can_admit(cand.nblocks):
                         # global pool pressure: the effective head
                         # waits, nothing overtakes it
-                        return
+                        return admitted
                     squota = self.tenancy.slot_quota(
                         cand.tenant, spec.cb_slots)
                     bquota = self.tenancy.kv_quota(
@@ -554,7 +571,7 @@ class ContinuousScheduler:
                     del self._pending[i]
                     break
                 if req is None:
-                    return        # every pending head is quota-held
+                    return admitted   # every pending head is quota-held
                 self.stats.gauge("queue_depth", len(self._pending))
             # last-instant guard AFTER the pop, BEFORE any blocks or
             # engine work: an engine never prefills a request that is
@@ -574,14 +591,24 @@ class ContinuousScheduler:
                 continue
             slot = int(free[0])
             req.t_admit = now
+            queued = now - req.t_submit
+            self.stats.observe_admission(queued)
+            trace_id, parent = req.link if req.link else (None, None)
+            session = obs.active()
+            if session is not None:
+                # the wait is over only now, so it is recorded post hoc
+                session.tracer.add_span(
+                    "scheduler.queue", time.perf_counter() - queued,
+                    queued, corr=req.corr, trace=trace_id,
+                    parent=parent, plen=req.plen, tenant=req.tenant)
             row = self.kv.alloc(slot, req.nblocks)
             toks = np.zeros((1, spec.cb_prefill_len), np.int32)
             toks[0, :req.plen] = req.tokens
             try:
                 with obs.span("scheduler.prefill", corr=req.corr,
-                              trace=req.link[0] if req.link else None,
-                              parent=req.link[1] if req.link else None,
-                              slot=slot, plen=req.plen):
+                              trace=trace_id, parent=parent,
+                              slot=slot, plen=req.plen,
+                              queue_ms=queued * 1e3):
                     tok0, self.kv.pools = self.engine.run_cb_prefill(
                         params, self.kv.pools, toks, req.plen,
                         row[:spec.cb_prefill_len // spec.cb_block_len])
@@ -595,31 +622,35 @@ class ContinuousScheduler:
                          f"({type(e).__name__}: {e}); request "
                          f"{req.corr} failed, server continues")
                 req.ticket._fail(RuntimeError(f"prefill failed: {e}"))
-                return
+                return admitted
+            admitted += 1
+            self.stats.count("cb_prefills")
             self._slot_req[slot] = req
             self._active[slot] = True
             self._ntoks[slot] = req.plen
             self._last[slot] = tok0
             req.produced.append(tok0)
             req.ticket._emit(tok0)
-            self._maybe_retire(slot, tok0, step_no,
-                               time.monotonic())
+            now = time.monotonic()
+            self.stats.observe_ttft(now - req.t_submit)
+            self._maybe_retire(slot, tok0, step_no, now)
 
-    def _decode_step(self, params, step_no: int) -> None:
-        faults.maybe_fault("serve.batch")
-        nxt, self.kv.pools = self.engine.run_cb_decode(
-            params, self.kv.pools, self._last, self._ntoks,
-            self.kv.table_array())
-        now = time.monotonic()
-        for slot in np.flatnonzero(self._active):
-            slot = int(slot)
-            self._ntoks[slot] += 1
-            tok = int(nxt[slot])
-            self._last[slot] = tok
-            req = self._slot_req[slot]
-            req.produced.append(tok)
-            req.ticket._emit(tok)
-            self._maybe_retire(slot, tok, step_no, now)
+    def _decode_step(self, params, step_no: int, active: int) -> None:
+        with obs.span("scheduler.decode", active=active):
+            faults.maybe_fault("serve.batch")
+            nxt, self.kv.pools = self.engine.run_cb_decode(
+                params, self.kv.pools, self._last, self._ntoks,
+                self.kv.table_array())
+            now = time.monotonic()
+            for slot in np.flatnonzero(self._active):
+                slot = int(slot)
+                self._ntoks[slot] += 1
+                tok = int(nxt[slot])
+                self._last[slot] = tok
+                req = self._slot_req[slot]
+                req.produced.append(tok)
+                req.ticket._emit(tok)
+                self._maybe_retire(slot, tok, step_no, now)
 
     def _maybe_retire(self, slot: int, tok: int, step_no: int,
                       now: float) -> None:
@@ -659,8 +690,8 @@ class ContinuousScheduler:
                 "cancelled by caller mid-decode"))
             return
         self.stats.observe_latency(now - req.t_submit)
-        self.stats.observe_request(req.t_admit - req.t_submit,
-                                   now - req.t_admit,
+        # the queue wait was observed at admission (observe_admission)
+        self.stats.observe_request(None, now - req.t_admit,
                                    len(req.produced))
         self.stats.tenants.count("completed", req.tenant)
         self.stats.tenants.observe_latency(now - req.t_submit,

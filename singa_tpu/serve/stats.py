@@ -63,6 +63,8 @@ class ServeStats:
         self._queue_waits: deque = deque(
             maxlen=max(int(latency_window), 1))
         self._services: deque = deque(maxlen=max(int(latency_window), 1))
+        # submit -> first token, one sample a request (observe_ttft)
+        self._ttfts: deque = deque(maxlen=max(int(latency_window), 1))
         self._tok_rates: deque = deque(maxlen=max(int(latency_window), 1))
         # completion timestamps for the windowed QPS (bounded: at most
         # latency_window recent completions contribute)
@@ -102,6 +104,10 @@ class ServeStats:
         self.generated_tokens = 0
         # continuous batching (serve/scheduler.py)
         self.cb_steps = 0             # scheduler iterations run
+        self.cb_prefills = 0          # prefills that ran to their end
+        self.cb_admit_steps = 0       # iterations that admitted >= 1:
+                                      # each held every slot for its
+                                      # prefills before decoding
         self.cb_active_slot_steps = 0  # sum of active slots per step
         self.cb_block_use_steps = 0    # sum of blocks in use per step
         self.cb_slot_capacity = 0      # gauge: compiled slot count S
@@ -131,6 +137,7 @@ class ServeStats:
         self._hist_latency = None
         self._hist_queue_wait = None
         self._hist_service = None
+        self._hist_ttft = None
 
     # -- mutation ----------------------------------------------------------
     def count(self, field: str, n: int = 1) -> None:
@@ -168,23 +175,44 @@ class ServeStats:
         if self._hist_latency is not None:
             self._hist_latency.observe(float(seconds))
 
-    def observe_request(self, queue_wait_s: float, service_s: float,
-                        ntokens: int) -> None:
+    def _observe_queue_wait(self, seconds: float) -> None:
+        seconds = max(float(seconds), 0.0)
+        with self._lock:
+            self._queue_waits.append(seconds)
+        if self._hist_queue_wait is not None:
+            self._hist_queue_wait.observe(seconds)
+
+    def observe_request(self, queue_wait_s: Optional[float],
+                        service_s: float, ntokens: int) -> None:
         """Attribute one completed request: time queued before
         dispatch vs time being served, and its generated-token count
         (tok/s recorded when both are positive).  Called next to
         `observe_latency` by both the MicroBatcher and the
-        ContinuousScheduler."""
+        ContinuousScheduler; the latter passes no queue wait, having
+        observed it when the wait ended (`observe_admission`)."""
+        if queue_wait_s is not None:
+            self._observe_queue_wait(queue_wait_s)
         with self._lock:
-            self._queue_waits.append(max(queue_wait_s, 0.0))
             self._services.append(max(service_s, 0.0))
             self.generated_tokens += int(ntokens)
             if ntokens > 0 and service_s > 0:
                 self._tok_rates.append(ntokens / service_s)
-        if self._hist_queue_wait is not None:
-            self._hist_queue_wait.observe(max(float(queue_wait_s), 0.0))
         if self._hist_service is not None:
             self._hist_service.observe(max(float(service_s), 0.0))
+
+    def observe_admission(self, queue_wait_s: float) -> None:
+        """One request left the queue for a slot: its queue wait is
+        observed now, not at completion, so a backlog shows while it
+        grows (a request that never completes still waited)."""
+        self._observe_queue_wait(queue_wait_s)
+
+    def observe_ttft(self, seconds: float) -> None:
+        """Submit to first token of one request."""
+        seconds = max(float(seconds), 0.0)
+        with self._lock:
+            self._ttfts.append(seconds)
+        if self._hist_ttft is not None:
+            self._hist_ttft.observe(seconds)
 
     def observe_cb_step(self, active_slots: int,
                         blocks_in_use: int) -> None:
@@ -208,10 +236,10 @@ class ServeStats:
 
     def split_quantile(self, kind: str, q: float) -> Optional[float]:
         """Nearest-rank quantile over one of the observe_request
-        reservoirs: kind in ("queue_wait", "service",
+        reservoirs: kind in ("queue_wait", "service", "ttft",
         "tokens_per_s")."""
         src = {"queue_wait": self._queue_waits,
-               "service": self._services,
+               "service": self._services, "ttft": self._ttfts,
                "tokens_per_s": self._tok_rates}[kind]
         with self._lock:
             vals = sorted(src)
@@ -336,6 +364,7 @@ class ServeStats:
                     "shed_best_effort", "rejected", "resumed",
                     "generated_tokens", "batches",
                     "batched_requests", "batch_slots", "cb_steps",
+                    "cb_prefills", "cb_admit_steps",
                     "compiles", "reloads", "reload_failures",
                     "reloads_refused", "torn_polls",
                     "reload_poll_deaths")
@@ -345,7 +374,8 @@ class ServeStats:
                   "shed_rate_recent", "p95_latency_recent_ms",
                   "p99_latency_recent_ms", "p50_queue_wait_ms",
                   "p95_queue_wait_ms", "p50_service_ms",
-                  "p95_service_ms", "p50_tokens_per_s",
+                  "p95_service_ms", "p50_ttft_ms", "p95_ttft_ms",
+                  "p50_tokens_per_s",
                   "p95_tokens_per_s", "batch_occupancy",
                   "cb_slot_occupancy", "cb_slot_occupancy_recent",
                   "cb_block_utilization",
@@ -379,6 +409,9 @@ class ServeStats:
         self._hist_service = registry.histogram(
             f"{prefix}_service_seconds",
             "time being served after dispatch")
+        self._hist_ttft = registry.histogram(
+            f"{prefix}_ttft_seconds",
+            "submit to first token (continuous batching)")
 
     def snapshot(self) -> Dict[str, Any]:
         """JSON-ready view for /stats and BENCH_pr5.json."""
@@ -409,6 +442,8 @@ class ServeStats:
                 "batched_requests": self.batched_requests,
                 "batch_slots": self.batch_slots,
                 "cb_steps": self.cb_steps,
+                "cb_prefills": self.cb_prefills,
+                "cb_admit_steps": self.cb_admit_steps,
                 "cb_blocks_in_use": self.cb_blocks_in_use,
                 "cb_blocks_total": self.cb_blocks_total,
                 "consecutive_batch_failures":
@@ -434,7 +469,8 @@ class ServeStats:
         out["p99_latency_ms"] = (round(p99 * 1e3, 3)
                                  if p99 is not None else None)
         for kind, label in (("queue_wait", "queue_wait_ms"),
-                            ("service", "service_ms")):
+                            ("service", "service_ms"),
+                            ("ttft", "ttft_ms")):
             for q, pre in ((0.50, "p50"), (0.95, "p95")):
                 v = self.split_quantile(kind, q)
                 out[f"{pre}_{label}"] = (round(v * 1e3, 3)

@@ -74,8 +74,8 @@ def run(conf: str, target: float, max_steps: int, out: str,
     warm = [next(train_iter) for _ in range(chunk)]
     warm_stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *warm)
     # through the trainer's AOT cache: CompileWatch times the compile,
-    # CostWatch harvests it, and profile_phases-style consumers reuse
-    # the same executable instead of compiling their own
+    # CostWatch harvests it, and later consumers reuse the same
+    # executable instead of compiling their own
     trainer.compiled_scan(params, opt_state, warm_stacked, 0, rng,
                           chunk, True)
     while step < max_steps:

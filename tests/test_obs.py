@@ -22,7 +22,7 @@ from singa_tpu.data.synthetic import synthetic_image_batches
 from singa_tpu.obs.log import EventLog
 from singa_tpu.obs.metrics import (MetricsRegistry, Sample,
                                    parse_prometheus)
-from singa_tpu.obs.trace import NULL_SPAN
+from singa_tpu.obs.trace import NULL_HANDLE, NULL_SPAN
 from singa_tpu.serve.stats import ServeStats
 from singa_tpu.utils.faults import FaultSchedule, inject
 
@@ -40,8 +40,10 @@ def _no_leaked_session():
 
 def test_span_is_null_when_off():
     assert obs.active() is None
-    assert obs.span("anything", corr="x") is NULL_SPAN
-    with obs.span("anything") as sp:
+    with NULL_SPAN as sp:
+        assert sp is NULL_HANDLE
+    with obs.span("anything", corr="x") as sp:
+        assert sp is NULL_HANDLE         # no handle, no id, no lock
         sp.set(k=1)                      # no-op, no error
     assert obs.current_corr() is None
     obs.emit_event("nothing", a=1)       # no-op, no error

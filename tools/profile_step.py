@@ -5,10 +5,11 @@ attributed to Python source, so MFU work targets measured cost centers.
     python tools/profile_step.py [--model alexnet|transformer]
         [--batch 8192] [--iters 3] [--top 40]
 
-Parsing recipe: events in the trace with ph=="X" under the TPU device
-pid are per-op durations; dividing by the iteration count gives
-ms/step.  Op names are XLA fusion names; the table groups by the
-leading source annotation when present.
+Parsing: the benchmark's reader (`benchmark/trace/reduce.py`) over the
+trace's xplane file: per-op SELF time on the device planes' `XLA Ops`
+line (a loop's body nests inside the loop's own event), divided by the
+iteration count.  Op names are XLA instruction names; the tag beside
+each is its `op_name` and source line from the compiled module's text.
 """
 
 from __future__ import annotations
@@ -94,18 +95,31 @@ def attribute(trainer, params, opt_state, batch_d, iters):
 
 
 def parse(outdir, iters, top, attr=None):
-    from singa_tpu.utils.profiler import parse_trace_ops
+    import collections
+    import glob
 
-    try:
-        per_op, total_us = parse_trace_ops(outdir)
-    except FileNotFoundError as e:
-        raise SystemExit(str(e))
+    from benchmark.trace import reduce as reducer
+
+    paths = sorted(glob.glob(os.path.join(
+        outdir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise SystemExit(f"no profiler trace under {outdir}")
+    per_op = collections.Counter()
+    for plane in reducer.read_planes(paths[-1]):
+        if reducer.DEVICE_PLANE.match(plane["name"]):
+            ops = reducer.line_events(plane, reducer.OPS_LINE)
+            for line, sec in reducer.self_times(ops).items():
+                per_op[line.split(" = ")[0].lstrip("%")] += sec * 1e6
+    total_us = sum(per_op.values())
+    if not total_us:
+        raise SystemExit(f"no device op in the trace under {outdir} "
+                         f"(device planes are /device:TPU:<n>)")
     print(f"# trace {outdir}")
     print(f"# total device time {total_us / 1e3 / iters:.2f} ms/step over "
           f"{iters} iters, {len(per_op)} distinct ops")
     print(f"{'ms/step':>9s}  {'%':>5s}  op")
     for name, us in per_op.most_common(top):
-        tag = (attr or {}).get(name.split("(")[0], "")
+        tag = (attr or {}).get(name, "")
         print(f"{us / 1e3 / iters:9.3f}  {100 * us / total_us:5.1f}  "
               f"{name[:40]:40s}  {tag[:120]}")
 
