@@ -18,7 +18,10 @@ weights from a seed:
           calls for the flash forward, dq, dkv and the fused head.
   serve   `serve -model_conf <12x768> --smoke N` with cb=on: exactly
           the two cb programs compile, every request returns its
-          tokens, no recompile anomaly.
+          tokens, no recompile anomaly.  The same 768 wide as 6 heads
+          x 128: the paged decode attention kernel copies whole
+          (sublane, 128-lane) tiles and refuses a head_dim of 64 on
+          the chip (ops/paged_attention.py).
   conv    `-model_conf examples/cifar10/alexnet.conf --synthetic`.
 
 `--chips 4` runs `__graft_entry__.dryrun_multichip(4)`, then the train
@@ -60,12 +63,12 @@ NO_TPU = 3               # exit code: JAX's default backend is not a TPU
 BUDGET_S = 1150.0        # every leg together, compilation included
 
 FULL = dict(vocab=32768, layers=12, embed=768, heads=12, head_dim=64,
-            seq=1024, batch=32, steps=24, chunk=8,
+            seq=1024, batch=32, steps=24, chunk=8, serve_heads=(6, 128),
             serve_spec="buckets=8x128,max_new_tokens=64,cb=on,"
                        "cb_slots=8,cb_block_len=16",
             requests=8, conv_args=["--steps", "8", "--scan_chunk", "4"])
 TINY = dict(vocab=2048, layers=1, embed=128, heads=2, head_dim=64,
-            seq=128, batch=4, steps=4, chunk=2,
+            seq=128, batch=4, steps=4, chunk=2, serve_heads=(1, 128),
             serve_spec="buckets=2x16,max_new_tokens=4,cb=on,"
                        "cb_slots=2,cb_block_len=4",
             requests=2, conv_args=["--steps", "2", "--scan_chunk", "2",
@@ -251,14 +254,15 @@ class Leg:
         return rec
 
     # -- configs from the tracked builders ---------------------------------
-    def lm_conf(self, seq_parallel: str = "none") -> str:
+    def lm_conf(self, seq_parallel: str = "none", heads=None) -> str:
         from singa_tpu.config import model_config_to_text
         from singa_tpu.models.transformer import transformer_lm
         z = self.size
+        num_heads, head_dim = heads or (z["heads"], z["head_dim"])
         cfg = transformer_lm(
             vocab_size=z["vocab"], num_layers=z["layers"],
-            embed_dim=z["embed"], num_heads=z["heads"],
-            head_dim=z["head_dim"], seq_len=z["seq"],
+            embed_dim=z["embed"], num_heads=num_heads,
+            head_dim=head_dim, seq_len=z["seq"],
             batchsize=z["batch"], precision="bfloat16",
             seq_parallel=seq_parallel)
         cfg.display_frequency = 1        # every step's loss in the log
@@ -430,7 +434,8 @@ class Leg:
     def serve(self) -> dict:
         from singa_tpu.obs import perf
         z = self.size
-        text = self.main(["serve", "-model_conf", self.lm_conf(),
+        text = self.main(["serve", "-model_conf",
+                          self.lm_conf(heads=z["serve_heads"]),
                           "--smoke", str(z["requests"]),
                           "--serve_spec", z["serve_spec"]])
         snap = json.loads(text.strip().splitlines()[-1])

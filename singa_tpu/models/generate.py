@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from ..core.layers import Context
 from ..core.net import NeuralNet
+from ..ops.paged_attention import paged_decode_attention
 
 CacheEntry = Dict[str, jnp.ndarray]   # {"k","v"}: (B, Hkv, max_len, D)
 Cache = Dict[str, CacheEntry]         # attention-layer name -> entry
@@ -154,6 +155,23 @@ def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
     return logits.astype(jnp.float32), new_cache
 
 
+def _write_token(pool, bidx, off, new):
+    """Row `off[s]` of pool block `bidx[s]` becomes `new[s]` (Hkv, D),
+    for every slot s; inactive slots all write the null block.
+
+    Whole blocks are read, patched and scattered back, so the scatter's
+    window is the pool's trailing (Hkv, block_len, D) dims.  The direct
+    form, `pool.at[bidx, :, off].set(new)`, has the window (Hkv, D)
+    around the scattered block_len axis; XLA:TPU gives that scatter's
+    operand another layout than the pool arrives and leaves in, and
+    copies the WHOLE pool there and back, for each side of each layer
+    of every decode step."""
+    rows = jnp.arange(pool.shape[2])[None, None, :, None]
+    blocks = jnp.where(rows == off[:, None, None, None],
+                       new.astype(pool.dtype)[:, :, None, :], pool[bidx])
+    return pool.at[bidx].set(blocks)
+
+
 def _attn_paged(layer, params, x, entry: CacheEntry, tables,
                 ntoks) -> Tuple[jnp.ndarray, CacheEntry]:
     """Single-token decode attention over a block/paged KV pool.
@@ -169,17 +187,19 @@ def _attn_paged(layer, params, x, entry: CacheEntry, tables,
     `entry` holds the layer's {"k","v"} pools, each (num_blocks, Hkv,
     block_len, D); `tables` (S, T) int32 maps slot s's logical block t
     to a pool index (block 0 = null: inactive slots and table tails
-    point there; its contents are never visible through the mask).
-    Token position p of slot s lives at pool[tables[s, p // bl], :,
-    p % bl] — flat gathered position p equals absolute position p, so
-    the score row matches `_attn_cached`'s contiguous row entry for
-    entry, and with masked lanes contributing exact zeros after
-    softmax the paged read is bit-identical to the contiguous one
-    (the parity tests pin this).
+    point there).  Token position p of slot s lives at
+    pool[tables[s, p // bl], :, p % bl].
 
     Write-before-read: the new K/V is scattered at position ntoks[s]
-    first, then the gather reads `kpos <= ntoks[s]` — the same
-    self-inclusive causal horizon as `_attn_cached` at T=1."""
+    first, then `ops.paged_attention.paged_decode_attention` attends
+    positions `<= ntoks[s]` — the same self-inclusive causal horizon as
+    `_attn_cached` at T=1 — walking ntoks[s] // bl + 1 blocks of the
+    slot's table row and no more (an inactive slot: the null block).
+    It is the one formulation on every backend (interpreted off the
+    TPU).  Same math as the contiguous read, f32 scores and softmax,
+    but summed chunk by chunk: the tests pin greedy-token identity with
+    `generate()` and a tolerance against the gather reference, not
+    bit-equality."""
     assert layer.causal, f"{layer.name}: decode requires causal attention"
     _, s, _ = x.shape
     bl = entry["k"].shape[2]
@@ -189,40 +209,12 @@ def _attn_paged(layer, params, x, entry: CacheEntry, tables,
     off = ntoks % bl                               # (S,) offset in block
     k_new = k[0].transpose(1, 0, 2)                # (S, Hkv, D)
     v_new = v[0].transpose(1, 0, 2)
-    # advanced indices (S,) around the ":" land the (S, Hkv, D) update
-    # at [block, :, offset]; inactive slots write the null block
-    k_pool = entry["k"].at[bidx, :, off].set(k_new.astype(entry["k"].dtype))
-    v_pool = entry["v"].at[bidx, :, off].set(v_new.astype(entry["v"].dtype))
+    k_pool = _write_token(entry["k"], bidx, off, k_new)
+    v_pool = _write_token(entry["v"], bidx, off, v_new)
 
-    t = tables.shape[1]
-    kk = k_pool[tables]                            # (S, T, Hkv, bl, D)
-    vv = v_pool[tables]
-    kk = kk.transpose(0, 2, 1, 3, 4).reshape(
-        s, layer.kv_heads, t * bl, layer.head_dim).astype(q.dtype)
-    vv = vv.transpose(0, 2, 1, 3, 4).reshape(
-        s, layer.kv_heads, t * bl, layer.head_dim).astype(q.dtype)
-
-    qs = q[0].transpose(1, 0, 2)[:, :, None, :]    # (S, H, 1, D)
-    kpos = jnp.arange(t * bl)[None, :]             # (1, T*bl)
-    allowed = kpos <= ntoks[:, None]               # (S, T*bl)
-    groups = layer.heads // layer.kv_heads
-    if groups == 1:
-        scores = jnp.einsum("bhqd,bhkd->bhqk", qs, kk,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(layer.head_dim))
-        scores = jnp.where(allowed[:, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vv.dtype), vv)
-    else:
-        qg = qs.reshape(s, layer.kv_heads, groups, 1, layer.head_dim)
-        scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kk,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(layer.head_dim))
-        scores = jnp.where(allowed[:, None, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(vv.dtype), vv)
-        out = out.reshape(s, layer.heads, 1, layer.head_dim)
-    out = out[:, :, 0, :].reshape(1, s, -1)        # back to (1, S, H*D)
+    out = paged_decode_attention(q[0].transpose(1, 0, 2), k_pool, v_pool,
+                                 tables, ntoks)    # (S, H, D)
+    out = out.reshape(1, s, -1)
     out = layer._proj(params, layer.wo, out.astype(x.dtype), _CTX)
     return out, {"k": k_pool, "v": v_pool}
 

@@ -1,0 +1,243 @@
+"""Paged decode attention: one query token per serving slot against that
+slot's blocks of a paged KV pool, as a Pallas kernel that reads the
+live blocks only.
+
+The pool of one attention layer is (num_blocks, Hkv, block_len, D) per
+side (serve/kvcache.py); slot s's logical block t is pool block
+`tables[s, t]`, and token position p lives at
+pool[tables[s, p // block_len], :, p % block_len].  A slot that has
+written `ntoks[s]` tokens attends positions 0..ntoks[s] (its newest
+token was written just before the call), which is
+ntoks[s] // block_len + 1 blocks of its table row; the rest of the row
+(null-block tail, reserved-but-unwritten blocks) is never read.
+
+`paged_decode_attention` is the kernel: the pools stay in HBM, the block
+table and the lengths are scalar-prefetched, and each slot's live blocks
+are copied HBM -> VMEM a chunk (256 positions) at a time, double
+buffered, the next slot's first chunk in flight while this slot's last
+one is computed.  Scores and the online softmax are f32; GQA is done in
+the kernel (q grouped (Hkv, G, D), no expanded K/V).
+`paged_attention_reference` is the plain-jnp gather of every slot's
+whole table, the formulation the serving engine ran before the kernel:
+it materialises (S, T, Hkv, block_len, D) per side and is kept only as
+the oracle the tests compare the kernel against.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import attention as _attention
+
+#: Stable name of the Mosaic custom call in compiled modules.
+KERNEL_NAME = "singa_paged_decode_kernel"
+
+# Key positions one loop iteration attends.  On the v5e at 32 slots x 80
+# blocks of (8, 16, 128) bf16 (tools/paged_kernel_bench.py): 128 / 256 /
+# 512 positions took 161 / 143 / 142 us a layer with 57 % of the table
+# live and 41 / 44 / 67 us with 8 % live.
+_CHUNK_POSITIONS = 256
+
+
+def paged_attention_reference(q, k_pool, v_pool, tables, ntoks):
+    """Gather formulation.  q (S, H, D); pools (num_blocks, Hkv, bl, D);
+    tables (S, T) int32; ntoks (S,) int32.  Returns (S, H, D) in q's
+    dtype: softmax(q k^T / sqrt(D)) v over positions <= ntoks[s]."""
+    s, h, d = q.shape
+    _, hkv, bl, _ = k_pool.shape
+    t = tables.shape[1]
+    groups = h // hkv
+
+    def flat(pool):                               # (S, Hkv, T*bl, D)
+        return pool[tables].transpose(0, 2, 1, 3, 4).reshape(
+            s, hkv, t * bl, d).astype(q.dtype)
+
+    kk, vv = flat(k_pool), flat(v_pool)
+    allowed = jnp.arange(t * bl)[None, :] <= ntoks[:, None]    # (S, T*bl)
+    qg = q.reshape(s, hkv, groups, d)
+    scores = jnp.einsum("shgd,shkd->shgk", qg, kk,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(d))
+    scores = jnp.where(allowed[:, None, None], scores, _attention.NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    # a masked lane's probability is an exact zero, but 0 * (inf | nan)
+    # is nan: values the mask hides must not reach the product
+    vv = jnp.where(allowed[:, None, :, None], vv, 0)
+    out = jnp.einsum("shgk,shkd->shgd", probs.astype(vv.dtype), vv)
+    return out.reshape(s, h, d)
+
+
+def _kernel(ntoks_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, base_ref, *, bl, cb, tw, scale):
+    """Grid step s attends slot s.  k_buf / v_buf are (2, Hkv, cb*bl, D):
+    two chunks of cb blocks each, a block's (Hkv, bl, D) slab copied to
+    rows [c*bl, (c+1)*bl) of every head.  The buffer that holds a slot's
+    first chunk alternates with the number of chunks walked so far
+    (`base_ref`), because the copy of slot s+1's first chunk is started
+    under slot s's last."""
+    s = pl.program_id(0)
+    slots = pl.num_programs(0)
+    span = cb * bl
+
+    def horizon(slot):
+        """Last position the slot attends; held inside its table row,
+        so that no length can send a copy after a block index read
+        from beyond the table."""
+        return jnp.minimum(ntoks_ref[slot], tw * bl - 1)
+
+    n = horizon(s)
+    chunks = (n // bl + cb) // cb             # ceil((n // bl + 1) / cb)
+    base = jnp.where(s == 0, 0, base_ref[0])
+
+    def copies(slot, chunk, buf, c):
+        """Block c of a chunk: its K and its V copy."""
+        blk = tables_ref[slot * tw + chunk * cb + c]
+        rows = pl.ds(pl.multiple_of(c * bl, bl), bl)
+        return (pltpu.make_async_copy(k_hbm.at[blk],
+                                      k_buf.at[buf, :, rows, :],
+                                      sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[blk],
+                                      v_buf.at[buf, :, rows, :],
+                                      sems.at[1, buf]))
+
+    def each_live_block(slot, chunk, buf, do):
+        """`do` on the copies of the chunk's live blocks: all cb of
+        them in every chunk of a slot but its last."""
+        live = jnp.minimum(horizon(slot) // bl + 1 - chunk * cb, cb)
+
+        def block(c, carry):
+            for cp in copies(slot, chunk, buf, c):
+                do(cp)
+            return carry
+
+        jax.lax.fori_loop(0, live, block, None)
+
+    def start(slot, chunk, buf):
+        each_live_block(slot, chunk, buf, lambda cp: cp.start())
+
+    def wait(slot, chunk, buf):
+        each_live_block(slot, chunk, buf, lambda cp: cp.wait())
+
+    def attend(chunk, buf, carry, last):
+        m, l, acc = carry
+        q = q_ref[0]                                    # (Hkv, G, D)
+        k = k_buf[buf].astype(q.dtype)                  # (Hkv, span, D)
+        v = v_buf[buf].astype(q.dtype)
+        sc = jnp.einsum("hgd,htd->hgt", q, k,
+                        preferred_element_type=jnp.float32) * scale
+        if last:
+            # the only chunk with positions past the slot's horizon:
+            # rows no copy wrote hold whatever the buffer held before
+            first = chunk * span
+            lane = first + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, span), 2)
+            sc = jnp.where(lane <= n, sc, _attention.NEG_INF)
+            row = first + jax.lax.broadcasted_iota(
+                jnp.int32, (1, span, 1), 1)
+            v = jnp.where(row <= n, v, jnp.zeros_like(v))
+        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(sc - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum(
+            "hgt,htd->hgd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    @pl.when(s == 0)
+    def _():
+        start(0, 0, 0)
+
+    def full_chunk(i, carry):
+        buf = (base + i) % 2
+        start(s, i + 1, 1 - buf)
+        wait(s, i, buf)
+        return attend(i, buf, carry, last=False)
+
+    hkv, g, d = q_ref.shape[1:]
+    init = (jnp.full((hkv, g, 1), _attention.NEG_INF, jnp.float32),
+            jnp.zeros((hkv, g, 1), jnp.float32),
+            jnp.zeros((hkv, g, d), jnp.float32))
+    carry = jax.lax.fori_loop(0, chunks - 1, full_chunk, init)
+    buf = (base + chunks - 1) % 2
+
+    @pl.when(s + 1 < slots)
+    def _():
+        start(s + 1, 0, 1 - buf)
+
+    wait(s, chunks - 1, buf)
+    _, l, acc = attend(chunks - 1, buf, carry, last=True)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    base_ref[0] = 1 - buf
+
+
+def _check_tiling(q, k_pool):
+    """Mosaic copies whole (sublane, lane) tiles: a block's (bl, D) face
+    has to be made of them.  Interpreted kernels take any shape."""
+    _, _, bl, d = k_pool.shape
+    sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
+    if bl % sublanes or d % 128:
+        raise ValueError(
+            f"paged_decode_attention cannot tile a {k_pool.dtype} pool of "
+            f"shape {k_pool.shape} (q {q.shape}) on the TPU: block_len "
+            f"must be a multiple of {sublanes} and head_dim of 128")
+
+
+def paged_decode_attention(q, k_pool, v_pool, tables, ntoks):
+    """q (S, H, D) against pools (num_blocks, Hkv, bl, D) through
+    `tables` (S, T) int32 and `ntoks` (S,) int32.  Returns (S, H, D) in
+    q's dtype, equal to `paged_attention_reference` up to the order of
+    the f32 sums.  Reads ntoks[s] // bl + 1 blocks of slot s's row and
+    nothing else of the pools.  Compiled by Mosaic on the TPU,
+    interpreted elsewhere (`ops.attention._on_tpu`)."""
+    if q.shape[1] % k_pool.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over "
+                         f"{k_pool.shape[1]} key/value heads")
+    interpret = not _attention._on_tpu()
+    if not interpret:
+        _check_tiling(q, k_pool)
+    return singa_paged_decode(q, k_pool, v_pool, tables, ntoks,
+                              interpret=interpret, chunk=_CHUNK_POSITIONS)
+
+
+# Jitted, so that the layers of one program share one trace and one
+# Mosaic lowering (16 of them cost a process 1.3 s of every start), and
+# named as the kernel: the function's name is the name of the op, and so
+# of the row, that holds the kernel's time in a device trace.
+@functools.partial(jax.jit, static_argnames=("interpret", "chunk"))
+def singa_paged_decode(q, k_pool, v_pool, tables, ntoks, *, interpret,
+                       chunk):
+    s, h, d = q.shape
+    _, hkv, bl, _ = k_pool.shape
+    tw = tables.shape[1]
+    cb = max(1, min(chunk // bl, tw))
+    groups = h // hkv
+    q_spec = pl.BlockSpec((1, hkv, groups, d), lambda i, *_: (i, 0, 0, 0))
+    buf = pltpu.VMEM((2, hkv, cb * bl, d), k_pool.dtype)
+    out = pl.pallas_call(
+        functools.partial(_kernel, bl=bl, cb=cb, tw=tw,
+                          scale=1.0 / math.sqrt(d)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s,),
+            in_specs=[q_spec,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_spec,
+            scratch_shapes=[buf, buf,
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s, hkv, groups, d), q.dtype),
+        compiler_params=(None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(ntoks.astype(jnp.int32), tables.astype(jnp.int32).reshape(-1),
+      q.reshape(s, hkv, groups, d), k_pool, v_pool)
+    return out.reshape(s, h, d)

@@ -257,6 +257,8 @@ class ContinuousScheduler:
                 block_len=spec.cb_block_len, dtype=dtype)
             self.stats.gauge("cb_slot_capacity", spec.cb_slots)
             self.stats.gauge("cb_blocks_total", self.kv.usable_blocks)
+            self.stats.gauge("cb_table_blocks",
+                             spec.cb_slots * spec.cb_blocks_per_slot)
             # MemoryWatch: the pools just allocated, from the same
             # block geometry init_pools used (analytic == actual here)
             from ..obs import perf
@@ -491,14 +493,19 @@ class ContinuousScheduler:
                     if admitted:
                         self.stats.count("cb_admit_steps")
                 active = int(self._active.sum())
+                live = 0
                 if active:
+                    # what the decode program walks: every slot's row
+                    # up to its write position, an idle slot one block
+                    live = int((self._ntoks // self.spec.cb_block_len
+                                + 1).sum())
                     self._decode_step(params, step_no, active)
             except Exception as e:  # noqa: BLE001 — fail step, keep serving
                 self._fail_step(e)
                 return
             if self.kv is not None:
                 self.stats.observe_cb_step(int(self._active.sum()),
-                                           self.kv.blocks_in_use)
+                                           self.kv.blocks_in_use, live)
                 self.stats.gauge("cb_blocks_in_use", self.kv.blocks_in_use)
 
     def _expire_pending(self, now: float) -> None:

@@ -26,7 +26,10 @@ handler, the bench smoke, and tests all see the same semantics:
   * `observe_cb_step` feeds the continuous-batching occupancy pair:
     `cb_slot_occupancy` (active slots / compiled slots, averaged over
     scheduler steps) and `cb_block_utilization` (KV blocks in use /
-    pool size).
+    pool size), and `cb_live_block_share` (table blocks the paged
+    attention kernel walked / slots x table width, averaged over the
+    steps that decoded: how much of what a whole-table read would
+    touch this traffic keeps live).
 
 `register_into(registry)` additionally exposes every snapshot field
 through an `obs.MetricsRegistry` pull-time collector (the /metrics
@@ -110,6 +113,9 @@ class ServeStats:
                                       # prefills before decoding
         self.cb_active_slot_steps = 0  # sum of active slots per step
         self.cb_block_use_steps = 0    # sum of blocks in use per step
+        self.cb_decode_steps = 0       # iterations that ran a decode
+        self.cb_live_block_steps = 0   # sum of table blocks they walked
+        self.cb_table_blocks = 0       # gauge: slots x blocks per slot
         self.cb_slot_capacity = 0      # gauge: compiled slot count S
         self.cb_blocks_total = 0       # gauge: usable pool blocks
         self.cb_blocks_in_use = 0      # gauge: blocks held right now
@@ -214,12 +220,17 @@ class ServeStats:
         if self._hist_ttft is not None:
             self._hist_ttft.observe(seconds)
 
-    def observe_cb_step(self, active_slots: int,
-                        blocks_in_use: int) -> None:
+    def observe_cb_step(self, active_slots: int, blocks_in_use: int,
+                        live_blocks: int = 0) -> None:
+        """`live_blocks`: table blocks the step's decode program walked
+        (0 for a step that ran none)."""
         with self._lock:
             self.cb_steps += 1
             self.cb_active_slot_steps += int(active_slots)
             self.cb_block_use_steps += int(blocks_in_use)
+            if live_blocks:
+                self.cb_decode_steps += 1
+                self.cb_live_block_steps += int(live_blocks)
             self._cb_t.append((time.monotonic(), int(active_slots)))
 
     # -- reads -------------------------------------------------------------
@@ -292,6 +303,13 @@ class ServeStats:
                 return None
             return self.cb_block_use_steps / (
                 self.cb_steps * self.cb_blocks_total)
+
+    def cb_live_block_share(self) -> Optional[float]:
+        with self._lock:
+            if self.cb_decode_steps == 0 or self.cb_table_blocks == 0:
+                return None
+            return self.cb_live_block_steps / (
+                self.cb_decode_steps * self.cb_table_blocks)
 
     def occupancy(self) -> Optional[float]:
         with self._lock:
@@ -378,7 +396,7 @@ class ServeStats:
                   "p50_tokens_per_s",
                   "p95_tokens_per_s", "batch_occupancy",
                   "cb_slot_occupancy", "cb_slot_occupancy_recent",
-                  "cb_block_utilization",
+                  "cb_block_utilization", "cb_live_block_share",
                   "cb_blocks_in_use", "cb_blocks_total")
 
         def collect():
@@ -422,6 +440,7 @@ class ServeStats:
         cb_occ = self.cb_slot_occupancy()
         cb_occ_recent = self.cb_slot_occupancy_recent()
         cb_util = self.cb_block_utilization()
+        cb_live = self.cb_live_block_share()
         with self._lock:
             out = {
                 "submitted": self.submitted,
@@ -488,5 +507,7 @@ class ServeStats:
             if cb_occ_recent is not None else None)
         out["cb_block_utilization"] = (round(cb_util, 4)
                                        if cb_util is not None else None)
+        out["cb_live_block_share"] = (round(cb_live, 4)
+                                      if cb_live is not None else None)
         out["by_tenant"] = self.tenants.snapshot()
         return out

@@ -8,9 +8,10 @@ gate against the static bucket path.
 Correctness anchor: a request decoded through the paged cache must
 produce the EXACT greedy tokens `generate()` produces on a contiguous
 cache — token position p of slot s lives at
-pool[table[s, p // block_len], :, p % block_len], the gather
-reassembles it in absolute-position order, and masked scores underflow
-to exact zeros, so paging changes memory layout and nothing else.
+pool[table[s, p // block_len], :, p % block_len], the paged attention
+kernel walks the slot's blocks in absolute-position order, and masked
+scores underflow to exact zeros, so paging changes memory layout and
+the order of the f32 sums and nothing else.
 
 Cost control: compiled-program tests share two module-scoped engines
 (one cb, one static for the p95 gate) over the tiny 2-layer test LM;

@@ -34,10 +34,12 @@ sys.path.insert(0, REPO)
 VOCAB, SEQ, BATCH = 32768, 1024, 32
 
 
-def lm_config(layers: int, seq: int = SEQ, batch: int = BATCH):
+def lm_config(layers: int, seq: int = SEQ, batch: int = BATCH,
+              heads=(12, 64)):
     from singa_tpu.models.transformer import transformer_lm
     return transformer_lm(vocab_size=VOCAB, num_layers=layers,
-                          embed_dim=768, num_heads=12, head_dim=64,
+                          embed_dim=768, num_heads=heads[0],
+                          head_dim=heads[1],
                           seq_len=seq, batchsize=batch,
                           precision="bfloat16")
 
@@ -120,7 +122,8 @@ def check_tokens() -> None:
     from singa_tpu.core.net import build_net
     from singa_tpu.serve import InferenceEngine, InferenceServer, ServeSpec
 
-    net = build_net(lm_config(12), "kTest",
+    # 6 x 128: the paged decode kernel refuses a head_dim of 64 on the chip
+    net = build_net(lm_config(12, heads=(6, 128)), "kTest",
                     {"data": {"input": (SEQ,), "target": (SEQ,)}})
     params = net.init_params(jax.random.PRNGKey(0))
     prompt = np.random.default_rng(0).integers(0, VOCAB, 100).astype(

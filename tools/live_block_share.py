@@ -1,0 +1,34 @@
+"""What `cb_live_block_share` reads under one of the benchmark's serving
+cells (a builder's tool; the benchmark does not report the counter):
+
+    python tools/live_block_share.py --workload serve-chat-r80 --seed 1 \\
+        --seconds 45
+
+runs the cell as `benchmark/run.py` does and prints, when the runner
+stops its scheduler, the counters of that engine's `ServeStats` (warm-up
+requests included: 4 decode steps of some 11,000)."""
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark import run as bench_run  # noqa: E402
+from singa_tpu.serve.scheduler import ContinuousScheduler  # noqa: E402
+
+_stop = ContinuousScheduler.stop
+
+
+def stop(self, *args, **kwargs):
+    snap = self.stats.snapshot()
+    print(json.dumps({"tool": "live_block_share", **{
+        k: snap[k] for k in ("cb_live_block_share", "cb_slot_occupancy",
+                             "cb_block_utilization", "cb_steps")},
+        "cb_decode_steps": self.stats.cb_decode_steps}), flush=True)
+    return _stop(self, *args, **kwargs)
+
+
+ContinuousScheduler.stop = stop
+
+if __name__ == "__main__":
+    sys.exit(bench_run.main())
