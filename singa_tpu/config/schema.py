@@ -222,6 +222,42 @@ class MoEConfig:
 
 
 @dataclass
+class RoutedMoEConfig:
+    """kRoutedMoE: a router over `num_routed` experts of which this
+    process holds `num_held`, routed experts first_held ..
+    first_held + num_held - 1 (0 held = all of them)."""
+    num_routed: int = 8
+    experts_per_token: int = 2
+    num_held: int = 0
+    first_held: int = 0
+    expert_hidden: int = 0
+    shared_hidden: int = 0       # width of the shared expert, 0 = none
+    renormalize: bool = True     # weights over the chosen experts' sum
+    routed_scale: float = 1.0
+
+
+@dataclass
+class KDAConfig:
+    """kKDA: Kimi Delta Attention.  The low-rank decay and gate
+    projections are head_dim wide, as in the public implementation."""
+    num_heads: int = 8
+    head_dim: int = 64
+    conv_kernel: int = 4
+    epsilon: float = 1e-5        # of the output's per-head RMSNorm
+
+
+@dataclass
+class MLAConfig:
+    """kMLA: multi-head latent attention without positions (NoPE)."""
+    num_heads: int = 8
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    epsilon: float = 1e-5        # of the latent's RMSNorm
+
+
+@dataclass
 class EmbedConfig:
     vocab_size: int = 0
     embed_dim: int = 0
@@ -314,6 +350,9 @@ class LayerConfig:
     # TPU-native additions
     attention_param: Optional[AttentionConfig] = _msg(AttentionConfig)
     moe_param: Optional[MoEConfig] = _msg(MoEConfig)
+    routed_moe_param: Optional[RoutedMoEConfig] = _msg(RoutedMoEConfig)
+    kda_param: Optional[KDAConfig] = _msg(KDAConfig)
+    mla_param: Optional[MLAConfig] = _msg(MLAConfig)
     embed_param: Optional[EmbedConfig] = _msg(EmbedConfig)
     rmsnorm_param: Optional[RMSNormConfig] = _msg(RMSNormConfig)
     rbm_param: Optional[RBMConfig] = _msg(RBMConfig)

@@ -1,0 +1,472 @@
+"""Mixer and expert layers of hybrid recurrent / latent / sparse LMs:
+
+  kKDA        Kimi Delta Attention: a gated delta rule whose state is a
+              fixed (H, Dk, Dv) float32 matrix per sequence plus the
+              short convolution's tail (ops/kda.py)
+  kMLA        multi-head latent attention without positions: what is
+              cached per token is one latent row (kv_lora_rank + rope
+              dims) shared by every head
+  kRoutedMoE  sigmoid router with a selection bias over ALL routed
+              experts, the top k renormalised and scaled, a shared
+              expert on every token, and only the experts this process
+              holds computed (ops/moe.py); no token is dropped
+
+Each implements the decode-state protocol of models/generate.py beside
+`apply`, as kAttention does, so the same walkers and the same cache
+manager serve them.  Matrices are stored (in, out).  `apply` is the
+forward pass over whole sequences; training these layers (gradients
+through the chunked recurrence, an auxiliary loss, expert parallelism
+over a mesh) is not done yet (ROADMAP M1/M3/M4).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ..config.schema import ParamConfig
+from ..ops import kda as kda_ops
+from ..ops import moe as moe_ops
+from ..ops.attention import NEG_INF
+from .layers import Layer, LayerError, ParamSpec, register_layer
+from .seq_layers import _declare_with_default
+
+
+def _dot(x, w):
+    """x (..., in) by w (in, out): operands as stored, float32 sums."""
+    return jnp.einsum("...i,io->...o", x, w,
+                      preferred_element_type=jnp.float32)
+
+
+def _rms(x, scale, eps):
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def _declare_const(layer: Layer, name: str, shape, value: float) -> str:
+    key = f"{layer.name}/{name}"
+    layer.param_specs.append(ParamSpec(
+        key, tuple(shape), 0,
+        ParamConfig(init_method="kConstant", value=value)))
+    return key
+
+
+def _valid_rows(t: int, pos, kmask, plen):
+    """(B, T) bool or None: which rows of a chunk at offset `pos` are
+    real, from the serving tier's left-pad key mask and/or the count of
+    real rows of a right-padded chunk."""
+    valid = None
+    if kmask is not None:
+        valid = jax.lax.dynamic_slice_in_dim(kmask, pos, t, axis=1)
+    if plen is not None:
+        real = (jnp.arange(t) < plen)[None, :]
+        valid = real if valid is None else valid & real
+    return valid
+
+
+# ---------------------------------------------------------------------------
+
+@register_layer("kKDA")
+class KDALayer(Layer):
+    """Kimi Delta Attention over (B, S, E).
+
+    q~, k~, v~ = x Wq, x Wk, x Wv, each through a causal depthwise conv
+    (kernel `conv_kernel`) and SiLU; per head q = l2norm(q~) / sqrt(D),
+    k = l2norm(k~), v = v~; beta = sigmoid(x Wbeta); log-decay per head
+    and key channel g = -exp(A_log) softplus(Wfb (Wfa x) + dt_bias);
+    the delta rule of ops/kda.py; o = RMSNorm_D(o) * sigmoid(Wgb (Wga
+    x)) per head, then Wo.  The recurrence, its decay and the norms are
+    float32 whatever the params' dtype."""
+
+    def setup(self, src_shapes):
+        p = self.cfg.kda_param
+        if p is None:
+            raise LayerError(f"{self.name}: kda_param required")
+        b, s, e = tuple(src_shapes[0])
+        self.heads, self.head_dim = p.num_heads, p.head_dim
+        self.conv_kernel, self.eps = p.conv_kernel, p.epsilon
+        self.out_shape = (b, s, e)
+        h, d = self.heads, self.head_dim
+        hd, se, sd = h * d, 1.0 / math.sqrt(e), 1.0 / math.sqrt(d)
+        dec = _declare_with_default
+        self.wq = dec(self, 0, "wq", (e, hd), se, 1)
+        self.wk = dec(self, 1, "wk", (e, hd), se, 1)
+        self.wv = dec(self, 2, "wv", (e, hd), se, 1)
+        sk = 1.0 / math.sqrt(self.conv_kernel)
+        self.conv = [dec(self, 3 + i, f"conv_{n}", (hd, self.conv_kernel), sk)
+                     for i, n in enumerate("qkv")]
+        self.w_beta = dec(self, 6, "w_beta", (e, h), se)
+        self.w_fa = dec(self, 7, "w_fa", (e, d), se)
+        self.w_fb = dec(self, 8, "w_fb", (d, hd), sd)
+        self.w_ga = dec(self, 9, "w_ga", (e, d), se)
+        self.w_gb = dec(self, 10, "w_gb", (d, hd), sd)
+        self.wo = dec(self, 11, "wo", (hd, e), 1.0 / math.sqrt(hd), 0)
+        # A = 4, dt = softplus(-3) ~ 0.05: a horizon of a few tokens;
+        # real values come with the weights
+        self.a_log = _declare_const(self, "a_log", (h,), math.log(4.0))
+        self.dt_bias = _declare_const(self, "dt_bias", (hd,), -3.0)
+        self.o_norm = _declare_const(self, "o_norm", (d,), 1.0)
+
+    # -- the layer's mathematics, around the recurrence -------------------
+    def _inputs(self, params, x, tails, valid):
+        """x (B, T, E) -> q, k, v, g (B, T, H, D) float32, beta (B, T, H)
+        and the new conv tails (B, K-1, 3 H D)."""
+        b, t, _ = x.shape
+        h, d = self.heads, self.head_dim
+        pre = jnp.concatenate([_dot(x, params[w]).astype(x.dtype)
+                               for w in (self.wq, self.wk, self.wv)], -1)
+        w = jnp.concatenate([params[c] for c in self.conv], 0)
+        mixed, tails = kda_ops.short_conv(pre, tails, w, valid)
+        q, k, v = (a.reshape(b, t, h, d) for a in jnp.split(mixed, 3, -1))
+        norm = lambda a: a * jax.lax.rsqrt(                  # noqa: E731
+            jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+        q, k = norm(q) * d ** -0.5, norm(k)
+        beta = jax.nn.sigmoid(_dot(x, params[self.w_beta]))
+        low = _dot(x, params[self.w_fa]).astype(x.dtype)
+        f = _dot(low, params[self.w_fb]) + params[self.dt_bias].astype(
+            jnp.float32)
+        a = jnp.exp(params[self.a_log].astype(jnp.float32))
+        g = -a[:, None] * jax.nn.softplus(f).reshape(b, t, h, d)
+        return q, k, v, g, beta, tails
+
+    def _output(self, params, x, o):
+        """o (B, T, H, D) float32 -> (B, T, E): per-head norm, the
+        sigmoid gate, Wo."""
+        b, t, h, d = o.shape
+        low = _dot(x, params[self.w_ga]).astype(x.dtype)
+        gate = jax.nn.sigmoid(_dot(low, params[self.w_gb]))
+        o = _rms(o, params[self.o_norm], self.eps) * gate.reshape(b, t, h, d)
+        return _dot(o.reshape(b, t, h * d).astype(x.dtype),
+                    params[self.wo]).astype(x.dtype)
+
+    def apply(self, params, srcs, ctx):
+        x = srcs[0]
+        return self.apply_cached(
+            params, x, self.init_cache(x.shape[0], 0, x.dtype), 0)[0]
+
+    # -- decode state ------------------------------------------------------
+    def _state(self, rows: int, dtype):
+        h, d = self.heads, self.head_dim
+        return {"S": jnp.zeros((rows, h, d, d), jnp.float32),
+                "conv": jnp.zeros((rows, self.conv_kernel - 1, 3 * h * d),
+                                  dtype)}
+
+    def init_cache(self, batch: int, max_len: int, dtype):
+        return self._state(batch, dtype)
+
+    def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
+                  dtype):
+        if num_slots < 1:
+            raise ValueError(f"{self.name}: a state per slot needs "
+                             f"num_slots >= 1")
+        return self._state(num_slots, dtype)
+
+    def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
+        """A chunk of T tokens from the state `entry` holds; the state
+        handed back is the one after the chunk's last REAL row."""
+        valid = _valid_rows(x.shape[1], pos, kmask, plen)
+        q, k, v, g, beta, tails = self._inputs(params, x, entry["conv"],
+                                               valid)
+        if x.shape[1] == 1:
+            o, state = kda_ops.delta_rule_step(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], entry["S"])
+            if valid is not None:
+                state = jnp.where(valid[:, 0, None, None, None], state,
+                                  entry["S"])
+            o = o[:, None]
+        else:
+            o, state = kda_ops.delta_rule_chunked(q, k, v, g, beta,
+                                                  entry["S"], valid)
+        return self._output(params, x, o), {"S": state, "conv": tails}
+
+    def apply_paged(self, params, x, entry, tables, ntoks):
+        """x (1, S, E): slot s's token against slot s's state, stepped
+        in place.  A slot that is not in use (ntoks 0) keeps its state:
+        admission overwrites it whole."""
+        xs = x[0][:, None, :]                                # (S, 1, E)
+        busy = ntoks > 0
+        q, k, v, g, beta, tails = self._inputs(params, xs, entry["conv"],
+                                               None)
+        o, state = kda_ops.delta_rule_step(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], entry["S"])
+        state = jnp.where(busy[:, None, None, None], state, entry["S"])
+        tails = jnp.where(busy[:, None, None], tails, entry["conv"])
+        out = self._output(params, xs, o[:, None])           # (S, 1, E)
+        return out[:, 0][None], {"S": state, "conv": tails}
+
+    @staticmethod
+    def scatter_prefill(pool, cache, table_row, slot):
+        return {"S": pool["S"].at[slot].set(cache["S"][0]),
+                "conv": pool["conv"].at[slot].set(
+                    cache["conv"][0].astype(pool["conv"].dtype))}
+
+
+# ---------------------------------------------------------------------------
+
+@register_layer("kMLA")
+class MLALayer(Layer):
+    """Multi-head latent attention, NoPE, over (B, S, E).
+
+    q = x Wq -> H x (nope + rope dims); x Wkva -> rank + rope dims: the
+    first `rank` through RMSNorm are the latent c, the rest k_pe, one
+    row shared by all heads and never rotated; [k_nope | v] = c Wkvb
+    per head; scores (q_nope . k_nope + q_pe . k_pe) / sqrt(nope +
+    rope), causal softmax, Wo.  What is cached per token is [c | k_pe].
+
+    A chunk of tokens expands the cached rows to per-head keys and
+    values; a decode step absorbs Wkvb into the query and the output
+    instead and attends the latent rows themselves (one shared key of
+    rank + rope dims, one shared value of rank dims): same sums, in
+    another order."""
+
+    def setup(self, src_shapes):
+        p = self.cfg.mla_param
+        if p is None:
+            raise LayerError(f"{self.name}: mla_param required")
+        b, s, e = tuple(src_shapes[0])
+        self.heads = p.num_heads
+        self.nope, self.rope = p.qk_nope_head_dim, p.qk_rope_head_dim
+        self.vdim, self.rank, self.eps = (p.v_head_dim, p.kv_lora_rank,
+                                          p.epsilon)
+        self.causal = True
+        self.out_shape = (b, s, e)
+        h, se = self.heads, 1.0 / math.sqrt(e)
+        dec = _declare_with_default
+        self.wq = dec(self, 0, "wq", (e, h * (self.nope + self.rope)), se, 1)
+        self.w_kva = dec(self, 1, "w_kva", (e, self.rank + self.rope), se)
+        self.w_kvb = dec(self, 2, "w_kvb",
+                         (self.rank, h * (self.nope + self.vdim)),
+                         1.0 / math.sqrt(self.rank), 1)
+        self.wo = dec(self, 3, "wo", (h * self.vdim, e),
+                      1.0 / math.sqrt(h * self.vdim), 0)
+        self.kv_norm = _declare_const(self, "kv_norm", (self.rank,), 1.0)
+
+    @property
+    def latent_dim(self) -> int:
+        return self.rank + self.rope
+
+    @property
+    def pool_row(self) -> int:
+        """Width of a token's row in the paged pool: the latent padded
+        with zeros to whole 128-lane tiles.  The tiled layout stores
+        that much a row anyway, and for a last dim that is not whole
+        tiles (576) XLA:TPU gives the pool a transposed layout instead
+        and copies the WHOLE pool there and back around every scatter
+        and gather (0.75 ms each way a layer at 12 k blocks)."""
+        return -(-self.latent_dim // 128) * 128
+
+    def _project(self, params, x):
+        """x (B, T, E) -> q (B, T, H, nope + rope), latent rows
+        (B, T, rank + rope), both in x's dtype."""
+        b, t, _ = x.shape
+        q = _dot(x, params[self.wq]).astype(x.dtype).reshape(
+            b, t, self.heads, self.nope + self.rope)
+        kva = _dot(x, params[self.w_kva])
+        c = _rms(kva[..., :self.rank], params[self.kv_norm], self.eps)
+        lat = jnp.concatenate([c, kva[..., self.rank:]], -1)
+        return q, lat.astype(x.dtype)
+
+    def _wkvb(self, params):
+        return params[self.w_kvb].reshape(self.rank, self.heads,
+                                          self.nope + self.vdim)
+
+    def _attend_expanded(self, params, q, lat, allowed):
+        """q (B, T, H, .) against latent rows lat (B, L, .) expanded to
+        per-head keys and values; allowed (B or 1, T, L) bool."""
+        b, t = q.shape[:2]
+        kv = jnp.einsum("blr,rhd->blhd", lat[..., :self.rank],
+                        self._wkvb(params),
+                        preferred_element_type=jnp.float32).astype(q.dtype)
+        k_nope, v = kv[..., :self.nope], kv[..., self.nope:]
+        sc = (jnp.einsum("bthd,blhd->bhtl", q[..., :self.nope], k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bthd,bld->bhtl", q[..., self.nope:],
+                           lat[..., self.rank:],
+                           preferred_element_type=jnp.float32))
+        sc = sc / math.sqrt(self.nope + self.rope)
+        sc = jnp.where(allowed[:, None], sc, NEG_INF)
+        p = jax.nn.softmax(sc, axis=-1)
+        o = jnp.einsum("bhtl,blhd->bthd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(b, t, -1).astype(q.dtype)
+
+    def _attend_absorbed(self, params, q, lat, allowed):
+        """q (N, H, .) one token a row against its latent rows lat
+        (N, L, rank + rope, or wider with zeros behind); allowed (N, L)
+        bool."""
+        w = self._wkvb(params)
+        q_lat = jnp.einsum("nhd,rhd->nhr", q[..., :self.nope],
+                           w[..., :self.nope],
+                           preferred_element_type=jnp.float32)
+        q_lat = jnp.concatenate(
+            [q_lat.astype(q.dtype), q[..., self.nope:]], -1)
+        q_lat = jnp.pad(q_lat, ((0, 0), (0, 0),
+                                (0, lat.shape[-1] - self.latent_dim)))
+        sc = jnp.einsum("nhr,nlr->nhl", q_lat, lat,
+                        preferred_element_type=jnp.float32)
+        sc = sc / math.sqrt(self.nope + self.rope)
+        sc = jnp.where(allowed[:, None, :], sc, NEG_INF)
+        p = jax.nn.softmax(sc, axis=-1)
+        # 0 * (inf | nan) is nan: rows the mask hides may hold anything
+        c = jnp.where(allowed[:, :, None], lat[..., :self.rank], 0)
+        o_lat = jnp.einsum("nhl,nlr->nhr", p.astype(c.dtype), c,
+                           preferred_element_type=jnp.float32)
+        o = jnp.einsum("nhr,rhd->nhd", o_lat.astype(q.dtype),
+                       w[..., self.nope:],
+                       preferred_element_type=jnp.float32)
+        return o.reshape(o.shape[0], -1).astype(q.dtype)
+
+    def apply(self, params, srcs, ctx):
+        x = srcs[0]
+        t = x.shape[1]
+        q, lat = self._project(params, x)
+        causal = jnp.tril(jnp.ones((t, t), bool))[None]
+        o = self._attend_expanded(params, q, lat, causal)
+        return _dot(o, params[self.wo]).astype(x.dtype)
+
+    # -- decode state ------------------------------------------------------
+    def init_cache(self, batch: int, max_len: int, dtype):
+        return {"c": jnp.zeros((batch, max_len, self.latent_dim), dtype)}
+
+    def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
+                  dtype):
+        return {"c": jnp.zeros((num_blocks, block_len, self.pool_row), dtype)}
+
+    def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
+        t = x.shape[1]
+        q, lat = self._project(params, x)
+        cache = jax.lax.dynamic_update_slice(
+            entry["c"], lat.astype(entry["c"].dtype), (0, pos, 0))
+        qpos = pos + jnp.arange(t)[:, None]
+        allowed = (jnp.arange(cache.shape[1])[None, :] <= qpos)[None]
+        if kmask is not None:
+            allowed = allowed & kmask[:, None, :]
+        o = self._attend_expanded(params, q, cache.astype(x.dtype), allowed)
+        return _dot(o, params[self.wo]).astype(x.dtype), {"c": cache}
+
+    def apply_paged(self, params, x, entry, tables, ntoks):
+        """x (1, S, E): slot s's token against the latent rows of slot
+        s's blocks, its own written first (position ntoks[s]).  Plain
+        XLA: every slot's whole table row is gathered, live or not (the
+        formulation kAttention had before its paged kernel)."""
+        pool = entry["c"]
+        s, bl, tw = x.shape[1], pool.shape[1], tables.shape[1]
+        q, lat = self._project(params, x[0][:, None, :])
+        lat = jnp.pad(lat, ((0, 0), (0, 0),
+                            (0, self.pool_row - self.latent_dim)))
+        bidx = tables[jnp.arange(s), ntoks // bl]
+        rows = jnp.arange(bl)[None, :, None]
+        blocks = jnp.where(rows == (ntoks % bl)[:, None, None],
+                           lat.astype(pool.dtype), pool[bidx])
+        pool = pool.at[bidx].set(blocks)
+        mine = pool[tables].reshape(s, tw * bl, self.pool_row)
+        allowed = jnp.arange(tw * bl)[None, :] <= ntoks[:, None]
+        o = self._attend_absorbed(params, q[:, 0], mine.astype(x.dtype),
+                                  allowed)
+        return _dot(o, params[self.wo]).astype(x.dtype)[None], {"c": pool}
+
+    @staticmethod
+    def scatter_prefill(pool, cache, table_row, slot=None):
+        _, bl, row = pool["c"].shape
+        rows = cache["c"][0]                                 # (P, .)
+        rows = jnp.pad(rows, ((0, 0), (0, row - rows.shape[1])))
+        return {"c": pool["c"].at[table_row].set(
+            rows.reshape(rows.shape[0] // bl, bl, row).astype(
+                pool["c"].dtype))}
+
+
+# ---------------------------------------------------------------------------
+
+@register_layer("kRoutedMoE")
+class RoutedMoELayer(Layer):
+    """Sparse experts over (B, S, E): out = shared(x) + sum over the
+    token's chosen experts THAT ARE HELD HERE of weight_i expert_i(x).
+
+    The router scores all `num_routed` experts and chooses among all of
+    them; `num_held` of them, from `first_held`, have their weights in
+    this process, and what the others would add is left out (their
+    chips add it).  Experts and the shared expert are SwiGLU."""
+
+    def setup(self, src_shapes):
+        p = self.cfg.routed_moe_param
+        if p is None:
+            raise LayerError(f"{self.name}: routed_moe_param required")
+        b, s, e = tuple(src_shapes[0])
+        self.n_routed, self.k = p.num_routed, p.experts_per_token
+        self.n_held = p.num_held or p.num_routed
+        self.first = p.first_held
+        if self.first < 0 or self.first + self.n_held > self.n_routed:
+            raise LayerError(
+                f"{self.name}: held experts {self.first}.."
+                f"{self.first + self.n_held - 1} of {self.n_routed}")
+        self.renormalize, self.scale = p.renormalize, p.routed_scale
+        f, fs = p.expert_hidden or 4 * e, p.shared_hidden
+        self.out_shape = (b, s, e)
+        se, sf = 1.0 / math.sqrt(e), 1.0 / math.sqrt(f)
+        dec = _declare_with_default
+        self.router = dec(self, 0, "router", (e, self.n_routed), se)
+        x = self.n_held
+        self.w_gate = dec(self, 1, "w_gate", (x, e, f), se, 0,
+                          mesh_axis="expert")
+        self.w_up = dec(self, 2, "w_up", (x, e, f), se, 0,
+                        mesh_axis="expert")
+        self.w_down = dec(self, 3, "w_down", (x, f, e), sf, 0,
+                          mesh_axis="expert")
+        self.shared = None
+        if fs:
+            self.shared = (dec(self, 4, "shared_gate", (e, fs), se, 1),
+                           dec(self, 5, "shared_up", (e, fs), se, 1),
+                           dec(self, 6, "shared_down", (fs, e),
+                               1.0 / math.sqrt(fs), 0))
+        self.router_bias = _declare_const(self, "router_bias",
+                                          (self.n_routed,), 0.0)
+
+    def _ffn(self, params, x, valid):
+        """x (T, E) -> (out (T, E), counts int32 (2,))."""
+        idx, weights = moe_ops.route_sigmoid(
+            x, params[self.router], params[self.router_bias], self.k,
+            self.renormalize, self.scale)
+        y, counts = moe_ops.held_experts_ffn(
+            x, idx, weights, params[self.w_gate], params[self.w_up],
+            params[self.w_down], self.first, valid)
+        if self.shared is not None:
+            gate, up, down = (params[w] for w in self.shared)
+            hid = (jax.nn.silu(_dot(x, gate)) * _dot(x, up)).astype(x.dtype)
+            y = y + _dot(hid, down)
+        return y.astype(x.dtype), counts
+
+    def apply(self, params, srcs, ctx):
+        x = srcs[0]
+        b, s, e = x.shape
+        return self._ffn(params, x.reshape(b * s, e), None)[0].reshape(
+            b, s, e)
+
+    # -- decode state: none but the last decode step's routing counts -----
+    def init_cache(self, batch: int, max_len: int, dtype):
+        return {}
+
+    def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
+                  dtype):
+        """[assignments on held experts, held experts touched] of the
+        last decode step, busy slots only: the engine hands them to the
+        host with the step's tokens."""
+        return {"routed": jnp.zeros((2,), jnp.int32)}
+
+    def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
+        b, t, e = x.shape
+        valid = _valid_rows(t, pos, kmask, plen)
+        if valid is not None:
+            valid = jnp.broadcast_to(valid, (b, t)).reshape(b * t)
+        out, _ = self._ffn(params, x.reshape(b * t, e), valid)
+        return out.reshape(b, t, e), entry
+
+    def apply_paged(self, params, x, entry, tables, ntoks):
+        out, counts = self._ffn(params, x[0], ntoks > 0)
+        return out[None], {"routed": counts}
+
+    @staticmethod
+    def scatter_prefill(pool, cache, table_row, slot=None):
+        return pool

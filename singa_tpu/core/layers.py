@@ -604,7 +604,7 @@ def create_layer(cfg: LayerConfig) -> Layer:
         # the sequence family registers on import and is kept lazy
         # (it pulls in Pallas); load it on first unknown type.  kRBM
         # registers the same way from its model family.
-        from . import seq_layers  # noqa: F401
+        from . import hybrid_layers, seq_layers  # noqa: F401
         from ..models.rbm import register_rbm_layer
         register_rbm_layer()
     if cfg.type not in LAYER_REGISTRY:

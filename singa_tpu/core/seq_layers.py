@@ -24,7 +24,8 @@ from ..config.schema import ParamConfig
 from ..ops import moe as moe_ops
 from ..ops.attention import (attention_reference, expand_kv_heads,
                              flash_attention, rope)
-from .layers import Layer, LayerError, register_layer
+from ..ops.paged_attention import paged_decode_attention
+from .layers import Context, Layer, LayerError, register_layer
 
 # keys of the fallbacks already reported once: (layer name, seq_len,
 # head_dim) for dense attention, (layer name, "head", shapes...) for
@@ -134,6 +135,30 @@ class RMSNormLayer(Layer):
                        keepdims=True)
         y = x * jax.lax.rsqrt(var + self.eps).astype(x.dtype)
         return y * params[self.w_key].astype(x.dtype)
+
+
+# what a layer's decode-time methods hand to shared helpers that take a
+# Context: inference, no rng, no mesh, the params' own dtype
+DECODE_CTX = Context(batch={}, train=False, rng=None, layer_index=0,
+                     mesh=None, compute_dtype=None)
+
+
+def write_token(pool, bidx, off, new):
+    """Row `off[s]` of pool block `bidx[s]` becomes `new[s]` (Hkv, D),
+    for every slot s; inactive slots all write the null block.
+
+    Whole blocks are read, patched and scattered back, so the scatter's
+    window is the pool's trailing (Hkv, block_len, D) dims.  The direct
+    form, `pool.at[bidx, :, off].set(new)`, has the window (Hkv, D)
+    around the scattered block_len axis; XLA:TPU gives that scatter's
+    operand another layout than the pool arrives and leaves in, and
+    copies the WHOLE pool there and back, for each side of each layer
+    of every decode step."""
+    rows = jnp.arange(pool.shape[2])[None, None, :, None]
+    blocks = jnp.where(rows == off[:, None, None, None],
+                       new.astype(pool.dtype)[:, :, None, :], pool[bidx])
+    return pool.at[bidx].set(blocks)
+
 
 
 @register_layer("kAttention")
@@ -277,6 +302,138 @@ class AttentionLayer(Layer):
                                       self.causal)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
         return self._proj(params, self.wo, out.astype(x.dtype), ctx)
+
+
+
+    # -- decode state: the protocol models/generate.py and
+    # serve/kvcache.py drive for every mixer layer -------------------------
+    def init_cache(self, batch: int, max_len: int, dtype):
+        """Contiguous K/V for `batch` sequences of up to `max_len`."""
+        shape = (batch, self.kv_heads, max_len, self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
+                  dtype):
+        """Paged K/V: (num_blocks, Hkv, block_len, D) per side."""
+        shape = (num_blocks, self.kv_heads, block_len, self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    @staticmethod
+    def scatter_prefill(pool, cache, table_row, slot=None):
+        """A batch-1 contiguous prefill cache ((1, Hkv, P, D), P a
+        block_len multiple) into the pool blocks `table_row` names."""
+        bl = pool["k"].shape[2]
+        hkv, p, d = cache["k"].shape[1:]
+        nb = p // bl
+        kb = cache["k"][0].transpose(1, 0, 2).reshape(
+            nb, bl, hkv, d).transpose(0, 2, 1, 3)   # (nb, Hkv, bl, D)
+        vb = cache["v"][0].transpose(1, 0, 2).reshape(
+            nb, bl, hkv, d).transpose(0, 2, 1, 3)
+        return {"k": pool["k"].at[table_row].set(kb.astype(pool["k"].dtype)),
+                "v": pool["v"].at[table_row].set(vb.astype(pool["v"].dtype))}
+
+    def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
+        """`plen` (real rows of a right-padded chunk) is for recurrent
+        mixers; the causal mask alone keeps pad keys out here.
+
+        Attention for a (B, T, E) chunk whose first token sits at absolute
+        position `pos` (traced scalar), against the running KV cache.
+
+        `kmask` (B, max_len) bool, optional: per-sequence validity of key
+        positions, ANDed with the causal mask.  The serving tier LEFT-pads
+        variable-length prompts to a bucket length and masks the pad keys —
+        with RoPE's relative rotations, left-padding keeps every attended
+        (query, key) distance identical to the unpadded sequence, so a
+        padded batched decode matches the unpadded one.
+
+        GQA reads the cache at Hkv width: q is grouped to (B, Hkv, G, T, D)
+        and contracted against the (B, Hkv, max_len, D) cache directly — no
+        expand_kv_heads copy, so the per-step HBM cache read (the decode
+        bottleneck once weights are amortized over batch) scales with Hkv,
+        not H."""
+        assert self.causal, f"{self.name}: decode requires causal attention"
+        b, t, e = x.shape
+        q, k, v = self.qkv(params, x, pos + jnp.arange(t), DECODE_CTX)
+
+        k_cache = jax.lax.dynamic_update_slice(
+            entry["k"], k.astype(entry["k"].dtype), (0, 0, pos, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            entry["v"], v.astype(entry["v"].dtype), (0, 0, pos, 0))
+
+        groups = self.heads // self.kv_heads
+        kk = k_cache.astype(q.dtype)
+        vv = v_cache.astype(q.dtype)
+        qpos = pos + jnp.arange(t)[:, None]            # (T, 1) absolute
+        kpos = jnp.arange(kk.shape[2])[None, :]        # (1, max_len)
+        allowed = (kpos <= qpos)[None]                 # (1, T, max_len)
+        if kmask is not None:
+            allowed = allowed & kmask[:, None, :]      # (B, T, max_len)
+        if groups == 1:
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, kk,
+                                preferred_element_type=jnp.float32)
+            scores = scores / jnp.sqrt(jnp.float32(self.head_dim))
+            scores = jnp.where(allowed[:, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vv.dtype), vv)
+        else:
+            qg = q.reshape(b, self.kv_heads, groups, t, self.head_dim)
+            scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kk,
+                                preferred_element_type=jnp.float32)
+            scores = scores / jnp.sqrt(jnp.float32(self.head_dim))
+            scores = jnp.where(allowed[:, None, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(vv.dtype), vv)
+            out = out.reshape(b, self.heads, t, self.head_dim)
+        out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
+        out = self._proj(params, self.wo, out.astype(x.dtype), DECODE_CTX)
+        return out, {"k": k_cache, "v": v_cache}
+
+
+
+    def apply_paged(self, params, x, entry, tables, ntoks):
+        """Single-token decode attention over a block/paged KV pool.
+
+        `x` is (1, S, E): the serving tier's S decode slots ride the SEQ
+        axis of a batch-1 chunk, so every position-wise layer (embed,
+        rmsnorm, ffn, lmhead) and `self.qkv`'s per-position RoPE treat a
+        slot exactly like a sequence position — `ntoks` (S,) int32 is both
+        the per-slot absolute position vector RoPE rotates by and the
+        per-slot key-visibility horizon.  The slots never attend each
+        other: attention below is per-slot against that slot's own blocks.
+
+        `entry` holds the layer's {"k","v"} pools, each (num_blocks, Hkv,
+        block_len, D); `tables` (S, T) int32 maps slot s's logical block t
+        to a pool index (block 0 = null: inactive slots and table tails
+        point there).  Token position p of slot s lives at
+        pool[tables[s, p // bl], :, p % bl].
+
+        Write-before-read: the new K/V is scattered at position ntoks[s]
+        first, then `ops.paged_attention.paged_decode_attention` attends
+        positions `<= ntoks[s]` — the same self-inclusive causal horizon as
+        `_attn_cached` at T=1 — walking ntoks[s] // bl + 1 blocks of the
+        slot's table row and no more (an inactive slot: the null block).
+        It is the one formulation on every backend (interpreted off the
+        TPU).  Same math as the contiguous read, f32 scores and softmax,
+        but summed chunk by chunk: the tests pin greedy-token identity with
+        `generate()` and a tolerance against the gather reference, not
+        bit-equality."""
+        assert self.causal, f"{self.name}: decode requires causal attention"
+        _, s, _ = x.shape
+        bl = entry["k"].shape[2]
+        q, k, v = self.qkv(params, x, ntoks, DECODE_CTX)    # (1,H,S,D)/(1,Hkv,S,D)
+
+        bidx = tables[jnp.arange(s), ntoks // bl]      # (S,) pool block
+        off = ntoks % bl                               # (S,) offset in block
+        k_new = k[0].transpose(1, 0, 2)                # (S, Hkv, D)
+        v_new = v[0].transpose(1, 0, 2)
+        k_pool = write_token(entry["k"], bidx, off, k_new)
+        v_pool = write_token(entry["v"], bidx, off, v_new)
+
+        out = paged_decode_attention(q[0].transpose(1, 0, 2), k_pool, v_pool,
+                                     tables, ntoks)    # (S, H, D)
+        out = out.reshape(1, s, -1)
+        out = self._proj(params, self.wo, out.astype(x.dtype), DECODE_CTX)
+        return out, {"k": k_pool, "v": v_pool}
 
 
 @register_layer("kFeedForward")

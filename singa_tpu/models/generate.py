@@ -32,98 +32,44 @@ import jax.numpy as jnp
 
 from ..core.layers import Context
 from ..core.net import NeuralNet
-from ..ops.paged_attention import paged_decode_attention
+from ..core.seq_layers import DECODE_CTX, AttentionLayer
 
-CacheEntry = Dict[str, jnp.ndarray]   # {"k","v"}: (B, Hkv, max_len, D)
-Cache = Dict[str, CacheEntry]         # attention-layer name -> entry
+CacheEntry = Dict[str, jnp.ndarray]   # one layer's decode state
+Cache = Dict[str, CacheEntry]         # layer name -> its entry
+
+# A layer that keeps state between decode steps declares it and steps it
+# itself; the walkers below only hand it over.  The protocol (duck-typed
+# methods of a core.layers.Layer):
+#   init_cache(batch, max_len, dtype)            contiguous state
+#   apply_cached(params, x, entry, pos, kmask=, plen=) -> (out, entry)
+#   init_pool(num_slots, num_blocks, block_len, dtype)   serving state:
+#       rows per token in paged blocks, or one fixed state per slot
+#   apply_paged(params, x, entry, tables, ntoks)  -> (out, entry)
+#   scatter_prefill(pool, cache, table_row, slot) -> pool
+# kAttention (K/V per token), kMLA (a latent row per token), kKDA (a
+# recurrent state and a conv tail per slot) and kRoutedMoE (no state;
+# its step's routing counts) implement it.
+
+
+def keeps_state(layer) -> bool:
+    return hasattr(layer, "apply_cached")
 
 
 def init_cache(net: NeuralNet, batchsize: int, max_len: int,
                dtype=jnp.float32) -> Cache:
-    """Zeroed KV cache for every kAttention layer in the net."""
-    cache: Cache = {}
-    for name in net.topo:
-        layer = net.layers[name]
-        if layer.cfg.type != "kAttention":
-            continue
-        shape = (batchsize, layer.kv_heads, max_len, layer.head_dim)
-        cache[name] = {"k": jnp.zeros(shape, dtype),
-                      "v": jnp.zeros(shape, dtype)}
-    return cache
+    """Zeroed contiguous decode state of every layer that keeps one."""
+    return {name: net.layers[name].init_cache(batchsize, max_len, dtype)
+            for name in net.topo if keeps_state(net.layers[name])}
 
 
-def _attn_cached(layer, params, x, entry: CacheEntry, pos,
-                 kmask: Optional[jnp.ndarray] = None
-                 ) -> Tuple[jnp.ndarray, CacheEntry]:
-    """Attention for a (B, T, E) chunk whose first token sits at absolute
-    position `pos` (traced scalar), against the running KV cache.
 
-    `kmask` (B, max_len) bool, optional: per-sequence validity of key
-    positions, ANDed with the causal mask.  The serving tier LEFT-pads
-    variable-length prompts to a bucket length and masks the pad keys —
-    with RoPE's relative rotations, left-padding keeps every attended
-    (query, key) distance identical to the unpadded sequence, so a
-    padded batched decode matches the unpadded one.
-
-    GQA reads the cache at Hkv width: q is grouped to (B, Hkv, G, T, D)
-    and contracted against the (B, Hkv, max_len, D) cache directly — no
-    expand_kv_heads copy, so the per-step HBM cache read (the decode
-    bottleneck once weights are amortized over batch) scales with Hkv,
-    not H."""
-    assert layer.causal, f"{layer.name}: decode requires causal attention"
-    b, t, e = x.shape
-    q, k, v = layer.qkv(params, x, pos + jnp.arange(t), _CTX)
-
-    k_cache = jax.lax.dynamic_update_slice(
-        entry["k"], k.astype(entry["k"].dtype), (0, 0, pos, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        entry["v"], v.astype(entry["v"].dtype), (0, 0, pos, 0))
-
-    groups = layer.heads // layer.kv_heads
-    kk = k_cache.astype(q.dtype)
-    vv = v_cache.astype(q.dtype)
-    qpos = pos + jnp.arange(t)[:, None]            # (T, 1) absolute
-    kpos = jnp.arange(kk.shape[2])[None, :]        # (1, max_len)
-    allowed = (kpos <= qpos)[None]                 # (1, T, max_len)
-    if kmask is not None:
-        allowed = allowed & kmask[:, None, :]      # (B, T, max_len)
-    if groups == 1:
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, kk,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(layer.head_dim))
-        scores = jnp.where(allowed[:, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vv.dtype), vv)
-    else:
-        qg = q.reshape(b, layer.kv_heads, groups, t, layer.head_dim)
-        scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kk,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(layer.head_dim))
-        scores = jnp.where(allowed[:, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(vv.dtype), vv)
-        out = out.reshape(b, layer.heads, t, layer.head_dim)
-    out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
-    out = layer._proj(params, layer.wo, out.astype(x.dtype), _CTX)
-    return out, {"k": k_cache, "v": v_cache}
-
-
-_CTX = Context(batch={}, train=False, rng=None, layer_index=0, mesh=None,
-               compute_dtype=None)
-
-
-def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
-                   cache: Cache, pos,
-                   kmask: Optional[jnp.ndarray] = None
-                   ) -> Tuple[jnp.ndarray, Cache]:
-    """Run the LM over a (B, T) token chunk at absolute offset `pos`.
-    Returns (logits (B, T, V) float32, updated cache).  `kmask`
-    (B, max_len) bool marks per-sequence attendable key positions
-    (see `_attn_cached` — the serving tier's left-pad mask); None
-    keeps the pure causal mask."""
+def _walk(net: NeuralNet, params, tokens, state: Cache, step):
+    """The LM over `tokens` with `step(layer, full, x, entry)` ->
+    (out, entry) at every layer that keeps state; every other layer
+    runs its normal `apply`.  Returns (logits float32, new state)."""
     full = net._resolve_params(params)
     outputs: Dict[str, Any] = {}
-    new_cache: Cache = dict(cache)
+    new_state: Cache = dict(state)
     logits = None
     for idx, name in enumerate(net.topo):
         layer = net.layers[name]
@@ -133,12 +79,11 @@ def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
             outputs[name] = {"input": tokens, "target": tokens}
         elif ltype == "kSeqLabel":
             outputs[name] = tokens
-        elif ltype == "kAttention":
-            out, new_cache[name] = _attn_cached(
-                layer, full, srcs[0], cache[name], pos, kmask=kmask)
-            outputs[name] = out
+        elif keeps_state(layer):
+            outputs[name], new_state[name] = step(layer, full, srcs[0],
+                                                  state[name])
         elif ltype == "kLMHead":
-            outputs[name] = layer.apply(full, srcs, _CTX)
+            outputs[name] = layer.apply(full, srcs, DECODE_CTX)
             logits = outputs[name]
         elif ltype == "kLMHeadLoss":
             # reuse the fused loss layer's projection to emit logits
@@ -152,134 +97,55 @@ def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
             outputs[name] = layer.apply(full, srcs, ctx)
     if logits is None:
         raise ValueError("net has no kLMHead/kLMHeadLoss layer")
-    return logits.astype(jnp.float32), new_cache
+    return logits.astype(jnp.float32), new_state
 
 
-def _write_token(pool, bidx, off, new):
-    """Row `off[s]` of pool block `bidx[s]` becomes `new[s]` (Hkv, D),
-    for every slot s; inactive slots all write the null block.
-
-    Whole blocks are read, patched and scattered back, so the scatter's
-    window is the pool's trailing (Hkv, block_len, D) dims.  The direct
-    form, `pool.at[bidx, :, off].set(new)`, has the window (Hkv, D)
-    around the scattered block_len axis; XLA:TPU gives that scatter's
-    operand another layout than the pool arrives and leaves in, and
-    copies the WHOLE pool there and back, for each side of each layer
-    of every decode step."""
-    rows = jnp.arange(pool.shape[2])[None, None, :, None]
-    blocks = jnp.where(rows == off[:, None, None, None],
-                       new.astype(pool.dtype)[:, :, None, :], pool[bidx])
-    return pool.at[bidx].set(blocks)
-
-
-def _attn_paged(layer, params, x, entry: CacheEntry, tables,
-                ntoks) -> Tuple[jnp.ndarray, CacheEntry]:
-    """Single-token decode attention over a block/paged KV pool.
-
-    `x` is (1, S, E): the serving tier's S decode slots ride the SEQ
-    axis of a batch-1 chunk, so every position-wise layer (embed,
-    rmsnorm, ffn, lmhead) and `layer.qkv`'s per-position RoPE treat a
-    slot exactly like a sequence position — `ntoks` (S,) int32 is both
-    the per-slot absolute position vector RoPE rotates by and the
-    per-slot key-visibility horizon.  The slots never attend each
-    other: attention below is per-slot against that slot's own blocks.
-
-    `entry` holds the layer's {"k","v"} pools, each (num_blocks, Hkv,
-    block_len, D); `tables` (S, T) int32 maps slot s's logical block t
-    to a pool index (block 0 = null: inactive slots and table tails
-    point there).  Token position p of slot s lives at
-    pool[tables[s, p // bl], :, p % bl].
-
-    Write-before-read: the new K/V is scattered at position ntoks[s]
-    first, then `ops.paged_attention.paged_decode_attention` attends
-    positions `<= ntoks[s]` — the same self-inclusive causal horizon as
-    `_attn_cached` at T=1 — walking ntoks[s] // bl + 1 blocks of the
-    slot's table row and no more (an inactive slot: the null block).
-    It is the one formulation on every backend (interpreted off the
-    TPU).  Same math as the contiguous read, f32 scores and softmax,
-    but summed chunk by chunk: the tests pin greedy-token identity with
-    `generate()` and a tolerance against the gather reference, not
-    bit-equality."""
-    assert layer.causal, f"{layer.name}: decode requires causal attention"
-    _, s, _ = x.shape
-    bl = entry["k"].shape[2]
-    q, k, v = layer.qkv(params, x, ntoks, _CTX)    # (1,H,S,D)/(1,Hkv,S,D)
-
-    bidx = tables[jnp.arange(s), ntoks // bl]      # (S,) pool block
-    off = ntoks % bl                               # (S,) offset in block
-    k_new = k[0].transpose(1, 0, 2)                # (S, Hkv, D)
-    v_new = v[0].transpose(1, 0, 2)
-    k_pool = _write_token(entry["k"], bidx, off, k_new)
-    v_pool = _write_token(entry["v"], bidx, off, v_new)
-
-    out = paged_decode_attention(q[0].transpose(1, 0, 2), k_pool, v_pool,
-                                 tables, ntoks)    # (S, H, D)
-    out = out.reshape(1, s, -1)
-    out = layer._proj(params, layer.wo, out.astype(x.dtype), _CTX)
-    return out, {"k": k_pool, "v": v_pool}
+def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
+                   cache: Cache, pos,
+                   kmask: Optional[jnp.ndarray] = None,
+                   plen=None) -> Tuple[jnp.ndarray, Cache]:
+    """Run the LM over a (B, T) token chunk at absolute offset `pos`.
+    Returns (logits (B, T, V) float32, updated cache).  `kmask`
+    (B, max_len) bool marks per-sequence attendable key positions
+    (the serving tier's left-pad mask, `AttentionLayer.apply_cached`);
+    None keeps the pure causal mask.  `plen` (traced scalar) says that
+    only the chunk's first `plen` rows are real (the cb prefill's right
+    padding): attention needs no telling, its causal mask hides what
+    follows, but a recurrence must stop its state at the last real
+    row."""
+    return _walk(net, params, tokens, cache,
+                 lambda layer, full, x, entry: layer.apply_cached(
+                     full, x, entry, pos, kmask=kmask, plen=plen))
 
 
 def forward_paged(net: NeuralNet, params, tokens: jnp.ndarray,
                   pools: Cache, tables, ntoks
                   ) -> Tuple[jnp.ndarray, Cache]:
-    """One decode step for S slots against the paged KV pool.
+    """One decode step for S slots against the serving state.
     `tokens` (1, S) int32 — slot s's last sampled token on the seq
     axis; `tables` (S, T) int32 block tables; `ntoks` (S,) int32
     tokens already written per slot (= the incoming token's absolute
-    position).  Returns (logits (1, S, V) float32, updated pools)."""
-    full = net._resolve_params(params)
-    outputs: Dict[str, Any] = {}
-    new_pools: Cache = dict(pools)
-    logits = None
-    for idx, name in enumerate(net.topo):
-        layer = net.layers[name]
-        ltype = layer.cfg.type
-        srcs = [net._src_out(outputs, s, name) for s in layer.cfg.srclayers]
-        if ltype == "kSequenceData":
-            outputs[name] = {"input": tokens, "target": tokens}
-        elif ltype == "kSeqLabel":
-            outputs[name] = tokens
-        elif ltype == "kAttention":
-            out, new_pools[name] = _attn_paged(
-                layer, full, srcs[0], pools[name], tables, ntoks)
-            outputs[name] = out
-        elif ltype == "kLMHead":
-            outputs[name] = layer.apply(full, srcs, _CTX)
-            logits = outputs[name]
-        elif ltype == "kLMHeadLoss":
-            logits = layer.project_logits(full, srcs[0])
-            outputs[name] = logits
-        elif ltype == "kSoftmaxLoss":
-            outputs[name] = None
-        else:
-            ctx = Context(batch={}, train=False, rng=None, layer_index=idx,
-                          mesh=None, compute_dtype=None)
-            outputs[name] = layer.apply(full, srcs, ctx)
-    if logits is None:
-        raise ValueError("net has no kLMHead/kLMHeadLoss layer")
-    return logits.astype(jnp.float32), new_pools
+    position; 0 for a slot that is not in use).  Returns (logits
+    (1, S, V) float32, updated pools)."""
+    return _walk(net, params, tokens, pools,
+                 lambda layer, full, x, entry: layer.apply_paged(
+                     full, x, entry, tables, ntoks))
 
 
-def scatter_prefill(pools: Cache, cache: Cache, table_row) -> Cache:
-    """Scatter a batch-1 contiguous prefill cache ((1, Hkv, P, D) per
-    layer, P a block_len multiple) into the paged pools at the blocks
-    named by `table_row` (P // block_len,) int32.  Table entries
-    beyond the slot's real reservation are 0: garbage from pad
-    positions lands in the null block, where no mask ever looks."""
-    out: Cache = {}
+def scatter_prefill(pools: Cache, cache: Cache, table_row,
+                    slot=None, net: Optional[NeuralNet] = None) -> Cache:
+    """Hand a batch-1 contiguous prefill state to the serving pools:
+    rows per token go to the blocks `table_row` (P // block_len,) int32
+    names (entries beyond the slot's real reservation are 0: garbage
+    from pad positions lands in the null block, where no mask ever
+    looks), a fixed state overwrites slot `slot`'s.  Each layer of
+    `net` scatters its own entry; without `net` every entry is K/V of a
+    kAttention layer.  Pool entries with no contiguous twin are kept."""
+    out: Cache = dict(pools)
     for name, entry in cache.items():
-        bl = pools[name]["k"].shape[2]
-        hkv, p, d = entry["k"].shape[1:]
-        nb = p // bl
-        kb = entry["k"][0].transpose(1, 0, 2).reshape(
-            nb, bl, hkv, d).transpose(0, 2, 1, 3)   # (nb, Hkv, bl, D)
-        vb = entry["v"][0].transpose(1, 0, 2).reshape(
-            nb, bl, hkv, d).transpose(0, 2, 1, 3)
-        out[name] = {
-            "k": pools[name]["k"].at[table_row].set(
-                kb.astype(pools[name]["k"].dtype)),
-            "v": pools[name]["v"].at[table_row].set(
-                vb.astype(pools[name]["v"].dtype))}
+        layer = AttentionLayer if net is None else net.layers[name]
+        out[name] = layer.scatter_prefill(pools[name], entry, table_row,
+                                          slot)
     return out
 
 

@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 from ..config.schema import ModelConfig, model_config_from_dict
-from ..core import seq_layers  # noqa: F401  (registers the layer types)
+from ..core import hybrid_layers, seq_layers  # noqa: F401  (register types)
 
 
 def transformer_lm(vocab_size: int = 32000,
@@ -142,6 +142,70 @@ def transformer_lm(vocab_size: int = 32000,
                     "learning_rate_change_method": "kFixed"},
         "neuralnet": {"layer": layers},
     })
+
+
+def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
+              ffns: List[Dict], seq_len: int = 1024, batchsize: int = 1,
+              epsilon: float = 1e-5, precision: str = "float32",
+              train_steps: int = 1000,
+              learning_rate: float = 3e-4) -> ModelConfig:
+    """A decoder whose layers differ: block i is
+
+        x += mixer_i(rmsnorm(x));  x += ffn_i(rmsnorm(x))
+
+    with `mixers[i]` one of {"kda": {...KDAConfig}}, {"mla":
+    {...MLAConfig}}, {"attention": {...AttentionConfig}} and `ffns[i]`
+    one of {"dense": {...FFNConfig}}, {"moe": {...RoutedMoEConfig}}
+    (a leading dense layer before sparse ones, mixers in any period).
+    Final RMSNorm, untied fused head.  Layer names follow
+    `transformer_lm`'s: ln{i}a, <kind>{i}, res{i}a, ln{i}b, ffn{i} or
+    moe{i}, res{i}b, ln_f, loss."""
+    if len(mixers) != len(ffns):
+        raise ValueError(f"{len(mixers)} mixers for {len(ffns)} ffns")
+    kinds = {"kda": ("kKDA", "kda_param"), "mla": ("kMLA", "mla_param"),
+             "attention": ("kAttention", "attention_param"),
+             "dense": ("kFeedForward", "ffn_param"),
+             "moe": ("kRoutedMoE", "routed_moe_param")}
+    norm = {"rmsnorm_param": {"epsilon": epsilon}}
+    layers: List[Dict] = [
+        {"name": "data", "type": "kSequenceData",
+         "seqdata_param": {"batchsize": batchsize, "seq_len": seq_len,
+                           "vocab_size": vocab_size}},
+        {"name": "labels", "type": "kSeqLabel", "srclayers": "data"},
+        {"name": "embed", "type": "kEmbed", "srclayers": "data",
+         "embed_param": {"vocab_size": vocab_size, "embed_dim": embed_dim}},
+    ]
+    src = "embed"
+    for i, (mixer, ffn) in enumerate(zip(mixers, ffns)):
+        for half, spec, want in (("a", mixer, ("kda", "mla", "attention")),
+                                 ("b", ffn, ("dense", "moe"))):
+            (kind, param), = spec.items()
+            if kind not in want:
+                raise ValueError(f"layer {i}: {kind!r} is not one of {want}")
+            ltype, field = kinds[kind]
+            name = f"{'ffn' if kind == 'dense' else kind}{i}"
+            layers += [
+                {"name": f"ln{i}{half}", "type": "kRMSNorm",
+                 "srclayers": src, **norm},
+                {"name": name, "type": ltype, "srclayers": f"ln{i}{half}",
+                 field: dict(param)},
+                {"name": f"res{i}{half}", "type": "kResidualAdd",
+                 "srclayers": [src, name]}]
+            src = f"res{i}{half}"
+    layers += [
+        {"name": "ln_f", "type": "kRMSNorm", "srclayers": src, **norm},
+        {"name": "loss", "type": "kLMHeadLoss",
+         "srclayers": ["ln_f", "labels"],
+         "embed_param": {"vocab_size": vocab_size, "embed_dim": embed_dim},
+         "softmaxloss_param": {"topk": 1}}]
+    return model_config_from_dict({
+        "name": f"hybrid-lm-{len(mixers)}L{embed_dim}E",
+        "train_steps": train_steps, "display_frequency": 50,
+        "precision": precision,
+        "updater": {"type": "kAdam", "base_learning_rate": learning_rate,
+                    "weight_decay": 0.0,
+                    "learning_rate_change_method": "kFixed"},
+        "neuralnet": {"layer": layers}})
 
 
 def synthetic_token_batches(batchsize: int, seq_len: int, vocab_size: int,

@@ -14,6 +14,11 @@ gather across that axis to all-to-alls over ICI.
 
 Router aux loss follows Switch Transformer (mean fraction × mean prob
 per expert, scaled by n_experts).
+
+Below `moe_ffn`, the serving form of a sparse layer that is told which
+experts it holds (`route_sigmoid`, `held_experts_ffn`; the `kRoutedMoE`
+layer of core/hybrid_layers.py): it routes over ALL experts, computes
+the held ones' part, and has no capacity and no dropped token.
 """
 
 from __future__ import annotations
@@ -85,3 +90,88 @@ def moe_ffn(x: jnp.ndarray, params: Dict[str, jnp.ndarray], k: int = 2,
     frac_probs = jnp.mean(probs, axis=0)
     aux = n_exp * jnp.sum(frac_tokens * frac_probs)
     return y.reshape(b, s, e).astype(x.dtype), aux
+
+
+# -- routing over all experts, computing the held ones ------------------------
+
+# Rows of a longer run that go through the held experts at once.
+ROW_BLOCK = 256
+
+
+def route_sigmoid(x, router, bias, k: int, renormalize: bool,
+                  scale: float):
+    """Sigmoid scores over every routed expert, the k with the largest
+    score + bias chosen, weighted by their scores alone (over the
+    chosen ones' sum when `renormalize`), times `scale`.  x (T, E);
+    router (E, N); bias (N,).  Returns (idx (T, k) int32, weights (T, k)
+    float32).  Scores are float32 whatever x is."""
+    logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalize:
+        chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), chosen * scale
+
+
+def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
+                     valid=None):
+    """What the experts held here add for each token: expert j of the
+    stacked weights is routed expert `first + j`.  x (T, E); idx,
+    weights (T, k) from the router over ALL experts; w_gate, w_up
+    (X, E, F); w_down (X, F, E); valid (T,) bool or None (a pad is
+    routed nowhere).  Returns (y (T, E) float32, counts int32 (2,):
+    assignments that fell on held experts, held experts some token
+    chose).  A token none of whose experts is held gets zeros; no token
+    is dropped and no row depends on another.
+
+    Every row goes through every held expert, the pairs the router did
+    not choose weighted 0: with the experts' weights as the traffic and
+    a few rows an expert (a decode step, a balanced router) nothing is
+    saved by sorting, and the step costs the same whatever the routing.
+    A run longer than ROW_BLOCK rows is walked a block at a time, and
+    no block after the last one that holds a real row is walked: a
+    right-padded prompt costs its own length."""
+    t, e = x.shape
+    n_held = w_gate.shape[0]
+    local = idx - first                                      # (T, k)
+    held = (local >= 0) & (local < n_held)
+    if valid is not None:
+        held = held & valid[:, None]
+    onehot = (local[:, :, None] == jnp.arange(n_held)) & held[:, :, None]
+    combine = jnp.sum(jnp.where(onehot, weights[:, :, None], 0.0), axis=1)
+    per_expert = jnp.sum(jnp.any(onehot, axis=1), axis=0)    # (X,)
+    counts = jnp.stack([jnp.sum(per_expert),
+                        jnp.sum(per_expert > 0)]).astype(jnp.int32)
+
+    def through(rows, row_combine):
+        gate = jnp.einsum("te,xef->txf", rows, w_gate,
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("te,xef->txf", rows, w_up,
+                        preferred_element_type=jnp.float32)
+        # weighted before the way down, so that the sum over experts is
+        # the contraction of one matmul: (T, X*F) by (X*F, E)
+        hid = (jax.nn.silu(gate) * up
+               * row_combine[:, :, None]).astype(rows.dtype)
+        return jnp.einsum("txf,xfe->te", hid, w_down,
+                          preferred_element_type=jnp.float32)
+
+    block = ROW_BLOCK
+    if t <= block:
+        return through(x, combine), counts
+    pad = -t % block
+    xp = jnp.pad(x, ((0, pad), (0, 0)))
+    cp = jnp.pad(combine, ((0, pad), (0, 0)))
+    n = (t + pad) // block
+    live = n if valid is None else -(-jnp.max(
+        jnp.where(valid, jnp.arange(t) + 1, 0)) // block)
+
+    def walk(i, out):
+        rows = jax.lax.dynamic_slice_in_dim(xp, i * block, block)
+        row_combine = jax.lax.dynamic_slice_in_dim(cp, i * block, block)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, through(rows, row_combine), i * block, 0)
+
+    out = jax.lax.fori_loop(0, live, walk,
+                            jnp.zeros((t + pad, e), jnp.float32))
+    return out[:t], counts

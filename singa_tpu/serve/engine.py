@@ -53,7 +53,7 @@ from ..models.generate import (_sample, forward_cached, forward_paged,
                                init_cache, scatter_prefill)
 from ..utils import faults
 from ..utils.checkpoint import CheckpointManager
-from .kvcache import init_pools
+from .kvcache import init_pools, state_bytes
 from .stats import ServeStats
 
 MODES = ("generate", "predict")
@@ -291,6 +291,17 @@ class InferenceEngine:
                              "workspace or explicit params")
         self.net = net
         self.spec = spec
+        # what the cb programs are shaped by, read off the layers'
+        # declared serving state: a state per slot (the prefill program
+        # takes the slot's index), routing counts (the decode program
+        # returns them behind its tokens)
+        self._per_slot_state = state_bytes(net, 1)["slot"] > 0
+        self._routed_layers = tuple(
+            name for name, entry in jax.eval_shape(
+                lambda: init_pools(net, 2, 1, jnp.float32, 1)).items()
+            if "routed" in entry)
+        # how many routing counts ride behind a decode step's tokens
+        self._cb_tail = 2 if self._routed_layers else 0
         self.stats = stats if stats is not None else ServeStats()
         self.log = log_fn
         self.ckpt = (CheckpointManager(workspace, log_fn=log_fn)
@@ -667,16 +678,29 @@ class InferenceEngine:
         temperature, top_k, top_p = (float(spec.temperature),
                                      int(spec.top_k), float(spec.top_p))
 
-        # the function's name is the program's in a device trace
-        # (`jit_cb_prefill`)
-        def cb_prefill(params, pools, tokens, plen, row, key):
+        def prefill(params, pools, tokens, plen, row, slot, key):
             dtype = jax.tree_util.tree_leaves(params)[0].dtype
             cache = init_cache(net, 1, p_len, dtype)
-            logits, cache = forward_cached(net, params, tokens, cache, 0)
+            # a recurrence does not forgive padding: the layers are told
+            # how many rows are real (attention needs no telling)
+            logits, cache = forward_cached(net, params, tokens, cache, 0,
+                                           plen=plen)
             last = jax.lax.dynamic_index_in_dim(logits[0], plen - 1,
                                                 axis=0, keepdims=True)
             tok0 = _sample(last, key, temperature, top_k, top_p)[0]
-            return tok0, scatter_prefill(pools, cache, row)
+            return tok0, scatter_prefill(pools, cache, row, slot, net)
+
+        # the function's name is the program's in a device trace
+        # (`jit_cb_prefill`).  A state per slot goes to the slot's own
+        # place: there `row` carries the slot's index behind the table
+        # row (`PagedKVCache.prefill_target`)
+        if self._per_slot_state:
+            def cb_prefill(params, pools, tokens, plen, row, key):
+                return prefill(params, pools, tokens, plen, row[:-1],
+                               row[-1], key)
+        else:
+            def cb_prefill(params, pools, tokens, plen, row, key):
+                return prefill(params, pools, tokens, plen, row, None, key)
 
         return cb_prefill
 
@@ -696,6 +720,20 @@ class InferenceEngine:
             nxt = _sample(logits[0], key, temperature, top_k, top_p)
             return nxt, pools
 
+        if not self._routed_layers:
+            return cb_decode
+        step = cb_decode
+
+        # the step's routing counts ride behind its tokens: one array,
+        # one fetch; and what comes out can go in again as it is (the
+        # step dispatched before this one's tokens are read)
+        def cb_decode(params, pools, tokens, ntoks, tables, key):  # noqa: F811
+            nxt, pools = step(params, pools, tokens[:spec.cb_slots], ntoks,
+                              tables, key)
+            routed = sum(pools[name]["routed"]
+                         for name in self._routed_layers)
+            return jnp.concatenate([nxt, routed]), pools
+
         return cb_decode
 
     @property
@@ -705,10 +743,9 @@ class InferenceEngine:
         return jax.tree_util.tree_leaves(self._params)[0].dtype
 
     def _pools_spec(self):
-        pools = init_pools(self.net, self.spec.cb_pool_blocks,
-                           self.spec.cb_block_len, self.serve_dtype)
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), pools)
+        return jax.eval_shape(lambda: init_pools(
+            self.net, self.spec.cb_pool_blocks, self.spec.cb_block_len,
+            self.serve_dtype, self.spec.cb_slots))
 
     def _compile_cb(self, which: str):
         """AOT-compile the cb prefill or decode program (same lock,
@@ -749,14 +786,15 @@ class InferenceEngine:
                         (1, spec.cb_prefill_len), jnp.int32)
                     plen = jax.ShapeDtypeStruct((), jnp.int32)
                     row = jax.ShapeDtypeStruct(
-                        (spec.cb_prefill_len // spec.cb_block_len,),
-                        jnp.int32)
+                        (spec.cb_prefill_len // spec.cb_block_len
+                         + int(self._per_slot_state),), jnp.int32)
                     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
                         p_spec, pools, tok, plen, row, rng).compile()
                 elif which == "decode":
                     fn = self._build_cb_decode()
                     s = spec.cb_slots
-                    tok = jax.ShapeDtypeStruct((s,), jnp.int32)
+                    tok = jax.ShapeDtypeStruct((s + self._cb_tail,),
+                                               jnp.int32)
                     ntoks = jax.ShapeDtypeStruct((s,), jnp.int32)
                     tables = jax.ShapeDtypeStruct(
                         (s, spec.cb_blocks_per_slot), jnp.int32)
@@ -776,9 +814,20 @@ class InferenceEngine:
     def run_cb_prefill(self, params, pools, tokens: np.ndarray,
                        plen: int, row: np.ndarray):
         """One slot prefill: `tokens` (1, P) int32 RIGHT-padded,
-        `row` the first P//block_len entries of the slot's block
-        table.  Returns (first sampled token (int), new pools) —
-        `pools` was donated; callers must use the returned tree."""
+        `row` what `PagedKVCache.prefill_target` gives: the first
+        P//block_len entries of the slot's block table and, where some
+        layer keeps a state per slot, the slot's index behind them.
+        Returns (first sampled token (int), new pools) — `pools` was
+        donated; callers must use the returned tree."""
+        flying, pools = self.dispatch_cb_prefill(params, pools, tokens,
+                                                 plen, row)
+        return self.fetch_cb_prefill(flying), pools
+
+    def dispatch_cb_prefill(self, params, pools, tokens: np.ndarray,
+                            plen: int, row: np.ndarray):
+        """`run_cb_prefill` up to the hand-over to the device: returns
+        (what `fetch_cb_prefill` takes, new pools) without waiting for
+        the first token."""
         self._maybe_stall()
         compiled = self._compile_cb("prefill")
         t0 = time.perf_counter()
@@ -787,10 +836,15 @@ class InferenceEngine:
                                jnp.int32(plen),
                                jnp.asarray(row, jnp.int32),
                                self._next_key())
+        return (tok0, t0), pools
+
+    def fetch_cb_prefill(self, flying) -> int:
+        """The first sampled token of a dispatched prefill (waits)."""
+        tok0, t0 = flying
         tok0 = int(tok0)
         perf.observe_step("cb_prefill", time.perf_counter() - t0)
         perf.mark_serving_ready()      # first warm token (latch)
-        return tok0, pools
+        return tok0
 
     def run_cb_decode(self, params, pools, tokens: np.ndarray,
                       ntoks: np.ndarray, tables: np.ndarray):
@@ -801,15 +855,53 @@ class InferenceEngine:
         t0 = time.perf_counter()
         with obs.span("engine.cb_decode"):
             with obs.span("engine.upload"):
-                args = (jnp.asarray(tokens, jnp.int32),
-                        jnp.asarray(ntoks, jnp.int32),
-                        jnp.asarray(tables, jnp.int32), self._next_key())
+                args = self._cb_decode_args(tokens, ntoks, tables)
             with obs.span("engine.dispatch"):
                 nxt, pools = compiled(params, pools, *args)
             with obs.span("engine.fetch"):
-                nxt = np.asarray(nxt)
+                nxt = self._cb_tokens(np.asarray(nxt))
         perf.observe_step("cb_decode", time.perf_counter() - t0)
         return nxt, pools
+
+    def _cb_decode_args(self, tokens, ntoks, tables):
+        """The decode program's small inputs, on the device.  `tokens`
+        is the host's (S,) array or, as it is, what the step before
+        gave the device."""
+        if isinstance(tokens, np.ndarray) and self._cb_tail:
+            tokens = np.concatenate(
+                [tokens, np.zeros((self._cb_tail,), tokens.dtype)])
+        return (jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(ntoks, jnp.int32),
+                jnp.asarray(tables, jnp.int32), self._next_key())
+
+    def _cb_tokens(self, nxt: np.ndarray) -> np.ndarray:
+        """A fetched step's (S,) tokens; the routing counts behind them
+        go to the stats."""
+        if self._cb_tail:
+            s = self.spec.cb_slots
+            self.stats.observe_routing(int(nxt[s]), int(nxt[s + 1]),
+                                       len(self._routed_layers))
+            nxt = nxt[:s]
+        return nxt
+
+    def dispatch_cb_decode(self, params, pools, tokens, ntoks: np.ndarray,
+                           tables: np.ndarray):
+        """`run_cb_decode` up to the hand-over to the device: returns
+        (the step's tokens as they lie on the device, new pools)
+        without waiting.  `tokens` may be such a return of the step
+        before: then that step's tokens go in unread."""
+        self._maybe_stall()
+        compiled = self._compile_cb("decode")
+        with obs.span("engine.cb_decode"):
+            with obs.span("engine.upload"):
+                args = self._cb_decode_args(tokens, ntoks, tables)
+            with obs.span("engine.dispatch"):
+                return compiled(params, pools, *args)
+
+    def fetch_cb_decode(self, flying) -> np.ndarray:
+        """The (S,) host tokens of a dispatched step (waits)."""
+        with obs.span("engine.fetch"):
+            return self._cb_tokens(np.asarray(flying))
 
     def _compile(self, mode: str, batch: int, prompt_len: int):
         key = (mode, batch, prompt_len)

@@ -1,17 +1,24 @@
-"""Paged KV cache: a fixed pool of key/value blocks shared by every
-serving slot, with host-side block tables and refcounts.
+"""The serving cache: every layer's decode state behind one allocator,
+with host-side block tables and refcounts.
 
-The static bucket path allocates each batch a contiguous
-(B, Hkv, max_len, D) cache — O(max_len) per slot whether the sequence
-uses it or not, and the whole allocation lives until the slowest
-sequence in the batch finishes.  The paged layout cuts slot memory to
-O(active tokens): every kAttention layer owns one
-(num_blocks, Hkv, block_len, D) pool per side, a slot holds an ordered
-list of block indices (its *block table* row), and retiring a slot
-returns its blocks to the free list immediately — the memory shape
-BASELINE.md's decode sweep says the tok/s ceiling lives in (the cache
-read overtakes the weight read at batch 64; reads here stay at Hkv
-width exactly like `_attn_cached`).
+A layer that keeps state between decode steps declares it
+(`layer.init_pool`, the decode-state protocol of `models/generate.py`)
+in one of two kinds, and this manager holds both:
+
+  * rows per token in PAGED BLOCKS — K and V of a `kAttention` layer,
+    (num_blocks, Hkv, block_len, D) per side; the latent row of a `kMLA`
+    layer, (num_blocks, block_len, rank + rope).  A slot holds an
+    ordered list of block indices (its *block table* row), one table for
+    all paged layers, and retiring a slot returns its blocks to the
+    free list immediately.  Slot memory is O(active tokens), where the
+    static bucket path allocates each batch a contiguous cache at
+    max_len (reads stay at Hkv width exactly like
+    `AttentionLayer.apply_cached`).
+  * one FIXED STATE PER SLOT — the (H, Dk, Dv) float32 state and the
+    conv tail of a `kKDA` layer, (num_slots, ...).  It does not grow:
+    admission overwrites the slot's state whole (the prefill program is
+    told the slot behind the table row, `prefill_target`), a decode step steps it in place,
+    retiring needs nothing.
 
 Split of responsibilities:
 
@@ -40,44 +47,54 @@ from typing import Any, Dict, List
 import jax.numpy as jnp
 import numpy as np
 
-Pools = Dict[str, Dict[str, jnp.ndarray]]   # layer -> {"k","v"} pools
+Pools = Dict[str, Dict[str, jnp.ndarray]]   # layer -> its serving state
 
 NULL_BLOCK = 0
 
 
+def _stateful(net):
+    """(name, layer) of every layer that keeps serving state
+    (`models/generate.py`, the decode-state protocol)."""
+    return [(n, net.layers[n]) for n in net.topo
+            if hasattr(net.layers[n], "init_pool")]
+
+
 def init_pools(net, num_blocks: int, block_len: int,
-               dtype=jnp.float32) -> Pools:
-    """Zeroed (num_blocks, Hkv, block_len, D) k/v pools for every
-    kAttention layer (the paged sibling of `generate.init_cache`)."""
-    pools: Pools = {}
-    for name in net.topo:
-        layer = net.layers[name]
-        if layer.cfg.type != "kAttention":
-            continue
-        shape = (num_blocks, layer.kv_heads, block_len, layer.head_dim)
-        pools[name] = {"k": jnp.zeros(shape, dtype),
-                       "v": jnp.zeros(shape, dtype)}
-    return pools
+               dtype=jnp.float32, num_slots: int = 0) -> Pools:
+    """Zeroed serving state of every layer that keeps one, each in the
+    shape its kind declares (`layer.init_pool`): rows per token in
+    `num_blocks` paged blocks of `block_len` (K/V of kAttention, the
+    latent of kMLA), or one fixed state for each of `num_slots` slots
+    (kKDA).  The paged sibling of `generate.init_cache`."""
+    return {name: layer.init_pool(num_slots, num_blocks, block_len, dtype)
+            for name, layer in _stateful(net)}
 
 
 def pool_bytes(net, num_blocks: int, block_len: int,
-               dtype=jnp.float32) -> int:
-    """Analytic byte count of the pools `init_pools` would allocate —
-    k and v per kAttention layer, (num_blocks, Hkv, block_len, D)
-    each.  MemoryWatch's HBM fallback on backends that expose no
-    `memory_stats()` (the CPU test platform) uses this, so it must
-    track `init_pools` shape-for-shape."""
-    elems = 0
-    for name in net.topo:
-        layer = net.layers[name]
-        if layer.cfg.type != "kAttention":
-            continue
-        elems += 2 * num_blocks * layer.kv_heads * block_len * layer.head_dim
-    return elems * int(np.dtype(dtype).itemsize)
+               dtype=jnp.float32, num_slots: int = 0) -> int:
+    """Byte count of the pools `init_pools` would allocate, from their
+    shapes alone (nothing is allocated).  MemoryWatch's HBM fallback on
+    backends that expose no `memory_stats()` (the CPU test platform)
+    uses this."""
+    import jax
+    shapes = jax.eval_shape(
+        lambda: init_pools(net, num_blocks, block_len, dtype, num_slots))
+    return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(shapes))
+
+
+def state_bytes(net, block_len: int, dtype=jnp.float32) -> Dict[str, int]:
+    """What one slot and one block cost: `slot` the bytes of the fixed
+    per-slot states of all layers, `block` the bytes one more paged
+    block adds over all layers."""
+    at = lambda slots, blocks: pool_bytes(      # noqa: E731
+        net, blocks, block_len, dtype, slots)
+    base = at(1, 1)
+    return {"slot": at(2, 1) - base, "block": at(1, 2) - base}
 
 
 class PagedKVCache:
-    """Block pool + slot tables for one serving engine.  Single-owner:
+    """Every layer's serving state + slot tables for one engine.  Single-owner:
     the `ContinuousScheduler` thread is the only mutator, so the
     bookkeeping needs no lock; `snapshot()` reads are approximate from
     other threads (ints are swapped atomically in CPython)."""
@@ -95,7 +112,12 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.block_len = int(block_len)
         self.pools: Pools = init_pools(net, self.num_blocks,
-                                       self.block_len, dtype)
+                                       self.block_len, dtype,
+                                       self.num_slots)
+        # a layer with one state per slot: the prefill program is told
+        # the slot behind the table row (`prefill_target`)
+        self.per_slot_state = state_bytes(net, self.block_len,
+                                          dtype)["slot"] > 0
         # host bookkeeping: block 0 never enters the free list
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._refcounts = np.zeros((self.num_blocks,), np.int32)
@@ -148,6 +170,15 @@ class PagedKVCache:
         self.tables[slot, :nblocks] = blocks
         self._slot_blocks[slot] = blocks
         return self.tables[slot].copy()
+
+    def prefill_target(self, slot: int, nblocks: int) -> np.ndarray:
+        """Where a prefill of `nblocks` blocks writes slot `slot`'s
+        state, as the prefill program takes it: the head of the slot's
+        table row and, where some layer keeps a state per slot, the
+        slot's index behind it."""
+        row = self.tables[slot, :nblocks]
+        return np.append(row, slot).astype(np.int32) \
+            if self.per_slot_state else row.copy()
 
     def free(self, slot: int) -> None:
         """Retire `slot`: drop each block's refcount and return

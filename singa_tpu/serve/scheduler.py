@@ -232,6 +232,10 @@ class ContinuousScheduler:
         self._ntoks = np.zeros((s,), np.int32)
         self._last = np.zeros((s,), np.int32)
         self._slot_req: List[Optional[_CBRequest]] = [None] * s
+        # a decode step handed to the device whose tokens are not read
+        # yet: (its tokens on the device, who held each slot then).
+        # Only a step in which every slot was busy is left so
+        self._flying: Optional[tuple] = None
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ContinuousScheduler":
@@ -262,12 +266,15 @@ class ContinuousScheduler:
             # MemoryWatch: the pools just allocated, from the same
             # block geometry init_pools used (analytic == actual here)
             from ..obs import perf
-            from .kvcache import pool_bytes
+            from .kvcache import pool_bytes, state_bytes
             perf.set_memory(
                 "kv_pool",
                 pool_bytes(self.engine.net, spec.cb_pool_blocks,
-                           spec.cb_block_len, dtype),
+                           spec.cb_block_len, dtype, spec.cb_slots),
                 scope=getattr(self.engine, "_perf_scope", "scheduler"))
+            per = state_bytes(self.engine.net, spec.cb_block_len, dtype)
+            self.stats.gauge("cb_slot_state_bytes", per["slot"])
+            self.stats.gauge("cb_block_bytes", per["block"])
         self._stop = False
         self._thread = threading.Thread(target=self._loop,
                                         name="serve-cb", daemon=True)
@@ -288,6 +295,7 @@ class ContinuousScheduler:
         for r in leftovers:
             self.stats.count("failed")
             r.ticket._fail(RuntimeError("server shutting down"))
+        self._flying = None
         for s, r in enumerate(self._slot_req):
             if r is not None:
                 self._retire(s, "shutdown", self.engine.params_step)
@@ -462,7 +470,7 @@ class ContinuousScheduler:
         while True:
             with self._cv:
                 while (not self._pending and not self._active.any()
-                       and not self._stop):
+                       and self._flying is None and not self._stop):
                     self._cv.wait(0.05)
                 if self._stop:
                     return
@@ -492,6 +500,9 @@ class ContinuousScheduler:
                         sp.set(admitted=admitted)     # a session's tracer only
                     if admitted:
                         self.stats.count("cb_admit_steps")
+                if self._flying is not None and not self._active.all():
+                    # a slot fell free and nothing took it
+                    self._collect(step_no)
                 active = int(self._active.sum())
                 live = 0
                 if active:
@@ -608,7 +619,7 @@ class ContinuousScheduler:
                     "scheduler.queue", time.perf_counter() - queued,
                     queued, corr=req.corr, trace=trace_id,
                     parent=parent, plen=req.plen, tenant=req.tenant)
-            row = self.kv.alloc(slot, req.nblocks)
+            self.kv.alloc(slot, req.nblocks)
             toks = np.zeros((1, spec.cb_prefill_len), np.int32)
             toks[0, :req.plen] = req.tokens
             try:
@@ -616,9 +627,19 @@ class ContinuousScheduler:
                               trace=trace_id, parent=parent,
                               slot=slot, plen=req.plen,
                               queue_ms=queued * 1e3):
-                    tok0, self.kv.pools = self.engine.run_cb_prefill(
-                        params, self.kv.pools, toks, req.plen,
-                        row[:spec.cb_prefill_len // spec.cb_block_len])
+                    row = self.kv.prefill_target(
+                        slot, spec.cb_prefill_len // spec.cb_block_len)
+                    if self._flying is None:
+                        tok0, self.kv.pools = self.engine.run_cb_prefill(
+                            params, self.kv.pools, toks, req.plen, row)
+                    else:
+                        # behind the step in flight, whose tokens are
+                        # read (in time) while the prefill runs
+                        first, self.kv.pools = \
+                            self.engine.dispatch_cb_prefill(
+                                params, self.kv.pools, toks, req.plen, row)
+                        self._collect(step_no)
+                        tok0 = self.engine.fetch_cb_prefill(first)
             except Exception as e:  # noqa: BLE001 — fail req, keep going
                 # the slot is not in _slot_req yet: clean it here so
                 # the blocks cannot leak, fail only this request
@@ -645,6 +666,9 @@ class ContinuousScheduler:
     def _decode_step(self, params, step_no: int, active: int) -> None:
         with obs.span("scheduler.decode", active=active):
             faults.maybe_fault("serve.batch")
+            if active == len(self._active):
+                self._decode_ahead(params, step_no)
+                return
             nxt, self.kv.pools = self.engine.run_cb_decode(
                 params, self.kv.pools, self._last, self._ntoks,
                 self.kv.table_array())
@@ -658,6 +682,42 @@ class ContinuousScheduler:
                 req.produced.append(tok)
                 req.ticket._emit(tok)
                 self._maybe_retire(slot, tok, step_no, now)
+
+    def _decode_ahead(self, params, step_no: int) -> None:
+        """Every slot is busy: nothing can be admitted before one
+        retires, so no request waits on this step, and it goes to the
+        device BEFORE the step before it is read (its tokens go in as
+        they lie on the device).  The host's part of a step then runs
+        while the device works.  A slot that the step before retires
+        has made one token too many in this one: `_collect` drops it,
+        and what it wrote lies in blocks and state that an admission
+        overwrites."""
+        before = self._flying
+        # copies: the host's arrays change before the device has run
+        nxt, self.kv.pools = self.engine.dispatch_cb_decode(
+            params, self.kv.pools,
+            self._last.copy() if before is None else before[0],
+            self._ntoks.copy(), self.kv.table_array())
+        self._ntoks += 1
+        self._flying = (nxt, list(self._slot_req))
+        if before is not None:
+            self._collect(step_no, before)
+
+    def _collect(self, step_no: int, flying: Optional[tuple] = None) -> None:
+        """Read a dispatched step's tokens and hand them out: to the
+        requests that held their slots then and still do."""
+        if flying is None:
+            flying, self._flying = self._flying, None
+        nxt = self.engine.fetch_cb_decode(flying[0])
+        now = time.monotonic()
+        for slot, req in enumerate(flying[1]):
+            if req is not self._slot_req[slot]:
+                continue               # retired since: a token too many
+            tok = int(nxt[slot])
+            self._last[slot] = tok
+            req.produced.append(tok)
+            req.ticket._emit(tok)
+            self._maybe_retire(slot, tok, step_no, now)
 
     def _maybe_retire(self, slot: int, tok: int, step_no: int,
                       now: float) -> None:
@@ -715,6 +775,7 @@ class ContinuousScheduler:
         everything, keep the loop alive (the batcher's degrade
         story)."""
         n = int(self._active.sum())
+        self._flying = None
         self.stats.count("failed", n)
         self.stats.observe_batch_failure()
         self.log(f"warning: cb decode step failed "

@@ -119,6 +119,16 @@ class ServeStats:
         self.cb_slot_capacity = 0      # gauge: compiled slot count S
         self.cb_blocks_total = 0       # gauge: usable pool blocks
         self.cb_blocks_in_use = 0      # gauge: blocks held right now
+        # what one slot's fixed state and one paged block cost, over all
+        # layers (serve/kvcache.py state_bytes): with the slots in use
+        # and the live blocks, the bytes a decode step has to move
+        self.cb_slot_state_bytes = 0   # gauge
+        self.cb_block_bytes = 0        # gauge
+        # routing of the experts held here, summed over decode steps
+        # and routed layers, busy slots only (engine.run_cb_decode)
+        self.cb_routed_layer_steps = 0   # layers x steps counted
+        self.cb_routed_assignments = 0   # (token, held expert) pairs
+        self.cb_routed_experts_touched = 0  # held experts some token chose
         # batching
         self.batches = 0
         self.batched_requests = 0
@@ -232,6 +242,15 @@ class ServeStats:
                 self.cb_decode_steps += 1
                 self.cb_live_block_steps += int(live_blocks)
             self._cb_t.append((time.monotonic(), int(active_slots)))
+
+    def observe_routing(self, assignments: int, experts_touched: int,
+                        layers: int) -> None:
+        """One decode step's routing counts, summed over its `layers`
+        routed layers."""
+        with self._lock:
+            self.cb_routed_layer_steps += int(layers)
+            self.cb_routed_assignments += int(assignments)
+            self.cb_routed_experts_touched += int(experts_touched)
 
     # -- reads -------------------------------------------------------------
     def latency_quantile(self, q: float) -> Optional[float]:
@@ -383,6 +402,8 @@ class ServeStats:
                     "generated_tokens", "batches",
                     "batched_requests", "batch_slots", "cb_steps",
                     "cb_prefills", "cb_admit_steps",
+                    "cb_routed_layer_steps", "cb_routed_assignments",
+                    "cb_routed_experts_touched",
                     "compiles", "reloads", "reload_failures",
                     "reloads_refused", "torn_polls",
                     "reload_poll_deaths")
@@ -397,7 +418,8 @@ class ServeStats:
                   "p95_tokens_per_s", "batch_occupancy",
                   "cb_slot_occupancy", "cb_slot_occupancy_recent",
                   "cb_block_utilization", "cb_live_block_share",
-                  "cb_blocks_in_use", "cb_blocks_total")
+                  "cb_blocks_in_use", "cb_blocks_total",
+                  "cb_slot_state_bytes", "cb_block_bytes")
 
         def collect():
             snap = self.snapshot()
@@ -465,6 +487,12 @@ class ServeStats:
                 "cb_admit_steps": self.cb_admit_steps,
                 "cb_blocks_in_use": self.cb_blocks_in_use,
                 "cb_blocks_total": self.cb_blocks_total,
+                "cb_slot_state_bytes": self.cb_slot_state_bytes,
+                "cb_block_bytes": self.cb_block_bytes,
+                "cb_routed_layer_steps": self.cb_routed_layer_steps,
+                "cb_routed_assignments": self.cb_routed_assignments,
+                "cb_routed_experts_touched":
+                    self.cb_routed_experts_touched,
                 "consecutive_batch_failures":
                     self.consecutive_batch_failures,
                 "compiles": self.compiles,
