@@ -59,6 +59,13 @@ from .stats import ServeStats
 MODES = ("generate", "predict")
 
 
+# The narrowest compiled cb prefill (`ServeSpec.cb_prefill_widths`).
+# Under about 240 rows (197 TFLOP/s over 819 GB/s, 2 FLOP and 2 bytes a
+# parameter) a prefill is bound by reading the weights, so a narrower
+# program is no faster; and each rung costs set-up time in every run.
+CB_PREFILL_FLOOR = 256
+
+
 @dataclass(frozen=True)
 class ServeSpec:
     """Serving configuration.  `buckets` is the closed set of compiled
@@ -92,8 +99,9 @@ class ServeSpec:
     # continuous batching (serve/scheduler.py): cb=on replaces the
     # static generate buckets with a paged-KV slot scheduler.  The
     # compiled geometry is (cb_slots, blocks-per-slot, cb_block_len,
-    # pool size) ONLY — exactly two programs (prefill + decode step)
-    # regardless of traffic mix, so the zero-recompile guarantee holds
+    # pool size) ONLY — one decode step and a short ladder of prefill
+    # programs (`cb_prefill_widths`) regardless of traffic mix, so the
+    # zero-recompile guarantee holds
     cb: str = "off"           # "on" | "off"
     cb_slots: int = 8         # concurrent decode slots (S)
     cb_block_len: int = 16    # tokens per KV block
@@ -180,6 +188,29 @@ class ServeSpec:
         cap = int(self.cb_prompt_cap) or self.max_prompt_len
         bl = int(self.cb_block_len)
         return -(-cap // bl) * bl
+
+    @property
+    def cb_prefill_widths(self) -> Tuple[int, ...]:
+        """The ladder of compiled prefill widths: the floor doubled
+        while it is under the cap, then the cap `cb_prefill_len`
+        itself; every rung a block multiple.  A cap at or under the
+        floor is the only rung."""
+        cap, bl = self.cb_prefill_len, int(self.cb_block_len)
+        rungs = []
+        width = -(-CB_PREFILL_FLOOR // bl) * bl
+        while width < cap:
+            rungs.append(width)
+            width *= 2
+        return tuple(rungs) + (cap,)
+
+    def cb_prefill_width(self, plen: int) -> int:
+        """The rung a prompt of `plen` tokens is prefilled at: the
+        narrowest that holds it."""
+        for width in self.cb_prefill_widths:
+            if width >= plen:
+                return width
+        raise ValueError(f"prompt length {plen} exceeds the widest "
+                         f"prefill program ({self.cb_prefill_len})")
 
     @property
     def cb_max_prompt_len(self) -> int:
@@ -665,8 +696,9 @@ class InferenceEngine:
         return fn
 
     # -- continuous-batching programs ---------------------------------------
-    def _build_cb_prefill(self):
-        """ONE compiled prefill at fixed (1, P): the prompt is
+    def _build_cb_prefill(self, p_len: Optional[int] = None):
+        """The prefill at fixed (1, P), P one rung of
+        `spec.cb_prefill_widths` (the cap when not given): the prompt is
         RIGHT-padded to P (the causal mask alone keeps pad keys out of
         every real query's horizon; pad K/V garbage lands in reserved
         or null blocks and is masked/overwritten downstream), runs
@@ -674,7 +706,8 @@ class InferenceEngine:
         first token from the last REAL position, and scatters the
         contiguous cache into the slot's pool blocks."""
         net, spec = self.net, self.spec
-        p_len = spec.cb_prefill_len
+        if p_len is None:
+            p_len = spec.cb_prefill_len
         temperature, top_k, top_p = (float(spec.temperature),
                                      int(spec.top_k), float(spec.top_p))
 
@@ -691,9 +724,9 @@ class InferenceEngine:
             return tok0, scatter_prefill(pools, cache, row, slot, net)
 
         # the function's name is the program's in a device trace
-        # (`jit_cb_prefill`).  A state per slot goes to the slot's own
-        # place: there `row` carries the slot's index behind the table
-        # row (`PagedKVCache.prefill_target`)
+        # (`jit_cb_prefill`, every rung).  A state per slot goes to the
+        # slot's own place: there `row` carries the slot's index behind
+        # the table row (`PagedKVCache.prefill_target`)
         if self._per_slot_state:
             def cb_prefill(params, pools, tokens, plen, row, key):
                 return prefill(params, pools, tokens, plen, row[:-1],
@@ -747,14 +780,33 @@ class InferenceEngine:
             self.net, self.spec.cb_pool_blocks, self.spec.cb_block_len,
             self.serve_dtype, self.spec.cb_slots))
 
-    def _compile_cb(self, which: str):
-        """AOT-compile the cb prefill or decode program (same lock,
-        same `compiles` accounting as `_compile` — the counter still
-        moves ONLY inside the two compile paths).  Pools are donated:
+    def _cb_prefill_name(self, p_len: int) -> str:
+        """What the compile and cost accounts (`obs.perf`) call the
+        prefill program of width `p_len`: the FLOPs and the step time
+        that make an MFU have to be one executable's, so each rung
+        under the cap has a name of its own."""
+        if p_len == self.spec.cb_prefill_len:
+            return "cb_prefill"
+        if p_len not in self.spec.cb_prefill_widths:
+            raise ValueError(
+                f"no cb prefill program is {p_len} rows wide (the "
+                f"rungs are {self.spec.cb_prefill_widths})")
+        return f"cb_prefill_{p_len}"
+
+    def _compile_cb(self, which: str, p_len: Optional[int] = None):
+        """AOT-compile the cb decode program or the prefill program of
+        width `p_len` (the cap when not given; same lock, same
+        `compiles` accounting as `_compile` — the counter still moves
+        ONLY inside the two compile paths).  Pools are donated:
         the scheduler threads the returned pools into the next call,
         so the pool never exists twice on device."""
         spec = self.spec
-        key = (f"cb_{which}", spec.cb_slots, spec.cb_blocks_per_slot)
+        name = f"cb_{which}"
+        if which == "prefill":
+            if p_len is None:
+                p_len = spec.cb_prefill_len
+            name = self._cb_prefill_name(p_len)
+        key = (name, spec.cb_slots, spec.cb_blocks_per_slot)
         got = self._compiled.get(key)
         if got is not None:
             perf.lookup_hit(key[0])
@@ -769,7 +821,7 @@ class InferenceEngine:
             geometry = (f"slots={spec.cb_slots},"
                         f"blocks={spec.cb_pool_blocks},"
                         f"block_len={spec.cb_block_len}")
-            with obs.span("engine.compile", mode=f"cb_{which}",
+            with obs.span("engine.compile", mode=name,
                           slots=spec.cb_slots,
                           blocks=spec.cb_pool_blocks), \
                  perf.compile_span(key[0], geometry=geometry,
@@ -781,12 +833,11 @@ class InferenceEngine:
                 pools = self._pools_spec()
                 rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
                 if which == "prefill":
-                    fn = self._build_cb_prefill()
-                    tok = jax.ShapeDtypeStruct(
-                        (1, spec.cb_prefill_len), jnp.int32)
+                    fn = self._build_cb_prefill(p_len)
+                    tok = jax.ShapeDtypeStruct((1, p_len), jnp.int32)
                     plen = jax.ShapeDtypeStruct((), jnp.int32)
                     row = jax.ShapeDtypeStruct(
-                        (spec.cb_prefill_len // spec.cb_block_len
+                        (p_len // spec.cb_block_len
                          + int(self._per_slot_state),), jnp.int32)
                     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
                         p_spec, pools, tok, plen, row, rng).compile()
@@ -813,10 +864,11 @@ class InferenceEngine:
 
     def run_cb_prefill(self, params, pools, tokens: np.ndarray,
                        plen: int, row: np.ndarray):
-        """One slot prefill: `tokens` (1, P) int32 RIGHT-padded,
-        `row` what `PagedKVCache.prefill_target` gives: the first
-        P//block_len entries of the slot's block table and, where some
-        layer keeps a state per slot, the slot's index behind them.
+        """One slot prefill: `tokens` (1, P) int32 RIGHT-padded to a
+        rung P of `spec.cb_prefill_widths` (its width picks the
+        program), `row` what `PagedKVCache.prefill_target` gives: the
+        first P//block_len entries of the slot's block table and, where
+        some layer keeps a state per slot, the slot's index behind them.
         Returns (first sampled token (int), new pools) — `pools` was
         donated; callers must use the returned tree."""
         flying, pools = self.dispatch_cb_prefill(params, pools, tokens,
@@ -829,20 +881,43 @@ class InferenceEngine:
         (what `fetch_cb_prefill` takes, new pools) without waiting for
         the first token."""
         self._maybe_stall()
-        compiled = self._compile_cb("prefill")
+        width = int(tokens.shape[1])
+        compiled = self._compile_cb("prefill", width)
         t0 = time.perf_counter()
+        # host arrays as they are, for the call's own transfer
+        # (`_cb_decode_args`)
         tok0, pools = compiled(params, pools,
-                               jnp.asarray(tokens, jnp.int32),
-                               jnp.int32(plen),
-                               jnp.asarray(row, jnp.int32),
+                               np.asarray(tokens, np.int32),
+                               np.int32(plen),
+                               np.asarray(row, np.int32),
                                self._next_key())
-        return (tok0, t0), pools
+        tok0.copy_to_host_async()
+        return (tok0, t0, width), pools
+
+    def run_cb_prefill_rungs(self, params, pools):
+        """Every rung of the ladder run once, on pools no request
+        holds yet: a program's first run on a device costs more than
+        its later ones, and traffic reaches a wide rung only with its
+        first long prompt.  One pad row at table entries of zeros
+        writes the null block; a state per slot lands in slot 0, which
+        its first admission overwrites whole.  Nothing is counted,
+        and no key of the sampling stream is spent.  Returns the new
+        pools (`pools` was donated)."""
+        spec = self.spec
+        for width in spec.cb_prefill_widths:
+            row = jnp.zeros((width // spec.cb_block_len
+                             + int(self._per_slot_state),), jnp.int32)
+            _, pools = self._compile_cb("prefill", width)(
+                params, pools, jnp.zeros((1, width), jnp.int32),
+                jnp.int32(1), row, jnp.zeros((2,), jnp.uint32))
+        return pools
 
     def fetch_cb_prefill(self, flying) -> int:
         """The first sampled token of a dispatched prefill (waits)."""
-        tok0, t0 = flying
+        tok0, t0, width = flying
         tok0 = int(tok0)
-        perf.observe_step("cb_prefill", time.perf_counter() - t0)
+        perf.observe_step(self._cb_prefill_name(width),
+                          time.perf_counter() - t0)
         perf.mark_serving_ready()      # first warm token (latch)
         return tok0
 
@@ -858,21 +933,28 @@ class InferenceEngine:
                 args = self._cb_decode_args(tokens, ntoks, tables)
             with obs.span("engine.dispatch"):
                 nxt, pools = compiled(params, pools, *args)
+                # on its way to the host as soon as the device has it
+                nxt.copy_to_host_async()
             with obs.span("engine.fetch"):
                 nxt = self._cb_tokens(np.asarray(nxt))
         perf.observe_step("cb_decode", time.perf_counter() - t0)
         return nxt, pools
 
     def _cb_decode_args(self, tokens, ntoks, tables):
-        """The decode program's small inputs, on the device.  `tokens`
-        is the host's (S,) array or, as it is, what the step before
-        gave the device."""
-        if isinstance(tokens, np.ndarray) and self._cb_tail:
-            tokens = np.concatenate(
-                [tokens, np.zeros((self._cb_tail,), tokens.dtype)])
-        return (jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(ntoks, jnp.int32),
-                jnp.asarray(tables, jnp.int32), self._next_key())
+        """The decode program's small inputs as the call takes them.
+        `tokens` is the host's (S,) array or, as it is, what the step
+        before gave the device.  Host arrays go in as they are and the
+        call's own transfer moves them: three `jnp.asarray` cost a
+        step 0.8 ms of Python with the device idle (PERF.md 6, PR 28).
+        The caller keeps them unchanged until the step is read, or
+        hands over copies."""
+        if isinstance(tokens, np.ndarray):
+            if self._cb_tail:
+                tokens = np.concatenate(
+                    [tokens, np.zeros((self._cb_tail,), tokens.dtype)])
+            tokens = np.asarray(tokens, np.int32)
+        return (tokens, np.asarray(ntoks, np.int32),
+                np.asarray(tables, np.int32), self._next_key())
 
     def _cb_tokens(self, nxt: np.ndarray) -> np.ndarray:
         """A fetched step's (S,) tokens; the routing counts behind them
@@ -896,7 +978,9 @@ class InferenceEngine:
             with obs.span("engine.upload"):
                 args = self._cb_decode_args(tokens, ntoks, tables)
             with obs.span("engine.dispatch"):
-                return compiled(params, pools, *args)
+                nxt, pools = compiled(params, pools, *args)
+                nxt.copy_to_host_async()
+                return nxt, pools
 
     def fetch_cb_decode(self, flying) -> np.ndarray:
         """The (S,) host tokens of a dispatched step (waits)."""
@@ -952,10 +1036,13 @@ class InferenceEngine:
         before = self.stats.compiles
         for mode in modes:
             if mode == "generate" and self.spec.cb_on:
-                # cb replaces the generate buckets with exactly two
-                # programs — prefill + decode step — whatever the
-                # bucket list says; predict stays on buckets
-                self._compile_cb("prefill")
+                # cb replaces the generate buckets with the prefill
+                # ladder and the decode step, whatever the bucket list
+                # says; predict stays on buckets.  One after another: on
+                # a TPU their compiles, and the persistent cache's
+                # fetches, run slower side by side (PERF.md 6, PR 28)
+                for width in self.spec.cb_prefill_widths:
+                    self._compile_cb("prefill", width)
                 self._compile_cb("decode")
                 continue
             for b, p in self.spec.buckets:

@@ -275,6 +275,9 @@ class ContinuousScheduler:
             per = state_bytes(self.engine.net, spec.cb_block_len, dtype)
             self.stats.gauge("cb_slot_state_bytes", per["slot"])
             self.stats.gauge("cb_block_bytes", per["block"])
+            # no request's first token waits on a program's first run
+            self.kv.pools = self.engine.run_cb_prefill_rungs(
+                self.engine.params, self.kv.pools)
         self._stop = False
         self._thread = threading.Thread(target=self._loop,
                                         name="serve-cb", daemon=True)
@@ -620,15 +623,17 @@ class ContinuousScheduler:
                     queued, corr=req.corr, trace=trace_id,
                     parent=parent, plen=req.plen, tenant=req.tenant)
             self.kv.alloc(slot, req.nblocks)
-            toks = np.zeros((1, spec.cb_prefill_len), np.int32)
+            # the prompt's own rung of the prefill ladder
+            width = spec.cb_prefill_width(req.plen)
+            toks = np.zeros((1, width), np.int32)
             toks[0, :req.plen] = req.tokens
             try:
                 with obs.span("scheduler.prefill", corr=req.corr,
                               trace=trace_id, parent=parent,
-                              slot=slot, plen=req.plen,
+                              slot=slot, plen=req.plen, width=width,
                               queue_ms=queued * 1e3):
                     row = self.kv.prefill_target(
-                        slot, spec.cb_prefill_len // spec.cb_block_len)
+                        slot, width // spec.cb_block_len)
                     if self._flying is None:
                         tok0, self.kv.pools = self.engine.run_cb_prefill(
                             params, self.kv.pools, toks, req.plen, row)
@@ -652,7 +657,7 @@ class ContinuousScheduler:
                 req.ticket._fail(RuntimeError(f"prefill failed: {e}"))
                 return admitted
             admitted += 1
-            self.stats.count("cb_prefills")
+            self.stats.observe_cb_prefill(req.plen, width)
             self._slot_req[slot] = req
             self._active[slot] = True
             self._ntoks[slot] = req.plen

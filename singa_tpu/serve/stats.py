@@ -29,7 +29,10 @@ handler, the bench smoke, and tests all see the same semantics:
     pool size), and `cb_live_block_share` (table blocks the paged
     attention kernel walked / slots x table width, averaged over the
     steps that decoded: how much of what a whole-table read would
-    touch this traffic keeps live).
+    touch this traffic keeps live);
+  * `observe_cb_prefill` feeds `cb_prefill_fill_share` (prompt tokens /
+    rows the prefill programs ran: how much of each prompt's rung of
+    the ladder, `ServeSpec.cb_prefill_widths`, was real).
 
 `register_into(registry)` additionally exposes every snapshot field
 through an `obs.MetricsRegistry` pull-time collector (the /metrics
@@ -108,6 +111,9 @@ class ServeStats:
         # continuous batching (serve/scheduler.py)
         self.cb_steps = 0             # scheduler iterations run
         self.cb_prefills = 0          # prefills that ran to their end
+        self.cb_prefill_rows = 0      # prompt tokens they held
+        self.cb_prefill_width_rows = 0  # rows their programs ran: each
+                                        # prompt's rung of the ladder
         self.cb_admit_steps = 0       # iterations that admitted >= 1:
                                       # each held every slot for its
                                       # prefills before decoding
@@ -230,6 +236,14 @@ class ServeStats:
         if self._hist_ttft is not None:
             self._hist_ttft.observe(seconds)
 
+    def observe_cb_prefill(self, plen: int, width: int) -> None:
+        """One prefill that ran to its end: `plen` prompt tokens
+        through the program compiled `width` rows wide."""
+        with self._lock:
+            self.cb_prefills += 1
+            self.cb_prefill_rows += int(plen)
+            self.cb_prefill_width_rows += int(width)
+
     def observe_cb_step(self, active_slots: int, blocks_in_use: int,
                         live_blocks: int = 0) -> None:
         """`live_blocks`: table blocks the step's decode program walked
@@ -323,6 +337,12 @@ class ServeStats:
             return self.cb_block_use_steps / (
                 self.cb_steps * self.cb_blocks_total)
 
+    def cb_prefill_fill_share(self) -> Optional[float]:
+        with self._lock:
+            if self.cb_prefill_width_rows == 0:
+                return None
+            return self.cb_prefill_rows / self.cb_prefill_width_rows
+
     def cb_live_block_share(self) -> Optional[float]:
         with self._lock:
             if self.cb_decode_steps == 0 or self.cb_table_blocks == 0:
@@ -401,7 +421,8 @@ class ServeStats:
                     "shed_best_effort", "rejected", "resumed",
                     "generated_tokens", "batches",
                     "batched_requests", "batch_slots", "cb_steps",
-                    "cb_prefills", "cb_admit_steps",
+                    "cb_prefills", "cb_prefill_rows",
+                    "cb_prefill_width_rows", "cb_admit_steps",
                     "cb_routed_layer_steps", "cb_routed_assignments",
                     "cb_routed_experts_touched",
                     "compiles", "reloads", "reload_failures",
@@ -418,7 +439,8 @@ class ServeStats:
                   "p95_tokens_per_s", "batch_occupancy",
                   "cb_slot_occupancy", "cb_slot_occupancy_recent",
                   "cb_block_utilization", "cb_live_block_share",
-                  "cb_blocks_in_use", "cb_blocks_total",
+                  "cb_prefill_fill_share", "cb_blocks_in_use",
+                  "cb_blocks_total",
                   "cb_slot_state_bytes", "cb_block_bytes")
 
         def collect():
@@ -463,6 +485,7 @@ class ServeStats:
         cb_occ_recent = self.cb_slot_occupancy_recent()
         cb_util = self.cb_block_utilization()
         cb_live = self.cb_live_block_share()
+        cb_fill = self.cb_prefill_fill_share()
         with self._lock:
             out = {
                 "submitted": self.submitted,
@@ -484,6 +507,8 @@ class ServeStats:
                 "batch_slots": self.batch_slots,
                 "cb_steps": self.cb_steps,
                 "cb_prefills": self.cb_prefills,
+                "cb_prefill_rows": self.cb_prefill_rows,
+                "cb_prefill_width_rows": self.cb_prefill_width_rows,
                 "cb_admit_steps": self.cb_admit_steps,
                 "cb_blocks_in_use": self.cb_blocks_in_use,
                 "cb_blocks_total": self.cb_blocks_total,
@@ -537,5 +562,7 @@ class ServeStats:
                                        if cb_util is not None else None)
         out["cb_live_block_share"] = (round(cb_live, 4)
                                       if cb_live is not None else None)
+        out["cb_prefill_fill_share"] = (round(cb_fill, 4)
+                                        if cb_fill is not None else None)
         out["by_tenant"] = self.tenants.snapshot()
         return out

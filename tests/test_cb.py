@@ -72,6 +72,31 @@ def test_spec_parse_cb_grammar():
         ServeSpec.parse("cb_block_len=0")
 
 
+@pytest.mark.parametrize("cap,block_len,want", [
+    (16, 4, (16,)),                   # every tiny test spec: as it was
+    (200, 16, (208,)),                # under the floor, rounded to blocks
+    (256, 16, (256,)),                # at the floor
+    (512, 16, (256, 512)),
+    (1024, 16, (256, 512, 1024)),     # the benchmark's serving cells
+    (768, 16, (256, 512, 768)),       # a cap that is no power of two
+    (300, 16, (256, 304)),
+    (1000, 48, (288, 576, 1008)),     # a block that does not divide 256
+])
+def test_prefill_ladder_from_the_geometry(cap, block_len, want):
+    spec = ServeSpec(buckets=((1, cap),), cb="on", cb_block_len=block_len)
+    widths = spec.cb_prefill_widths
+    assert widths == want
+    assert all(w % block_len == 0 for w in widths)
+    assert list(widths) == sorted(set(widths))
+    assert widths[-1] == spec.cb_prefill_len
+    # the narrowest rung that holds the prompt
+    for rung, below in zip(widths, (0,) + widths):
+        assert spec.cb_prefill_width(below + 1) == rung
+        assert spec.cb_prefill_width(min(rung, cap)) == rung
+    with pytest.raises(ValueError):
+        spec.cb_prefill_width(widths[-1] + 1)
+
+
 # -- paged cache bookkeeping (no compiled programs) --------------------------
 
 def test_kvcache_alloc_free_refcounts():
@@ -218,6 +243,142 @@ def test_overlong_prompt_fast_reject_both_paths(cb_served):
 
 
 # -- EOS retire + slot reuse -------------------------------------------------
+
+# -- the prefill ladder (floor patched down to the tiny net's sizes) ---------
+
+LADDER_PLENS = (3, 4, 5, 7, 8, 9, 15, 16)     # rung-1, rung, rung+1
+
+
+@pytest.fixture(scope="module")
+def ladder_run():
+    """One engine whose ladder is (4, 8, 16), warmed, then a load on
+    both sides of every rung boundary; what the tests below check is
+    recorded here, and the floor is put back before any of them runs."""
+    from singa_tpu import obs
+    from singa_tpu.serve import engine as engine_mod
+    from singa_tpu.serve.scheduler import ContinuousScheduler
+
+    net, params = _net_and_params()
+    spec = ServeSpec(buckets=((1, SEQ),), max_new_tokens=6,
+                     temperature=0.0, request_timeout_s=60.0,
+                     cb="on", cb_slots=4, cb_block_len=4)
+    got = {"net": net, "params": params, "spec": spec}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "CB_PREFILL_FLOOR", 4)
+        got["widths"] = spec.cb_prefill_widths
+        engine = InferenceEngine(net, spec, params=params,
+                                 log_fn=lambda s: None)
+        got["warm_compiles"] = engine.warmup()
+        got["programs"] = sorted(k[0] for k in engine._compiled)
+        rng = np.random.default_rng(3)
+        got["prompts"] = [rng.integers(1, VOCAB, p).astype(np.int32)
+                          for p in LADDER_PLENS]
+        sched = ContinuousScheduler(engine, log_fn=lambda s: None).start()
+        try:
+            with obs.session(obs.ObsSpec()) as o:
+                tickets = [sched.submit(p) for p in got["prompts"]]
+                got["served"] = [t.wait(timeout=120)["tokens"]
+                                 for t in tickets]
+                got["prefills"] = {
+                    e["args"]["plen"]: e["args"]["width"]
+                    for e in o.tracer.events()
+                    if e["name"] == "scheduler.prefill"}
+            got["compiles_after"] = engine.stats.compiles
+            got["snapshot"] = engine.stats.snapshot()
+            for width in (12, 32):     # a block multiple, and no rung
+                with pytest.raises(ValueError, match="rungs"):
+                    engine.dispatch_cb_prefill(
+                        engine.params, sched.kv.pools,
+                        np.zeros((1, width), np.int32), 1,
+                        np.zeros((width // 4,), np.int32))
+        finally:
+            sched.stop()
+        got["engine"] = engine
+        got["cap_is_default"] = engine._compile_cb("prefill") is \
+            engine._compile_cb("prefill", spec.cb_prefill_len)
+    return got
+
+
+@pytest.mark.parametrize("i", range(len(LADDER_PLENS)),
+                         ids=[f"plen{p}" for p in LADDER_PLENS])
+def test_ladder_tokens_equal_generates_around_every_rung(ladder_run, i):
+    prompt = ladder_run["prompts"][i]
+    want = np.asarray(generate(ladder_run["net"], ladder_run["params"],
+                               prompt[None], 6))[0].tolist()
+    assert ladder_run["served"][i] == want, f"plen={prompt.size}"
+
+
+def test_warmup_compiles_every_rung_and_a_mixed_load_none(ladder_run):
+    assert ladder_run["widths"] == (4, 8, 16)
+    assert ladder_run["warm_compiles"] == len(ladder_run["widths"]) + 1
+    assert ladder_run["programs"] == ["cb_decode", "cb_prefill",
+                                      "cb_prefill_4", "cb_prefill_8"]
+    assert ladder_run["compiles_after"] == ladder_run["warm_compiles"]
+    assert ladder_run["cap_is_default"]
+
+
+def test_fill_share_and_span_width_by_hand(ladder_run):
+    # each prompt at the narrowest rung that holds it
+    assert ladder_run["prefills"] == {3: 4, 4: 4, 5: 8, 7: 8, 8: 8,
+                                      9: 16, 15: 16, 16: 16}
+    snap = ladder_run["snapshot"]
+    assert snap["cb_prefills"] == 8
+    assert snap["cb_prefill_rows"] == sum(LADDER_PLENS) == 67
+    assert snap["cb_prefill_width_rows"] == 4 + 4 + 8 + 8 + 8 + 16 * 3
+    assert snap["cb_prefill_fill_share"] == round(67 / 80, 4)
+    from singa_tpu.obs.metrics import MetricsRegistry
+    reg = MetricsRegistry()
+    ladder_run["engine"].stats.register_into(reg)
+    text = reg.render_prometheus()
+    assert "singa_serve_cb_prefill_fill_share 0.8375" in text
+    assert "singa_serve_cb_prefill_rows_total 67" in text
+
+
+def test_build_cb_prefill_without_a_width_is_the_caps_program(ladder_run):
+    """`tests/benchmark/test_bench_preflight.py` compiles the prefill
+    it gets from `_build_cb_prefill()` at the cap's shapes."""
+    import jax.numpy as jnp
+    from singa_tpu.serve.kvcache import init_pools
+    engine, spec = ladder_run["engine"], ladder_run["spec"]
+    pools = jax.eval_shape(lambda: init_pools(
+        ladder_run["net"], spec.cb_pool_blocks, spec.cb_block_len,
+        jnp.float32))
+
+    def lowered(fn, width):
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        return jax.jit(fn).lower(
+            ladder_run["params"], pools, i32(1, width), i32(),
+            i32(width // spec.cb_block_len),
+            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
+
+    cap = spec.cb_prefill_len
+    assert lowered(engine._build_cb_prefill(), cap) == \
+        lowered(engine._build_cb_prefill(cap), cap)
+    assert "jit_cb_prefill" in lowered(engine._build_cb_prefill(8), 8)
+    assert ServeSpec().cb_prefill_widths == (ServeSpec().cb_prefill_len,)
+
+
+@pytest.mark.parametrize("on_device", [False, True])
+def test_decode_args_stay_on_the_host_until_the_call(cb_served, on_device):
+    """The step's small inputs reach the compiled call as int32 host
+    arrays (its own transfer moves them: no `jnp.asarray` on the
+    per-token path); tokens the step before left on the device go in as
+    they are."""
+    import jax.numpy as jnp
+    _, _, engine, _ = cb_served
+    spec = engine.spec
+    toks = np.arange(spec.cb_slots, dtype=np.int64)
+    given = jnp.asarray(toks, jnp.int32) if on_device else toks
+    tokens, ntoks, tables, key = engine._cb_decode_args(
+        given, np.ones(spec.cb_slots, np.int64),
+        np.zeros((spec.cb_slots, spec.cb_blocks_per_slot), np.int64))
+    assert (tokens is given) if on_device else \
+        (isinstance(tokens, np.ndarray) and tokens.dtype == np.int32)
+    assert np.array_equal(np.asarray(tokens), toks)
+    for arr in (ntoks, tables):
+        assert isinstance(arr, np.ndarray) and arr.dtype == np.int32
+    assert isinstance(key, np.ndarray) and key.dtype == np.uint32
+
 
 def test_eos_retires_slot_mid_batch_and_slot_is_reused():
     net, params = _net_and_params()

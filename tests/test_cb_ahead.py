@@ -147,6 +147,35 @@ def test_a_prefill_goes_behind_the_step_in_flight(lm):
                               "fetch_cb_prefill"]
 
 
+@pytest.mark.parametrize("rung,plens", [(4, (3, 4, 5)), (8, (7, 8, 9)),
+                                        (16, (14, 15, 16))])
+def test_a_prefill_behind_a_step_takes_its_prompts_rung(lm, monkeypatch,
+                                                        rung, plens):
+    """The ladder (serve/engine.py `cb_prefill_widths`; here 4, 8, 16)
+    through the full house: two requests fill it, and the three
+    admitted behind steps in flight lie on both sides of a rung."""
+    from singa_tpu.serve import engine as engine_mod
+    monkeypatch.setattr(engine_mod, "CB_PREFILL_FLOOR", 4)
+    engine = _engine(lm, 2)
+    assert engine.spec.cb_prefill_widths == (4, 8, 16)
+    log = _logged(engine)
+    prompts = _prompts(10 + rung, (6, 2) + plens)
+    news = [3, 5, 4, NEW, 6]
+    outs = _serve(engine, prompts, news, hold=True)
+    for p, n, out in zip(prompts, news, outs):
+        assert out["tokens"] == _ref(lm, p, n), f"plen={p.size}"
+    names = [n for n, _ in log]
+    assert names.count("run_cb_prefill") == 2      # the two that filled it
+    behind = [(a[2].shape[1], a[3]) for k, (n, a) in enumerate(log)
+              if n == "dispatch_cb_prefill"
+              and names[k - 1] != "run_cb_prefill"]
+    assert behind == [(engine.spec.cb_prefill_width(p), p) for p in plens]
+    assert {w for w, _ in behind} == ({rung, 2 * rung} if rung < 16
+                                      else {16})
+    assert engine.stats.cb_prefill_width_rows == 8 + 4 + sum(
+        w for w, _ in behind)
+
+
 @pytest.mark.parametrize("slots", [1, 2])
 def test_an_eos_nobody_foresaw_drops_the_token_made_too_many(lm, slots):
     prompts = _prompts(8, (5, 2, 9, 4))

@@ -251,6 +251,51 @@ def test_cb_greedy_tokens_equal_generates(lm):
         assert list(out) == list(want)
 
 
+RUNG_PLENS = (3, 4, 5, 7, 8, 9)      # rung-1, rung, rung+1 of (4, 8, 16)
+
+
+@pytest.fixture(scope="module")
+def ladder_served(lm):
+    """The prefill ladder (serve/engine.py `cb_prefill_widths`) with its
+    floor patched down to one block: prompts on both sides of the 4 and
+    8 rungs through two slots, so the later ones are admitted behind a
+    step in flight.  The floor is put back before any test runs."""
+    from singa_tpu.serve import engine as engine_mod
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(0, CFG["vocab_size"], p).astype(np.int32)
+               for p in RUNG_PLENS]
+    with pytest.MonkeyPatch.context() as mp, \
+            jax.default_matmul_precision("highest"):
+        mp.setattr(engine_mod, "CB_PREFILL_FLOOR", BL)
+        engine = _engine(lm, 2)
+        assert engine.spec.cb_prefill_widths == (4, 8, 16)
+        # compiled before anything queues: a request's deadline runs
+        # while `start()` compiles and runs the rungs
+        assert engine.warmup() == 4
+        sched = ContinuousScheduler(engine, log_fn=lambda *a, **k: None)
+        tickets = [sched.submit(p, max_new=6) for p in prompts]
+        sched.start()
+        try:
+            served = [t.wait(timeout=300)["tokens"] for t in tickets]
+        finally:
+            sched.stop()
+    return prompts, served, engine.stats.snapshot()
+
+
+@pytest.mark.parametrize("i", range(len(RUNG_PLENS)),
+                         ids=[f"plen{p}" for p in RUNG_PLENS])
+def test_ladder_tokens_equal_generates_around_every_rung(lm, ladder_served,
+                                                         i):
+    """KDA state, MLA latent and routed experts at a prefill width that
+    follows the prompt."""
+    net, params, _ = lm
+    prompts, served, snap = ladder_served
+    want = np.asarray(generate(net, params, prompts[i][None], 6))[0]
+    assert list(served[i]) == list(want), f"plen={prompts[i].size}"
+    assert snap["cb_prefill_width_rows"] == 4 + 4 + 8 + 8 + 8 + 16
+    assert snap["cb_prefill_fill_share"] == round(sum(RUNG_PLENS) / 48, 4)
+
+
 def test_static_path_left_padding_matches_the_unpadded_prompt(lm):
     """The bucket path LEFT-pads: pads leave the recurrence alone and
     read as the zeros before a sequence's start."""

@@ -12,6 +12,7 @@ the profiler traces are CPU traces of a few spans each."""
 import glob
 import os
 import threading
+import time
 
 import jax
 import numpy as np
@@ -203,6 +204,12 @@ def test_one_step_is_spanned_from_inside(cb_served):
     with obs.session(obs.ObsSpec()) as o:
         with obs.span("client.request", corr="req-1") as root:
             out = server.generate(PROMPT)
+        # the last token is out before its step's span closes
+        deadline = time.monotonic() + 10
+        while (sum(e["name"] == "scheduler.step"
+                   for e in o.tracer.events()) < 5
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         events = o.tracer.events()
     assert len(out["tokens"]) == 6
     kids = _children(events)

@@ -7,6 +7,8 @@ The kernel runs interpreted here (CPU), at geometries kept tiny: the
 Mosaic compile at the serving cell's real geometry is
 tests/benchmark/test_bench_preflight.py's."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -170,6 +172,11 @@ def test_cb_live_block_share_counts_what_the_kernel_walks():
             for n in range(plen, plen + new - 1):
                 walked += n // bl + 1 + (slots - 1)
                 steps += 1
+        # a request's last token is out before its step is counted
+        deadline = time.monotonic() + 10
+        while (engine.stats.cb_decode_steps < steps
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         snap = server.snapshot()
         reg = MetricsRegistry()
         engine.stats.register_into(reg)

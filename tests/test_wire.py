@@ -588,6 +588,10 @@ def test_wire_listener_death_does_not_lose_inflight_stream(tiny_lm):
                    if "token" in ev]
 
             h0 = fleet.router.handle_for("engine-0")
+            # a step slow enough that the stream is still in flight
+            # when the listener dies (8 steps take a few ms otherwise,
+            # and whether any were left was a matter of timing)
+            a.engine.set_stall(0.02)
             stream = fleet.generate_stream(prompt, max_new=8)
             seen, killed = [], False
             for ev in stream:

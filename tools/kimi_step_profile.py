@@ -72,7 +72,9 @@ def main() -> int:
     for s in range(slots):
         kv.alloc(s, kv.blocks_for(args.live + args.steps + 2))
     params = engine.params
-    toks = np.zeros((1, spec.cb_prefill_len), np.int32)
+    # the rung a cell's scheduler gives a prompt of this length
+    width = spec.cb_prefill_width(args.plen)
+    toks = np.zeros((1, width), np.int32)
     toks[0, :args.plen] = np.random.default_rng(0).integers(
         0, cell.config["vocab_size"], args.plen)
     out = os.path.join(ROOT, ".bench_trace", "kimi_step_profile")
@@ -81,8 +83,7 @@ def main() -> int:
         for s in range(4):
             _, kv.pools = engine.run_cb_prefill(
                 params, kv.pools, toks, args.plen,
-                kv.prefill_target(s, spec.cb_prefill_len
-                                  // spec.cb_block_len))
+                kv.prefill_target(s, width // spec.cb_block_len))
         jax.block_until_ready(kv.pools)
         return 4
 
@@ -96,7 +97,8 @@ def main() -> int:
         return args.steps
 
     prefills(), decodes()                            # warm
-    print(f"prefill, {args.plen} real rows of {spec.cb_prefill_len}:")
+    print(f"prefill, {args.plen} real rows of {width} "
+          f"(rungs {spec.cb_prefill_widths}):")
     _trace(out, prefills)
     print(f"decode, {slots} busy slots of {args.live} tokens:")
     _trace(out, decodes)

@@ -1,12 +1,14 @@
-"""What `cb_live_block_share` reads under one of the benchmark's serving
-cells (a builder's tool; the benchmark does not report the counter):
+"""What `cb_live_block_share` and `cb_prefill_fill_share` read under one
+of the benchmark's serving cells (a builder's tool; the benchmark does
+not report the counters):
 
     python tools/live_block_share.py --workload serve-chat-r80 --seed 1 \\
         --seconds 45
 
 runs the cell as `benchmark/run.py` does and prints, when the runner
 stops its scheduler, the counters of that engine's `ServeStats` (warm-up
-requests included: 4 decode steps of some 11,000)."""
+requests included: 4 decode steps of some 11,000, and two 8-token
+prompts among the window's prefills)."""
 import json
 import os
 import sys
@@ -22,7 +24,9 @@ _stop = ContinuousScheduler.stop
 def stop(self, *args, **kwargs):
     snap = self.stats.snapshot()
     print(json.dumps({"tool": "live_block_share", **{
-        k: snap[k] for k in ("cb_live_block_share", "cb_slot_occupancy",
+        k: snap[k] for k in ("cb_live_block_share", "cb_prefill_fill_share",
+                             "cb_prefills", "cb_prefill_rows",
+                             "cb_prefill_width_rows", "cb_slot_occupancy",
                              "cb_block_utilization", "cb_steps")},
         "cb_decode_steps": self.stats.cb_decode_steps}), flush=True)
     return _stop(self, *args, **kwargs)
