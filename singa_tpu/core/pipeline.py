@@ -37,8 +37,7 @@ waiting.  Both are 0 in steady state; a lag that only grows means the
 loop is open (rollout dead, every canary rejected, or the fleet
 wedged) — `spec.lag_alarm_s` logs it loudly.
 
-Safety invariants (tested in tests/test_pipeline_mode.py, measured in
-`bench.py --pipeline-smoke`):
+Safety invariants (tested in tests/test_pipeline_mode.py):
   * a DIVERGED/NONFINITE window never reaches disk (save refused), a
     suspect one never passes the canary gate — so a bad step is never
     served by more than the canary, and traffic never regresses below

@@ -264,7 +264,7 @@ class Trainer:
     #: AlexNet gate workload (batch 8192, bf16) steps in 121.5 ms with
     #: the option and 127.8 ms without.  The sweep that picked 112MB
     #: (96MB 136 -> 128 ms, 120MB slightly worse, 128MB spilling to
-    #: 2.8 s/step; tools/mfu_ab.py) and the transformer's regression
+    #: 2.8 s/step; BASELINE.md) and the transformer's regression
     #: under this budget (0.201 -> 0.179 MFU) date from an earlier
     #: installation and were not re-measured.  `auto` applies the
     #: option only to nets whose widest convolution has >= 96 filters

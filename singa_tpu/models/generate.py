@@ -336,8 +336,7 @@ def generate(net: NeuralNet, params, prompt,
     top-k first).  After `eos_id` is produced, a sequence keeps
     emitting `eos_id`.  `max_len` over-allocates the KV cache beyond
     prompt+new (the tail is mask-ignored) — lets callers fix the cache
-    geometry across runs of different lengths (bench.py isolates
-    prefill this way)."""
+    geometry across runs of different lengths."""
     if key is None:
         key = jax.random.PRNGKey(0)
     prompt = jnp.asarray(prompt, jnp.int32)

@@ -35,7 +35,7 @@ pipeline mode) consume.  Four rules:
 
 CLI: `--obs on|off` plus `--obs_spec 'trace=path,events=path,
 metrics_period_s=5'` (main.py), mirroring `--health_spec`.  Artifacts:
-a Chrome trace JSON (Perfetto-loadable next to `utils/profiler`
+a Chrome trace JSON (Perfetto-loadable next to `jax.profiler`
 device traces), a JSONL event log, and flight-recorder dumps
 (`flightrec.py`).  `collect.py` merges per-process buffers into one
 fleet trace.  See docs/OBSERVABILITY.md.

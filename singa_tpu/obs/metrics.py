@@ -271,7 +271,7 @@ class MetricsRegistry:
 
 def parse_prometheus(text: str) -> Dict[str, float]:
     """Minimal parser for the text exposition format — enough for
-    tests and the smoke script to assert /metrics agrees with /stats.
+    tests to assert /metrics agrees with /stats.
     Returns {sample_name_with_labels: value}; raises ValueError on a
     line that is neither a comment nor `name[{labels}] value`."""
     out: Dict[str, float] = {}

@@ -31,18 +31,16 @@ NEG_INF = -1e30
 
 # Tuned flash block geometry.  (512, 512) won the S=1024 sweep
 # (BASELINE.md "Explored and rejected": strided 1024 ties but pays
-# transposes); the long-S rows come
-# from tools/longctx_sweep.py.  `set_flash_blocks` pins an override for
-# in-process A/B sweeps.
+# transposes); the long-S rows come from BASELINE.md "Long context
+# (round-4 kernel work)".  `set_flash_blocks` pins an override (the
+# training cell pins (256, 512) through it).
 _FLASH_BLOCK_OVERRIDE: Optional[tuple] = None
 
-# Causal kernels CAN compile two compute bodies: fully-visible blocks
-# (no mask select) and diagonal-partial ones.  Measured on v5e at
-# S=4096 (tools/longctx_sweep.py, in-process A/B): the split is a wash
-# at 512x512 (-0.3%, noise) and a 55% REGRESSION at 512x1024 (536 vs
-# 347 ms/step) — the duplicated body defeats Mosaic's pipelining — so
-# it stays off; kept A/B-able for future geometries.
-MASK_SPLIT = False
+# Causal kernels compile ONE compute body, masked on every visited
+# block.  A second, unmasked body for fully-visible blocks measured a
+# wash at 512x512 and -55% at 512x1024 on v5e at S=4096 (the duplicated
+# body defeats Mosaic's pipelining): BASELINE.md, same section,
+# "Measured negatives".
 
 
 def set_flash_blocks(override: Optional[tuple]) -> None:
@@ -55,7 +53,7 @@ def set_flash_blocks(override: Optional[tuple]) -> None:
 def flash_blocks(seq_len: int) -> tuple:
     """Tuned (block_q, block_k) for a sequence length.
 
-    v5e, in-process in-net A/B (tools/longctx_sweep.py, round 4):
+    v5e, in-process in-net A/B (BASELINE.md, round 4):
     bk=1024 wins at every S >= 1024 — the fatter KV block halves the
     per-block VPU overhead passes (rescale/max bookkeeping) per score —
     by +1.1% (S=1024), +10% (S=4096), +12% (S=8192) over 512x512;
@@ -390,18 +388,7 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                 preferred_element_type=jnp.float32)
             m_ref[:, h:h + 1] = m_new
 
-    if causal and MASK_SPLIT:
-        # fully-visible blocks (max kpos <= min qpos) skip the mask
-        full = (ik + 1) * bk - 1 <= iq * bq
-
-        @pl.when(full)
-        def _():
-            compute(False)
-
-        @pl.when(jnp.logical_not(full) & (ik * bk <= (iq + 1) * bq - 1))
-        def _():
-            compute(True)
-    elif causal:
+    if causal:
         @pl.when(ik * bk <= (iq + 1) * bq - 1)
         def _():
             compute(True)
@@ -458,17 +445,7 @@ def _packed_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    if causal and MASK_SPLIT:
-        full = (ik + 1) * bk - 1 <= iq * bq
-
-        @pl.when(full)
-        def _():
-            compute(False)
-
-        @pl.when(jnp.logical_not(full) & (ik * bk <= (iq + 1) * bq - 1))
-        def _():
-            compute(True)
-    elif causal:
+    if causal:
         @pl.when(ik * bk <= (iq + 1) * bq - 1)
         def _():
             compute(True)
@@ -526,17 +503,7 @@ def _packed_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    if causal and MASK_SPLIT:
-        full = (ik + 1) * bk - 1 <= iq * bq
-
-        @pl.when(full)
-        def _():
-            compute(False)
-
-        @pl.when(jnp.logical_not(full) & (ik * bk <= (iq + 1) * bq - 1))
-        def _():
-            compute(True)
-    elif causal:
+    if causal:
         @pl.when(ik * bk <= (iq + 1) * bq - 1)
         def _():
             compute(True)

@@ -37,8 +37,11 @@ touch HBM (the net marks these chains — see NeuralNet._fuse_relu_lrn).
 A hand-written Pallas kernel for this chain was tried and measured
 *slower* (43.7 vs 36.3 ms/step): XLA lays conv activations out
 batch-in-lanes here, and the (N·H·W, C) view a row-blocked kernel
-needs forces full relayout copies at the kernel boundary.  The jnp
-form lets XLA keep its layouts and fuse around the custom_vjp.
+needs forces full relayout copies at the kernel boundary.  A
+batch-in-lanes kernel lost as well (13 ms forward on norm1 against
+6.4 ms for XLA's fused band-dot: the window sum costs ~12 VPU passes
+when done with sublane shifts; PERF.md 7).  The jnp form lets XLA
+keep its layouts and fuse around the custom_vjp.
 """
 
 from __future__ import annotations
@@ -86,16 +89,12 @@ def _p_of_s(s: jnp.ndarray, local_size: int, alpha: float, beta: float,
     return n, _pow_neg_beta(n, beta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def _lrn_nhwc(x, local_size, alpha, beta, knorm, relu, impl="jnp"):
-    return _lrn_nhwc_fwd(x, local_size, alpha, beta, knorm, relu, impl)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _lrn_nhwc(x, local_size, alpha, beta, knorm, relu):
+    return _lrn_nhwc_fwd(x, local_size, alpha, beta, knorm, relu)[0]
 
 
-def _lrn_nhwc_fwd(x, local_size, alpha, beta, knorm, relu, impl="jnp"):
-    if impl != "jnp":
-        from .lrn_pallas import lrn_fwd_pallas
-        return lrn_fwd_pallas(x, local_size, alpha, beta, knorm, relu,
-                              interpret=impl == "interpret"), x
+def _lrn_nhwc_fwd(x, local_size, alpha, beta, knorm, relu):
     a = jnp.maximum(x, jnp.zeros((), x.dtype)) if relu else x
     s = _window_sum(a, local_size)
     _, p = _p_of_s(s, local_size, alpha, beta, knorm)
@@ -105,17 +104,13 @@ def _lrn_nhwc_fwd(x, local_size, alpha, beta, knorm, relu, impl="jnp"):
     return a * p, x
 
 
-def _lrn_nhwc_bwd(local_size, alpha, beta, knorm, relu, impl, res, g):
+def _lrn_nhwc_bwd(local_size, alpha, beta, knorm, relu, res, g):
     # d/da of y_i = a_i·n_i^-β with n = k + (α/L)·B(a²):
     #   da = g·n^-β − 2β(α/L)·a·Bᵀ(g·a·n^{-β-1})
     # (B symmetric, so Bᵀ = B); matches the reference's closed form
     # (layer.cc:366-377).  With relu fused, a = max(x, 0) is recomputed
     # from the residual x (register op) and da is masked by x > 0.
     x = res
-    if impl != "jnp":
-        from .lrn_pallas import lrn_bwd_pallas
-        return (lrn_bwd_pallas(x, g, local_size, alpha, beta, knorm, relu,
-                               interpret=impl == "interpret"),)
     a = jnp.maximum(x, jnp.zeros((), x.dtype)) if relu else x
     s = _window_sum(a, local_size)
     n, p = _p_of_s(s, local_size, alpha, beta, knorm)
@@ -135,25 +130,12 @@ def _lrn_nhwc_bwd(local_size, alpha, beta, knorm, relu, impl, res, g):
 _lrn_nhwc.defvjp(_lrn_nhwc_fwd, _lrn_nhwc_bwd)
 
 
-def _impl_for(x) -> str:
-    """Kernel selection for the NHWC path.  A Pallas batch-in-lanes
-    kernel (ops/lrn_pallas.py) was measured AND REJECTED on chip: the
-    channel-window sum needs ~12 VPU passes over the activation when
-    done with sublane shifts (13ms fwd on norm1 vs XLA's 6.4ms fused
-    band-dot, which rides the MXU 5-tap conv emitter), so the jnp band
-    matmul is the production path; the kernel stays as the
-    interpret-mode oracle for the closed-form backward
-    (tests/test_ops.py) and for future re-measurement."""
-    return "jnp"
-
-
 def lrn(x: jnp.ndarray, local_size: int = 5, alpha: float = 1.0,
         beta: float = 0.75, knorm: float = 1.0,
         layout: str = "NCHW") -> jnp.ndarray:
     """Cross-channel LRN; x (N, C, H, W) or (N, H, W, C) per layout."""
     if layout == "NHWC":
-        return _lrn_nhwc(x, local_size, alpha, beta, knorm, False,
-                         _impl_for(x))
+        return _lrn_nhwc(x, local_size, alpha, beta, knorm, False)
     half = local_size // 2
     sq = jnp.square(x.astype(jnp.float32))
     dims = (1, local_size, 1, 1)
@@ -169,7 +151,6 @@ def relu_lrn(x: jnp.ndarray, local_size: int = 5, alpha: float = 1.0,
     """(optionally ReLU, then) cross-channel LRN — the fused form the
     net builder selects for conv→relu→lrn chains (NHWC only)."""
     if layout == "NHWC":
-        return _lrn_nhwc(x, local_size, alpha, beta, knorm, relu,
-                         _impl_for(x))
+        return _lrn_nhwc(x, local_size, alpha, beta, knorm, relu)
     a = jnp.maximum(x, jnp.zeros((), x.dtype)) if relu else x
     return lrn(a, local_size, alpha, beta, knorm, layout)

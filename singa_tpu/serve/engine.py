@@ -1057,7 +1057,7 @@ class InferenceEngine:
         """CostWatch sweep: re-harvest `cost_analysis()` off every
         already-compiled executable.  Reads cached objects only —
         never lowers or compiles — so `stats.compiles` is unchanged
-        (the --perf-smoke gate).  Returns programs harvested."""
+        (tests/test_perf_obs.py).  Returns programs harvested."""
         with self._compile_lock:
             items = list(self._compiled.items())
         for key, compiled in items:

@@ -148,7 +148,7 @@ class RolloutController:
         self._extends: int = 0
         self._pre: Dict[str, Any] = {}          # canary stats pre-reload
         self._baseline_p95: Optional[float] = None
-        # outcome counters (fleet snapshot / BENCH_pr7.json)
+        # outcome counters (fleet snapshot)
         self.canaries = 0
         self.canary_restarts = 0
         self.promotions = 0

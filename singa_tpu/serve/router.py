@@ -1000,7 +1000,6 @@ class Router:
         # deques behind windowed() are too big for the hot path)
         self._hedge_cache: float = float(self.spec.hedge_max_s)
         self._hedge_cache_t: float = 0.0
-        self._pressure: float = 0.0
         self._pressure_by_tenant: Dict[str, float] = {}
         self._pressure_t: float = 0.0
         self._probe_stop = threading.Event()
@@ -1463,7 +1462,6 @@ class Router:
         now = time.monotonic()
         if now - self._pressure_t > 0.5:
             win = self.stats.windowed(5.0)
-            self._pressure = float(win["capacity_shed_rate"])
             self._pressure_by_tenant = dict(
                 win.get("capacity_shed_rate_by_tenant") or {})
             self._pressure_t = now

@@ -4,8 +4,8 @@ decode batch over the paged KV cache.
 The static MicroBatcher ties a request's fate to its batch: the
 compiled bucket program decodes all `max_new_tokens` for every row,
 so one long generation holds every co-batched short request hostage
-(BENCH_pr5's p50 7.6 ms vs p95 108.8 ms is exactly that head-of-line
-gap).  Here a request occupies one of `cb_slots` SLOTS instead:
+(head-of-line blocking).  Here a request occupies one of `cb_slots`
+SLOTS instead:
 
   admit    a free slot at ANY decode step — reserve its worst-case
            blocks (ceil((plen + max_new) / block_len), so pool

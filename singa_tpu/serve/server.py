@@ -7,8 +7,8 @@ JSON, calls `MicroBatcher.submit` (or `ContinuousScheduler.submit`
 under `cb=on`), and parks on the request's `Ticket` (or drains its
 `StreamTicket`); all device work happens on the single dispatch
 thread through compiled programs.  In-process callers
-(`InferenceServer.generate` / `.predict`, used by tests and the bench
-smoke) take the same submit/wait path, so both frontends share one
+(`InferenceServer.generate` / `.predict`, used by tests and `serve
+--smoke`) take the same submit/wait path, so both frontends share one
 admission-control, batching, and stats story.
 
 Endpoints:

@@ -5,7 +5,7 @@ One `ServeStats` instance is shared by the `InferenceEngine` (compile /
 reload accounting), the `MicroBatcher` (admission / batching / latency),
 and the `InferenceServer` (the /stats endpoint).  All mutation goes
 through the lock; `snapshot()` is the single read surface, so the HTTP
-handler, the bench smoke, and tests all see the same semantics:
+handler and tests see the same semantics:
 
   * latency quantiles (p50/p95) come from a bounded reservoir of the
     most recent completions — a serving dashboard number, not an exact
@@ -65,7 +65,7 @@ class ServeStats:
         # the total-latency split (observe_request): time in queue
         # before dispatch/admission vs time being served, plus the
         # per-request generated-token count and tok/s — the
-        # attribution BENCH_pr5's bare p50/p95 gap was missing
+        # attribution a bare p50/p95 gap lacks
         self._queue_waits: deque = deque(
             maxlen=max(int(latency_window), 1))
         self._services: deque = deque(maxlen=max(int(latency_window), 1))
@@ -476,7 +476,7 @@ class ServeStats:
             "submit to first token (continuous batching)")
 
     def snapshot(self) -> Dict[str, Any]:
-        """JSON-ready view for /stats and BENCH_pr5.json."""
+        """JSON-ready view for /stats."""
         p50, p95, p99 = (self.latency_quantile(0.50),
                          self.latency_quantile(0.95),
                          self.latency_quantile(0.99))

@@ -66,9 +66,8 @@ carrying several lines), so both surfaces share one flush story.
 
 `singa_wire_*` metrics split serialization time out of the stage
 taxonomy: `ser/deser_seconds_total` for the binary codec,
-`json_ser/json_deser_seconds_total` for the JSON surface — the
-A/B proof of where `bench.py --transport-smoke`'s saved time comes
-from.  Fault site `wire.frame` (utils/faults.py) drops, corrupts, or
+`json_ser/json_deser_seconds_total` for the JSON surface — where
+the binary transport's saved time comes from.  Fault site `wire.frame` (utils/faults.py) drops, corrupts, or
 tears one outbound frame; all three degrade to a counted reconnect
 or a per-request failure the Router's retry/failover machinery
 absorbs.

@@ -10,8 +10,7 @@ test stream* (same templates, independent noise/labels — the model
 must generalize, not memoize batches), and a noise level set so the
 net starts at chance and has to learn.
 
-Writes CONVERGENCE.json at the repo root; bench.py folds its numbers
-into the judged stdout line.  Two wall-clocks are reported:
+Writes CONVERGENCE.json at the repo root.  Two wall-clocks are reported:
 `time_to_99_seconds` from the start of run() (includes XLA compiles —
 what a user experiences) and `train_time_to_99_seconds` counting every
 train chunk and eval at warm-execution speed (programs pre-compiled

@@ -3,4 +3,3 @@ from .faults import (Backoff, CorruptRecord, FaultError, FaultSchedule,
                      FaultSpec, Preemption, inject, maybe_fault)
 from .health import (HealthMonitor, HealthSpec, NumericDivergence,
                      delta_health, health_probes)
-from .profiler import StepTimer, flops_of

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-Every process entry point (`singa_tpu.main`, `bench.py`,
+Every process entry point (`singa_tpu.main`, `benchmark/run.py`,
 `chip_smoke.py`, `__graft_entry__`, the convergence tool, the timing
 scripts under `tools/`) calls `enable()` before it compiles anything,
 so a second process compiling the same programs reads them back

@@ -15,9 +15,9 @@ Correctness anchors:
   * the traffic generator is open-loop: arrivals never wait on
     completions.
 
-Cost control: everything here runs on stub handles and fabricated
-signals — no compiled programs; the one real-fleet traffic run lives
-in `bench.py --traffic-smoke`."""
+Cost control: everything but the last test runs on stub handles and
+fabricated signals — no compiled programs; the last one rides a real
+one-engine fleet through a flash crowd and back."""
 
 import tempfile
 import threading
@@ -539,3 +539,98 @@ def test_traffic_streams_with_slow_reader():
     tot = rep["totals"]
     assert tot["completed"] == tot["offered"] and tot["failed"] == 0
     assert events["n"] == 3 * tot["completed"]
+
+
+# -- the real fleet: capacity follows the load, a drain drops nothing --------
+
+def test_real_fleet_grows_under_flash_shrinks_when_quiet_and_drains():
+    """One real engine (2 slots, a 4-deep queue, a throttled step)
+    under an open-loop flash crowd: the autoscaler answers the sheds
+    with a second compiled engine, retires one again once the window
+    is quiet, and nothing but `Overloaded` ever reaches a client.
+    Then an engine holding a live slow-reader stream is retired with
+    drain=True: every token and the done event arrive before the
+    member leaves."""
+    from singa_tpu.serve import EngineFleet, ServeSpec
+    from test_fleet import SEQ, _net_and_params
+
+    net, params = _net_and_params()
+    spec = ServeSpec(buckets=((2, SEQ),), max_new_tokens=8,
+                     batch_window_s=0.002, request_timeout_s=30.0,
+                     queue_capacity=4, cb="on", cb_slots=2,
+                     cb_block_len=4)
+    fleet = EngineFleet.local(
+        net, spec, 1, params=params,
+        router_spec=RouterSpec(probe_period_s=0.05,
+                               quarantine_after=3),
+        log_fn=lambda s: None)
+    fleet.start()
+    # 8 steps x 10 ms a request, 2 slots: ~25 req/s an engine, well
+    # under the flash's 120
+    fleet.router.handle_for("engine-0").engine.set_stall(0.01)
+    scaler = AutoScaler(
+        fleet, spec=AutoScaleSpec(
+            slo_p95_ms=5000.0, max_shed_rate=0.02, min_engines=1,
+            max_engines=2, cooldown_s=0.2, window_s=1.0, tick_s=0.05,
+            quiet_ticks=5, drain_timeout_s=20.0),
+        log_fn=lambda s: None)
+    scaler.start()
+    try:
+        gen = TrafficGen(lambda toks: fleet.generate(toks.tolist()),
+                         vocab=64, seed=0, max_outstanding=512,
+                         log_fn=lambda s: None)
+        rep = gen.run([steady("flash", 1.5, 120.0, prompt_lens=(4,)),
+                       steady("quiet", 1.0, 2.0, prompt_lens=(4,))],
+                      drain_timeout_s=30.0)
+        tot = rep["totals"]
+        assert tot["shed"] >= 1              # the flash did overflow
+        assert tot["failed"] == 0, tot["errors"][:3]
+        assert tot["dropped_harness"] == 0
+        stop = time.monotonic() + 30.0       # a grow compiles 2 programs
+        while scaler.scale_ups < 1 and time.monotonic() < stop:
+            time.sleep(0.05)
+        assert scaler.scale_ups >= 1
+        stop = time.monotonic() + 30.0
+        while scaler.scale_downs < 1 and time.monotonic() < stop:
+            time.sleep(0.05)
+        assert scaler.scale_downs >= 1
+        _join_action(scaler, timeout=25.0)
+        assert len(fleet.router.names()) == 1
+    finally:
+        scaler.stop()
+    try:
+        # -- drain: retire the engine that holds a live stream --------
+        fleet.grow()
+        for n in fleet.router.names():
+            fleet.router.handle_for(n).engine.set_stall(0.0)
+        events, errors = [], []
+        started = threading.Event()
+
+        def slow_reader():
+            try:
+                for ev in fleet.generate_stream([1, 2, 3, 4],
+                                                max_new=6):
+                    events.append(ev)
+                    started.set()
+                    if "token" in ev:
+                        time.sleep(0.05)     # slower than the decode
+            except Exception as e:  # noqa: BLE001 — asserted below
+                errors.append(repr(e))
+                started.set()
+
+        reader = threading.Thread(target=slow_reader)
+        reader.start()
+        assert started.wait(10.0)
+        victim, stop = None, time.monotonic() + 5.0
+        while victim is None and time.monotonic() < stop:
+            victim = next((m["name"] for m in fleet.router.members()
+                           if m["in_flight"] > 0), None)
+        assert victim is not None
+        assert fleet.retire(victim, drain=True, timeout_s=20.0)
+        reader.join(30.0)
+        assert not reader.is_alive() and not errors, errors
+        assert sum("token" in ev for ev in events) == 6
+        assert events[-1].get("done")
+        assert victim not in fleet.router.names()
+    finally:
+        fleet.stop()

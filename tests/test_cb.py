@@ -528,8 +528,8 @@ def test_cb_p95_beats_static_under_mixed_load():
     """23 shorts + 1 long through both paths: the static bucket
     decodes every batch to full max_new_tokens, so shorts queue behind
     longs; cb retires shorts as they finish.  The acceptance gate is
-    cb p95 <= 0.5x static p95 (the bench asserts the same over real
-    HTTP).  Both engines use a 256-token decode horizon — the regime
+    cb p95 <= 0.5x static p95.  Both engines use a 256-token decode
+    horizon — the regime
     where the static path's pay-for-max pathology is the device time,
     not per-call overhead."""
     net, params = _net_and_params()

@@ -269,8 +269,10 @@ def test_distributed_replica_set_multiprocess_e2e(tmp_path, param_type,
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
     hostfile = tmp_path / "hostfile"
-    hostfile.write_text(f"127.0.0.1:{port}\n"
-                        + "127.0.0.1\n" * (nprocs - 1))
+    # one host per member (a duplicate is rejected); all of 127/8 is
+    # loopback
+    hostfile.write_text(f"127.0.0.1:{port}\n" + "".join(
+        f"127.0.0.{i + 1}\n" for i in range(1, nprocs)))
 
     child = tmp_path / "child.py"
     child.write_text(textwrap.dedent(f"""

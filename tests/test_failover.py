@@ -23,10 +23,10 @@ Correctness anchors:
     remaining end-to-end deadline (the flat `+30s` leak).
 
 Cost control: the failover choreography runs on scriptable stub
-handles (the test_autoscale.py mold — no compiled programs); the one
-compiled engine is module-scoped and only backs the scheduler-level
-resume admission tests.  The full kill-mid-stream/fault/watchdog run
-over real engines lives in `bench.py --failover-smoke`."""
+handles (the test_autoscale.py mold — no compiled programs); one
+module-scoped compiled engine backs the scheduler-level resume
+admission tests, and one parametrised test loses a real engine of a
+two-engine fleet mid-stream (killed, or silently stalled)."""
 
 import threading
 import time
@@ -326,12 +326,17 @@ SHAPES = {"data": {"input": (SEQ,), "target": (SEQ,)}}
 
 
 @pytest.fixture(scope="module")
-def fo_served():
+def fo_lm():
     cfg = transformer_lm(vocab_size=VOCAB, num_layers=2, embed_dim=32,
                          num_heads=4, head_dim=8, seq_len=SEQ,
                          batchsize=2)
     net = build_net(cfg, "kTest", SHAPES)
-    params = net.init_params(jax.random.PRNGKey(0))
+    return net, net.init_params(jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def fo_served(fo_lm):
+    net, params = fo_lm
     spec = ServeSpec(buckets=((2, SEQ),), max_new_tokens=32,
                      temperature=0.0, request_timeout_s=30.0,
                      cb="on", cb_slots=4, cb_block_len=4, eos_id=EOS)
@@ -390,3 +395,66 @@ def test_resume_readmission_bit_identical(fo_served):
         f"resume at {k} diverged: {out['tokens']} vs {ref[k:]}"
     assert events == ref[k:]
     assert engine.stats.resumed == resumed0 + 1
+
+
+# -- a real engine lost mid-stream (two compiled engines, one fleet) ---------
+
+@pytest.mark.parametrize("loss", ["kill", "silent-stall"])
+def test_real_engine_lost_mid_stream_splices_exactly_once(fo_lm, loss):
+    """The choreography above over REAL compiled engines: the engine
+    decoding a live stream is killed (a transport break) or goes
+    silent (caught by the `stream_idle_s` watchdog) after the client
+    holds 6 tokens; the sibling re-admits (prompt || prefix) and the
+    client sees every index once, bit-identical to an uninterrupted
+    decode of the same prompt."""
+    from singa_tpu.serve import EngineFleet
+
+    net, params = fo_lm
+    max_new = 24
+    spec = ServeSpec(buckets=((2, SEQ),), max_new_tokens=max_new,
+                     temperature=0.0, batch_window_s=0.002,
+                     request_timeout_s=60.0,
+                     cb="on", cb_slots=2, cb_block_len=4)
+    rspec = RouterSpec(
+        probe_period_s=0.1, quarantine_after=5, hedge="off",
+        request_timeout_s=60.0,
+        stream_idle_s=0.3 if loss == "silent-stall" else 0.0)
+    fleet = EngineFleet.local(net, spec, 2, params=params,
+                              router_spec=rspec, log_fn=lambda s: None)
+    fleet.start()
+    try:
+        prompt = [3, 1, 4, 1]
+        ref = [ev for ev in fleet.generate_stream(prompt,
+                                                  max_new=max_new)
+               if ev.get("done")][0]["tokens"]
+        assert len(ref) == max_new
+        # a step slow enough that the stream is still decoding when
+        # its engine is lost
+        for n in fleet.router.names():
+            fleet.router.handle_for(n).engine.set_stall(0.01)
+        seen, done, victim = [], None, None
+        for ev in fleet.generate_stream(prompt, max_new=max_new,
+                                        timeout=60.0):
+            if ev.get("done"):
+                done = ev
+                break
+            seen.append((ev["i"], ev["token"]))
+            if len(seen) == 6:
+                victim = fleet.router.sessions.snapshot()[
+                    "sessions"][0]["engine"]
+                h = fleet.router.handle_for(victim)
+                if loss == "kill":
+                    h.kill()
+                else:
+                    h.engine.set_stall(1.5)  # alive, probing ok, mute
+        assert done is not None and "error" not in done, done
+        assert [i for i, _ in seen] == list(range(max_new))
+        assert [t for _, t in seen] == ref
+        assert done["tokens"] == ref
+        assert done["spliced"] is True and done["resumes"] >= 1
+        snap = fleet.router.sessions.stats.snapshot()
+        assert snap["resumed"] >= 1 and snap["failed"] == 0
+        if loss == "silent-stall":
+            assert snap["idle_timeouts"] >= 1
+    finally:
+        fleet.stop()

@@ -146,8 +146,7 @@ def test_generate_with_moe_and_gqa():
 
 def test_generate_max_len_overallocation_equivalent():
     """An over-allocated KV cache (max_len > prompt+new) must not change
-    the tokens: the tail slots are mask-ignored.  bench.py relies on
-    this to time the prefill probe at the full run's cache geometry."""
+    the tokens: the tail slots are mask-ignored."""
     net, params = _net_and_params(False)
     toks = jnp.asarray(
         np.random.default_rng(5).integers(0, VOCAB, (B, 6)), jnp.int32)

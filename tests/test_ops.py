@@ -347,30 +347,6 @@ def test_mnist_layer_applies_distortion_only_in_train():
     assert float(jnp.max(jnp.abs(out_train["mnist"] - plain))) > 1e-4
 
 
-def test_lrn_pallas_interpret_matches_band_path():
-    """The Pallas batch-in-lanes LRN kernels (ops/lrn_pallas.py) against
-    the production jnp band-matmul custom_vjp, in interpreter mode on
-    the CPU test platform.  (On chip the kernels measured slower than
-    XLA's fused band-dot emitter and are not selected — see
-    ops/lrn.py:_impl_for — but they remain the independent oracle for
-    the closed-form backward and the benchmark baseline for
-    tools/ablate.py.)"""
-    from singa_tpu.ops.lrn import _lrn_nhwc
-    from singa_tpu.ops.lrn_pallas import eligible
-
-    x = jnp.asarray(RNG.standard_normal((128, 4, 4, 8)).astype(np.float32))
-    g = jnp.asarray(RNG.standard_normal((128, 4, 4, 8)).astype(np.float32))
-    assert eligible(x)
-    for relu in (False, True):
-        args = (3, 5e-3, 0.75, 1.0, relu)
-        y_j, vjp_j = jax.vjp(lambda t: _lrn_nhwc(t, *args, "jnp"), x)
-        y_p, vjp_p = jax.vjp(lambda t: _lrn_nhwc(t, *args, "interpret"), x)
-        np.testing.assert_allclose(y_p, y_j, atol=1e-5)
-        np.testing.assert_allclose(vjp_p(g)[0], vjp_j(g)[0], atol=1e-5)
-    # non-lane-multiple batch is not eligible
-    assert not eligible(jnp.zeros((100, 4, 4, 8)))
-
-
 def test_maxpool_equality_mask_vjp_ties_match_reference():
     """_max_pool_nhwc routes gradient to EVERY tied max (mshadow
     unpool<red::maximum> semantics, tensor_expr_ext.h:148-163): with a

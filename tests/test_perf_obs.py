@@ -6,7 +6,9 @@ readiness-timer latch monotonicity, the process-level collector, and
 the labeled-Sample exposition round trip.
 
 Cost control: everything here is host-side except one tiny jit (one
-add) proving `compiled_flops` still accepts a jit-wrapped callable."""
+add) proving `compiled_flops` still accepts a jit-wrapped callable,
+and the last test, which reads the observatory off one real cb engine
+through its own /metrics."""
 
 import glob
 import json
@@ -254,3 +256,87 @@ def test_labeled_samples_render_one_header_per_name():
     got = parse_prometheus(text)
     assert got['singa_compiles_total{program="a"}'] == 1
     assert got['singa_compiles_total{program="b"}'] == 1
+
+
+# -- the observatory on a real engine, as an operator scrapes it -------------
+
+def test_real_engine_metrics_carry_the_observatory_and_harvest_is_free():
+    """One tiny cb engine behind its HTTP server: warmup compiles its
+    cb programs once, requests compile nothing (no anomaly), a
+    CostWatch sweep over the compiled programs moves no compile
+    counter, and /metrics exports readiness, the HBM watermark, the
+    process collector and the decode program's FLOPs."""
+    import urllib.request
+
+    from singa_tpu.serve import (InferenceEngine, InferenceServer,
+                                 ServeSpec)
+    from test_fleet import SEQ, _net_and_params
+
+    net, params = _net_and_params()
+    spec = ServeSpec(buckets=((2, SEQ),), max_new_tokens=8,
+                     temperature=0.0, request_timeout_s=60.0,
+                     reload_poll_s=100.0,
+                     cb="on", cb_slots=2, cb_block_len=4)
+    engine = InferenceEngine(net, spec, params=params,
+                             log_fn=lambda s: None)
+    server = InferenceServer(engine, port=0, log_fn=lambda s: None)
+    server.start()                 # load + warmup
+    try:
+        warm = engine.stats.compiles
+        assert warm == len(spec.cb_prefill_widths) + 1
+        for plen, max_new in ((3, 8), (7, 2), (12, 4)):
+            out = server.generate(np.arange(1, plen + 1, dtype=np.int32),
+                                  max_new=max_new)
+            assert len(out["tokens"]) == max_new
+        assert engine.stats.compiles == warm
+        assert engine.harvest_costs() >= 2
+        assert engine.stats.compiles == warm
+        host, port = server.address
+        with urllib.request.urlopen(f"http://{host}:{port}/metrics",
+                                    timeout=10) as r:
+            got = parse_prometheus(r.read().decode())
+    finally:
+        server.stop()
+    snap = perf.snapshot()
+    assert snap["anomalies"] == 0
+    assert snap["cost"]["cb_decode"]["flops"] > 0
+    assert got["singa_restart_to_serving_seconds"] > 0
+    assert got["singa_hbm_watermark_bytes"] > 0
+    assert got["singa_process_rss_bytes"] > 0
+    assert got["singa_recompile_anomalies_total"] == 0
+
+
+def test_real_trainer_run_latches_restart_to_training():
+    """A tiny MLP through `Trainer.run`'s fused scan: the first
+    completed dispatch latches restart-to-training, and nothing it
+    compiles counts as an anomaly."""
+    from singa_tpu.config.schema import model_config_from_dict
+    from singa_tpu.core.trainer import Trainer
+    from singa_tpu.data.synthetic import synthetic_image_batches
+
+    cfg = model_config_from_dict({
+        "name": "perf_mlp", "train_steps": 8, "display_frequency": 0,
+        "updater": {"type": "kSGD", "base_learning_rate": 0.1,
+                    "learning_rate_change_method": "kFixed"},
+        "neuralnet": {"layer": [
+            {"name": "data", "type": "kShardData",
+             "data_param": {"batchsize": 8}},
+            {"name": "mnist", "type": "kMnistImage",
+             "srclayers": "data"},
+            {"name": "label", "type": "kLabel", "srclayers": "data"},
+            {"name": "ip", "type": "kInnerProduct",
+             "srclayers": "mnist",
+             "inner_product_param": {"num_output": 10},
+             "param": [{"name": "weight"}, {"name": "bias"}]},
+            {"name": "loss", "type": "kSoftmaxLoss",
+             "srclayers": ["ip", "label"]}]}})
+    trainer = Trainer(cfg, {"data": {"pixel": (28, 28), "label": ()}},
+                      donate=False, log_fn=lambda s: None)
+    params, opt_state = trainer.init(0)
+    assert perf.snapshot()["training_ready_s"] is None
+    trainer.run(params, opt_state,
+                synthetic_image_batches(8, seed=1, stream_seed=7),
+                seed=0, scan_chunk=4)
+    snap = perf.snapshot()
+    assert snap["training_ready_s"] > 0
+    assert snap["anomalies"] == 0

@@ -19,10 +19,10 @@ Correctness anchors:
     launder a strike streak or a Retry-After escalation.
 
 Cost control: WAL/replay/fencing logic runs on plain files and stub
-handles; exactly ONE test builds real engines (module-scoped net),
-covering restart + handoff in a single fleet sequence.  The
-subprocess SIGKILL leg over HTTP lives in `bench.py --router-smoke`
-(and its slow twin here)."""
+handles; three tests build real engines over one module-scoped net:
+restart + handoff in a single fleet sequence, a faulted recovery, and
+the handoff as a client sees it over HTTP.  The subprocess SIGKILL
+restart over HTTP is the `slow` test at the end."""
 
 import json
 import os
@@ -670,6 +670,88 @@ def test_recovery_fault_degrades_to_serving_without_replay(tiny_lm):
         out = f1.generate(_np.arange(1, 5, dtype=_np.int32))
         assert out["step"] == 1
         f1.stop()
+
+
+def test_handoff_over_http_refuses_with_409_at_the_successor(tiny_lm):
+    """The handoff as a client sees it: POST /admin/handoff lame-ducks
+    the primary mid-stream, a fresh admission is refused with 409 whose
+    body and Retry-After point at the successor, the in-flight stream
+    still finishes on the lame duck, and after POST /admin/promote the
+    standby serves the same prompt bit-identically."""
+    import urllib.error
+    import urllib.request
+
+    import numpy as _np
+
+    from singa_tpu.serve import FleetServer
+    from singa_tpu.utils.checkpoint import CheckpointManager
+
+    net, params, seq = tiny_lm
+    prompt = [1, 2, 3, 4]
+
+    def post(url, body):
+        req = urllib.request.Request(
+            url, data=json.dumps(body).encode(),
+            headers={"Content-Type": "application/json"})
+        return urllib.request.urlopen(req, timeout=60.0)
+
+    def stream(url, out):
+        with post(url + "/generate", {"tokens": prompt, "stream": True,
+                                      "max_new": 8}) as r:
+            for line in r:
+                ev = json.loads(line)
+                if "token" in ev:
+                    out.append(int(ev["token"]))
+
+    with tempfile.TemporaryDirectory() as ws:
+        CheckpointManager(ws, log_fn=lambda s: None).save(
+            1, params, {"t": _np.zeros(())}, health={"verdict": "ok"})
+        primary = _make_fleet(tiny_lm, ws)
+        standby = _make_fleet(tiny_lm, ws, standby=True)
+        primary.start()
+        standby.start()
+        front1 = FleetServer(primary, log_fn=lambda s: None).start()
+        front2 = FleetServer(standby, log_fn=lambda s: None).start()
+        url1, url2 = (f"http://{h}:{p}"
+                      for h, p in (front1.address, front2.address))
+        try:
+            ref = []
+            stream(url1, ref)
+            assert len(ref) == 8
+            # a step slow enough that the stream is still in flight
+            # when the primary is lame-ducked
+            primary.router.handle_for("engine-0").engine.set_stall(0.05)
+            inflight = []
+            t = threading.Thread(target=stream, args=(url1, inflight))
+            t.start()
+            stop = time.monotonic() + 10.0
+            while not inflight and time.monotonic() < stop:
+                time.sleep(0.005)
+            with post(url1 + "/admin/handoff",
+                      {"successor": url2, "retry_after": 0.2}) as r:
+                assert r.status == 200 and json.loads(r.read())[
+                    "lame_duck"]
+            with pytest.raises(urllib.error.HTTPError) as ei:
+                post(url1 + "/generate", {"tokens": prompt})
+            assert ei.value.code == 409
+            assert float(ei.value.headers["Retry-After"]) == \
+                pytest.approx(0.2)
+            body = json.loads(ei.value.read())
+            assert body["successor"] == url2
+            with post(url2 + "/admin/promote", {}) as r:
+                assert r.status == 200
+                assert int(json.loads(r.read())["epoch"]) >= 2
+            t.join(60.0)
+            assert not t.is_alive()
+            assert inflight == ref       # finished on the lame duck
+            after = []
+            stream(url2, after)
+            assert after == ref          # the successor, bit-identical
+        finally:
+            front1.stop()
+            front2.stop()
+            standby.stop()
+            primary.stop()
 
 
 # -- the real thing: SIGKILL a fleet-router subprocess, restart it -----------

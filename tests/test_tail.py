@@ -16,9 +16,7 @@ Correctness anchors:
     a healthy dispatch (the regression this file pins).
 
 Cost control: router paths run on scriptable stubs; the two real-cb
-tests share one module-scoped tiny engine.  The full three-leg gate
-(stalled straggler, brownout overload, DOA) is `bench.py
---tail-smoke`."""
+tests share one module-scoped tiny engine."""
 
 import threading
 import time
@@ -225,8 +223,8 @@ def test_hedge_beats_a_straggler_and_cancels_the_loser():
 def test_hedge_budget_exhaustion_degrades_to_single_shot():
     slow = TailStub("e0", delay_s=0.15)
     fast = TailStub("e1")
-    r = _router([slow, fast], hedge_min_s=0.01, hedge_max_s=0.03)
-    r.retry_budget = qos.RetryBudget(ratio=0.0, burst=0.0)
+    r = _router([slow, fast], hedge_min_s=0.01, hedge_max_s=0.03,
+                retry_budget_ratio=0.0, retry_budget_burst=0.0)
     out = r.route("generate", [1, 2])
     assert out["engine"] == "e0"          # served, slowly, by the
     assert r.stats.hedges == 0            # primary: never shed because
@@ -263,9 +261,10 @@ def test_hedge_delay_tracks_windowed_p95():
 
 # -- priority brownout -------------------------------------------------------
 
-def _pressurize(r, rate=1.0):
-    """Pin the router's cached capacity-shed pressure reading."""
-    r._pressure = rate
+def _pressurize(r, rate=1.0, tenant="default"):
+    """Pin the router's cached capacity-shed pressure reading for one
+    tenant (brownout reads the requesting tenant's own rate)."""
+    r._pressure_by_tenant[tenant] = rate
     r._pressure_t = time.monotonic() + 60.0   # cache never refreshes
 
 

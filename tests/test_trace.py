@@ -20,11 +20,10 @@ Correctness anchors:
     quarantine, failover, shed storm, divergence, faulted flush —
     rate-limited per trigger, WITHOUT any trace exporter configured.
 
-Cost control: everything below the two-process test runs on
-scriptable stubs and hand-built buffers (no compiled programs).  The
-one real worker subprocess test is `@pytest.mark.slow` — the
-tier-1 bar runs it only in the nightly/chaos lane, alongside
-`bench.py --trace-smoke` and `scripts/obs_smoke.sh`."""
+Cost control: everything but the last two tests runs on scriptable
+stubs and hand-built buffers (no compiled programs).  One test kills
+a real engine of a two-engine in-process fleet mid-stream; the one
+real worker subprocess test is `@pytest.mark.slow`."""
 
 import glob
 import json
@@ -664,3 +663,67 @@ def test_worker_spans_carry_router_trace_two_process(tmp_path):
             r.stop()
         proc.kill()
         proc.wait(30)
+
+
+# -- one trace id across REAL engines (two compiled engines, one fleet) ------
+
+def test_one_trace_id_spans_real_engines_across_a_failover():
+    """The stub choreography above over real compiled engines: a
+    stream whose engine is killed mid-decode keeps ONE trace id from
+    the router's root span through both engines' scheduler spans, the
+    merged buffer has no orphan span, and the lifecycle row indexes
+    the same trace."""
+    from singa_tpu.serve import EngineFleet, ServeSpec
+    from test_fleet import SEQ, _net_and_params
+
+    net, params = _net_and_params()
+    max_new = 24
+    spec = ServeSpec(buckets=((2, SEQ),), max_new_tokens=max_new,
+                     temperature=0.0, batch_window_s=0.002,
+                     request_timeout_s=60.0,
+                     cb="on", cb_slots=2, cb_block_len=4)
+    rspec = RouterSpec(probe_period_s=0.1, quarantine_after=5,
+                       hedge="off", request_timeout_s=60.0)
+    with obs.session(obs.ObsSpec(process="router", trace_ring=65536)):
+        fleet = EngineFleet.local(net, spec, 2, params=params,
+                                  router_spec=rspec,
+                                  log_fn=lambda s: None)
+        fleet.start()
+        try:
+            for n in fleet.router.names():
+                fleet.router.handle_for(n).engine.set_stall(0.01)
+            ntok, done = 0, None
+            for ev in fleet.generate_stream([3, 1, 4, 1],
+                                            max_new=max_new,
+                                            timeout=60.0):
+                if ev.get("done"):
+                    done = ev
+                    break
+                ntok += 1
+                if ntok == 6:
+                    fleet.router.handle_for(
+                        fleet.router.sessions.snapshot()[
+                            "sessions"][0]["engine"]).kill()
+            row = fleet.router.requests.snapshot()["recent"][-1]
+        finally:
+            fleet.stop()
+        # dumped once the schedulers and the prober have stopped: a
+        # span still open on another thread is not in the buffer yet,
+        # and its closed children would read as orphans
+        merged = collect.merge([obs.trace_dump()])
+    assert done is not None and done["spliced"] and ntok == max_new
+    assert row["outcome"] == "spliced" and row["resumes"] >= 1
+    tagged = [e["args"] for e in merged["traceEvents"]
+              if e.get("ph") == "X"
+              and e["args"].get("corr") == row["corr"]]
+    assert {a.get("trace") for a in tagged} == {row["trace"]}
+    spans = collect.spans_of(merged, row["trace"])
+    names = {e["name"] for e in spans}
+    assert {"router.stream", "router.resume", "stream.decode"} <= names
+    # the engines' own spans joined the router's trace: one prefill
+    # for the first leg, one for the resumed leg
+    assert sum(e["name"] == "scheduler.prefill" for e in spans) >= 2
+    engines = {e["args"].get("engine") for e in spans
+               if e["args"].get("engine")}
+    assert len(engines) >= 2, engines
+    assert collect.orphans(merged, row["trace"]) == []

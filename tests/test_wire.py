@@ -119,6 +119,12 @@ def test_error_payload_roundtrip():
 
 # -- fuzz hardening: malformed input is a counted close, never a hang --------
 
+def _whole_request_frame() -> bytes:
+    return b"".join(bytes(p) for p in frame_parts(
+        K_REQ, 7, encode_qos_header(tenant="t"),
+        [encode_request(OP_GENERATE, [1, 2, 3])]))
+
+
 def _read_with_stats(raw: bytes):
     """Feed raw bytes to a FrameReader over a socketpair; return
     (result_or_exception, stats)."""
@@ -169,9 +175,7 @@ def test_truncated_frames_every_cut_point():
     """EOF at any offset inside a frame is a counted malformed close —
     never a hang, never a crash.  (EOF exactly at a frame boundary is
     the one clean shutdown.)"""
-    whole = b"".join(bytes(p) for p in frame_parts(
-        K_REQ, 7, encode_qos_header(tenant="t"),
-        [encode_request(OP_GENERATE, [1, 2, 3])]))
+    whole = _whole_request_frame()
     clean, st = _read_with_stats(b"")
     assert clean is None and st.snapshot()["malformed"] == 0
     for cut in range(1, len(whole)):
@@ -376,17 +380,56 @@ def test_binary_error_mapping_admission(wire_server):
         bh.close()
 
 
-def test_malformed_bytes_close_a_live_server_connection(wire_server):
-    """A client that frames wrong gets its connection closed (counted)
-    — and the server keeps serving other connections."""
+def _live_fuzz(case):
+    """The byte strings of one class of wrong framing."""
+    if case == "truncated-frame":
+        whole = _whole_request_frame()
+        return [whole[:cut] for cut in range(1, len(whole), 7)]
+    if case == "random-bytes":
+        rng = np.random.default_rng(11)
+        return [rng.integers(0, 256, int(rng.integers(1, 48)))
+                .astype(np.uint8).tobytes() for _ in range(25)]
+    return [{
+        "http-request": b"GET / HTTP/1.1\r\n\r\n",
+        "garbage-magic": b"XX" + b"\x00" * 14,
+        "version-skew": wire._PREAMBLE.pack(
+            MAGIC, VERSION + 1, K_HELLO, 0, 0, 1, 0, 0),
+        "oversized-prefix": wire._PREAMBLE.pack(
+            MAGIC, VERSION, K_REQ, 0, 0, 1, 0, wire.MAX_PAYLOAD_LEN + 1),
+    }[case]]
+
+
+#: the classes whose bytes stop inside a frame; the others hold a whole
+#: preamble, and the server closes on it while the client's side of the
+#: socket stays open
+_CUT_SHORT = ("truncated-frame", "random-bytes")
+
+
+@pytest.mark.parametrize("case", [
+    "http-request", "garbage-magic", "version-skew", "oversized-prefix",
+    "truncated-frame", "random-bytes"])
+def test_malformed_bytes_close_a_live_server_connection(wire_server,
+                                                        case):
+    """A client that frames wrong — another protocol, a bad magic, a
+    skewed version, a hostile length prefix, a frame cut short, noise
+    — gets its connection closed within the timeout (counted, never a
+    hang) and the listener keeps serving other connections."""
+    raws = _live_fuzz(case)
     before = wire.STATS.snapshot()["malformed"]
-    s = socket.create_connection(wire_server.wire_address,
-                                 timeout=5.0)
-    s.sendall(b"GET / HTTP/1.1\r\n\r\n")      # not our protocol
-    s.settimeout(5.0)
-    assert s.recv(64) == b""                  # closed, not hung
-    s.close()
-    assert wire.STATS.snapshot()["malformed"] > before
+    for raw in raws:
+        s = socket.create_connection(wire_server.wire_address,
+                                     timeout=5.0)
+        try:
+            s.sendall(raw)
+            s.settimeout(5.0)
+            if case in _CUT_SHORT:
+                # an incomplete frame is only known to be one when
+                # no more bytes can follow
+                s.shutdown(socket.SHUT_WR)
+            assert s.recv(64) == b""          # closed, not hung
+        finally:
+            s.close()
+    assert wire.STATS.snapshot()["malformed"] - before == len(raws)
     # the listener survives: a well-formed client still works
     h = BinaryEngineHandle("e0", wire_server.wire_address)
     try:

@@ -4,7 +4,7 @@ Not part of `chip_smoke.py` (which is pass/fail on the main path):
 these establish one fact each and print it as one JSON line, naming
 the device.  One process, run through the chip tool:
 
-    python tools/chip_checks.py [sync] [feeder] [tokens] [convopt]
+    python tools/chip_checks.py [sync] [feeder] [tokens]
 
   sync     `jax.block_until_ready` against a host fetch of the result,
            on one timed window of the 12x768 train scan: after either
@@ -15,8 +15,6 @@ the device.  One process, run through the chip tool:
   tokens   one fixed prompt, greedy, through the static generate
            bucket and through continuous batching at full width: do
            the tokens agree, and if not, from which index.
-  convopt  AlexNet step time with and without
-           `Trainer.TPU_CONV_COMPILER_OPTIONS`.
 """
 
 from __future__ import annotations
@@ -147,23 +145,8 @@ def check_tokens() -> None:
            first_differing_index=diff[0] if diff else None)
 
 
-def check_convopt() -> None:
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    from mfu_ab import measure
-
-    with_opt = measure(8192, 10, 3)
-    os.environ["SINGA_TPU_SCOPED_VMEM"] = "off"
-    try:
-        without = measure(8192, 10, 3)
-    finally:
-        del os.environ["SINGA_TPU_SCOPED_VMEM"]
-    report("convopt", batch=8192, precision="bfloat16",
-           step_ms_with_option=round(with_opt, 3),
-           step_ms_without_option=round(without, 3))
-
-
 CHECKS = {"sync": check_sync, "feeder": check_feeder,
-          "tokens": check_tokens, "convopt": check_convopt}
+          "tokens": check_tokens}
 
 
 def main() -> None:
