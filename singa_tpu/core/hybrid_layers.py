@@ -30,6 +30,7 @@ from ..config.schema import ParamConfig
 from ..ops import kda as kda_ops
 from ..ops import moe as moe_ops
 from ..ops.attention import NEG_INF
+from ..ops.paged_attention import paged_decode_attention
 from .layers import Layer, LayerError, ParamSpec, register_layer
 from .seq_layers import _declare_with_default
 
@@ -293,19 +294,34 @@ class MLALayer(Layer):
                        preferred_element_type=jnp.float32)
         return o.reshape(b, t, -1).astype(q.dtype)
 
-    def _attend_absorbed(self, params, q, lat, allowed):
-        """q (N, H, .) one token a row against its latent rows lat
-        (N, L, rank + rope, or wider with zeros behind); allowed (N, L)
-        bool."""
-        w = self._wkvb(params)
+    def _absorb_query(self, params, q, width):
+        """q (N, H, nope + rope) -> (N, H, width): Wkvb's key half
+        folded into the query, so that it scores latent rows of `width`
+        columns (rank + rope, zeros behind) directly."""
         q_lat = jnp.einsum("nhd,rhd->nhr", q[..., :self.nope],
-                           w[..., :self.nope],
+                           self._wkvb(params)[..., :self.nope],
                            preferred_element_type=jnp.float32)
         q_lat = jnp.concatenate(
             [q_lat.astype(q.dtype), q[..., self.nope:]], -1)
-        q_lat = jnp.pad(q_lat, ((0, 0), (0, 0),
-                                (0, lat.shape[-1] - self.latent_dim)))
-        sc = jnp.einsum("nhr,nlr->nhl", q_lat, lat,
+        return jnp.pad(q_lat, ((0, 0), (0, 0),
+                               (0, width - self.latent_dim)))
+
+    def _expand_output(self, params, o_lat):
+        """o_lat (N, H, rank), the attended latents -> (N, H * vdim):
+        Wkvb's value half applied after the sum over positions."""
+        o = jnp.einsum("nhr,rhd->nhd", o_lat,
+                       self._wkvb(params)[..., self.nope:],
+                       preferred_element_type=jnp.float32)
+        return o.reshape(o.shape[0], -1).astype(o_lat.dtype)
+
+    def _attend_absorbed(self, params, q, lat, allowed):
+        """q (N, H, .) one token a row against its latent rows lat
+        (N, L, rank + rope, or wider with zeros behind); allowed (N, L)
+        bool.  The decode step's sums over a dense table: what
+        `apply_paged` ran before the paged kernel took the middle, kept
+        as the oracle the tests hold the kernel to."""
+        sc = jnp.einsum("nhr,nlr->nhl",
+                        self._absorb_query(params, q, lat.shape[-1]), lat,
                         preferred_element_type=jnp.float32)
         sc = sc / math.sqrt(self.nope + self.rope)
         sc = jnp.where(allowed[:, None, :], sc, NEG_INF)
@@ -314,10 +330,7 @@ class MLALayer(Layer):
         c = jnp.where(allowed[:, :, None], lat[..., :self.rank], 0)
         o_lat = jnp.einsum("nhl,nlr->nhr", p.astype(c.dtype), c,
                            preferred_element_type=jnp.float32)
-        o = jnp.einsum("nhr,rhd->nhd", o_lat.astype(q.dtype),
-                       w[..., self.nope:],
-                       preferred_element_type=jnp.float32)
-        return o.reshape(o.shape[0], -1).astype(q.dtype)
+        return self._expand_output(params, o_lat.astype(q.dtype))
 
     def apply(self, params, srcs, ctx):
         x = srcs[0]
@@ -349,11 +362,15 @@ class MLALayer(Layer):
 
     def apply_paged(self, params, x, entry, tables, ntoks):
         """x (1, S, E): slot s's token against the latent rows of slot
-        s's blocks, its own written first (position ntoks[s]).  Plain
-        XLA: every slot's whole table row is gathered, live or not (the
-        formulation kAttention had before its paged kernel)."""
+        s's blocks, its own written first (position ntoks[s], a whole
+        block rewritten: a row-wise scatter makes XLA:TPU copy the
+        pool).  The absorbed step is paged multi-query attention over
+        the pool itself, one key row a token shared by all heads whose
+        first `rank` columns are the value, so the paged kernel attends
+        it and reads blocks 0 .. ntoks[s] // bl of slot s's table row
+        only; Wkvb goes into the query and the output here, in XLA."""
         pool = entry["c"]
-        s, bl, tw = x.shape[1], pool.shape[1], tables.shape[1]
+        s, bl = x.shape[1], pool.shape[1]
         q, lat = self._project(params, x[0][:, None, :])
         lat = jnp.pad(lat, ((0, 0), (0, 0),
                             (0, self.pool_row - self.latent_dim)))
@@ -362,10 +379,11 @@ class MLALayer(Layer):
         blocks = jnp.where(rows == (ntoks % bl)[:, None, None],
                            lat.astype(pool.dtype), pool[bidx])
         pool = pool.at[bidx].set(blocks)
-        mine = pool[tables].reshape(s, tw * bl, self.pool_row)
-        allowed = jnp.arange(tw * bl)[None, :] <= ntoks[:, None]
-        o = self._attend_absorbed(params, q[:, 0], mine.astype(x.dtype),
-                                  allowed)
+        o_lat = paged_decode_attention(
+            self._absorb_query(params, q[:, 0], self.pool_row),
+            pool[:, None], None, tables, ntoks, value_dim=self.rank,
+            scale=1.0 / math.sqrt(self.nope + self.rope))
+        o = self._expand_output(params, o_lat)
         return _dot(o, params[self.wo]).astype(x.dtype)[None], {"c": pool}
 
     @staticmethod

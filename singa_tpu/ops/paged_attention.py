@@ -16,11 +16,17 @@ table and the lengths are scalar-prefetched, and each slot's live blocks
 are copied HBM -> VMEM a chunk (256 positions) at a time, double
 buffered, the next slot's first chunk in flight while this slot's last
 one is computed.  Scores and the online softmax are f32; GQA is done in
-the kernel (q grouped (Hkv, G, D), no expanded K/V).
+the kernel (q grouped (Hkv, G, D), no expanded K/V).  Two callers, one
+body, told apart by their arguments' shapes: `kAttention` (separate key
+and value pools, 1 / sqrt(D)) and `kMLA`'s absorbed decode step (one
+pool of latent rows shared by all heads, Hkv 1, whose leading columns
+are the value, so a block is copied once; its own scale).
 `paged_attention_reference` is the plain-jnp gather of every slot's
 whole table, the formulation the serving engine ran before the kernel:
 it materialises (S, T, Hkv, block_len, D) per side and is kept only as
-the oracle the tests compare the kernel against.
+the oracle the tests compare the kernel against (kMLA's is its own
+`MLALayer._attend_absorbed` over the gathered rows) and as the row
+`tools/paged_kernel_bench.py` times the kernel beside.
 """
 
 from __future__ import annotations
@@ -45,10 +51,12 @@ KERNEL_NAME = "singa_paged_decode_kernel"
 _CHUNK_POSITIONS = 256
 
 
-def paged_attention_reference(q, k_pool, v_pool, tables, ntoks):
-    """Gather formulation.  q (S, H, D); pools (num_blocks, Hkv, bl, D);
-    tables (S, T) int32; ntoks (S,) int32.  Returns (S, H, D) in q's
-    dtype: softmax(q k^T / sqrt(D)) v over positions <= ntoks[s]."""
+def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
+                              scale=None, value_dim=None):
+    """Gather formulation, `paged_decode_attention`'s arguments.  q
+    (S, H, D); pools (num_blocks, Hkv, bl, D); tables (S, T) int32;
+    ntoks (S,) int32.  Returns (S, H, D) in q's dtype: softmax(q k^T /
+    sqrt(D)) v over positions <= ntoks[s]."""
     s, h, d = q.shape
     _, hkv, bl, _ = k_pool.shape
     t = tables.shape[1]
@@ -58,32 +66,39 @@ def paged_attention_reference(q, k_pool, v_pool, tables, ntoks):
         return pool[tables].transpose(0, 2, 1, 3, 4).reshape(
             s, hkv, t * bl, d).astype(q.dtype)
 
-    kk, vv = flat(k_pool), flat(v_pool)
+    kk = flat(k_pool)
+    vv = kk[..., :value_dim] if v_pool is None else flat(v_pool)
     allowed = jnp.arange(t * bl)[None, :] <= ntoks[:, None]    # (S, T*bl)
     qg = q.reshape(s, hkv, groups, d)
     scores = jnp.einsum("shgd,shkd->shgk", qg, kk,
                         preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(d))
+    scores = (scores / jnp.sqrt(jnp.float32(d)) if scale is None
+              else scores * scale)
     scores = jnp.where(allowed[:, None, None], scores, _attention.NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # a masked lane's probability is an exact zero, but 0 * (inf | nan)
     # is nan: values the mask hides must not reach the product
     vv = jnp.where(allowed[:, None, :, None], vv, 0)
     out = jnp.einsum("shgk,shkd->shgd", probs.astype(vv.dtype), vv)
-    return out.reshape(s, h, d)
+    return out.reshape(s, h, vv.shape[-1])
 
 
-def _kernel(ntoks_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, base_ref, *, bl, cb, tw, scale):
-    """Grid step s attends slot s.  k_buf / v_buf are (2, Hkv, cb*bl, D):
-    two chunks of cb blocks each, a block's (Hkv, bl, D) slab copied to
-    rows [c*bl, (c+1)*bl) of every head.  The buffer that holds a slot's
-    first chunk alternates with the number of chunks walked so far
-    (`base_ref`), because the copy of slot s+1's first chunk is started
-    under slot s's last."""
+def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides):
+    """Grid step s attends slot s.  `refs` is the `sides` pools in HBM
+    (keys, then values; one pool where the values are columns of the key
+    rows), the output, a buffer a pool, the copies' semaphores and
+    `base_ref`.  A buffer is (2, Hkv, cb*bl, D): two chunks of cb blocks
+    each, a block's (Hkv, bl, D) slab copied to rows [c*bl, (c+1)*bl)
+    of every head.  The buffer that holds a slot's first chunk
+    alternates with the number of chunks walked so far (`base_ref`),
+    because the copy of slot s+1's first chunk is started under slot
+    s's last."""
+    pools, o_ref, bufs = refs[:sides], refs[sides], refs[sides + 1:-2]
+    sems, base_ref = refs[-2:]
     s = pl.program_id(0)
     slots = pl.num_programs(0)
     span = cb * bl
+    hkv, g, vd = o_ref.shape[1:]
 
     def horizon(slot):
         """Last position the slot attends; held inside its table row,
@@ -96,15 +111,13 @@ def _kernel(ntoks_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     base = jnp.where(s == 0, 0, base_ref[0])
 
     def copies(slot, chunk, buf, c):
-        """Block c of a chunk: its K and its V copy."""
+        """Block c of a chunk: one copy a pool."""
         blk = tables_ref[slot * tw + chunk * cb + c]
         rows = pl.ds(pl.multiple_of(c * bl, bl), bl)
-        return (pltpu.make_async_copy(k_hbm.at[blk],
-                                      k_buf.at[buf, :, rows, :],
-                                      sems.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[blk],
-                                      v_buf.at[buf, :, rows, :],
-                                      sems.at[1, buf]))
+        return [pltpu.make_async_copy(hbm.at[blk],
+                                      into.at[buf, :, rows, :],
+                                      sems.at[side, buf])
+                for side, (hbm, into) in enumerate(zip(pools, bufs))]
 
     def each_live_block(slot, chunk, buf, do):
         """`do` on the copies of the chunk's live blocks: all cb of
@@ -127,8 +140,10 @@ def _kernel(ntoks_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     def attend(chunk, buf, carry, last):
         m, l, acc = carry
         q = q_ref[0]                                    # (Hkv, G, D)
-        k = k_buf[buf].astype(q.dtype)                  # (Hkv, span, D)
-        v = v_buf[buf].astype(q.dtype)
+        k = bufs[0][buf].astype(q.dtype)                # (Hkv, span, D)
+        # one pool: a row's leading columns are its value
+        v = (bufs[1][buf].astype(q.dtype) if sides == 2
+             else k[..., :vd])                          # (Hkv, span, Dv)
         sc = jnp.einsum("hgd,htd->hgt", q, k,
                         preferred_element_type=jnp.float32) * scale
         if last:
@@ -160,10 +175,9 @@ def _kernel(ntoks_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         wait(s, i, buf)
         return attend(i, buf, carry, last=False)
 
-    hkv, g, d = q_ref.shape[1:]
     init = (jnp.full((hkv, g, 1), _attention.NEG_INF, jnp.float32),
             jnp.zeros((hkv, g, 1), jnp.float32),
-            jnp.zeros((hkv, g, d), jnp.float32))
+            jnp.zeros((hkv, g, vd), jnp.float32))
     carry = jax.lax.fori_loop(0, chunks - 1, full_chunk, init)
     buf = (base + chunks - 1) % 2
 
@@ -177,67 +191,94 @@ def _kernel(ntoks_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     base_ref[0] = 1 - buf
 
 
-def _check_tiling(q, k_pool):
+def _check_tiling(q, k_pool, value_dim):
     """Mosaic copies whole (sublane, lane) tiles: a block's (bl, D) face
-    has to be made of them.  Interpreted kernels take any shape."""
+    has to be made of them, and so has the part of a row that is its
+    value.  Interpreted kernels take any shape."""
     _, _, bl, d = k_pool.shape
     sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
-    if bl % sublanes or d % 128:
+    if bl % sublanes or d % 128 or value_dim % 128:
         raise ValueError(
             f"paged_decode_attention cannot tile a {k_pool.dtype} pool of "
-            f"shape {k_pool.shape} (q {q.shape}) on the TPU: block_len "
-            f"must be a multiple of {sublanes} and head_dim of 128")
+            f"shape {k_pool.shape} (q {q.shape}, values of {value_dim}) on "
+            f"the TPU: block_len must be a multiple of {sublanes}, "
+            f"head_dim and the value's width of 128")
 
 
-def paged_decode_attention(q, k_pool, v_pool, tables, ntoks):
+def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
+                           value_dim=None):
     """q (S, H, D) against pools (num_blocks, Hkv, bl, D) through
     `tables` (S, T) int32 and `ntoks` (S,) int32.  Returns (S, H, D) in
     q's dtype, equal to `paged_attention_reference` up to the order of
     the f32 sums.  Reads ntoks[s] // bl + 1 blocks of slot s's row and
-    nothing else of the pools.  Compiled by Mosaic on the TPU,
-    interpreted elsewhere (`ops.attention._on_tpu`)."""
+    nothing else of the pools.  `scale` multiplies the f32 scores
+    (default 1 / sqrt(D)).
+
+    `v_pool` None: one pool holds both sides, a row's first `value_dim`
+    columns (default all D) being its value, and every block is copied
+    once; the result is (S, H, value_dim).  That is a latent (MLA)
+    cache under an absorbed query: Hkv 1, one row a token shared by all
+    heads, scale 1 / sqrt(nope + rope).
+
+    Compiled by Mosaic on the TPU, interpreted elsewhere
+    (`ops.attention._on_tpu`)."""
     if q.shape[1] % k_pool.shape[1]:
         raise ValueError(f"{q.shape[1]} query heads over "
                          f"{k_pool.shape[1]} key/value heads")
+    d = q.shape[-1]
+    if value_dim is None:
+        value_dim = d
+    if not 0 < value_dim <= d or (v_pool is not None and value_dim != d):
+        raise ValueError(f"values of {value_dim} columns from "
+                         f"{'the key' if v_pool is None else 'value'} rows "
+                         f"of {d}")
     interpret = not _attention._on_tpu()
     if not interpret:
-        _check_tiling(q, k_pool)
-    return singa_paged_decode(q, k_pool, v_pool, tables, ntoks,
-                              interpret=interpret, chunk=_CHUNK_POSITIONS)
+        _check_tiling(q, k_pool, value_dim)
+    return singa_paged_decode(
+        q, k_pool, v_pool, tables, ntoks, interpret=interpret,
+        chunk=_CHUNK_POSITIONS, value_dim=value_dim,
+        scale=1.0 / math.sqrt(d) if scale is None else float(scale))
 
 
 # Jitted, so that the layers of one program share one trace and one
 # Mosaic lowering (16 of them cost a process 1.3 s of every start), and
 # named as the kernel: the function's name is the name of the op, and so
 # of the row, that holds the kernel's time in a device trace.
-@functools.partial(jax.jit, static_argnames=("interpret", "chunk"))
+@functools.partial(jax.jit, static_argnames=("interpret", "chunk", "scale",
+                                             "value_dim"))
 def singa_paged_decode(q, k_pool, v_pool, tables, ntoks, *, interpret,
-                       chunk):
+                       chunk, scale, value_dim):
     s, h, d = q.shape
     _, hkv, bl, _ = k_pool.shape
     tw = tables.shape[1]
     cb = max(1, min(chunk // bl, tw))
     groups = h // hkv
-    q_spec = pl.BlockSpec((1, hkv, groups, d), lambda i, *_: (i, 0, 0, 0))
-    buf = pltpu.VMEM((2, hkv, cb * bl, d), k_pool.dtype)
+    pools = [k_pool] if v_pool is None else [k_pool, v_pool]
+
+    def heads(width):
+        return pl.BlockSpec((1, hkv, groups, width),
+                            lambda i, *_: (i, 0, 0, 0))
+
     out = pl.pallas_call(
-        functools.partial(_kernel, bl=bl, cb=cb, tw=tw,
-                          scale=1.0 / math.sqrt(d)),
+        functools.partial(_kernel, bl=bl, cb=cb, tw=tw, scale=scale,
+                          sides=len(pools)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),
-            in_specs=[q_spec,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=q_spec,
-            scratch_shapes=[buf, buf,
-                            pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((s, hkv, groups, d), q.dtype),
+            in_specs=[heads(d)] + [pl.BlockSpec(memory_space=pl.ANY)
+                                   for _ in pools],
+            out_specs=heads(value_dim),
+            scratch_shapes=[pltpu.VMEM((2, hkv, cb * bl, d), pool.dtype)
+                            for pool in pools]
+            + [pltpu.SemaphoreType.DMA((len(pools), 2)),
+               pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s, hkv, groups, value_dim),
+                                       q.dtype),
         compiler_params=(None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))),
         interpret=interpret,
         name=KERNEL_NAME,
     )(ntoks.astype(jnp.int32), tables.astype(jnp.int32).reshape(-1),
-      q.reshape(s, hkv, groups, d), k_pool, v_pool)
-    return out.reshape(s, h, d)
+      q.reshape(s, hkv, groups, d), *pools)
+    return out.reshape(s, h, value_dim)

@@ -188,6 +188,41 @@ def _engine(lm, slots):
     return engine
 
 
+def test_decode_program_never_materialises_the_gathered_latent_tables(lm):
+    """What `MLALayer.apply_paged` used to build per layer, every slot's
+    whole table row of latent rows (S, T * bl, row), is in no value of
+    the lowered cb decode program: the paged kernel (interpreted here:
+    a `while`) reads the pool through the table.  The old formulation's
+    lowering, `_attend_absorbed` over the gathered table, is the same
+    search's control."""
+    net, _, _ = lm
+    engine = _engine(lm, 3)
+    spec = engine.spec
+    s, t, bl = spec.cb_slots, spec.cb_blocks_per_slot, spec.cb_block_len
+    layer = next(net.layers[n] for n in net.topo
+                 if net.layers[n].cfg.type == "kMLA")
+    gathered = f"tensor<{s}x{t * bl}x{layer.pool_row}x"
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.int32)  # noqa: E731
+    text = jax.jit(engine._build_cb_decode(), donate_argnums=(1,)).lower(
+        engine.params, engine._pools_spec(), shape(s), shape(s),
+        shape(s, t), jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
+    assert "while" in text
+    assert gathered not in text
+
+    def gather_formulation(params, q, pool, tables, ntoks):
+        mine = pool[tables].reshape(s, t * bl, layer.pool_row)
+        allowed = jnp.arange(t * bl)[None, :] <= ntoks[:, None]
+        return layer._attend_absorbed(params, q, mine, allowed)
+
+    f32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32)  # noqa: E731
+    control = jax.jit(gather_formulation).lower(
+        net._resolve_params(engine.params),
+        f32(s, layer.heads, layer.nope + layer.rope),
+        f32(spec.cb_pool_blocks, bl, layer.pool_row), shape(s, t),
+        shape(s)).as_text()
+    assert gathered in control
+
+
 def test_reused_slots_carry_nothing_of_their_last_tenant(lm):
     """Two slots, seven requests: every slot is retired and re-admitted.
     A freed slot's recurrent state and conv tail are filled with NaN, so

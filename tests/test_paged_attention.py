@@ -1,12 +1,15 @@
 """Paged decode attention (ops/paged_attention.py): the kernel against
-the plain gather reference on poisoned pools, the compiled cb decode
-program's freedom from the materialised gather, and the scheduler's
-`cb_live_block_share` counter.
+the plain gather reference on poisoned pools, in kAttention's geometry
+(a key and a value pool) and in kMLA's (one pool of latent rows shared
+by all heads, its own scale), the compiled cb decode program's freedom
+from the materialised gather, and the scheduler's `cb_live_block_share`
+counter.
 
 The kernel runs interpreted here (CPU), at geometries kept tiny: the
 Mosaic compile at the serving cell's real geometry is
 tests/benchmark/test_bench_preflight.py's."""
 
+import functools
 import time
 
 import jax
@@ -14,6 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from singa_tpu.config.schema import LayerConfig, MLAConfig
+from singa_tpu.core.hybrid_layers import MLALayer
 from singa_tpu.core.net import build_net
 from singa_tpu.models.transformer import transformer_lm
 from singa_tpu.ops.paged_attention import (paged_attention_reference,
@@ -36,23 +41,24 @@ LENGTHS = {
     "mixed": [1, BL, 2 * BL + 1, FULL],
     "inactive_among_active": [None, 5, None, 17],
 }
-_kernel = paged_decode_attention        # jitted inside
-_reference = jax.jit(paged_attention_reference)
+_dense_kernel = paged_decode_attention        # jitted inside
+_dense_reference = jax.jit(paged_attention_reference)
 
 
-def _case(lengths, groups, dtype, seed):
-    """q, a clean and a poisoned copy of the pools, tables, ntoks.  The
-    table is a shuffled (non-monotone) draw of the pool's blocks; a
-    slot's reservation ends somewhere at or after its last live block
-    and the row's tail is the null block.  In the poisoned copy every
-    position no slot may see is nan (K) or inf (V): blocks no live
-    table entry names, the tail of each last live block, and the null
-    block past its position 0 (which an inactive slot attends)."""
+def _case(lengths, groups, dtype, seed, hkv=HKV, d=D, sides=2):
+    """q, a clean and a poisoned copy of the `sides` pools, tables,
+    ntoks.  The table is a shuffled (non-monotone) draw of the pool's
+    blocks; a slot's reservation ends somewhere at or after its last
+    live block and the row's tail is the null block.  In the poisoned
+    copy every position no slot may see is nan (K, or the one pool of
+    both sides) or inf (V): blocks no live table entry names, the tail
+    of each last live block, and the null block past its position 0
+    (which an inactive slot attends)."""
     rng = np.random.default_rng(seed)
     nb = S * T + 1
-    q = rng.standard_normal((S, HKV * groups, D)).astype(np.float32)
-    k = rng.standard_normal((nb, HKV, BL, D)).astype(np.float32)
-    v = rng.standard_normal((nb, HKV, BL, D)).astype(np.float32)
+    q = rng.standard_normal((S, hkv * groups, d)).astype(np.float32)
+    pools = [rng.standard_normal((nb, hkv, BL, d)).astype(np.float32)
+             for _ in range(sides)]
     tables = rng.permutation(np.arange(1, nb)).reshape(S, T).astype(np.int32)
     ntoks = np.zeros((S,), np.int32)
     seen = np.zeros((nb, BL), bool)
@@ -67,31 +73,97 @@ def _case(lengths, groups, dtype, seed):
         for p in range(n + 1):
             seen[tables[s, p // BL], p % BL] = True
     hide = ~seen[:, None, :, None]
-    clean = [np.where(hide, 0.0, a) for a in (k, v)]
+    clean = [np.where(hide, 0.0, a) for a in pools]
     poisoned = [np.where(hide, bad, a)
-                for a, bad in ((k, np.nan), (v, np.inf))]
+                for a, bad in zip(pools, (np.nan, np.inf))]
     to = lambda a: jnp.asarray(a, dtype)                      # noqa: E731
     return (to(q), [to(a) for a in clean], [to(a) for a in poisoned],
             jnp.asarray(tables), jnp.asarray(ntoks))
 
 
+# kMLA's decode step at a tiny size: 3 heads over one latent row a
+# token, rank 8 + rope 4 = 12 columns stored as 16 (kMLA pads its rows
+# to whole lane tiles with zeros; here the pad holds noise, which a
+# query padded with zeros must not see), value = the first 8 columns,
+# scores over sqrt(nope + rope) = sqrt(9), not sqrt(16)
+MLA = MLAConfig(num_heads=3, kv_lora_rank=8, qk_nope_head_dim=5,
+                qk_rope_head_dim=4, v_head_dim=6)
+MLA_ROW = 16
+
+
+@pytest.fixture(scope="module")
+def mla():
+    layer = MLALayer(LayerConfig(name="mla", type="kMLA", mla_param=MLA))
+    layer.setup([(1, 1, 24)])
+    rng = np.random.default_rng(7)
+    params = {spec.name: jnp.asarray(rng.standard_normal(spec.shape),
+                                     jnp.float32)
+              for spec in layer.param_specs}
+    return layer, params
+
+
+def _latent_reference(layer, params, q, pool, tables, ntoks):
+    """`MLALayer._attend_absorbed` over every slot's gathered table:
+    what `apply_paged` ran before the kernel.  Returns the attended
+    latents' expansion, (S, H * vdim)."""
+    s, t = tables.shape
+    rows = pool[tables][:, :, 0].reshape(s, t * BL, pool.shape[-1])
+    allowed = jnp.arange(t * BL)[None, :] <= ntoks[:, None]
+    return layer._attend_absorbed(params, q, rows.astype(q.dtype), allowed)
+
+
+def _latent_kernel(layer, params, q, pool, tables, ntoks):
+    """`MLALayer.apply_paged`'s middle: the kernel between the two
+    halves of Wkvb."""
+    o_lat = paged_decode_attention(
+        layer._absorb_query(params, q, pool.shape[-1]), pool, None, tables,
+        ntoks, value_dim=layer.rank,
+        scale=1.0 / np.sqrt(layer.nope + layer.rope))
+    assert o_lat.shape == (S, layer.heads, layer.rank)
+    return layer._expand_output(params, o_lat)
+
+
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
                                        (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("groups", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("groups", [1, 4, "latent"],
+                         ids=["mha", "gqa4", "latent"])
 @pytest.mark.parametrize("name", list(LENGTHS))
 def test_kernel_matches_gather_reference_on_poisoned_pools(name, groups,
-                                                           dtype, tol):
-    q, clean, poisoned, tables, ntoks = _case(
-        LENGTHS[name], groups, dtype, seed=len(name) + groups)
+                                                           dtype, tol, mla):
+    if groups == "latent":
+        layer, params = mla
+        params = {k: v.astype(dtype) for k, v in params.items()}
+        q, clean, poisoned, tables, ntoks = _case(
+            LENGTHS[name], layer.heads, dtype, seed=len(name), hkv=1,
+            d=MLA_ROW, sides=1)
+        q = q[..., :layer.nope + layer.rope]
+        _reference = functools.partial(_latent_reference, layer, params)
+        _kernel = functools.partial(_latent_kernel, layer, params)
+    else:
+        q, clean, poisoned, tables, ntoks = _case(
+            LENGTHS[name], groups, dtype, seed=len(name) + groups)
+        _reference, _kernel = _dense_reference, _dense_kernel
     want = np.asarray(_reference(q, *clean, tables, ntoks), np.float32)
     got = np.asarray(_kernel(q, *poisoned, tables, ntoks), np.float32)
     assert np.isfinite(got).all(), "the kernel read past a slot's horizon"
-    assert np.max(np.abs(got - want)) <= tol
+    # Wkvb's value half (unit normal here) scales the latent case's output
+    size = np.max(np.abs(want)) if groups == "latent" else 1.0
+    assert np.max(np.abs(got - want)) <= tol * size
     # the table's tail and the unseen blocks do not reach the result
     # of the reference either: it is a fair oracle on the same pools
     same = np.asarray(_reference(q, *poisoned, tables, ntoks), np.float32)
     assert np.array_equal(same, want)
+
+
+@pytest.mark.parametrize("bad", [{"value_dim": 0}, {"value_dim": D + 1},
+                                 {"value_dim": D // 2, "both": True}],
+                         ids=["none", "wider_than_a_row", "of_a_value_pool"])
+def test_kernel_refuses_values_that_are_no_part_of_a_row(bad):
+    q, clean, _, tables, ntoks = _case(LENGTHS["mixed"], 1, jnp.float32, 0)
+    v_pool = clean[1] if bad.pop("both", False) else None
+    with pytest.raises(ValueError, match="values of"):
+        paged_decode_attention(q, clean[0], v_pool, tables, ntoks, **bad)
 
 
 def test_kernel_refuses_a_shape_it_cannot_tile_on_the_chip(monkeypatch):

@@ -3,7 +3,8 @@
 time, op by op (chip only, about two minutes): builds the cell's engine
 as `benchmark/run.py` does, then traces a few prefills and a few decode
 steps with every slot busy, apart, and prints each program's longest ops
-under their HLO names.
+under their HLO names; the paged kernel's calls (kMLA's decode step,
+`ops/paged_attention.py`) are the kind `singa_paged_decode`.
 
     python3 tools/kimi_step_profile.py --workload serve-kimi-decode-sat [--plen 256] [--live 450]
 """

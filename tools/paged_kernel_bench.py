@@ -1,13 +1,16 @@
 """Microbenchmark of the paged decode attention kernel on the chip (a
-builder's tool, not part of the benchmark): the kernel alone at the
-serving cell's geometry under three slot mixes, against the gather
-formulation, as seconds a layer and as a share of the live bytes'
-time at the memory roofline.  `python tools/paged_kernel_bench.py`
+builder's tool, not part of the benchmark): the kernel alone at the two
+serving geometries (the dense cells' key and value pools, the Kimi
+cell's one pool of latent rows), each under its slot mixes, against the
+gather formulation, as seconds a layer and as a share of the live
+bytes' time at the memory roofline.  `python tools/paged_kernel_bench.py`
 prints one JSON line a measurement; fails off the TPU."""
 import functools
 import json
+import math
 import sys
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -16,77 +19,118 @@ import numpy as np
 sys.path.insert(0, ".")
 from singa_tpu.ops import paged_attention as pa  # noqa: E402
 
-S, H, HKV, D, BL, T = 32, 32, 8, 128, 16, 80
-LAYERS = 16
 HBM_BYTES_S = 819e9
 
 
-def mixes(rng):
-    chat = np.zeros(S, np.int32)
+class Geometry(NamedTuple):
+    """One caller's arguments: `sides` pools of (slots x table + 1, hkv,
+    bl, d) bf16, the calls a decode step makes, and what the caller
+    passes beside the arrays."""
+    slots: int
+    heads: int
+    hkv: int
+    d: int
+    bl: int
+    table: int
+    layers: int
+    sides: int
+    scale: float
+    value_dim: int
+
+
+GEOMETRIES = {
+    # mistral7b-serve-l16: kAttention
+    "dense": Geometry(32, 32, 8, 128, 16, 80, 16, 2, 1 / math.sqrt(128), 128),
+    # kimilinear-serve-l17-ep8: kMLA, rank 512 + rope 64 stored as 640
+    "latent": Geometry(96, 32, 1, 640, 16, 128, 4, 1,
+                       1 / math.sqrt(192), 512),
+}
+
+
+def mixes(name, g, rng):
+    full = np.full(g.slots, g.table * g.bl - 1, np.int32)
+    if name == "latent":
+        # the Kimi cell's full house: cb_live_block_share 0.22
+        return {"assist": rng.integers(150, 750, g.slots).astype(np.int32),
+                "full": full}
+    chat = np.zeros(g.slots, np.int32)
     chat[:16] = rng.integers(50, 400, 16)
-    code = rng.integers(300, 1100, S).astype(np.int32)
+    code = rng.integers(300, 1100, g.slots).astype(np.int32)
     code[-2:] = 0
-    return {"chat": chat, "code": code,
-            "full": np.full(S, T * BL - 1, np.int32)}
+    return {"chat": chat, "code": code, "full": full}
 
 
-def timed(fn, args, reps=20):
+def timed(fn, args, layers, reps=20):
     out = fn(*args)
     jax.block_until_ready(out)
     t0 = time.perf_counter()
     for _ in range(reps):
         out = fn(*args)
     jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / reps / LAYERS
+    return (time.perf_counter() - t0) / reps / layers
 
 
-def chain(attn):
-    """LAYERS calls in one program, each fed the one before."""
-    def run(q, kp, vp, tables, ntoks):
+def chain(attn, layers):
+    """`layers` calls in one program, each fed the one before."""
+    def run(q, pools, tables, ntoks):
         def body(_, x):
-            return attn(x, kp, vp, tables, ntoks).astype(x.dtype)
-        return jax.lax.fori_loop(0, LAYERS, body, q)
+            out = attn(x, *pools, tables, ntoks).astype(x.dtype)
+            return jnp.pad(out, ((0, 0), (0, 0),
+                                 (0, x.shape[-1] - out.shape[-1])))
+        return jax.lax.fori_loop(0, layers, body, q)
     return jax.jit(run)
+
+
+def bench(name, g):
+    rng = np.random.default_rng(0)
+    nb = g.slots * g.table + 1
+    dt = jnp.bfloat16
+    q = jnp.asarray(rng.standard_normal((g.slots, g.heads, g.d)), dt)
+    pools = [jnp.asarray(rng.standard_normal((nb, g.hkv, g.bl, g.d)), dt)
+             for _ in range(g.sides)]
+    pools += [None] * (2 - g.sides)
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb))
+                         .reshape(g.slots, g.table).astype(np.int32))
+    how = {"scale": g.scale, "value_dim": g.value_dim}
+    gather = functools.partial(pa.paged_attention_reference, **how)
+    # float32 pools (chip_smoke.py's serve leg serves them): parity only
+    f32 = [a if a is None else a.astype(jnp.float32) for a in [q] + pools]
+    last = list(mixes(name, g, np.random.default_rng(1)).values())[0]
+    err = jnp.max(jnp.abs(
+        pa.paged_decode_attention(*f32, tables, jnp.asarray(last), **how)
+        - gather(*f32, tables, jnp.asarray(last))))
+    print(json.dumps({"geometry": name, "what": "kernel_f32",
+                      "max_err_vs_gather": float(err)}), flush=True)
+    for mix, ntoks in mixes(name, g, rng).items():
+        nt = jnp.asarray(ntoks)
+        live = int(np.sum(ntoks // g.bl + 1))
+        need = live * g.sides * g.hkv * g.bl * g.d * 2 / HBM_BYTES_S
+        ref = gather(q, *pools, tables, nt)
+        rows = {"gather": gather}
+        for pos in (128, 256, 512):
+            rows[f"kernel_{pos}"] = functools.partial(
+                pa.singa_paged_decode, interpret=False, chunk=pos, **how)
+        for label, fn in rows.items():
+            one = jax.jit(fn)(q, *pools, tables, nt)
+            err = float(jnp.max(jnp.abs(one.astype(jnp.float32)
+                                        - ref.astype(jnp.float32))))
+            sec = timed(chain(fn, g.layers), (q, pools, tables, nt),
+                        g.layers)
+            print(json.dumps({
+                "geometry": name, "mix": mix, "what": label,
+                "live_blocks": live,
+                "live_block_share": live / (g.slots * g.table),
+                "us_a_layer": sec * 1e6,
+                f"ms_a_step_{g.layers}_layers": sec * g.layers * 1e3,
+                "roofline_share": need / sec,
+                "max_err_vs_gather": err}), flush=True)
 
 
 def main():
     if jax.default_backend() != "tpu":
         sys.exit("paged_kernel_bench: JAX's default backend is not a TPU")
-    rng = np.random.default_rng(0)
-    nb = S * T + 1
-    dt = jnp.bfloat16
-    q = jnp.asarray(rng.standard_normal((S, H, D)), dt)
-    kp = jnp.asarray(rng.standard_normal((nb, HKV, BL, D)), dt)
-    vp = jnp.asarray(rng.standard_normal((nb, HKV, BL, D)), dt)
-    tables = jnp.asarray(rng.permutation(np.arange(1, nb)).reshape(S, T)
-                         .astype(np.int32))
-    # float32 pools (chip_smoke.py's serve leg serves them): parity only
-    f32 = [a.astype(jnp.float32) for a in (q, kp, vp)]
-    nt = jnp.asarray(mixes(np.random.default_rng(1))["code"])
-    err = jnp.max(jnp.abs(pa.paged_decode_attention(*f32, tables, nt)
-                          - pa.paged_attention_reference(*f32, tables, nt)))
-    print(json.dumps({"mix": "code", "what": "kernel_f32",
-                      "max_err_vs_gather": float(err)}), flush=True)
-    for name, ntoks in mixes(rng).items():
-        nt = jnp.asarray(ntoks)
-        live = int(np.sum(ntoks // BL + 1))
-        need = live * 2 * HKV * BL * D * 2 / HBM_BYTES_S
-        ref = pa.paged_attention_reference(q, kp, vp, tables, nt)
-        rows = {"gather": pa.paged_attention_reference}
-        for pos in (128, 256, 512):
-            rows[f"kernel_{pos}"] = functools.partial(
-                pa.singa_paged_decode, interpret=False, chunk=pos)
-        for label, fn in rows.items():
-            one = jax.jit(fn)(q, kp, vp, tables, nt)
-            err = float(jnp.max(jnp.abs(one.astype(jnp.float32)
-                                        - ref.astype(jnp.float32))))
-            sec = timed(chain(fn), (q, kp, vp, tables, nt))
-            print(json.dumps({
-                "mix": name, "what": label, "live_blocks": live,
-                "live_block_share": live / (S * T),
-                "us_a_layer": sec * 1e6, "ms_a_step_16_layers":
-                sec * LAYERS * 1e3, "roofline_share": need / sec,
-                "max_err_vs_gather": err}), flush=True)
+    for name, g in GEOMETRIES.items():
+        bench(name, g)
 
 
 if __name__ == "__main__":
