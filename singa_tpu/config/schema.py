@@ -258,6 +258,35 @@ class MLAConfig:
 
 
 @dataclass
+class CCAConfig:
+    """kCCA: compressed convolutional attention.  Queries, keys and
+    values live in the compressed widths num_heads x head_dim and
+    num_kv_heads x head_dim; nothing is expanded."""
+    num_heads: int = 8
+    num_kv_heads: int = 2
+    head_dim: int = 128
+    conv_kernel0: int = 2        # the depthwise causal conv's taps
+    conv_kernel1: int = 2        # the per-head mixing conv's taps
+    rotary_factor: float = 0.5   # share of head_dim RoPE turns (the first)
+    rope_theta: float = 10000.0
+
+
+@dataclass
+class ZayaMoEConfig:
+    """kZayaMoE: a softmax MLP router `router_hidden` wide over
+    `num_routed` experts, its state summed with the expert layer's
+    before, of which this process holds `num_held` from `first_held`
+    (0 held = all of them)."""
+    num_routed: int = 16
+    experts_per_token: int = 1
+    num_held: int = 0
+    first_held: int = 0
+    expert_hidden: int = 0
+    router_hidden: int = 256
+    epsilon: float = 1e-5        # of the router's RMSNorm
+
+
+@dataclass
 class EmbedConfig:
     vocab_size: int = 0
     embed_dim: int = 0
@@ -353,6 +382,8 @@ class LayerConfig:
     routed_moe_param: Optional[RoutedMoEConfig] = _msg(RoutedMoEConfig)
     kda_param: Optional[KDAConfig] = _msg(KDAConfig)
     mla_param: Optional[MLAConfig] = _msg(MLAConfig)
+    cca_param: Optional[CCAConfig] = _msg(CCAConfig)
+    zaya_moe_param: Optional[ZayaMoEConfig] = _msg(ZayaMoEConfig)
     embed_param: Optional[EmbedConfig] = _msg(EmbedConfig)
     rmsnorm_param: Optional[RMSNormConfig] = _msg(RMSNormConfig)
     rbm_param: Optional[RBMConfig] = _msg(RBMConfig)

@@ -1,4 +1,5 @@
-"""Mixer and expert layers of hybrid recurrent / latent / sparse LMs:
+"""Mixer, expert and residual layers of hybrid recurrent / latent /
+sparse LMs:
 
   kKDA        Kimi Delta Attention: a gated delta rule whose state is a
               fixed (H, Dk, Dv) float32 matrix per sequence plus the
@@ -10,13 +11,26 @@
               experts, the top k renormalised and scaled, a shared
               expert on every token, and only the experts this process
               holds computed (ops/moe.py); no token is dropped
+  kCCA        compressed convolutional attention: GQA in the
+              compressed widths behind two causal convolutions, a q-k
+              mean and a value half shifted by one token (ops/cca.py).
+              What is cached is of BOTH kinds at once: K and V rows per
+              token in paged blocks, and per slot the convolutions'
+              tails and the last token's shifted value half
+  kZayaMoE    a softmax MLP router read from its own state, which each
+              expert layer hands to the next (a second source and a
+              second, named output: an edge of the layer DAG), top 1,
+              a balancing bias on the choice only; the experts held
+              here computed as kRoutedMoE's are
+  kScaledResidual   (a x + b) + (c f(x) + d), learned per channel
 
-Each implements the decode-state protocol of models/generate.py beside
-`apply`, as kAttention does, so the same walkers and the same cache
-manager serve them.  Matrices are stored (in, out).  `apply` is the
-forward pass over whole sequences; training these layers (gradients
-through the chunked recurrence, an auxiliary loss, expert parallelism
-over a mesh) is not done yet (ROADMAP M1/M3/M4).
+The first five implement the decode-state protocol of
+models/generate.py beside `apply`, as kAttention does, so the same
+walkers and the same cache manager serve them.  Matrices are stored
+(in, out).  `apply` is the forward pass over whole sequences; training
+these layers (gradients through the chunked recurrence, an auxiliary
+loss, expert parallelism over a mesh) is not done yet (ROADMAP
+M1/M3/M4/M8).
 """
 
 from __future__ import annotations
@@ -27,12 +41,14 @@ import jax
 import jax.numpy as jnp
 
 from ..config.schema import ParamConfig
+from ..ops import cca as cca_ops
 from ..ops import kda as kda_ops
 from ..ops import moe as moe_ops
 from ..ops.attention import NEG_INF
 from ..ops.paged_attention import paged_decode_attention
 from .layers import Layer, LayerError, ParamSpec, register_layer
-from .seq_layers import _declare_with_default
+from .seq_layers import (AttentionLayer, _declare_with_default, attend_cache,
+                         write_token)
 
 
 def _dot(x, w):
@@ -488,3 +504,300 @@ class RoutedMoELayer(Layer):
     @staticmethod
     def scatter_prefill(pool, cache, table_row, slot=None):
         return pool
+
+
+# ---------------------------------------------------------------------------
+
+@register_layer("kCCA")
+class CCALayer(Layer):
+    """Compressed convolutional attention over (B, S, E): the equations
+    of ops/cca.py, causal GQA over q^, k^, v in the compressed widths
+    (scores / sqrt(D), RoPE on the first `rotary_factor` of a head),
+    then Wo.  Norms, softmax and the convolutions' sums are float32
+    whatever the params' dtype; c and c' are rounded to the params'
+    dtype where they are kept (a tail holds what a longer run saw).
+
+    The key/value heads split into this token's and the token before's:
+    the first ceil(Hkv / 2) value heads are x_t Wv1, the rest
+    x_{t-1} Wv2."""
+
+    def setup(self, src_shapes):
+        p = self.cfg.cca_param
+        if p is None:
+            raise LayerError(f"{self.name}: cca_param required")
+        b, s, e = tuple(src_shapes[0])
+        self.heads, self.kv_heads = p.num_heads, p.num_kv_heads
+        self.head_dim = d = p.head_dim
+        self.k0, self.k1 = p.conv_kernel0, p.conv_kernel1
+        self.rot = int(d * p.rotary_factor) // 2 * 2
+        self.theta = p.rope_theta
+        if (self.heads % self.kv_heads or self.kv_heads < 2
+                or min(self.k0, self.k1) < 2):
+            raise LayerError(
+                f"{self.name}: {self.heads} query heads over "
+                f"{self.kv_heads} key/value heads (at least 2: one half is "
+                f"the token before's), convolutions of {self.k0} and "
+                f"{self.k1} taps (at least 2)")
+        self.causal = True
+        self.out_shape = (b, s, e)
+        n = self.heads + self.kv_heads
+        self.v_now = (self.kv_heads + 1) // 2 * d
+        self.v_prev = self.kv_heads * d - self.v_now
+        se = 1.0 / math.sqrt(e)
+        dec = _declare_with_default
+        self.wq = dec(self, 0, "wq", (e, self.heads * d), se, 1)
+        self.wk = dec(self, 1, "wk", (e, self.kv_heads * d), se, 1)
+        self.wv1 = dec(self, 2, "wv1", (e, self.v_now), se, 1)
+        self.wv2 = dec(self, 3, "wv2", (e, self.v_prev), se, 1)
+        self.conv0 = dec(self, 4, "conv0", (n * d, self.k0),
+                         1.0 / math.sqrt(self.k0))
+        self.conv1 = dec(self, 5, "conv1", (n, self.k1, d, d),
+                         1.0 / math.sqrt(self.k1 * d))
+        self.wo = dec(self, 6, "wo", (self.heads * d, e),
+                      1.0 / math.sqrt(self.heads * d), 0)
+        self.bias0 = _declare_const(self, "bias0", (n * d,), 0.0)
+        self.bias1 = _declare_const(self, "bias1", (n, d), 0.0)
+        self.tau = _declare_const(self, "tau", (self.kv_heads,), 1.0)
+
+    def _qkv(self, params, x, tails, valid, positions):
+        """x (B, T, E) behind `tails` -> q^ (B, H, T, D), k^, v
+        (B, Hkv, T, D) in x's dtype, and the tails after the last real
+        row."""
+        b, t, _ = x.shape
+        h, hk, d = self.heads, self.kv_heads, self.head_dim
+        proj = lambda w: _dot(x, params[w]).astype(x.dtype)  # noqa: E731
+        pre = jnp.concatenate([proj(self.wq), proj(self.wk)], -1)
+        full, conv = cca_ops.window(pre, tails["conv"], valid)
+        c1 = cca_ops.depthwise(full, params[self.conv0],
+                               params[self.bias0], t).astype(x.dtype)
+        full, mix = cca_ops.window(c1, tails["mix"], valid)
+        c2 = cca_ops.head_mix(full.reshape(b, -1, h + hk, d),
+                              params[self.conv1], params[self.bias1], t)
+        m_q, m_k = cca_ops.qk_mean(pre.reshape(b, t, h + hk, d), h, hk)
+        q = cca_ops.unit_heads(c2[:, :, :h] + m_q)
+        k = cca_ops.unit_heads(c2[:, :, h:] + m_k, params[self.tau])
+        q = cca_ops.partial_rope(q, positions, self.rot, self.theta)
+        k = cca_ops.partial_rope(k, positions, self.rot, self.theta)
+        full, vprev = cca_ops.window(proj(self.wv2), tails["vprev"], valid)
+        v = jnp.concatenate([proj(self.wv1), full[:, :t]], -1)
+        heads_first = lambda a, n: a.astype(x.dtype).reshape(  # noqa: E731
+            b, t, n, d).transpose(0, 2, 1, 3)
+        return (heads_first(q, h), heads_first(k, hk), heads_first(v, hk),
+                {"conv": conv, "mix": mix, "vprev": vprev})
+
+    def apply(self, params, srcs, ctx):
+        x = srcs[0]
+        return self.apply_cached(
+            params, x, self.init_cache(x.shape[0], x.shape[1], x.dtype),
+            0)[0]
+
+    # -- decode state: rows per token AND a tail per slot -------------------
+    def _tails(self, rows: int, dtype):
+        c = (self.heads + self.kv_heads) * self.head_dim
+        return {"conv": jnp.zeros((rows, self.k0 - 1, c), dtype),
+                "mix": jnp.zeros((rows, self.k1 - 1, c), dtype),
+                "vprev": jnp.zeros((rows, 1, self.v_prev), dtype)}
+
+    def init_cache(self, batch: int, max_len: int, dtype):
+        shape = (batch, self.kv_heads, max_len, self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+                **self._tails(batch, dtype)}
+
+    def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
+                  dtype):
+        """K and V in paged blocks, (num_blocks, Hkv, block_len, D) a
+        side, and the tails of each of `num_slots` slots."""
+        if num_slots < 1:
+            raise ValueError(f"{self.name}: a tail per slot needs "
+                             f"num_slots >= 1")
+        shape = (num_blocks, self.kv_heads, block_len, self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+                **self._tails(num_slots, dtype)}
+
+    def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
+        """A chunk of T tokens at offset `pos` against the cached rows,
+        from the tails `entry` holds; the tails handed back are those
+        after the chunk's last REAL row."""
+        t = x.shape[1]
+        valid = _valid_rows(t, pos, kmask, plen)
+        q, k, v, tails = self._qkv(params, x, entry, valid,
+                                   pos + jnp.arange(t))
+        k_cache = jax.lax.dynamic_update_slice(
+            entry["k"], k.astype(entry["k"].dtype), (0, 0, pos, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            entry["v"], v.astype(entry["v"].dtype), (0, 0, pos, 0))
+        o = attend_cache(q, k_cache, v_cache, pos, kmask)
+        return (_dot(o.astype(x.dtype), params[self.wo]).astype(x.dtype),
+                {"k": k_cache, "v": v_cache, **tails})
+
+    def apply_paged(self, params, x, entry, tables, ntoks):
+        """x (1, S, E): slot s's token from slot s's tails, its K and V
+        rows written at position ntoks[s] (whole blocks, as kAttention
+        writes them), then the paged kernel over the slot's live
+        blocks.  A slot that is not in use keeps its tails."""
+        s, bl = x.shape[1], entry["k"].shape[2]
+        q, k, v, tails = self._qkv(params, x[0][:, None, :], entry, None,
+                                   ntoks[:, None])
+        busy = (ntoks > 0)[:, None, None]
+        tails = {n: jnp.where(busy, a, entry[n]) for n, a in tails.items()}
+        bidx = tables[jnp.arange(s), ntoks // bl]
+        k_pool = write_token(entry["k"], bidx, ntoks % bl, k[:, :, 0])
+        v_pool = write_token(entry["v"], bidx, ntoks % bl, v[:, :, 0])
+        o = paged_decode_attention(q[:, :, 0], k_pool, v_pool, tables, ntoks)
+        out = _dot(o.reshape(s, -1).astype(x.dtype), params[self.wo])
+        return (out.astype(x.dtype)[None],
+                {"k": k_pool, "v": v_pool, **tails})
+
+    @staticmethod
+    def scatter_prefill(pool, cache, table_row, slot):
+        out = AttentionLayer.scatter_prefill(pool, cache, table_row)
+        for n in ("conv", "mix", "vprev"):
+            out[n] = pool[n].at[slot].set(cache[n][0].astype(pool[n].dtype))
+        return out
+
+
+# ---------------------------------------------------------------------------
+
+def named_output(src, name: str = "out"):
+    """The output `name` of a source that has several (a dict of named
+    outputs, as kZayaMoE's), the source itself where it has one."""
+    return src[name] if isinstance(src, dict) else src
+
+
+def _sources(srcs):
+    """A layer's sources as the decode walkers hand them (the one array,
+    or the list of several), as the list `apply` takes."""
+    return list(srcs) if isinstance(srcs, (list, tuple)) else [srcs]
+
+
+@register_layer("kZayaMoE")
+class ZayaMoELayer(Layer):
+    """Top-1 experts under an MLP router with a state of its own, over
+    (B, S, E).  Sources: the normed input and, in every expert layer
+    but the first, the expert layer before (its output "router").
+    Outputs, by name: "out" (B, S, E) and "router" (B, S, R) float32:
+
+        r = x Wd + bd (+ gamma * the state handed in)
+        p = softmax(W3 gelu(W2 gelu(W1 RMSNorm(r) + b1) + b2))
+        e* = argmax(p + bal);  out = p[e*] expert_{e*}(x)   where held
+
+    The router is float32 at full precision; `num_held` experts from
+    `first_held` are computed here, as kRoutedMoE's are."""
+
+    def setup(self, src_shapes):
+        p = self.cfg.zaya_moe_param
+        if p is None:
+            raise LayerError(f"{self.name}: zaya_moe_param required")
+        b, s, e = tuple(src_shapes[0])
+        self.n_routed, self.k = p.num_routed, p.experts_per_token
+        self.n_held = p.num_held or p.num_routed
+        self.first = p.first_held
+        if self.first < 0 or self.first + self.n_held > self.n_routed:
+            raise LayerError(
+                f"{self.name}: held experts {self.first}.."
+                f"{self.first + self.n_held - 1} of {self.n_routed}")
+        r, self.eps = p.router_hidden, p.epsilon
+        self.carried = len(src_shapes) > 1
+        if self.carried and tuple(src_shapes[1]["router"]) != (b, s, r):
+            raise LayerError(
+                f"{self.name}: a router state of {src_shapes[1]['router']} "
+                f"handed to a router {r} wide")
+        f = p.expert_hidden or 4 * e
+        self.out_shape = {"out": (b, s, e), "router": (b, s, r)}
+        se, sr, sf = (1.0 / math.sqrt(n) for n in (e, r, f))
+        dec = _declare_with_default
+        self.w_down_r = dec(self, 0, "router_down", (e, r), se)
+        self.mlp = [dec(self, 1, "router_w1", (r, r), sr),
+                    dec(self, 2, "router_w2", (r, r), sr),
+                    dec(self, 3, "router_w3", (r, self.n_routed), sr)]
+        x = self.n_held
+        self.w_gate = dec(self, 4, "w_gate", (x, e, f), se, 0,
+                          mesh_axis="expert")
+        self.w_up = dec(self, 5, "w_up", (x, e, f), se, 0,
+                        mesh_axis="expert")
+        self.w_down = dec(self, 6, "w_down", (x, f, e), sf, 0,
+                          mesh_axis="expert")
+        self.b_down_r = _declare_const(self, "router_down_bias", (r,), 0.0)
+        self.mlp_bias = [_declare_const(self, "router_b1", (r,), 0.0),
+                         _declare_const(self, "router_b2", (r,), 0.0), None]
+        self.router_norm = _declare_const(self, "router_norm", (r,), 1.0)
+        self.router_bias = _declare_const(self, "router_bias",
+                                          (self.n_routed,), 0.0)
+        self.gamma = (_declare_const(self, "gamma", (r,), 0.5)
+                      if self.carried else None)
+
+    def _ffn(self, params, x, state, valid):
+        """x (T, E), state (T, R) or None -> (out (T, E), the router's
+        state (T, R) float32, counts int32 (3,))."""
+        f32 = jnp.float32
+        r = _dot(x, params[self.w_down_r]) + params[self.b_down_r].astype(f32)
+        if self.carried:
+            r = r + params[self.gamma].astype(f32) * state.astype(f32)
+        idx, weights = moe_ops.route_mlp_softmax(
+            r, params[self.router_norm], self.eps,
+            [(params[w], None if b is None else params[b])
+             for w, b in zip(self.mlp, self.mlp_bias)],
+            params[self.router_bias], self.k)
+        y, counts = moe_ops.held_experts_ffn(
+            x, idx, weights, params[self.w_gate], params[self.w_up],
+            params[self.w_down], self.first, valid, max_load=True)
+        return y.astype(x.dtype), r, counts
+
+    def _run(self, params, srcs, valid):
+        """The sources (the normed input, then the layer before where
+        there is one) -> ({"out", "router"}, counts)."""
+        x = srcs[0]
+        b, t, e = x.shape
+        state = (named_output(srcs[1], "router").reshape(b * t, -1)
+                 if self.carried else None)
+        if valid is not None:
+            valid = jnp.broadcast_to(valid, (b, t)).reshape(b * t)
+        y, r, counts = self._ffn(params, x.reshape(b * t, e), state, valid)
+        return {"out": y.reshape(b, t, e),
+                "router": r.reshape(b, t, -1)}, counts
+
+    def apply(self, params, srcs, ctx):
+        return self._run(params, srcs, None)[0]
+
+    # -- decode state: none but the last decode step's routing counts -----
+    def init_cache(self, batch: int, max_len: int, dtype):
+        return {}
+
+    def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
+                  dtype):
+        """[assignments on held experts, held experts touched, the
+        busiest held expert's assignments] of the last decode step,
+        busy slots only."""
+        return {"routed": jnp.zeros((3,), jnp.int32)}
+
+    def apply_cached(self, params, srcs, entry, pos, kmask=None, plen=None):
+        srcs = _sources(srcs)
+        valid = _valid_rows(srcs[0].shape[1], pos, kmask, plen)
+        return self._run(params, srcs, valid)[0], entry
+
+    def apply_paged(self, params, srcs, entry, tables, ntoks):
+        out, counts = self._run(params, _sources(srcs),
+                                (ntoks > 0)[None, :])
+        return out, {"routed": counts}
+
+    @staticmethod
+    def scatter_prefill(pool, cache, table_row, slot=None):
+        return pool
+
+
+@register_layer("kScaledResidual")
+class ScaledResidualLayer(Layer):
+    """out = (a * srcs[0] + b) + (c * srcs[1] + d): a residual whose two
+    terms each carry a learned scale and bias per channel (a, c start
+    at 1 and b, d at 0: kResidualAdd).  Summed in float32."""
+
+    def setup(self, src_shapes):
+        self.out_shape = shape = tuple(src_shapes[0])
+        self.keys = [_declare_const(self, n, shape[-1:], v)
+                     for n, v in (("a", 1.0), ("b", 0.0), ("c", 1.0),
+                                  ("d", 0.0))]
+
+    def apply(self, params, srcs, ctx):
+        x, y = srcs[0], named_output(srcs[1])
+        a, b, c, d = (params[k].astype(jnp.float32) for k in self.keys)
+        return ((a * x + b) + (c * y + d)).astype(x.dtype)

@@ -161,6 +161,41 @@ def write_token(pool, bidx, off, new):
 
 
 
+def attend_cache(q, k_cache, v_cache, pos, kmask=None):
+    """A chunk's queries q (B, H, T, D), the first at absolute position
+    `pos`, against contiguous caches (B, Hkv, max_len, D) that already
+    hold the chunk's own rows: causal, `kmask` (B, max_len) ANDed in,
+    GQA read at Hkv width, f32 scores and softmax.  Returns
+    (B, T, H * D) in the values' dtype."""
+    b, heads, t, head_dim = q.shape
+    kv_heads = k_cache.shape[1]
+    groups = heads // kv_heads
+    kk = k_cache.astype(q.dtype)
+    vv = v_cache.astype(q.dtype)
+    qpos = pos + jnp.arange(t)[:, None]            # (T, 1) absolute
+    kpos = jnp.arange(kk.shape[2])[None, :]        # (1, max_len)
+    allowed = (kpos <= qpos)[None]                 # (1, T, max_len)
+    if kmask is not None:
+        allowed = allowed & kmask[:, None, :]      # (B, T, max_len)
+    if groups == 1:
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, kk,
+                            preferred_element_type=jnp.float32)
+        scores = scores / jnp.sqrt(jnp.float32(head_dim))
+        scores = jnp.where(allowed[:, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vv.dtype), vv)
+    else:
+        qg = q.reshape(b, kv_heads, groups, t, head_dim)
+        scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kk,
+                            preferred_element_type=jnp.float32)
+        scores = scores / jnp.sqrt(jnp.float32(head_dim))
+        scores = jnp.where(allowed[:, None, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(vv.dtype), vv)
+        out = out.reshape(b, heads, t, head_dim)
+    return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
+
+
 @register_layer("kAttention")
 class AttentionLayer(Layer):
     """Multi-head (GQA) causal self-attention with RoPE.
@@ -360,31 +395,7 @@ class AttentionLayer(Layer):
         v_cache = jax.lax.dynamic_update_slice(
             entry["v"], v.astype(entry["v"].dtype), (0, 0, pos, 0))
 
-        groups = self.heads // self.kv_heads
-        kk = k_cache.astype(q.dtype)
-        vv = v_cache.astype(q.dtype)
-        qpos = pos + jnp.arange(t)[:, None]            # (T, 1) absolute
-        kpos = jnp.arange(kk.shape[2])[None, :]        # (1, max_len)
-        allowed = (kpos <= qpos)[None]                 # (1, T, max_len)
-        if kmask is not None:
-            allowed = allowed & kmask[:, None, :]      # (B, T, max_len)
-        if groups == 1:
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, kk,
-                                preferred_element_type=jnp.float32)
-            scores = scores / jnp.sqrt(jnp.float32(self.head_dim))
-            scores = jnp.where(allowed[:, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vv.dtype), vv)
-        else:
-            qg = q.reshape(b, self.kv_heads, groups, t, self.head_dim)
-            scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kk,
-                                preferred_element_type=jnp.float32)
-            scores = scores / jnp.sqrt(jnp.float32(self.head_dim))
-            scores = jnp.where(allowed[:, None, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(vv.dtype), vv)
-            out = out.reshape(b, self.heads, t, self.head_dim)
-        out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
+        out = attend_cache(q, k_cache, v_cache, pos, kmask)
         out = self._proj(params, self.wo, out.astype(x.dtype), DECODE_CTX)
         return out, {"k": k_cache, "v": v_cache}
 

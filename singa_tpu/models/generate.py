@@ -43,12 +43,19 @@ Cache = Dict[str, CacheEntry]         # layer name -> its entry
 #   init_cache(batch, max_len, dtype)            contiguous state
 #   apply_cached(params, x, entry, pos, kmask=, plen=) -> (out, entry)
 #   init_pool(num_slots, num_blocks, block_len, dtype)   serving state:
-#       rows per token in paged blocks, or one fixed state per slot
+#       rows per token in paged blocks, one fixed state per slot, or
+#       both in one entry
 #   apply_paged(params, x, entry, tables, ntoks)  -> (out, entry)
 #   scatter_prefill(pool, cache, table_row, slot) -> pool
+# `x` is the layer's source, or the list of them where the layer has
+# several; `out` is an array, or a dict of named outputs that the
+# layer's consumers read by name (`hybrid_layers.named_output`).
 # kAttention (K/V per token), kMLA (a latent row per token), kKDA (a
-# recurrent state and a conv tail per slot) and kRoutedMoE (no state;
-# its step's routing counts) implement it.
+# recurrent state and a conv tail per slot), kCCA (K/V per token AND
+# conv tails and a shifted value per slot), kRoutedMoE (no state; its
+# step's routing counts) and kZayaMoE (the same; its second source and
+# second output are the router's state, an edge from expert layer to
+# expert layer) implement it.
 
 
 def keeps_state(layer) -> bool:
@@ -80,8 +87,9 @@ def _walk(net: NeuralNet, params, tokens, state: Cache, step):
         elif ltype == "kSeqLabel":
             outputs[name] = tokens
         elif keeps_state(layer):
-            outputs[name], new_state[name] = step(layer, full, srcs[0],
-                                                  state[name])
+            outputs[name], new_state[name] = step(
+                layer, full, srcs[0] if len(srcs) == 1 else srcs,
+                state[name])
         elif ltype == "kLMHead":
             outputs[name] = layer.apply(full, srcs, DECODE_CTX)
             logits = outputs[name]

@@ -148,24 +148,33 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
               ffns: List[Dict], seq_len: int = 1024, batchsize: int = 1,
               epsilon: float = 1e-5, precision: str = "float32",
               train_steps: int = 1000,
-              learning_rate: float = 3e-4) -> ModelConfig:
+              learning_rate: float = 3e-4, tie_head: bool = False,
+              scaled_residual: bool = False) -> ModelConfig:
     """A decoder whose layers differ: block i is
 
         x += mixer_i(rmsnorm(x));  x += ffn_i(rmsnorm(x))
 
     with `mixers[i]` one of {"kda": {...KDAConfig}}, {"mla":
-    {...MLAConfig}}, {"attention": {...AttentionConfig}} and `ffns[i]`
-    one of {"dense": {...FFNConfig}}, {"moe": {...RoutedMoEConfig}}
+    {...MLAConfig}}, {"attention": {...AttentionConfig}}, {"cca":
+    {...CCAConfig}} and `ffns[i]` one of {"dense": {...FFNConfig}},
+    {"moe": {...RoutedMoEConfig}}, {"zaya_moe": {...ZayaMoEConfig}}
     (a leading dense layer before sparse ones, mixers in any period).
-    Final RMSNorm, untied fused head.  Layer names follow
-    `transformer_lm`'s: ln{i}a, <kind>{i}, res{i}a, ln{i}b, ffn{i} or
-    moe{i}, res{i}b, ln_f, loss."""
+    A "zaya_moe" layer takes the one before it as a second source (its
+    router's state travels from expert layer to expert layer).  With
+    `scaled_residual` both terms of every residual carry a learned
+    scale and bias: x = (a x + b) + (c f(rmsnorm(x)) + d).  Final
+    RMSNorm and a fused head, tied to the embedding with `tie_head`.
+    Layer names follow `transformer_lm`'s: ln{i}a, <kind>{i}, res{i}a,
+    ln{i}b, ffn{i} or <kind>{i}, res{i}b, ln_f, loss."""
     if len(mixers) != len(ffns):
         raise ValueError(f"{len(mixers)} mixers for {len(ffns)} ffns")
     kinds = {"kda": ("kKDA", "kda_param"), "mla": ("kMLA", "mla_param"),
              "attention": ("kAttention", "attention_param"),
+             "cca": ("kCCA", "cca_param"),
              "dense": ("kFeedForward", "ffn_param"),
-             "moe": ("kRoutedMoE", "routed_moe_param")}
+             "moe": ("kRoutedMoE", "routed_moe_param"),
+             "zaya_moe": ("kZayaMoE", "zaya_moe_param")}
+    residual = "kScaledResidual" if scaled_residual else "kResidualAdd"
     norm = {"rmsnorm_param": {"epsilon": epsilon}}
     layers: List[Dict] = [
         {"name": "data", "type": "kSequenceData",
@@ -175,29 +184,38 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
         {"name": "embed", "type": "kEmbed", "srclayers": "data",
          "embed_param": {"vocab_size": vocab_size, "embed_dim": embed_dim}},
     ]
-    src = "embed"
+    src, router = "embed", None      # the last layer with a router state
     for i, (mixer, ffn) in enumerate(zip(mixers, ffns)):
-        for half, spec, want in (("a", mixer, ("kda", "mla", "attention")),
-                                 ("b", ffn, ("dense", "moe"))):
+        for half, spec, want in (
+                ("a", mixer, ("kda", "mla", "attention", "cca")),
+                ("b", ffn, ("dense", "moe", "zaya_moe"))):
             (kind, param), = spec.items()
             if kind not in want:
                 raise ValueError(f"layer {i}: {kind!r} is not one of {want}")
             ltype, field = kinds[kind]
             name = f"{'ffn' if kind == 'dense' else kind}{i}"
+            srcs = [f"ln{i}{half}"]
+            if kind == "zaya_moe":
+                if router:
+                    srcs.append(router)
+                router = name
             layers += [
                 {"name": f"ln{i}{half}", "type": "kRMSNorm",
                  "srclayers": src, **norm},
-                {"name": name, "type": ltype, "srclayers": f"ln{i}{half}",
+                {"name": name, "type": ltype, "srclayers": srcs,
                  field: dict(param)},
-                {"name": f"res{i}{half}", "type": "kResidualAdd",
+                {"name": f"res{i}{half}", "type": residual,
                  "srclayers": [src, name]}]
             src = f"res{i}{half}"
+    head = {"name": "loss", "type": "kLMHeadLoss",
+            "srclayers": ["ln_f", "labels"],
+            "embed_param": {"vocab_size": vocab_size, "embed_dim": embed_dim},
+            "softmaxloss_param": {"topk": 1}}
+    if tie_head:
+        head.update(share_param=["embed/embedding"], param=[{"name": "w"}])
     layers += [
         {"name": "ln_f", "type": "kRMSNorm", "srclayers": src, **norm},
-        {"name": "loss", "type": "kLMHeadLoss",
-         "srclayers": ["ln_f", "labels"],
-         "embed_param": {"vocab_size": vocab_size, "embed_dim": embed_dim},
-         "softmaxloss_param": {"topk": 1}}]
+        head]
     return model_config_from_dict({
         "name": f"hybrid-lm-{len(mixers)}L{embed_dim}E",
         "train_steps": train_steps, "display_frequency": 50,

@@ -114,16 +114,40 @@ def route_sigmoid(x, router, bias, k: int, renormalize: bool,
     return idx.astype(jnp.int32), chosen * scale
 
 
+def route_mlp_softmax(state, norm_scale, eps: float, layers, bias, k: int):
+    """A softmax MLP router over every routed expert, read from the
+    router's own state: p = softmax(W3 gelu(W2 gelu(W1 RMSNorm(state) +
+    b1) + b2)), the k with the largest p + bias chosen, weighted by
+    their p alone.  state (T, R) float32; `layers` the (weight, bias or
+    None) pairs of the MLP, stored (in, out); bias (N,) moves the
+    choice only.  All of it float32 at full precision whatever the
+    weights' dtype: a row's whole expert hangs on an argmax.  Returns
+    (idx (T, k) int32, weights (T, k) float32)."""
+    f32 = jnp.float32
+    var = jnp.mean(jnp.square(state), axis=-1, keepdims=True)
+    h = state * jax.lax.rsqrt(var + eps) * norm_scale.astype(f32)
+    for i, (w, b) in enumerate(layers):
+        if i:
+            h = jax.nn.gelu(h, approximate=False)
+        h = jnp.dot(h, w.astype(f32), precision=jax.lax.Precision.HIGHEST)
+        if b is not None:
+            h = h + b.astype(f32)
+    p = jax.nn.softmax(h, axis=-1)
+    _, idx = jax.lax.top_k(p + bias.astype(f32), k)
+    return idx.astype(jnp.int32), jnp.take_along_axis(p, idx, axis=-1)
+
+
 def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
-                     valid=None):
+                     valid=None, max_load: bool = False):
     """What the experts held here add for each token: expert j of the
     stacked weights is routed expert `first + j`.  x (T, E); idx,
     weights (T, k) from the router over ALL experts; w_gate, w_up
     (X, E, F); w_down (X, F, E); valid (T,) bool or None (a pad is
     routed nowhere).  Returns (y (T, E) float32, counts int32 (2,):
     assignments that fell on held experts, held experts some token
-    chose).  A token none of whose experts is held gets zeros; no token
-    is dropped and no row depends on another.
+    chose; with `max_load` a third, the busiest held expert's
+    assignments).  A token none of whose experts is held gets zeros; no
+    token is dropped and no row depends on another.
 
     Every row goes through every held expert, the pairs the router did
     not choose weighted 0: with the experts' weights as the traffic and
@@ -141,8 +165,10 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
     onehot = (local[:, :, None] == jnp.arange(n_held)) & held[:, :, None]
     combine = jnp.sum(jnp.where(onehot, weights[:, :, None], 0.0), axis=1)
     per_expert = jnp.sum(jnp.any(onehot, axis=1), axis=0)    # (X,)
-    counts = jnp.stack([jnp.sum(per_expert),
-                        jnp.sum(per_expert > 0)]).astype(jnp.int32)
+    counts = [jnp.sum(per_expert), jnp.sum(per_expert > 0)]
+    if max_load:
+        counts.append(jnp.max(per_expert))
+    counts = jnp.stack(counts).astype(jnp.int32)
 
     def through(rows, row_combine):
         gate = jnp.einsum("te,xef->txf", rows, w_gate,

@@ -327,12 +327,15 @@ class InferenceEngine:
         # takes the slot's index), routing counts (the decode program
         # returns them behind its tokens)
         self._per_slot_state = state_bytes(net, 1)["slot"] > 0
-        self._routed_layers = tuple(
-            name for name, entry in jax.eval_shape(
-                lambda: init_pools(net, 2, 1, jnp.float32, 1)).items()
-            if "routed" in entry)
-        # how many routing counts ride behind a decode step's tokens
-        self._cb_tail = 2 if self._routed_layers else 0
+        routed = {name: entry["routed"].shape[0]
+                  for name, entry in jax.eval_shape(
+                      lambda: init_pools(net, 2, 1, jnp.float32, 1)).items()
+                  if "routed" in entry}
+        self._routed_layers = tuple(routed)
+        # how many routing counts ride behind a decode step's tokens:
+        # assignments, experts touched and, where a layer counts it,
+        # the busiest expert's assignments
+        self._cb_tail = max(routed.values(), default=0)
         self.stats = stats if stats is not None else ServeStats()
         self.log = log_fn
         self.ckpt = (CheckpointManager(workspace, log_fn=log_fn)
@@ -763,8 +766,12 @@ class InferenceEngine:
         def cb_decode(params, pools, tokens, ntoks, tables, key):  # noqa: F811
             nxt, pools = step(params, pools, tokens[:spec.cb_slots], ntoks,
                               tables, key)
-            routed = sum(pools[name]["routed"]
-                         for name in self._routed_layers)
+            tail = self._cb_tail
+            routed = sum(
+                c if c.shape[0] == tail else jnp.pad(
+                    c, (0, tail - c.shape[0]))
+                for c in (pools[name]["routed"]
+                          for name in self._routed_layers))
             return jnp.concatenate([nxt, routed]), pools
 
         return cb_decode
@@ -962,7 +969,8 @@ class InferenceEngine:
         if self._cb_tail:
             s = self.spec.cb_slots
             self.stats.observe_routing(int(nxt[s]), int(nxt[s + 1]),
-                                       len(self._routed_layers))
+                                       len(self._routed_layers),
+                                       int(nxt[s + 2:].sum()))
             nxt = nxt[:s]
         return nxt
 

@@ -3,7 +3,11 @@ with host-side block tables and refcounts.
 
 A layer that keeps state between decode steps declares it
 (`layer.init_pool`, the decode-state protocol of `models/generate.py`)
-in one of two kinds, and this manager holds both:
+in one of two kinds, or in both at once (a `kCCA` layer: K and V rows
+per token AND its convolutions' tails and shifted value half per slot,
+in ONE entry), and this manager holds both, allocates them together,
+counts each part where it belongs (`state_bytes`) and hands the
+prefill program the table row and the slot behind it:
 
   * rows per token in PAGED BLOCKS — K and V of a `kAttention` layer,
     (num_blocks, Hkv, block_len, D) per side; the latent row of a `kMLA`
@@ -63,9 +67,10 @@ def init_pools(net, num_blocks: int, block_len: int,
                dtype=jnp.float32, num_slots: int = 0) -> Pools:
     """Zeroed serving state of every layer that keeps one, each in the
     shape its kind declares (`layer.init_pool`): rows per token in
-    `num_blocks` paged blocks of `block_len` (K/V of kAttention, the
-    latent of kMLA), or one fixed state for each of `num_slots` slots
-    (kKDA).  The paged sibling of `generate.init_cache`."""
+    `num_blocks` paged blocks of `block_len` (K/V of kAttention and
+    kCCA, the latent of kMLA), one fixed state for each of `num_slots`
+    slots (kKDA; kCCA's tails), or both in one entry.  The paged
+    sibling of `generate.init_cache`."""
     return {name: layer.init_pool(num_slots, num_blocks, block_len, dtype)
             for name, layer in _stateful(net)}
 
@@ -86,7 +91,8 @@ def pool_bytes(net, num_blocks: int, block_len: int,
 def state_bytes(net, block_len: int, dtype=jnp.float32) -> Dict[str, int]:
     """What one slot and one block cost: `slot` the bytes of the fixed
     per-slot states of all layers, `block` the bytes one more paged
-    block adds over all layers."""
+    block adds over all layers.  Read off the pools' shapes at two
+    sizes, so a layer whose entry holds both kinds adds to both."""
     at = lambda slots, blocks: pool_bytes(      # noqa: E731
         net, blocks, block_len, dtype, slots)
     base = at(1, 1)

@@ -135,6 +135,7 @@ class ServeStats:
         self.cb_routed_layer_steps = 0   # layers x steps counted
         self.cb_routed_assignments = 0   # (token, held expert) pairs
         self.cb_routed_experts_touched = 0  # held experts some token chose
+        self.cb_routed_max_load = 0      # the busiest held expert's pairs
         # batching
         self.batches = 0
         self.batched_requests = 0
@@ -258,13 +259,16 @@ class ServeStats:
             self._cb_t.append((time.monotonic(), int(active_slots)))
 
     def observe_routing(self, assignments: int, experts_touched: int,
-                        layers: int) -> None:
+                        layers: int, max_load: int = 0) -> None:
         """One decode step's routing counts, summed over its `layers`
-        routed layers."""
+        routed layers.  `max_load`: each layer's busiest held expert's
+        assignments, where the layers count it; over `assignments` /
+        experts held it is the imbalance (1 = every expert alike)."""
         with self._lock:
             self.cb_routed_layer_steps += int(layers)
             self.cb_routed_assignments += int(assignments)
             self.cb_routed_experts_touched += int(experts_touched)
+            self.cb_routed_max_load += int(max_load)
 
     # -- reads -------------------------------------------------------------
     def latency_quantile(self, q: float) -> Optional[float]:
@@ -424,7 +428,7 @@ class ServeStats:
                     "cb_prefills", "cb_prefill_rows",
                     "cb_prefill_width_rows", "cb_admit_steps",
                     "cb_routed_layer_steps", "cb_routed_assignments",
-                    "cb_routed_experts_touched",
+                    "cb_routed_experts_touched", "cb_routed_max_load",
                     "compiles", "reloads", "reload_failures",
                     "reloads_refused", "torn_polls",
                     "reload_poll_deaths")
@@ -518,6 +522,7 @@ class ServeStats:
                 "cb_routed_assignments": self.cb_routed_assignments,
                 "cb_routed_experts_touched":
                     self.cb_routed_experts_touched,
+                "cb_routed_max_load": self.cb_routed_max_load,
                 "consecutive_batch_failures":
                     self.consecutive_batch_failures,
                 "compiles": self.compiles,
