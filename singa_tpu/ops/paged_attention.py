@@ -13,14 +13,26 @@ ntoks[s] // block_len + 1 blocks of its table row; the rest of the row
 
 `paged_decode_attention` is the kernel: the pools stay in HBM, the block
 table and the lengths are scalar-prefetched, and each slot's live blocks
-are copied HBM -> VMEM a chunk (256 positions) at a time, double
-buffered, the next slot's first chunk in flight while this slot's last
-one is computed.  Scores and the online softmax are f32; GQA is done in
-the kernel (q grouped (Hkv, G, D), no expanded K/V).  Two callers, one
-body, told apart by their arguments' shapes: `kAttention` (separate key
-and value pools, 1 / sqrt(D)) and `kMLA`'s absorbed decode step (one
-pool of latent rows shared by all heads, Hkv 1, whose leading columns
-are the value, so a block is copied once; its own scale).
+are copied HBM -> VMEM a chunk at a time, double buffered, the next
+slot's first chunk in flight while this slot's last one is computed.
+Scores and the online softmax are f32; GQA is done in the kernel (q
+grouped (Hkv, G, D), no expanded K/V).  Three callers, one body, told
+apart by their arguments' shapes: `kAttention` (separate key and value
+pools, 1 / sqrt(D)), `kCCA` (the same with few, narrow heads) and
+`kMLA`'s absorbed decode step (one pool of latent rows shared by all
+heads, Hkv 1, whose leading columns are the value, so a block is copied
+once; its own scale).
+
+The copy schedule follows the pool's shape.  Beside its transfer a live
+byte costs scalar work a block (a table read, a descriptor and an issue
+a pool) and fixed work a chunk, and neither shrinks with the block: a
+block of (8, 16, 128) bf16 is a 32 KB copy that hides them, one of
+(2, 16, 128) an 8 KB copy that does not.  So a chunk is a number of
+bytes (`chunk_positions`); a chunk's copies of one pool signal one DMA
+semaphore, which counts bytes, and are waited for together, by size,
+with no second walk of the table; and `start` issues a group of blocks
+in straight-line code before it loops.
+
 `paged_attention_reference` is the plain-jnp gather of every slot's
 whole table, the formulation the serving engine ran before the kernel:
 it materialises (S, T, Hkv, block_len, D) per side and is kept only as
@@ -44,11 +56,37 @@ from . import attention as _attention
 #: Stable name of the Mosaic custom call in compiled modules.
 KERNEL_NAME = "singa_paged_decode_kernel"
 
-# Key positions one loop iteration attends.  On the v5e at 32 slots x 80
-# blocks of (8, 16, 128) bf16 (tools/paged_kernel_bench.py): 128 / 256 /
-# 512 positions took 161 / 143 / 142 us a layer with 57 % of the table
-# live and 41 / 44 / 67 us with 8 % live.
-_CHUNK_POSITIONS = 256
+# Bytes of one pool that a chunk (one trip of a slot's loop) brings in.
+# A trip's fixed work (the loop's carry, the softmax's rescale, a wait a
+# pool) is paid a chunk and a copy's scalar work a block, whatever the
+# bytes they move, so the chunk is a number of bytes and its positions
+# follow the pool's row: 256 at the dense cells' 8 heads x 128 bf16,
+# 512 at a latent pool's 1 x 640, 1,024 at kCCA's 2 x 128.  On the v5e
+# (tools/paged_kernel_bench.py; PERF.md 6, PR 34), at 128 / 256 / 512 /
+# 1,024 positions a chunk, us a layer: 32 slots x 80 blocks of
+# (8, 16, 128) 161 / 141 / 139 / 140 with 57 % of the table live and
+# 37 / 34 / 42 / 63 with 8 % live (the masked tail of a slot's last
+# chunk is computed whole); 96 x 128 blocks of (1, 16, 640), 23 % live,
+# 242 / 182 / 157 / 166; 64 x 256 blocks of (2, 16, 128), 26 % live,
+# 296 / 219 / 191 / 181 (2,048: slower again).
+_CHUNK_BYTES = 512 * 1024
+
+# Blocks whose copies `start` issues in straight-line code (the table
+# reads and descriptors of one overlap the next's) before it loops: 1 /
+# 4 / 8 / 16 read 211 / 194 / 181 / 180 us at the last geometry above,
+# and all the same at the first, where the transfer hides the issue.
+# Mosaic's only: the interpreter gains nothing from it and pays for
+# every copy it traces (a decode program's lowering and compile on the
+# CPU 1.4 -> 4.1 s), so `paged_decode_attention` gives it 1.
+_ISSUE_GROUP = 8
+
+
+def chunk_positions(pool_shape, dtype):
+    """Key positions a chunk holds of a (num_blocks, Hkv, bl, D) pool:
+    the power of two whose rows come nearest `_CHUNK_BYTES`."""
+    _, hkv, _, d = pool_shape
+    row = hkv * d * jnp.dtype(dtype).itemsize
+    return 2 ** round(math.log2(_CHUNK_BYTES / row))
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
@@ -83,7 +121,8 @@ def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
     return out.reshape(s, h, vv.shape[-1])
 
 
-def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides):
+def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
+            group):
     """Grid step s attends slot s.  `refs` is the `sides` pools in HBM
     (keys, then values; one pool where the values are columns of the key
     rows), the output, a buffer a pool, the copies' semaphores and
@@ -107,35 +146,47 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides):
         return jnp.minimum(ntoks_ref[slot], tw * bl - 1)
 
     n = horizon(s)
-    chunks = (n // bl + cb) // cb             # ceil((n // bl + 1) / cb)
+    blocks = n // bl + 1                      # the slot's live blocks
+    chunks = (blocks + cb - 1) // cb
     base = jnp.where(s == 0, 0, base_ref[0])
 
-    def copies(slot, chunk, buf, c):
-        """Block c of a chunk: one copy a pool."""
-        blk = tables_ref[slot * tw + chunk * cb + c]
-        rows = pl.ds(pl.multiple_of(c * bl, bl), bl)
-        return [pltpu.make_async_copy(hbm.at[blk],
-                                      into.at[buf, :, rows, :],
-                                      sems.at[side, buf])
-                for side, (hbm, into) in enumerate(zip(pools, bufs))]
+    def start(slot, chunk, buf, of):
+        """Start the copies of the chunk's live blocks, the slot having
+        `of` in all, one a pool and block; a pool's all signal its
+        semaphore of `buf`."""
+        live = jnp.minimum(of - chunk * cb, cb)
+        entry = slot * tw + chunk * cb
 
-    def each_live_block(slot, chunk, buf, do):
-        """`do` on the copies of the chunk's live blocks: all cb of
-        them in every chunk of a slot but its last."""
-        live = jnp.minimum(horizon(slot) // bl + 1 - chunk * cb, cb)
+        def block(c):
+            blk = tables_ref[entry + c]
+            rows = pl.ds(pl.multiple_of(c * bl, bl), bl)
+            for side, (hbm, into) in enumerate(zip(pools, bufs)):
+                pltpu.make_async_copy(hbm.at[blk], into.at[buf, :, rows, :],
+                                      sems.at[side, buf]).start()
 
-        def block(c, carry):
-            for cp in copies(slot, chunk, buf, c):
-                do(cp)
-            return carry
+        def issue(width):
+            # `width` blocks a trip in straight-line code: the table
+            # reads and the descriptors of one overlap the next's
+            def trip(i, carry):
+                for u in range(width):
+                    block(i * width + u)
+                return carry
+            return trip
 
-        jax.lax.fori_loop(0, live, block, None)
+        whole = live // group
+        jax.lax.fori_loop(0, whole, issue(group), None)
+        if group > 1:
+            jax.lax.fori_loop(whole * group, live, issue(1), None)
 
-    def start(slot, chunk, buf):
-        each_live_block(slot, chunk, buf, lambda cp: cp.start())
-
-    def wait(slot, chunk, buf):
-        each_live_block(slot, chunk, buf, lambda cp: cp.wait())
+    def wait(buf, count):
+        """Wait for `count` (static) blocks a pool.  A DMA semaphore
+        counts bytes, so one descriptor of the copies' size together
+        stands for them all, whatever blocks they brought: no wait
+        reads the table."""
+        rows = pl.ds(0, count * bl)
+        for side, into in enumerate(bufs):
+            landed = into.at[buf, :, rows, :]
+            pltpu.make_async_copy(landed, landed, sems.at[side, buf]).wait()
 
     def attend(chunk, buf, carry, last):
         m, l, acc = carry
@@ -167,12 +218,12 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides):
 
     @pl.when(s == 0)
     def _():
-        start(0, 0, 0)
+        start(0, 0, 0, blocks)
 
     def full_chunk(i, carry):
         buf = (base + i) % 2
-        start(s, i + 1, 1 - buf)
-        wait(s, i, buf)
+        start(s, i + 1, 1 - buf, blocks)
+        wait(buf, cb)
         return attend(i, buf, carry, last=False)
 
     init = (jnp.full((hkv, g, 1), _attention.NEG_INF, jnp.float32),
@@ -183,9 +234,16 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides):
 
     @pl.when(s + 1 < slots)
     def _():
-        start(s + 1, 0, 1 - buf)
+        start(s + 1, 0, 1 - buf, horizon(s + 1) // bl + 1)
 
-    wait(s, chunks - 1, buf)
+    # the slot's last chunk, 1..cb live blocks: a wait a binary digit
+    # of their count
+    live = blocks - (chunks - 1) * cb
+    for bit in range(cb.bit_length()):
+        @pl.when((live >> bit) & 1 == 1)
+        def _():
+            wait(buf, 1 << bit)
+
     _, l, acc = attend(chunks - 1, buf, carry, last=True)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     base_ref[0] = 1 - buf
@@ -237,7 +295,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
         _check_tiling(q, k_pool, value_dim)
     return singa_paged_decode(
         q, k_pool, v_pool, tables, ntoks, interpret=interpret,
-        chunk=_CHUNK_POSITIONS, value_dim=value_dim,
+        chunk=chunk_positions(k_pool.shape, k_pool.dtype),
+        group=1 if interpret else _ISSUE_GROUP, value_dim=value_dim,
         scale=1.0 / math.sqrt(d) if scale is None else float(scale))
 
 
@@ -245,10 +304,10 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
 # Mosaic lowering (16 of them cost a process 1.3 s of every start), and
 # named as the kernel: the function's name is the name of the op, and so
 # of the row, that holds the kernel's time in a device trace.
-@functools.partial(jax.jit, static_argnames=("interpret", "chunk", "scale",
-                                             "value_dim"))
+@functools.partial(jax.jit, static_argnames=("interpret", "chunk", "group",
+                                             "scale", "value_dim"))
 def singa_paged_decode(q, k_pool, v_pool, tables, ntoks, *, interpret,
-                       chunk, scale, value_dim):
+                       chunk, scale, value_dim, group=_ISSUE_GROUP):
     s, h, d = q.shape
     _, hkv, bl, _ = k_pool.shape
     tw = tables.shape[1]
@@ -262,7 +321,7 @@ def singa_paged_decode(q, k_pool, v_pool, tables, ntoks, *, interpret,
 
     out = pl.pallas_call(
         functools.partial(_kernel, bl=bl, cb=cb, tw=tw, scale=scale,
-                          sides=len(pools)),
+                          sides=len(pools), group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),

@@ -21,8 +21,10 @@ from singa_tpu.config.schema import LayerConfig, MLAConfig
 from singa_tpu.core.hybrid_layers import MLALayer
 from singa_tpu.core.net import build_net
 from singa_tpu.models.transformer import transformer_lm
-from singa_tpu.ops.paged_attention import (paged_attention_reference,
-                                           paged_decode_attention)
+from singa_tpu.ops.paged_attention import (chunk_positions,
+                                           paged_attention_reference,
+                                           paged_decode_attention,
+                                           singa_paged_decode)
 from singa_tpu.serve import InferenceEngine, InferenceServer, ServeSpec
 from singa_tpu.serve.kvcache import NULL_BLOCK
 
@@ -41,37 +43,66 @@ LENGTHS = {
     "mixed": [1, BL, 2 * BL + 1, FULL],
     "inactive_among_active": [None, 5, None, 17],
 }
+# The ZAYA cell's geometry (kCCA: 8 query over 2 key/value heads of
+# 128, blocks of 16, a table of 256 blocks), where a chunk is wider
+# than at the dense cells' 8 heads: lengths around the chunk's edges
+NARROW = dict(hkv=2, d=128, bl=16, t=256)
+
+
+def _narrow_lengths(dtype):
+    """ntoks a slot: the newest token one below, on and one past the
+    last position of a chunk, the same one chunk on, the table's full
+    256 blocks, no token, one whole block, an inactive slot."""
+    w = chunk_positions((1, NARROW["hkv"], NARROW["bl"], NARROW["d"]), dtype)
+    full = NARROW["t"] * NARROW["bl"] - 1
+    return {"one_chunk": [w - 2, w - 1, w, None],
+            "two_chunks": [2 * w - 2, 2 * w - 1, 2 * w, 0],
+            "full_table": [full, 0, NARROW["bl"] - 1, w - 1]}
+
+
 _dense_kernel = paged_decode_attention        # jitted inside
 _dense_reference = jax.jit(paged_attention_reference)
 
 
-def _case(lengths, groups, dtype, seed, hkv=HKV, d=D, sides=2):
+def _narrow_kernel(q, k_pool, v_pool, tables, ntoks):
+    """The schedule the chip runs: the interpreter is given groups of
+    one block (`paged_decode_attention`), Mosaic the default."""
+    return singa_paged_decode(
+        q, k_pool, v_pool, tables, ntoks, interpret=True,
+        chunk=chunk_positions(k_pool.shape, k_pool.dtype),
+        scale=1.0 / np.sqrt(NARROW["d"]), value_dim=NARROW["d"])
+
+
+def _case(lengths, groups, dtype, seed, hkv=HKV, d=D, sides=2, bl=BL,
+          t=T):
     """q, a clean and a poisoned copy of the `sides` pools, tables,
-    ntoks.  The table is a shuffled (non-monotone) draw of the pool's
-    blocks; a slot's reservation ends somewhere at or after its last
-    live block and the row's tail is the null block.  In the poisoned
-    copy every position no slot may see is nan (K, or the one pool of
-    both sides) or inf (V): blocks no live table entry names, the tail
-    of each last live block, and the null block past its position 0
-    (which an inactive slot attends)."""
+    ntoks, a slot a length.  The table is a shuffled (non-monotone)
+    draw of the pool's blocks; a slot's reservation ends somewhere at
+    or after its last live block and the row's tail is the null block.
+    In the poisoned copy every position no slot may see is nan (K, or
+    the one pool of both sides) or inf (V): blocks no live table entry
+    names, the tail of each last live block, and the null block past
+    its position 0 (which an inactive slot attends)."""
     rng = np.random.default_rng(seed)
-    nb = S * T + 1
-    q = rng.standard_normal((S, hkv * groups, d)).astype(np.float32)
-    pools = [rng.standard_normal((nb, hkv, BL, d)).astype(np.float32)
+    slots = len(lengths)
+    nb = slots * t + 1
+    q = rng.standard_normal((slots, hkv * groups, d)).astype(np.float32)
+    pools = [rng.standard_normal((nb, hkv, bl, d)).astype(np.float32)
              for _ in range(sides)]
-    tables = rng.permutation(np.arange(1, nb)).reshape(S, T).astype(np.int32)
-    ntoks = np.zeros((S,), np.int32)
-    seen = np.zeros((nb, BL), bool)
+    tables = rng.permutation(np.arange(1, nb)).reshape(slots, t).astype(
+        np.int32)
+    ntoks = np.zeros((slots,), np.int32)
+    seen = np.zeros((nb, bl), bool)
     seen[NULL_BLOCK, 0] = True
     for s, n in enumerate(lengths):
         if n is None:
             tables[s] = NULL_BLOCK
             continue
         ntoks[s] = n
-        live = n // BL + 1
-        tables[s, rng.integers(live, T + 1):] = NULL_BLOCK
-        for p in range(n + 1):
-            seen[tables[s, p // BL], p % BL] = True
+        live = n // bl + 1
+        tables[s, rng.integers(live, t + 1):] = NULL_BLOCK
+        at = np.arange(n + 1)
+        seen[tables[s, at // bl], at % bl] = True
     hide = ~seen[:, None, :, None]
     clean = [np.where(hide, 0.0, a) for a in pools]
     poisoned = [np.where(hide, bad, a)
@@ -119,19 +150,25 @@ def _latent_kernel(layer, params, q, pool, tables, ntoks):
         layer._absorb_query(params, q, pool.shape[-1]), pool, None, tables,
         ntoks, value_dim=layer.rank,
         scale=1.0 / np.sqrt(layer.nope + layer.rope))
-    assert o_lat.shape == (S, layer.heads, layer.rank)
+    assert o_lat.shape == (len(ntoks), layer.heads, layer.rank)
     return layer._expand_output(params, o_lat)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
                                        (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("groups", [1, 4, "latent"],
-                         ids=["mha", "gqa4", "latent"])
-@pytest.mark.parametrize("name", list(LENGTHS))
+@pytest.mark.parametrize(
+    "name,groups",
+    [(name, groups) for groups in (1, 4, "latent") for name in LENGTHS]
+    + [(name, "narrow") for name in _narrow_lengths(jnp.bfloat16)],
+    ids=lambda v: {1: "mha", 4: "gqa4"}.get(v, v))
 def test_kernel_matches_gather_reference_on_poisoned_pools(name, groups,
                                                            dtype, tol, mla):
-    if groups == "latent":
+    if groups == "narrow":
+        q, clean, poisoned, tables, ntoks = _case(
+            _narrow_lengths(dtype)[name], 4, dtype, seed=len(name), **NARROW)
+        _reference, _kernel = _dense_reference, _narrow_kernel
+    elif groups == "latent":
         layer, params = mla
         params = {k: v.astype(dtype) for k, v in params.items()}
         q, clean, poisoned, tables, ntoks = _case(
@@ -154,6 +191,51 @@ def test_kernel_matches_gather_reference_on_poisoned_pools(name, groups,
     # of the reference either: it is a fair oracle on the same pools
     same = np.asarray(_reference(q, *poisoned, tables, ntoks), np.float32)
     assert np.array_equal(same, want)
+
+
+# (Hkv, block_len, D) of a pool -> positions a chunk: the three serving
+# cells' pools, and the float32 pools `chip_smoke.py`'s serve leg and
+# the f32 tests run.  A chunk is a number of bytes, so it follows the
+# pool's row; at the dense cells' shape it has to stay 256 (512 there
+# read 25 % slower at chat's short rows: PERF.md 6, PR 30).
+CHUNKS = {
+    "dense": ((8, 16, 128), {"bfloat16": 256, "float32": 128}),
+    "latent": ((1, 16, 640), {"bfloat16": 512, "float32": 256}),
+    "narrow": ((2, 16, 128), {"bfloat16": 1024, "float32": 512}),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("cell", list(CHUNKS))
+def test_chunk_follows_the_pools_row_bytes(cell, dtype):
+    shape, want = CHUNKS[cell]
+    assert chunk_positions((4097,) + shape, jnp.dtype(dtype)) == want[dtype]
+
+
+@pytest.mark.parametrize("sides", [2, 1], ids=["two_pools", "one_pool"])
+def test_waits_cover_the_copies_under_the_dma_model(sides):
+    """One wait stands for a whole chunk's copies, and a few for the
+    live blocks of a slot's last chunk.  Under JAX's model of the TPU's
+    DMA engine (a semaphore counts bytes; a copy is carried out no
+    sooner than a wait asks for its bytes) a wait that covered fewer
+    bytes than were copied would leave rows of the buffer unwritten
+    (nan), and one that covered more would never return."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    # chunks of 3 blocks, copies issued two blocks at a time: slots of
+    # one and of two chunks, a last chunk of one, two and three live
+    # blocks, an inactive slot
+    lengths = [1, 2 * BL - 1, 3 * BL - 1, 3 * BL, 5 * BL + 1, FULL, None]
+    q, clean, poisoned, tables, ntoks = _case(lengths, 4, jnp.float32, 5,
+                                              sides=sides)
+    pad = [None] * (2 - sides)
+    got = singa_paged_decode(
+        q, *poisoned, *pad, tables, ntoks, chunk=3 * BL, group=2, scale=0.3,
+        value_dim=D, interpret=pltpu.InterpretParams(
+            dma_execution_mode="on_wait", detect_races=True))
+    want = paged_attention_reference(q, *clean, *pad, tables, ntoks,
+                                     scale=0.3)
+    assert np.max(np.abs(np.asarray(got) - np.asarray(want))) <= 2e-6
 
 
 @pytest.mark.parametrize("bad", [{"value_dim": 0}, {"value_dim": D + 1},

@@ -1,10 +1,12 @@
 """Microbenchmark of the paged decode attention kernel on the chip (a
-builder's tool, not part of the benchmark): the kernel alone at the two
+builder's tool, not part of the benchmark): the kernel alone at the three
 serving geometries (the dense cells' key and value pools, the Kimi
-cell's one pool of latent rows), each under its slot mixes, against the
-gather formulation, as seconds a layer and as a share of the live
-bytes' time at the memory roofline.  `python tools/paged_kernel_bench.py`
-prints one JSON line a measurement; fails off the TPU."""
+cell's one pool of latent rows, the ZAYA cell's narrow key and value
+pools), each under its slot mixes, against the gather formulation, as
+seconds a layer and as a share of the live bytes' time at the memory
+roofline, at the chunk the kernel's rule gives and at fixed ones beside
+it.  `python tools/paged_kernel_bench.py [geometry ...]` prints one JSON
+line a measurement; fails off the TPU."""
 import functools
 import json
 import math
@@ -44,6 +46,8 @@ GEOMETRIES = {
     # kimilinear-serve-l17-ep8: kMLA, rank 512 + rope 64 stored as 640
     "latent": Geometry(96, 32, 1, 640, 16, 128, 4, 1,
                        1 / math.sqrt(192), 512),
+    # zaya1-8b-serve-l16: kCCA
+    "cca": Geometry(64, 8, 2, 128, 16, 256, 16, 2, 1 / math.sqrt(128), 128),
 }
 
 
@@ -52,6 +56,12 @@ def mixes(name, g, rng):
     if name == "latent":
         # the Kimi cell's full house: cb_live_block_share 0.22
         return {"assist": rng.integers(150, 750, g.slots).astype(np.int32),
+                "full": full}
+    if name == "cca":
+        # the ZAYA cell's full house: rows of 300 to 4,000 tokens, mean
+        # ~1,100, live_block_share.zaya 0.26
+        reason = 300 + rng.exponential(800, g.slots)
+        return {"reason": np.minimum(reason, 4000).astype(np.int32),
                 "full": full}
     chat = np.zeros(g.slots, np.int32)
     chat[:16] = rng.integers(50, 400, 16)
@@ -106,11 +116,15 @@ def bench(name, g):
         live = int(np.sum(ntoks // g.bl + 1))
         need = live * g.sides * g.hkv * g.bl * g.d * 2 / HBM_BYTES_S
         ref = gather(q, *pools, tables, nt)
-        rows = {"gather": gather}
-        for pos in (128, 256, 512):
-            rows[f"kernel_{pos}"] = functools.partial(
-                pa.singa_paged_decode, interpret=False, chunk=pos, **how)
-        for label, fn in rows.items():
+        ruled = pa.chunk_positions(pools[0].shape, pools[0].dtype)
+        rows = {"gather": (gather, None),
+                "kernel": (functools.partial(pa.paged_decode_attention,
+                                             **how), ruled)}
+        for pos in (128, 256, 512, 1024):
+            rows[f"kernel_{pos}"] = (functools.partial(
+                pa.singa_paged_decode, interpret=False, chunk=pos, **how),
+                pos)
+        for label, (fn, chunk) in rows.items():
             one = jax.jit(fn)(q, *pools, tables, nt)
             err = float(jnp.max(jnp.abs(one.astype(jnp.float32)
                                         - ref.astype(jnp.float32))))
@@ -120,6 +134,8 @@ def bench(name, g):
                 "geometry": name, "mix": mix, "what": label,
                 "live_blocks": live,
                 "live_block_share": live / (g.slots * g.table),
+                "chunk_positions": chunk,
+                "copies_a_call": live * g.sides,
                 "us_a_layer": sec * 1e6,
                 f"ms_a_step_{g.layers}_layers": sec * g.layers * 1e3,
                 "roofline_share": need / sec,
@@ -129,8 +145,8 @@ def bench(name, g):
 def main():
     if jax.default_backend() != "tpu":
         sys.exit("paged_kernel_bench: JAX's default backend is not a TPU")
-    for name, g in GEOMETRIES.items():
-        bench(name, g)
+    for name in sys.argv[1:] or GEOMETRIES:
+        bench(name, GEOMETRIES[name])
 
 
 if __name__ == "__main__":
