@@ -210,6 +210,12 @@ class AttentionConfig:
     rope_theta: float = 10000.0
     window: int = 0          # sliding-window size, 0 = full
     num_kv_heads: int = 0    # 0 => = num_heads (MHA); else GQA/MQA
+    # a learned RMSNorm over head_dim on every query head and every
+    # key head (one scale vector for q, one for k), before RoPE
+    qk_norm: bool = False
+    norm_epsilon: float = 1e-5
+    # out = (sigmoid(x Wg) * attention) Wo, Wg as wide as the heads
+    gate: bool = False
 
 
 @dataclass
@@ -292,6 +298,8 @@ class EmbedConfig:
     embed_dim: int = 0
     # token-chunk size of the fused kLMHeadLoss layer (0 = default 4096)
     loss_chunk: int = 0
+    # kEmbed: the looked-up rows times this (muP's sqrt(embed_dim)); 0 = 1
+    scale: float = 0.0
 
 
 @dataclass

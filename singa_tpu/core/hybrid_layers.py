@@ -459,13 +459,13 @@ class RoutedMoELayer(Layer):
                                           (self.n_routed,), 0.0)
 
     def _ffn(self, params, x, valid):
-        """x (T, E) -> (out (T, E), counts int32 (2,))."""
+        """x (T, E) -> (out (T, E), counts int32 (3,))."""
         idx, weights = moe_ops.route_sigmoid(
             x, params[self.router], params[self.router_bias], self.k,
             self.renormalize, self.scale)
         y, counts = moe_ops.held_experts_ffn(
             x, idx, weights, params[self.w_gate], params[self.w_up],
-            params[self.w_down], self.first, valid)
+            params[self.w_down], self.first, valid, max_load=True)
         if self.shared is not None:
             gate, up, down = (params[w] for w in self.shared)
             hid = (jax.nn.silu(_dot(x, gate)) * _dot(x, up)).astype(x.dtype)
@@ -484,10 +484,11 @@ class RoutedMoELayer(Layer):
 
     def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
                   dtype):
-        """[assignments on held experts, held experts touched] of the
-        last decode step, busy slots only: the engine hands them to the
-        host with the step's tokens."""
-        return {"routed": jnp.zeros((2,), jnp.int32)}
+        """[assignments on held experts, held experts touched, the
+        busiest held expert's assignments] of the last decode step,
+        busy slots only: the engine hands them to the host with the
+        step's tokens."""
+        return {"routed": jnp.zeros((3,), jnp.int32)}
 
     def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
         b, t, e = x.shape

@@ -24,7 +24,7 @@ from ..config.schema import ParamConfig
 from ..ops import moe as moe_ops
 from ..ops.attention import (attention_reference, expand_kv_heads,
                              flash_attention, rope)
-from ..ops.paged_attention import paged_decode_attention
+from ..ops.paged_attention import paged_decode_attention, ring_blocks
 from .layers import Context, Layer, LayerError, register_layer
 
 # keys of the fallbacks already reported once: (layer name, seq_len,
@@ -92,6 +92,7 @@ class EmbedLayer(Layer):
         src = src_shapes[0]
         shape = src["input"] if isinstance(src, dict) else tuple(src)
         self.out_shape = tuple(shape) + (p.embed_dim,)
+        self.scale = p.scale
         self.w_key = _declare_with_default(
             self, 0, "embedding", (p.vocab_size, p.embed_dim),
             init_std=1.0 / math.sqrt(p.embed_dim), partition_dim=1)
@@ -102,7 +103,10 @@ class EmbedLayer(Layer):
         emb = params[self.w_key]
         if ctx.compute_dtype is not None:
             emb = emb.astype(ctx.compute_dtype)
-        return jnp.take(emb, tokens.astype(jnp.int32), axis=0)
+        rows = jnp.take(emb, tokens.astype(jnp.int32), axis=0)
+        if self.scale:
+            rows = (rows.astype(jnp.float32) * self.scale).astype(rows.dtype)
+        return rows
 
 
 @register_layer("kSeqLabel")
@@ -130,11 +134,16 @@ class RMSNormLayer(Layer):
         self.w_key = key
 
     def apply(self, params, srcs, ctx):
-        x = srcs[0]
-        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
-                       keepdims=True)
-        y = x * jax.lax.rsqrt(var + self.eps).astype(x.dtype)
-        return y * params[self.w_key].astype(x.dtype)
+        return rms_norm(srcs[0], params[self.w_key], self.eps)
+
+
+def rms_norm(x, scale, eps: float):
+    """x over the root of its last axis's mean square (float32), times
+    a learned scale; in x's dtype."""
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                   keepdims=True)
+    y = x * jax.lax.rsqrt(var + eps).astype(x.dtype)
+    return y * scale.astype(x.dtype)
 
 
 # what a layer's decode-time methods hand to shared helpers that take a
@@ -161,12 +170,13 @@ def write_token(pool, bidx, off, new):
 
 
 
-def attend_cache(q, k_cache, v_cache, pos, kmask=None):
+def attend_cache(q, k_cache, v_cache, pos, kmask=None, window=0):
     """A chunk's queries q (B, H, T, D), the first at absolute position
     `pos`, against contiguous caches (B, Hkv, max_len, D) that already
-    hold the chunk's own rows: causal, `kmask` (B, max_len) ANDed in,
-    GQA read at Hkv width, f32 scores and softmax.  Returns
-    (B, T, H * D) in the values' dtype."""
+    hold the chunk's own rows: causal (with `window`, the last `window`
+    positions only), `kmask` (B, max_len) ANDed in, GQA read at Hkv
+    width, f32 scores and softmax.  Returns (B, T, H * D) in the
+    values' dtype."""
     b, heads, t, head_dim = q.shape
     kv_heads = k_cache.shape[1]
     groups = heads // kv_heads
@@ -175,6 +185,8 @@ def attend_cache(q, k_cache, v_cache, pos, kmask=None):
     qpos = pos + jnp.arange(t)[:, None]            # (T, 1) absolute
     kpos = jnp.arange(kk.shape[2])[None, :]        # (1, max_len)
     allowed = (kpos <= qpos)[None]                 # (1, T, max_len)
+    if window:
+        allowed = allowed & (kpos > qpos - window)[None]
     if kmask is not None:
         allowed = allowed & kmask[:, None, :]      # (B, T, max_len)
     if groups == 1:
@@ -203,6 +215,14 @@ class AttentionLayer(Layer):
     seq_parallel: "none" → Pallas flash attention on the local chunk;
     "ring" / "ulysses" → sequence-parallel attention over the mesh's
     "seq" axis (singa_tpu.parallel.sequence).
+
+    Three options of attention_param, each off unless set, and a layer
+    without them is the program it was before they existed: `window` W
+    (query t sees keys t - W + 1 .. t; a mask over dense scores in
+    `apply` and `apply_cached`, and in the serving pools a RING of
+    blocks per slot read by the windowed walk of the paged kernel),
+    `qk_norm` (a learned RMSNorm over head_dim on every query and key
+    head, before RoPE), `gate` (out = (sigmoid(x Wg) * attention) Wo).
     """
 
     def setup(self, src_shapes):
@@ -217,6 +237,14 @@ class AttentionLayer(Layer):
         self.seq_parallel = p.seq_parallel
         self.use_rope = p.rope
         self.rope_theta = p.rope_theta
+        self.window = int(p.window)
+        self.qk_norm, self.norm_eps, self.gate = (p.qk_norm,
+                                                  p.norm_epsilon, p.gate)
+        if self.window < 0 or (self.window and (
+                not self.causal or self.seq_parallel != "none")):
+            raise LayerError(
+                f"{self.name}: a window of {self.window} needs causal "
+                f"attention on one chip's sequence")
         self.out_shape = (b, s, e)
         hd = self.heads * self.head_dim
         kvd = self.kv_heads * self.head_dim
@@ -225,6 +253,20 @@ class AttentionLayer(Layer):
         self.wk = _declare_with_default(self, 1, "wk", (e, kvd), std, 1)
         self.wv = _declare_with_default(self, 2, "wv", (e, kvd), std, 1)
         self.wo = _declare_with_default(self, 3, "wo", (hd, e), std, 0)
+        if self.gate:
+            self.wg = _declare_with_default(self, 4, "wg", (e, hd), std, 1)
+        if self.qk_norm:
+            from .layers import ParamSpec
+            one = ParamConfig(init_method="kConstant", value=1.0)
+            self.q_norm, self.k_norm = (f"{self.name}/q_norm",
+                                        f"{self.name}/k_norm")
+            self.param_specs += [
+                ParamSpec(key, (self.head_dim,), 0, one)
+                for key in (self.q_norm, self.k_norm)]
+        if self.window:
+            # the serving state is a ring per slot, not table blocks
+            self.init_pool = self._init_ring
+            self.scatter_prefill = self._scatter_ring
 
     def _proj(self, params, key, x, ctx):
         w = params[key]
@@ -245,10 +287,22 @@ class AttentionLayer(Layer):
             b, s, self.kv_heads, self.head_dim).transpose(0, 2, 1, 3)
         v = self._proj(params, self.wv, x, ctx).reshape(
             b, s, self.kv_heads, self.head_dim).transpose(0, 2, 1, 3)
+        if self.qk_norm:       # over a head's dims, one scale for all heads
+            q = rms_norm(q, params[self.q_norm], self.norm_eps)
+            k = rms_norm(k, params[self.k_norm], self.norm_eps)
         if self.use_rope:
             q = rope(q, positions, self.rope_theta)
             k = rope(k, positions, self.rope_theta)
         return q, k, v
+
+    def _out(self, params, x, attended, ctx):
+        """The heads' outputs (B, S, H * D), gated where the layer has a
+        gate, through Wo."""
+        attended = attended.astype(x.dtype)
+        if self.gate:
+            attended = attended * jax.nn.sigmoid(
+                self._proj(params, self.wg, x, ctx))
+        return self._proj(params, self.wo, attended, ctx)
 
     def _packed_eligible(self, b: int, s: int, ctx) -> bool:
         """The zero-transpose packed flash path: flash-legal shapes, GQA
@@ -261,7 +315,8 @@ class AttentionLayer(Layer):
         "pipe" never reaches here (stage bodies see ctx.mesh None)."""
         if not (self.seq_parallel == "none"
                 and self.heads % self.kv_heads == 0
-                and s % 128 == 0 and self.head_dim % 8 == 0):
+                and s % 128 == 0 and self.head_dim % 8 == 0
+                and not (self.window or self.qk_norm or self.gate)):
             return False
         if ctx.mesh is None:
             return True
@@ -305,6 +360,11 @@ class AttentionLayer(Layer):
             return self._proj(params, self.wo, out.astype(x.dtype), ctx)
         q, k, v = self.qkv(params, x, jnp.arange(s), ctx)
 
+        if self.window:
+            # the window as a mask over dense scores (the flash kernels
+            # skip no block for it yet)
+            return self._out(params, x, attend_cache(
+                q, k, v, 0, window=self.window), ctx)
         if self.seq_parallel == "ring" and ctx.mesh is not None:
             # k/v stay at Hkv width: the ring rotates (and Ulysses
             # all-to-alls) unexpanded KV; group expansion happens on
@@ -336,16 +396,21 @@ class AttentionLayer(Layer):
                                       expand_kv_heads(v, self.heads),
                                       self.causal)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
-        return self._proj(params, self.wo, out.astype(x.dtype), ctx)
+        return self._out(params, x, out, ctx)
 
 
 
     # -- decode state: the protocol models/generate.py and
     # serve/kvcache.py drive for every mixer layer -------------------------
     def init_cache(self, batch: int, max_len: int, dtype):
-        """Contiguous K/V for `batch` sequences of up to `max_len`."""
+        """Contiguous K/V for `batch` sequences of up to `max_len`; a
+        windowed layer's entry also says how many of its rows are real
+        (what its ring keeps of a prefill, `_scatter_ring`)."""
         shape = (batch, self.kv_heads, max_len, self.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        entry = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        if self.window:
+            entry["rows"] = jnp.zeros((), jnp.int32)
+        return entry
 
     def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
                   dtype):
@@ -366,6 +431,44 @@ class AttentionLayer(Layer):
             nb, bl, hkv, d).transpose(0, 2, 1, 3)
         return {"k": pool["k"].at[table_row].set(kb.astype(pool["k"].dtype)),
                 "v": pool["v"].at[table_row].set(vb.astype(pool["v"].dtype))}
+
+    # a windowed layer's serving state: a ring of blocks per slot.  It
+    # needs the last `window` positions of a slot and no more, so slot
+    # s owns pool blocks 1 + s R .. (s + 1) R, R = `ring_blocks`, for
+    # good, and position p lives in the slot's column (p // bl) % R.
+    # Nothing is allocated at admission and nothing freed at
+    # retirement, as with a recurrent layer's state per slot.
+    def _init_ring(self, num_slots: int, num_blocks: int, block_len: int,
+                   dtype):
+        """Paged K/V of `num_slots` rings (and the null block):
+        `num_blocks`, the table layers' pool size, plays no part."""
+        blocks = num_slots * ring_blocks(self.window, block_len) + 1
+        shape = (blocks, self.kv_heads, block_len, self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    @staticmethod
+    def ring_tables(slots: int, ring: int):
+        """(slots, ring) int32: the pool block behind every slot's
+        every ring column."""
+        return (1 + jnp.arange(slots, dtype=jnp.int32)[:, None] * ring
+                + jnp.arange(ring, dtype=jnp.int32)[None, :])
+
+    def _scatter_ring(self, pool, cache, table_row, slot=None):
+        """A batch-1 contiguous prefill cache into slot `slot`'s ring:
+        of the cache's blocks, the last `ring_blocks` that hold a real
+        row (`cache["rows"]` of them are), each to its column; the
+        others to the null block."""
+        if slot is None:
+            raise LayerError(f"{self.name}: a windowed layer's prefill "
+                             f"has to be told its slot")
+        bl = pool["k"].shape[2]
+        ring = ring_blocks(self.window, bl)
+        nb = cache["k"].shape[2] // bl
+        block = jnp.arange(nb, dtype=jnp.int32)
+        last = (cache["rows"] - 1) // bl
+        kept = (block <= last) & (block > last - ring)
+        row = jnp.where(kept, 1 + slot * ring + block % ring, 0)
+        return AttentionLayer.scatter_prefill(pool, cache, row)
 
     def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
         """`plen` (real rows of a right-padded chunk) is for recurrent
@@ -395,9 +498,13 @@ class AttentionLayer(Layer):
         v_cache = jax.lax.dynamic_update_slice(
             entry["v"], v.astype(entry["v"].dtype), (0, 0, pos, 0))
 
-        out = attend_cache(q, k_cache, v_cache, pos, kmask)
-        out = self._proj(params, self.wo, out.astype(x.dtype), DECODE_CTX)
-        return out, {"k": k_cache, "v": v_cache}
+        out = attend_cache(q, k_cache, v_cache, pos, kmask, self.window)
+        out = self._out(params, x, out, DECODE_CTX)
+        entry = {"k": k_cache, "v": v_cache}
+        if self.window:
+            entry["rows"] = jnp.asarray(pos + t if plen is None else plen,
+                                        jnp.int32)
+        return out, entry
 
 
 
@@ -427,13 +534,25 @@ class AttentionLayer(Layer):
         TPU).  Same math as the contiguous read, f32 scores and softmax,
         but summed chunk by chunk: the tests pin greedy-token identity with
         `generate()` and a tolerance against the gather reference, not
-        bit-equality."""
+        bit-equality.
+
+        A windowed layer takes no notice of `tables`: its pool is a ring
+        per slot (`_init_ring`), the new row goes to the slot's column
+        (ntoks[s] // bl) % R and the kernel walks the window's blocks
+        only."""
         assert self.causal, f"{self.name}: decode requires causal attention"
         _, s, _ = x.shape
         bl = entry["k"].shape[2]
         q, k, v = self.qkv(params, x, ntoks, DECODE_CTX)    # (1,H,S,D)/(1,Hkv,S,D)
 
-        bidx = tables[jnp.arange(s), ntoks // bl]      # (S,) pool block
+        if self.window:
+            # the slot's ring in place of its table row: position p in
+            # column (p // bl) % R, and the kernel's windowed walk
+            ring = ring_blocks(self.window, bl)
+            tables = self.ring_tables(s, ring)
+            bidx = tables[jnp.arange(s), (ntoks // bl) % ring]
+        else:
+            bidx = tables[jnp.arange(s), ntoks // bl]  # (S,) pool block
         off = ntoks % bl                               # (S,) offset in block
         k_new = k[0].transpose(1, 0, 2)                # (S, Hkv, D)
         v_new = v[0].transpose(1, 0, 2)
@@ -441,9 +560,9 @@ class AttentionLayer(Layer):
         v_pool = write_token(entry["v"], bidx, off, v_new)
 
         out = paged_decode_attention(q[0].transpose(1, 0, 2), k_pool, v_pool,
-                                     tables, ntoks)    # (S, H, D)
-        out = out.reshape(1, s, -1)
-        out = self._proj(params, self.wo, out.astype(x.dtype), DECODE_CTX)
+                                     tables, ntoks,
+                                     window=self.window)   # (S, H, D)
+        out = self._out(params, x, out.reshape(1, s, -1), DECODE_CTX)
         return out, {"k": k_pool, "v": v_pool}
 
 

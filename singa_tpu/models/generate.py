@@ -43,14 +43,16 @@ Cache = Dict[str, CacheEntry]         # layer name -> its entry
 #   init_cache(batch, max_len, dtype)            contiguous state
 #   apply_cached(params, x, entry, pos, kmask=, plen=) -> (out, entry)
 #   init_pool(num_slots, num_blocks, block_len, dtype)   serving state:
-#       rows per token in paged blocks, one fixed state per slot, or
-#       both in one entry
+#       rows per token in paged blocks that grow under the table or
+#       in a ring of blocks per slot (a windowed kAttention, which
+#       takes no notice of `tables`), one fixed state per slot, or two
+#       of these in one entry
 #   apply_paged(params, x, entry, tables, ntoks)  -> (out, entry)
 #   scatter_prefill(pool, cache, table_row, slot) -> pool
 # `x` is the layer's source, or the list of them where the layer has
 # several; `out` is an array, or a dict of named outputs that the
 # layer's consumers read by name (`hybrid_layers.named_output`).
-# kAttention (K/V per token), kMLA (a latent row per token), kKDA (a
+# kAttention (K/V per token, all of them or a window's), kMLA (a latent row per token), kKDA (a
 # recurrent state and a conv tail per slot), kCCA (K/V per token AND
 # conv tails and a shifted value per slot), kRoutedMoE (no state; its
 # step's routing counts) and kZayaMoE (the same; its second source and

@@ -149,7 +149,8 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
               epsilon: float = 1e-5, precision: str = "float32",
               train_steps: int = 1000,
               learning_rate: float = 3e-4, tie_head: bool = False,
-              scaled_residual: bool = False) -> ModelConfig:
+              scaled_residual: bool = False, post_norm: bool = False,
+              embed_scale: float = 0.0) -> ModelConfig:
     """A decoder whose layers differ: block i is
 
         x += mixer_i(rmsnorm(x));  x += ffn_i(rmsnorm(x))
@@ -158,14 +159,20 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
     {...MLAConfig}}, {"attention": {...AttentionConfig}}, {"cca":
     {...CCAConfig}} and `ffns[i]` one of {"dense": {...FFNConfig}},
     {"moe": {...RoutedMoEConfig}}, {"zaya_moe": {...ZayaMoEConfig}}
-    (a leading dense layer before sparse ones, mixers in any period).
+    (a leading dense layer before sparse ones, mixers in any period;
+    "attention" mixers may differ from layer to layer: a window or
+    none, RoPE or none).
     A "zaya_moe" layer takes the one before it as a second source (its
     router's state travels from expert layer to expert layer).  With
     `scaled_residual` both terms of every residual carry a learned
-    scale and bias: x = (a x + b) + (c f(rmsnorm(x)) + d).  Final
+    scale and bias: x = (a x + b) + (c f(rmsnorm(x)) + d).  With
+    `post_norm` a sublayer's output goes through an RMSNorm of its own
+    before the residual adds it: x += rmsnorm(f(rmsnorm(x))).
+    `embed_scale` multiplies the embedding's rows (0 = none).  Final
     RMSNorm and a fused head, tied to the embedding with `tie_head`.
     Layer names follow `transformer_lm`'s: ln{i}a, <kind>{i}, res{i}a,
-    ln{i}b, ffn{i} or <kind>{i}, res{i}b, ln_f, loss."""
+    ln{i}b, ffn{i} or <kind>{i}, res{i}b, ln_f, loss; a norm after a
+    sublayer is pn{i}a / pn{i}b."""
     if len(mixers) != len(ffns):
         raise ValueError(f"{len(mixers)} mixers for {len(ffns)} ffns")
     kinds = {"kda": ("kKDA", "kda_param"), "mla": ("kMLA", "mla_param"),
@@ -182,7 +189,8 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
                            "vocab_size": vocab_size}},
         {"name": "labels", "type": "kSeqLabel", "srclayers": "data"},
         {"name": "embed", "type": "kEmbed", "srclayers": "data",
-         "embed_param": {"vocab_size": vocab_size, "embed_dim": embed_dim}},
+         "embed_param": {"vocab_size": vocab_size, "embed_dim": embed_dim,
+                         "scale": embed_scale}},
     ]
     src, router = "embed", None      # the last layer with a router state
     for i, (mixer, ffn) in enumerate(zip(mixers, ffns)):
@@ -203,9 +211,14 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
                 {"name": f"ln{i}{half}", "type": "kRMSNorm",
                  "srclayers": src, **norm},
                 {"name": name, "type": ltype, "srclayers": srcs,
-                 field: dict(param)},
-                {"name": f"res{i}{half}", "type": residual,
-                 "srclayers": [src, name]}]
+                 field: dict(param)}]
+            out = name
+            if post_norm:
+                out = f"pn{i}{half}"
+                layers.append({"name": out, "type": "kRMSNorm",
+                               "srclayers": name, **norm})
+            layers.append({"name": f"res{i}{half}", "type": residual,
+                           "srclayers": [src, out]})
             src = f"res{i}{half}"
     head = {"name": "loss", "type": "kLMHeadLoss",
             "srclayers": ["ln_f", "labels"],

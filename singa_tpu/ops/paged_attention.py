@@ -33,6 +33,15 @@ semaphore, which counts bytes, and are waited for together, by size,
 with no second walk of the table; and `start` issues a group of blocks
 in straight-line code before it loops.
 
+A WINDOWED call (`window` W > 0, a sliding-window `kAttention` layer)
+attends positions max(0, ntoks[s] - W + 1)..ntoks[s] and its table row
+is a RING: logical block b lives in column b % T, T =
+`ring_blocks(W, block_len)` the most blocks a window can touch.  The
+walk starts at the window's first block, reads its column modulo the
+ring and masks the positions of that block that lie before the window.
+The window is a static argument: a call without one lowers to the
+kernel it lowered to before there was one.
+
 `paged_attention_reference` is the plain-jnp gather of every slot's
 whole table, the formulation the serving engine ran before the kernel:
 it materialises (S, T, Hkv, block_len, D) per side and is kept only as
@@ -89,12 +98,20 @@ def chunk_positions(pool_shape, dtype):
     return 2 ** round(math.log2(_CHUNK_BYTES / row))
 
 
+def ring_blocks(window: int, block_len: int) -> int:
+    """Blocks a window of `window` positions can touch, wherever it
+    lies: the width of a windowed layer's ring (W / block_len + 1 where
+    the block divides the window)."""
+    return -(-(int(window) - 1) // int(block_len)) + 1
+
+
 def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
-                              scale=None, value_dim=None):
+                              scale=None, value_dim=None, window=0):
     """Gather formulation, `paged_decode_attention`'s arguments.  q
     (S, H, D); pools (num_blocks, Hkv, bl, D); tables (S, T) int32;
     ntoks (S,) int32.  Returns (S, H, D) in q's dtype: softmax(q k^T /
-    sqrt(D)) v over positions <= ntoks[s]."""
+    sqrt(D)) v over positions <= ntoks[s], and with `window` over the
+    last `window` of them, the table row read as a ring."""
     s, h, d = q.shape
     _, hkv, bl, _ = k_pool.shape
     t = tables.shape[1]
@@ -106,7 +123,16 @@ def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
 
     kk = flat(k_pool)
     vv = kk[..., :value_dim] if v_pool is None else flat(v_pool)
-    allowed = jnp.arange(t * bl)[None, :] <= ntoks[:, None]    # (S, T*bl)
+    if window:
+        # column j holds the newest logical block b <= ntoks // bl with
+        # b % T == j (an older one it held has been overwritten)
+        newest = (ntoks // bl)[:, None]
+        blk = newest - (newest - jnp.arange(t)[None, :]) % t   # (S, T)
+        pos = (blk[:, :, None] * bl + jnp.arange(bl)).reshape(s, t * bl)
+        allowed = ((pos <= ntoks[:, None]) & (pos >= 0)
+                   & (pos > ntoks[:, None] - window))
+    else:
+        allowed = jnp.arange(t * bl)[None, :] <= ntoks[:, None]  # (S, T*bl)
     qg = q.reshape(s, hkv, groups, d)
     scores = jnp.einsum("shgd,shkd->shgk", qg, kk,
                         preferred_element_type=jnp.float32)
@@ -122,7 +148,7 @@ def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
 
 
 def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
-            group):
+            group, window):
     """Grid step s attends slot s.  `refs` is the `sides` pools in HBM
     (keys, then values; one pool where the values are columns of the key
     rows), the output, a buffer a pool, the copies' semaphores and
@@ -131,7 +157,8 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
     of every head.  The buffer that holds a slot's first chunk
     alternates with the number of chunks walked so far (`base_ref`),
     because the copy of slot s+1's first chunk is started under slot
-    s's last."""
+    s's last.  With a `window` the walk starts at the window's first
+    block and the table row is a ring of `tw` columns."""
     pools, o_ref, bufs = refs[:sides], refs[sides], refs[sides + 1:-2]
     sems, base_ref = refs[-2:]
     s = pl.program_id(0)
@@ -140,25 +167,47 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
     hkv, g, vd = o_ref.shape[1:]
 
     def horizon(slot):
-        """Last position the slot attends; held inside its table row,
-        so that no length can send a copy after a block index read
-        from beyond the table."""
+        """Last position the slot attends; without a ring held inside
+        its table row, so that no length can send a copy after a block
+        index read from beyond the table."""
+        if window:
+            return ntoks_ref[slot]
         return jnp.minimum(ntoks_ref[slot], tw * bl - 1)
 
+    def walk(upto):
+        """(the first block a walk up to position `upto` reads, how
+        many it reads)."""
+        last = upto // bl
+        if not window:
+            return 0, last + 1
+        first = jnp.maximum(upto - (window - 1), 0) // bl
+        return first, last - first + 1
+
     n = horizon(s)
-    blocks = n // bl + 1                      # the slot's live blocks
+    head, blocks = walk(n)                    # the slot's live blocks
     chunks = (blocks + cb - 1) // cb
     base = jnp.where(s == 0, 0, base_ref[0])
 
-    def start(slot, chunk, buf, of):
-        """Start the copies of the chunk's live blocks, the slot having
-        `of` in all, one a pool and block; a pool's all signal its
-        semaphore of `buf`."""
+    def start(slot, chunk, buf, walked):
+        """Start the copies of the chunk's live blocks, the slot's walk
+        being `walked` (its first block, its count), one a pool and
+        block; a pool's all signal its semaphore of `buf`."""
+        first, of = walked
         live = jnp.minimum(of - chunk * cb, cb)
-        entry = slot * tw + chunk * cb
+        if window:
+            # the chunk's first column of the ring; a block's own wraps
+            # by a compare, not by a division of its own
+            entry = slot * tw
+            column = (first + chunk * cb) % tw
+        else:
+            entry = slot * tw + chunk * cb
 
         def block(c):
-            blk = tables_ref[entry + c]
+            if window:
+                at = column + c
+                blk = tables_ref[entry + jnp.where(at >= tw, at - tw, at)]
+            else:
+                blk = tables_ref[entry + c]
             rows = pl.ds(pl.multiple_of(c * bl, bl), bl)
             for side, (hbm, into) in enumerate(zip(pools, bufs)):
                 pltpu.make_async_copy(hbm.at[blk], into.at[buf, :, rows, :],
@@ -197,16 +246,25 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
              else k[..., :vd])                          # (Hkv, span, Dv)
         sc = jnp.einsum("hgd,htd->hgt", q, k,
                         preferred_element_type=jnp.float32) * scale
+        if window or last:
+            first = chunk * span
+            if window:
+                first = first + head * bl
+            lane = first + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, span), 2)
+            # made where it is used: an unwindowed call traces the ops
+            # it traced before there was a window, in their order
+            rows = lambda: first + jax.lax.broadcasted_iota(  # noqa: E731
+                jnp.int32, (1, span, 1), 1)
+        if window:
+            # the walk's first block begins before the window does
+            sc = jnp.where(lane > n - window, sc, _attention.NEG_INF)
+            v = jnp.where(rows() > n - window, v, jnp.zeros_like(v))
         if last:
             # the only chunk with positions past the slot's horizon:
             # rows no copy wrote hold whatever the buffer held before
-            first = chunk * span
-            lane = first + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, span), 2)
             sc = jnp.where(lane <= n, sc, _attention.NEG_INF)
-            row = first + jax.lax.broadcasted_iota(
-                jnp.int32, (1, span, 1), 1)
-            v = jnp.where(row <= n, v, jnp.zeros_like(v))
+            v = jnp.where(rows() <= n, v, jnp.zeros_like(v))
         m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(sc - m_new)
@@ -218,11 +276,11 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
 
     @pl.when(s == 0)
     def _():
-        start(0, 0, 0, blocks)
+        start(0, 0, 0, (head, blocks))
 
     def full_chunk(i, carry):
         buf = (base + i) % 2
-        start(s, i + 1, 1 - buf, blocks)
+        start(s, i + 1, 1 - buf, (head, blocks))
         wait(buf, cb)
         return attend(i, buf, carry, last=False)
 
@@ -234,7 +292,7 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
 
     @pl.when(s + 1 < slots)
     def _():
-        start(s + 1, 0, 1 - buf, horizon(s + 1) // bl + 1)
+        start(s + 1, 0, 1 - buf, walk(horizon(s + 1)))
 
     # the slot's last chunk, 1..cb live blocks: a wait a binary digit
     # of their count
@@ -264,7 +322,7 @@ def _check_tiling(q, k_pool, value_dim):
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
-                           value_dim=None):
+                           value_dim=None, window=0):
     """q (S, H, D) against pools (num_blocks, Hkv, bl, D) through
     `tables` (S, T) int32 and `ntoks` (S,) int32.  Returns (S, H, D) in
     q's dtype, equal to `paged_attention_reference` up to the order of
@@ -278,8 +336,18 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
     cache under an absorbed query: Hkv 1, one row a token shared by all
     heads, scale 1 / sqrt(nope + rope).
 
+    `window` W > 0: slot s attends its last W positions only,
+    max(0, ntoks[s] - W + 1)..ntoks[s], and its table row is a ring
+    (position p in column (p // bl) % T, T >= `ring_blocks(W, bl)`);
+    the walk starts at the window's first block.
+
     Compiled by Mosaic on the TPU, interpreted elsewhere
     (`ops.attention._on_tpu`)."""
+    if window and tables.shape[1] < ring_blocks(window, k_pool.shape[2]):
+        raise ValueError(
+            f"a window of {window} positions touches up to "
+            f"{ring_blocks(window, k_pool.shape[2])} blocks of "
+            f"{k_pool.shape[2]}; the ring has {tables.shape[1]}")
     if q.shape[1] % k_pool.shape[1]:
         raise ValueError(f"{q.shape[1]} query heads over "
                          f"{k_pool.shape[1]} key/value heads")
@@ -297,7 +365,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
         q, k_pool, v_pool, tables, ntoks, interpret=interpret,
         chunk=chunk_positions(k_pool.shape, k_pool.dtype),
         group=1 if interpret else _ISSUE_GROUP, value_dim=value_dim,
-        scale=1.0 / math.sqrt(d) if scale is None else float(scale))
+        scale=1.0 / math.sqrt(d) if scale is None else float(scale),
+        window=int(window))
 
 
 # Jitted, so that the layers of one program share one trace and one
@@ -305,9 +374,10 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
 # named as the kernel: the function's name is the name of the op, and so
 # of the row, that holds the kernel's time in a device trace.
 @functools.partial(jax.jit, static_argnames=("interpret", "chunk", "group",
-                                             "scale", "value_dim"))
+                                             "scale", "value_dim", "window"))
 def singa_paged_decode(q, k_pool, v_pool, tables, ntoks, *, interpret,
-                       chunk, scale, value_dim, group=_ISSUE_GROUP):
+                       chunk, scale, value_dim, group=_ISSUE_GROUP,
+                       window=0):
     s, h, d = q.shape
     _, hkv, bl, _ = k_pool.shape
     tw = tables.shape[1]
@@ -321,7 +391,7 @@ def singa_paged_decode(q, k_pool, v_pool, tables, ntoks, *, interpret,
 
     out = pl.pallas_call(
         functools.partial(_kernel, bl=bl, cb=cb, tw=tw, scale=scale,
-                          sides=len(pools), group=group),
+                          sides=len(pools), group=group, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),

@@ -53,7 +53,7 @@ from ..models.generate import (_sample, forward_cached, forward_paged,
                                init_cache, scatter_prefill)
 from ..utils import faults
 from ..utils.checkpoint import CheckpointManager
-from .kvcache import init_pools, state_bytes
+from .kvcache import init_pools, slot_behind_row
 from .stats import ServeStats
 
 MODES = ("generate", "predict")
@@ -323,10 +323,10 @@ class InferenceEngine:
         self.net = net
         self.spec = spec
         # what the cb programs are shaped by, read off the layers'
-        # declared serving state: a state per slot (the prefill program
-        # takes the slot's index), routing counts (the decode program
-        # returns them behind its tokens)
-        self._per_slot_state = state_bytes(net, 1)["slot"] > 0
+        # declared serving state: a state or a ring per slot (the
+        # prefill program takes the slot's index), routing counts (the
+        # decode program returns them behind its tokens)
+        self._per_slot_state = slot_behind_row(net)
         routed = {name: entry["routed"].shape[0]
                   for name, entry in jax.eval_shape(
                       lambda: init_pools(net, 2, 1, jnp.float32, 1)).items()

@@ -3,21 +3,30 @@ with host-side block tables and refcounts.
 
 A layer that keeps state between decode steps declares it
 (`layer.init_pool`, the decode-state protocol of `models/generate.py`)
-in one of two kinds, or in both at once (a `kCCA` layer: K and V rows
+in one of three kinds, or in two at once (a `kCCA` layer: K and V rows
 per token AND its convolutions' tails and shifted value half per slot,
-in ONE entry), and this manager holds both, allocates them together,
-counts each part where it belongs (`state_bytes`) and hands the
-prefill program the table row and the slot behind it:
+in ONE entry), and this manager holds them all, allocates them
+together, counts each part where it belongs (`state_bytes`) and hands
+the prefill program the table row and the slot behind it:
 
-  * rows per token in PAGED BLOCKS — K and V of a `kAttention` layer,
-    (num_blocks, Hkv, block_len, D) per side; the latent row of a `kMLA`
-    layer, (num_blocks, block_len, rank + rope).  A slot holds an
-    ordered list of block indices (its *block table* row), one table for
-    all paged layers, and retiring a slot returns its blocks to the
-    free list immediately.  Slot memory is O(active tokens), where the
-    static bucket path allocates each batch a contiguous cache at
-    max_len (reads stay at Hkv width exactly like
-    `AttentionLayer.apply_cached`).
+  * rows per token in PAGED BLOCKS THAT GROW — K and V of a `kAttention`
+    layer without a window, (num_blocks, Hkv, block_len, D) per side;
+    the latent row of a `kMLA` layer, (num_blocks, block_len, rank +
+    rope).  A slot holds an ordered list of block indices (its *block
+    table* row), one table for all layers of this kind, and retiring a
+    slot returns its blocks to the free list immediately.  Slot memory
+    is O(active tokens), where the static bucket path allocates each
+    batch a contiguous cache at max_len (reads stay at Hkv width
+    exactly like `AttentionLayer.apply_cached`).
+  * rows per token in a RING OF BLOCKS PER SLOT — K and V of a
+    `kAttention` layer with a window W: it needs a slot's last W
+    positions and no more, so slot s owns `ring_blocks` = W / block_len
+    + 1 blocks of that layer's pool for good (blocks 1 + s R ..
+    (s + 1) R of (num_slots R + 1, Hkv, block_len, D)) and position p
+    lives in ring column (p // block_len) % R.  A column is used again
+    every R blocks; nothing is allocated at admission, nothing freed
+    at retirement, and the table of growing blocks plays no part.  A
+    context of any length costs such a layer W + block_len positions.
   * one FIXED STATE PER SLOT — the (H, Dk, Dv) float32 state and the
     conv tail of a `kKDA` layer, (num_slots, ...).  It does not grow:
     admission overwrites the slot's state whole (the prefill program is
@@ -37,11 +46,12 @@ Split of responsibilities:
     bookkeeping is plain numpy under the scheduler's single thread; no
     jax dispatch happens here.
 
-Blocks are reserved *conservatively at admission*: the scheduler asks
-for ceil((plen + max_new) / block_len) blocks up front, so pool
-exhaustion can only ever surface as an admission decision (queue, then
-shed) — never as a mid-decode OOM or a deadlock between half-admitted
-requests.
+Growing blocks are reserved *conservatively at admission*: the
+scheduler asks for ceil((plen + max_new) / block_len) blocks up front,
+so pool exhaustion can only ever surface as an admission decision
+(queue, then shed) — never as a mid-decode OOM or a deadlock between
+half-admitted requests.  The reservation is of the growing kind alone:
+a ring is the slot's already, whatever the request's length.
 """
 
 from __future__ import annotations
@@ -88,15 +98,53 @@ def pool_bytes(net, num_blocks: int, block_len: int,
                for a in jax.tree_util.tree_leaves(shapes))
 
 
+def window_of(net) -> int:
+    """The window of the net's windowed layers (0: none has one).  One
+    ring geometry a model: two windows would be two kinds more."""
+    windows = {layer.window for _, layer in _stateful(net)
+               if getattr(layer, "window", 0)}
+    if len(windows) > 1:
+        raise ValueError(f"layers with different windows "
+                         f"{sorted(windows)}: one ring geometry a model")
+    return windows.pop() if windows else 0
+
+
 def state_bytes(net, block_len: int, dtype=jnp.float32) -> Dict[str, int]:
-    """What one slot and one block cost: `slot` the bytes of the fixed
-    per-slot states of all layers, `block` the bytes one more paged
-    block adds over all layers.  Read off the pools' shapes at two
-    sizes, so a layer whose entry holds both kinds adds to both."""
-    at = lambda slots, blocks: pool_bytes(      # noqa: E731
-        net, blocks, block_len, dtype, slots)
-    base = at(1, 1)
-    return {"slot": at(2, 1) - base, "block": at(1, 2) - base}
+    """What one slot and one block cost, by kind: `slot` the bytes of
+    the fixed per-slot states of all layers, `block` the bytes one more
+    growing block adds over all layers that keep a table, `window_block`
+    the bytes of one ring block over all windowed layers (a slot holds
+    `ring_blocks` of them whatever its length).  Read off each layer's
+    pool shapes at two sizes, so a layer whose entry holds two kinds
+    adds to both."""
+    import jax
+    from ..ops.paged_attention import ring_blocks
+
+    def size(layer, slots, blocks):
+        shapes = jax.eval_shape(
+            lambda: layer.init_pool(slots, blocks, block_len, dtype))
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(shapes))
+
+    out = {"slot": 0, "block": 0, "window_block": 0}
+    for _, layer in _stateful(net):
+        base = size(layer, 1, 1)
+        a_slot, a_block = (size(layer, 2, 1) - base,
+                           size(layer, 1, 2) - base)
+        window = getattr(layer, "window", 0)
+        if window:
+            out["window_block"] += a_slot // ring_blocks(window, block_len)
+        else:
+            out["slot"] += a_slot
+        out["block"] += a_block
+    return out
+
+
+def slot_behind_row(net) -> bool:
+    """Whether a prefill has to be told its slot: some layer keeps a
+    state or a ring per slot (its pools grow with the slots)."""
+    return pool_bytes(net, 1, 1, num_slots=2) > pool_bytes(net, 1, 1,
+                                                           num_slots=1)
 
 
 class PagedKVCache:
@@ -120,10 +168,15 @@ class PagedKVCache:
         self.pools: Pools = init_pools(net, self.num_blocks,
                                        self.block_len, dtype,
                                        self.num_slots)
-        # a layer with one state per slot: the prefill program is told
-        # the slot behind the table row (`prefill_target`)
-        self.per_slot_state = state_bytes(net, self.block_len,
-                                          dtype)["slot"] > 0
+        # the windowed layers' ring: columns a slot, 0 where no layer
+        # has a window
+        from ..ops.paged_attention import ring_blocks
+        self.window = window_of(net)
+        self.ring_blocks = (ring_blocks(self.window, self.block_len)
+                            if self.window else 0)
+        # a layer with a state or a ring per slot: the prefill program
+        # is told the slot behind the table row (`prefill_target`)
+        self.per_slot_state = slot_behind_row(net)
         # host bookkeeping: block 0 never enters the free list
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._refcounts = np.zeros((self.num_blocks,), np.int32)
@@ -152,6 +205,25 @@ class PagedKVCache:
 
     def can_admit(self, nblocks: int) -> bool:
         return nblocks <= len(self._free)
+
+    def ring_block(self, slot: int, position: int) -> int:
+        """The pool block of a windowed layer that holds `position` of
+        slot `slot`: ring column (position // block_len) % ring_blocks."""
+        column = (int(position) // self.block_len) % self.ring_blocks
+        return 1 + int(slot) * self.ring_blocks + column
+
+    def walked_blocks(self, ntoks: np.ndarray) -> Dict[str, int]:
+        """Blocks one decode step's paged kernel walks over all slots,
+        once a kind: `table` every slot's row up to its write position
+        (an idle slot one block), `window` the blocks of its ring that
+        the window touches (0 where no layer has a window)."""
+        last = np.asarray(ntoks) // self.block_len
+        out = {"table": int((last + 1).sum()), "window": 0}
+        if self.window:
+            first = np.maximum(np.asarray(ntoks) - (self.window - 1),
+                               0) // self.block_len
+            out["window"] = int((last - first + 1).sum())
+        return out
 
     # -- slot lifecycle -----------------------------------------------------
     def alloc(self, slot: int, nblocks: int) -> np.ndarray:
@@ -220,4 +292,5 @@ class PagedKVCache:
                 "block_len": self.block_len,
                 "num_slots": self.num_slots,
                 "max_blocks_per_slot": self.max_blocks_per_slot,
+                "window": self.window, "ring_blocks": self.ring_blocks,
                 "utilization": round(self.utilization(), 4)}

@@ -275,6 +275,8 @@ class ContinuousScheduler:
             per = state_bytes(self.engine.net, spec.cb_block_len, dtype)
             self.stats.gauge("cb_slot_state_bytes", per["slot"])
             self.stats.gauge("cb_block_bytes", per["block"])
+            self.stats.gauge("cb_window_block_bytes", per["window_block"])
+            self.stats.gauge("cb_ring_blocks", self.kv.ring_blocks)
             # no request's first token waits on a program's first run
             self.kv.pools = self.engine.run_cb_prefill_rungs(
                 self.engine.params, self.kv.pools)
@@ -507,19 +509,21 @@ class ContinuousScheduler:
                     # a slot fell free and nothing took it
                     self._collect(step_no)
                 active = int(self._active.sum())
-                live = 0
+                walked = {"table": 0, "window": 0}
                 if active:
-                    # what the decode program walks: every slot's row
-                    # up to its write position, an idle slot one block
-                    live = int((self._ntoks // self.spec.cb_block_len
-                                + 1).sum())
+                    # what the decode program walks, once a kind of
+                    # paged layer: every slot's table row up to its
+                    # write position (an idle slot one block), and of
+                    # its ring what the window touches
+                    walked = self.kv.walked_blocks(self._ntoks)
                     self._decode_step(params, step_no, active)
             except Exception as e:  # noqa: BLE001 — fail step, keep serving
                 self._fail_step(e)
                 return
             if self.kv is not None:
-                self.stats.observe_cb_step(int(self._active.sum()),
-                                           self.kv.blocks_in_use, live)
+                self.stats.observe_cb_step(
+                    int(self._active.sum()), self.kv.blocks_in_use,
+                    walked["table"], walked["window"])
                 self.stats.gauge("cb_blocks_in_use", self.kv.blocks_in_use)
 
     def _expire_pending(self, now: float) -> None:

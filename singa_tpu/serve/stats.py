@@ -29,7 +29,9 @@ handler and tests see the same semantics:
     pool size), and `cb_live_block_share` (table blocks the paged
     attention kernel walked / slots x table width, averaged over the
     steps that decoded: how much of what a whole-table read would
-    touch this traffic keeps live);
+    touch this traffic keeps live), and `cb_window_block_share` (ring
+    blocks the windowed layers' walk read / table blocks the growing
+    layers' walk read: what of a context a window still reads);
   * `observe_cb_prefill` feeds `cb_prefill_fill_share` (prompt tokens /
     rows the prefill programs ran: how much of each prompt's rung of
     the ladder, `ServeSpec.cb_prefill_widths`, was real).
@@ -121,6 +123,8 @@ class ServeStats:
         self.cb_block_use_steps = 0    # sum of blocks in use per step
         self.cb_decode_steps = 0       # iterations that ran a decode
         self.cb_live_block_steps = 0   # sum of table blocks they walked
+        self.cb_window_block_steps = 0  # sum of ring blocks they walked
+                                        # (layers with a window)
         self.cb_table_blocks = 0       # gauge: slots x blocks per slot
         self.cb_slot_capacity = 0      # gauge: compiled slot count S
         self.cb_blocks_total = 0       # gauge: usable pool blocks
@@ -129,7 +133,10 @@ class ServeStats:
         # layers (serve/kvcache.py state_bytes): with the slots in use
         # and the live blocks, the bytes a decode step has to move
         self.cb_slot_state_bytes = 0   # gauge
-        self.cb_block_bytes = 0        # gauge
+        self.cb_block_bytes = 0        # gauge: a growing block
+        self.cb_window_block_bytes = 0  # gauge: a ring block, over the
+                                        # layers with a window
+        self.cb_ring_blocks = 0        # gauge: ring blocks a slot
         # routing of the experts held here, summed over decode steps
         # and routed layers, busy slots only (engine.run_cb_decode)
         self.cb_routed_layer_steps = 0   # layers x steps counted
@@ -246,9 +253,11 @@ class ServeStats:
             self.cb_prefill_width_rows += int(width)
 
     def observe_cb_step(self, active_slots: int, blocks_in_use: int,
-                        live_blocks: int = 0) -> None:
+                        live_blocks: int = 0,
+                        window_blocks: int = 0) -> None:
         """`live_blocks`: table blocks the step's decode program walked
-        (0 for a step that ran none)."""
+        (0 for a step that ran none); `window_blocks`: ring blocks its
+        windowed layers walked (`PagedKVCache.walked_blocks`)."""
         with self._lock:
             self.cb_steps += 1
             self.cb_active_slot_steps += int(active_slots)
@@ -256,6 +265,7 @@ class ServeStats:
             if live_blocks:
                 self.cb_decode_steps += 1
                 self.cb_live_block_steps += int(live_blocks)
+                self.cb_window_block_steps += int(window_blocks)
             self._cb_t.append((time.monotonic(), int(active_slots)))
 
     def observe_routing(self, assignments: int, experts_touched: int,
@@ -427,6 +437,7 @@ class ServeStats:
                     "batched_requests", "batch_slots", "cb_steps",
                     "cb_prefills", "cb_prefill_rows",
                     "cb_prefill_width_rows", "cb_admit_steps",
+                    "cb_live_block_steps", "cb_window_block_steps",
                     "cb_routed_layer_steps", "cb_routed_assignments",
                     "cb_routed_experts_touched", "cb_routed_max_load",
                     "compiles", "reloads", "reload_failures",
@@ -445,7 +456,9 @@ class ServeStats:
                   "cb_block_utilization", "cb_live_block_share",
                   "cb_prefill_fill_share", "cb_blocks_in_use",
                   "cb_blocks_total",
-                  "cb_slot_state_bytes", "cb_block_bytes")
+                  "cb_slot_state_bytes", "cb_block_bytes",
+                  "cb_window_block_bytes", "cb_ring_blocks",
+                  "cb_window_block_share")
 
         def collect():
             snap = self.snapshot()
@@ -518,6 +531,10 @@ class ServeStats:
                 "cb_blocks_total": self.cb_blocks_total,
                 "cb_slot_state_bytes": self.cb_slot_state_bytes,
                 "cb_block_bytes": self.cb_block_bytes,
+                "cb_window_block_bytes": self.cb_window_block_bytes,
+                "cb_ring_blocks": self.cb_ring_blocks,
+                "cb_live_block_steps": self.cb_live_block_steps,
+                "cb_window_block_steps": self.cb_window_block_steps,
                 "cb_routed_layer_steps": self.cb_routed_layer_steps,
                 "cb_routed_assignments": self.cb_routed_assignments,
                 "cb_routed_experts_touched":
@@ -569,5 +586,12 @@ class ServeStats:
                                       if cb_live is not None else None)
         out["cb_prefill_fill_share"] = (round(cb_fill, 4)
                                         if cb_fill is not None else None)
+        # of the blocks a growing table's walk reads, what a window's
+        # reads: under 1 once contexts pass the window
+        out["cb_window_block_share"] = (
+            round(out["cb_window_block_steps"]
+                  / out["cb_live_block_steps"], 4)
+            if out["cb_ring_blocks"] and out["cb_live_block_steps"]
+            else None)
         out["by_tenant"] = self.tenants.snapshot()
         return out

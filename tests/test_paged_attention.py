@@ -24,7 +24,7 @@ from singa_tpu.models.transformer import transformer_lm
 from singa_tpu.ops.paged_attention import (chunk_positions,
                                            paged_attention_reference,
                                            paged_decode_attention,
-                                           singa_paged_decode)
+                                           ring_blocks, singa_paged_decode)
 from singa_tpu.serve import InferenceEngine, InferenceServer, ServeSpec
 from singa_tpu.serve.kvcache import NULL_BLOCK
 
@@ -191,6 +191,138 @@ def test_kernel_matches_gather_reference_on_poisoned_pools(name, groups,
     # of the reference either: it is a fair oracle on the same pools
     same = np.asarray(_reference(q, *poisoned, tables, ntoks), np.float32)
     assert np.array_equal(same, want)
+
+
+# -- the windowed walk over a ring of blocks per slot -------------------------
+
+# (Hkv, G, D, block_len, window, pools, value columns): the Trinity
+# cell's geometry (two pools of (4, 16, 128) under 32 query heads, a
+# window of 2,048: a ring of 129 blocks, four chunks of 512 positions
+# and a fifth of one block), and a window over each of the three
+# geometries the kernel already served.  `tiny` walks several chunks of
+# two blocks with copies issued two at a time.
+RINGS = {
+    "trinity": (4, 8, 128, 16, 2048, 2, 128),
+    "dense": (8, 4, 128, 16, 512, 2, 128),
+    "latent": (1, 8, 640, 16, 1024, 1, 512),
+    "narrow": (2, 4, 128, 16, 2048, 2, 128),
+    "tiny": (2, 2, 8, 4, 10, 2, 8),
+}
+
+
+def _ring_lengths(window, bl):
+    """ntoks a slot: no token, one short of the window, the newest
+    token the window's last, one past it (the first block's head is
+    masked), a block's last row and the next block's first well past
+    the window, and a ring that has wrapped several times."""
+    ring = ring_blocks(window, bl)
+    return [0, window - 2, window - 1, window, window + bl - 1,
+            3 * window + bl, 5 * ring * bl + 3]
+
+
+def _ring_case(lengths, hkv, groups, d, bl, window, sides, dtype, seed):
+    """As `_case`, the table row a ring: position p of slot s lives in
+    pool block 1 + s R + (p // bl) % R, and every row that lies outside
+    the slot's window is poisoned."""
+    rng = np.random.default_rng(seed)
+    slots, ring = len(lengths), ring_blocks(window, bl)
+    nb = slots * ring + 1
+    q = rng.standard_normal((slots, hkv * groups, d)).astype(np.float32)
+    pools = [rng.standard_normal((nb, hkv, bl, d)).astype(np.float32)
+             for _ in range(sides)]
+    tables = (1 + np.arange(slots)[:, None] * ring
+              + np.arange(ring)[None, :]).astype(np.int32)
+    seen = np.zeros((nb, bl), bool)
+    for s, n in enumerate(lengths):
+        at = np.arange(max(0, n - window + 1), n + 1)
+        seen[tables[s, (at // bl) % ring], at % bl] = True
+    hide = ~seen[:, None, :, None]
+    clean = [np.where(hide, 0.0, a) for a in pools]
+    poisoned = [np.where(hide, bad, a)
+                for a, bad in zip(pools, (np.nan, np.inf))]
+    to = lambda a: jnp.asarray(a, dtype)                      # noqa: E731
+    return (to(q), [to(a) for a in clean], [to(a) for a in poisoned],
+            jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+
+
+def _window_oracle(q, pools, tables, ntoks, window, bl, vd):
+    """The window's rows gathered position by position, in numpy."""
+    q = np.asarray(q, np.float32)
+    k = np.asarray(pools[0], np.float32)
+    v = np.asarray(pools[-1], np.float32)[..., :vd]
+    slots, h, d = q.shape
+    groups, ring = h // k.shape[1], tables.shape[1]
+    out = np.zeros((slots, h, vd), np.float32)
+    for s in range(slots):
+        n = int(ntoks[s])
+        at = np.arange(max(0, n - window + 1), n + 1)
+        blk = np.asarray(tables)[s, (at // bl) % ring]
+        for head in range(h):
+            sc = k[blk, head // groups, at % bl] @ q[s, head] / np.sqrt(d)
+            w = np.exp(sc - sc.max())
+            out[s, head] = (w / w.sum()) @ v[blk, head // groups, at % bl]
+    return out
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("cell", list(RINGS))
+def test_windowed_walk_reads_the_ring_and_nothing_outside_the_window(
+        cell, dtype, tol):
+    hkv, groups, d, bl, window, sides, vd = RINGS[cell]
+    lengths = _ring_lengths(window, bl)
+    q, clean, poisoned, tables, ntoks = _ring_case(
+        lengths, hkv, groups, d, bl, window, sides, dtype, seed=len(cell))
+    v_of = lambda pools: pools[1] if sides == 2 else None     # noqa: E731
+    kw = dict(window=window, value_dim=vd)
+    if cell == "tiny":
+        def kernel(q, k, v, tables, ntoks):
+            return singa_paged_decode(
+                q, k, v, tables, ntoks, interpret=True, chunk=2 * bl,
+                group=2, scale=1.0 / np.sqrt(d), **kw)
+    else:
+        kernel = functools.partial(paged_decode_attention, **kw)
+    want = np.asarray(paged_attention_reference(
+        q, clean[0], v_of(clean), tables, ntoks, **kw), np.float32)
+    if dtype == jnp.float32:
+        # the gather itself against a position-by-position reading
+        oracle = _window_oracle(q, clean, tables, ntoks, window, bl, vd)
+        assert np.max(np.abs(want - oracle)) <= tol
+    got = np.asarray(kernel(q, poisoned[0], v_of(poisoned), tables, ntoks),
+                     np.float32)
+    assert np.isfinite(got).all(), "the kernel read outside a window"
+    assert np.max(np.abs(got - want)) <= tol
+    same = np.asarray(paged_attention_reference(
+        q, poisoned[0], v_of(poisoned), tables, ntoks, **kw), np.float32)
+    assert np.array_equal(same, want)
+
+
+def test_a_ring_narrower_than_its_window_is_refused():
+    q, clean, _, tables, ntoks = _ring_case(
+        [5, 40], 2, 2, 8, 4, 10, 2, jnp.float32, seed=1)
+    with pytest.raises(ValueError, match="the ring has 3"):
+        paged_decode_attention(q, *clean, tables[:, :3], ntoks, window=10)
+
+
+def test_an_unwindowed_call_lowers_without_a_trace_of_the_window():
+    """The window is a static argument: a call without one traces to
+    the kernel it traced to before there was one (no modulo, no lower
+    mask), so the cells that have no window do not pay for it."""
+    q, clean, _, tables, ntoks = _case(LENGTHS["mixed"], 4, jnp.float32, 3)
+    plain = str(jax.make_jaxpr(paged_decode_attention)(
+        q, *clean, tables, ntoks))
+    ring = _ring_case([5, 40], HKV, 4, D, BL, 10, 2, jnp.float32, seed=1)
+    windowed = str(jax.make_jaxpr(functools.partial(
+        paged_decode_attention, window=10))(
+            ring[0], *ring[1], ring[3], ring[4]))
+    # the lower mask's compares and the ring's column are the windowed
+    # call's alone
+    assert plain.count(" gt ") < windowed.count(" gt ")
+    assert plain.count("remainder") < windowed.count("remainder")
+    again = str(jax.make_jaxpr(functools.partial(
+        paged_decode_attention, window=0))(q, *clean, tables, ntoks))
+    assert again == plain
 
 
 # (Hkv, block_len, D) of a pool -> positions a chunk: the three serving

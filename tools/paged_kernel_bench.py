@@ -1,8 +1,9 @@
 """Microbenchmark of the paged decode attention kernel on the chip (a
-builder's tool, not part of the benchmark): the kernel alone at the three
+builder's tool, not part of the benchmark): the kernel alone at the
 serving geometries (the dense cells' key and value pools, the Kimi
 cell's one pool of latent rows, the ZAYA cell's narrow key and value
-pools), each under its slot mixes, against the gather formulation, as
+pools, the Trinity cell's pools under a growing table and under a ring
+read through a window), each under its slot mixes, against the gather formulation, as
 seconds a layer and as a share of the live bytes' time at the memory
 roofline, at the chunk the kernel's rule gives and at fixed ones beside
 it.  `python tools/paged_kernel_bench.py [geometry ...]` prints one JSON
@@ -38,6 +39,7 @@ class Geometry(NamedTuple):
     sides: int
     scale: float
     value_dim: int
+    window: int = 0         # > 0: `table` is the ring's width
 
 
 GEOMETRIES = {
@@ -48,11 +50,21 @@ GEOMETRIES = {
                        1 / math.sqrt(192), 512),
     # zaya1-8b-serve-l16: kCCA
     "cca": Geometry(64, 8, 2, 128, 16, 256, 16, 2, 1 / math.sqrt(128), 128),
+    # trinity-mini-serve-l16-ep8: kAttention, 4 full layers under the
+    # growing table, 12 windowed under a ring of 129 blocks a slot
+    "table": Geometry(64, 32, 4, 128, 16, 512, 4, 2, 1 / math.sqrt(128), 128),
+    "ring": Geometry(64, 32, 4, 128, 16, 129, 12, 2, 1 / math.sqrt(128), 128,
+                     window=2048),
 }
 
 
 def mixes(name, g, rng):
     full = np.full(g.slots, g.table * g.bl - 1, np.int32)
+    if name in ("table", "ring"):
+        # the Trinity cell's full house: 1k of prompt and 0-6k generated
+        agent = 300 + rng.exponential(2300, g.slots)
+        return {"agent": np.minimum(agent, 8000).astype(np.int32),
+                "full": np.full(g.slots, 8191, np.int32)}
     if name == "latent":
         # the Kimi cell's full house: cb_live_block_share 0.22
         return {"assist": rng.integers(150, 750, g.slots).astype(np.int32),
@@ -102,6 +114,8 @@ def bench(name, g):
     tables = jnp.asarray(rng.permutation(np.arange(1, nb))
                          .reshape(g.slots, g.table).astype(np.int32))
     how = {"scale": g.scale, "value_dim": g.value_dim}
+    if g.window:
+        how["window"] = g.window
     gather = functools.partial(pa.paged_attention_reference, **how)
     # float32 pools (chip_smoke.py's serve leg serves them): parity only
     f32 = [a if a is None else a.astype(jnp.float32) for a in [q] + pools]
@@ -113,7 +127,8 @@ def bench(name, g):
                       "max_err_vs_gather": float(err)}), flush=True)
     for mix, ntoks in mixes(name, g, rng).items():
         nt = jnp.asarray(ntoks)
-        live = int(np.sum(ntoks // g.bl + 1))
+        first = np.maximum(ntoks - g.window + 1, 0) // g.bl if g.window else 0
+        live = int(np.sum(ntoks // g.bl - first + 1))
         need = live * g.sides * g.hkv * g.bl * g.d * 2 / HBM_BYTES_S
         ref = gather(q, *pools, tables, nt)
         ruled = pa.chunk_positions(pools[0].shape, pools[0].dtype)
