@@ -14,6 +14,7 @@ kMoE, kLMHead.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List, Optional
 
@@ -23,7 +24,7 @@ import jax.numpy as jnp
 from ..config.schema import ParamConfig
 from ..ops import moe as moe_ops
 from ..ops.attention import (attention_reference, expand_kv_heads,
-                             flash_attention, rope)
+                             flash_attention, flash_prefill, rope)
 from ..ops.paged_attention import paged_decode_attention, ring_blocks
 from .layers import Context, Layer, LayerError, register_layer
 
@@ -169,14 +170,57 @@ def write_token(pool, bidx, off, new):
     return pool.at[bidx].set(blocks)
 
 
-
 def attend_cache(q, k_cache, v_cache, pos, kmask=None, window=0):
     """A chunk's queries q (B, H, T, D), the first at absolute position
     `pos`, against contiguous caches (B, Hkv, max_len, D) that already
     hold the chunk's own rows: causal (with `window`, the last `window`
     positions only), `kmask` (B, max_len) ANDed in, GQA read at Hkv
     width, f32 scores and softmax.  Returns (B, T, H * D) in the
-    values' dtype."""
+    values' dtype.
+
+    A whole chunk at position 0 (the cb prefill: `pos` the integer 0,
+    no `kmask`, the chunk the whole cache, whole lane tiles of rows and
+    a head the kernel is legal at) is plain causal self-attention, and
+    the flash forward kernel computes it with no score square in HBM;
+    everything else (a decode token, a traced position, a masked batch,
+    a chunk under a lane tile) is the dense scores below."""
+    _, heads, t, head_dim = q.shape
+    if (isinstance(pos, int) and pos == 0 and kmask is None
+            and t == k_cache.shape[2] and t % 128 == 0
+            and head_dim % 8 == 0 and heads % k_cache.shape[1] == 0):
+        return _attend_chunk(q, k_cache.astype(q.dtype),
+                             v_cache.astype(q.dtype), window)
+    return _attend_dense(q, k_cache, v_cache, pos, kmask, window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _attend_chunk(q, k, v, window):
+    """`attend_cache`'s whole chunk at position 0 through
+    `ops.attention.flash_prefill`: the kernel takes the projections'
+    (B, T, H * D), so the heads go back behind the rows.  The kernel is
+    forward only; a gradient is the dense scores'."""
+    b, heads, t, _ = q.shape
+
+    def packed(a):
+        return a.transpose(0, 2, 1, 3).reshape(b, t, -1)
+    return flash_prefill(packed(q), packed(k), packed(v), heads,
+                         k.shape[1], window)
+
+
+def _attend_chunk_fwd(q, k, v, window):
+    return _attend_chunk(q, k, v, window), (q, k, v)
+
+
+def _attend_chunk_bwd(window, res, g):
+    return jax.vjp(lambda q, k, v: _attend_dense(q, k, v, 0, None, window),
+                   *res)[1](g)
+
+
+_attend_chunk.defvjp(_attend_chunk_fwd, _attend_chunk_bwd)
+
+
+def _attend_dense(q, k_cache, v_cache, pos, kmask, window):
+    """`attend_cache` as masked-dense f32 scores."""
     b, heads, t, head_dim = q.shape
     kv_heads = k_cache.shape[1]
     groups = heads // kv_heads
@@ -219,8 +263,10 @@ class AttentionLayer(Layer):
     Three options of attention_param, each off unless set, and a layer
     without them is the program it was before they existed: `window` W
     (query t sees keys t - W + 1 .. t; a mask over dense scores in
-    `apply` and `apply_cached`, and in the serving pools a RING of
-    blocks per slot read by the windowed walk of the paged kernel),
+    `apply` and `apply_cached`, blocks skipped by the flash forward
+    kernel where `attend_cache` takes it, and in the serving pools a
+    RING of blocks per slot read by the windowed walk of the paged
+    kernel),
     `qk_norm` (a learned RMSNorm over head_dim on every query and key
     head, before RoPE), `gate` (out = (sigmoid(x Wg) * attention) Wo).
     """
@@ -361,10 +407,10 @@ class AttentionLayer(Layer):
         q, k, v = self.qkv(params, x, jnp.arange(s), ctx)
 
         if self.window:
-            # the window as a mask over dense scores (the flash kernels
-            # skip no block for it yet)
-            return self._out(params, x, attend_cache(
-                q, k, v, 0, window=self.window), ctx)
+            # the window as a mask over dense scores (the backward
+            # flash kernels know no window)
+            return self._out(params, x, _attend_dense(
+                q, k, v, 0, None, self.window), ctx)
         if self.seq_parallel == "ring" and ctx.mesh is not None:
             # k/v stay at Hkv width: the ring rotates (and Ulysses
             # all-to-alls) unexpanded KV; group expansion happens on

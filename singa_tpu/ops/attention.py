@@ -120,11 +120,15 @@ def _fit_block(s: int, want: int) -> int:
     return c
 
 
-def _causal_mask_block(iq, ik, block_q, block_k):
+def _causal_mask_block(iq, ik, block_q, block_k, window=0):
+    """(block_q, block_k) bool: key at or before the query and, with a
+    `window`, among the query's last `window` positions."""
     qpos = iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     kpos = ik * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
+    if window:
+        return (qpos >= kpos) & (kpos > qpos - window)
     return qpos >= kpos
 
 
@@ -319,10 +323,11 @@ def expand_kv_heads(kv: jnp.ndarray, num_heads: int) -> jnp.ndarray:
 # packed-layout flash attention: (B, S, H·D) in, (B, S, H·D) out
 
 
-def _packed_params(interpret):
+def _packed_params(interpret, vmem_limit_bytes=None):
     return (None if interpret
             else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")))
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=vmem_limit_bytes))
 
 
 LOG2E = 1.4426950408889634
@@ -336,7 +341,7 @@ KERNEL_NAMES = ("singa_flash_fwd", "singa_flash_dq", "singa_flash_dkv")
 
 def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                        acc_ref, *, heads, kv_heads, causal, scale, bq,
-                       bk):
+                       bk, window=0, fold_scale=True):
     """All-heads blocks: refs are (1, bq|bk, H·D); the head loop runs
     in-kernel over D-column slices (Mosaic rejects last-dim blocks
     narrower than a lane tile, so per-head blocks of D=64 are not an
@@ -348,7 +353,14 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     element instead of per score, exp → native exp2) — and causal
     blocks split into fully-visible (no mask select at all; the vast
     majority at long S) vs diagonal-partial (masked).  m/l trackers are
-    base-2; the stored lse converts back to natural once at finalize."""
+    base-2; the stored lse converts back to natural once at finalize.
+
+    `window` (static, causal only; 0 = none): a query sees its last
+    `window` keys.  A K block wholly before the q block's first window
+    is skipped as one wholly after the diagonal is; a partial one is
+    masked.  A row whose keys of a visited block are all masked adds
+    exp2(0) a key there, and the first block that holds a visible key
+    (its own diagonal at the latest) wipes that with alpha = 0."""
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -362,18 +374,23 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def compute(masked):
-        mask = (_causal_mask_block(iq, ik, bq, bk) if masked else None)
+        mask = (_causal_mask_block(iq, ik, bq, bk, window) if masked
+                else None)
         for h in range(heads):
             sl = slice(h * d, (h + 1) * d)
             slk = slice((h // grp) * d, (h // grp + 1) * d)
             # operands stay in their input dtype: bf16 x bf16 -> f32
             # runs the MXU at full rate (an f32 upcast halves it); the
             # base-2 scale folds into q in that dtype, flash-standard
-            q = q_ref[0, :, sl] * jnp.asarray(scale * LOG2E,
-                                              q_ref.dtype)
+            # (one more rounding of q), or multiplies the f32 scores
+            q = q_ref[0, :, sl]
+            if fold_scale:
+                q = q * jnp.asarray(scale * LOG2E, q_ref.dtype)
             k = k_ref[0, :, slk]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
+            if not fold_scale:
+                s = s * (scale * LOG2E)
             if mask is not None:
                 s = jnp.where(mask, s, NEG_INF)
             m_prev = m_ref[:, h:h + 1]
@@ -389,7 +406,11 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
             m_ref[:, h:h + 1] = m_new
 
     if causal:
-        @pl.when(ik * bk <= (iq + 1) * bq - 1)
+        visited = ik * bk <= (iq + 1) * bq - 1
+        if window:
+            visited &= (ik + 1) * bk - 1 >= iq * bq - window + 1
+
+        @pl.when(visited)
         def _():
             compute(True)
     else:
@@ -517,9 +538,26 @@ def _packed_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 
 def _packed_forward(q, k, v, num_heads, causal, block_q, block_k,
-                    interpret, num_kv_heads=None):
+                    interpret, num_kv_heads=None, window=0,
+                    fold_scale=True, heads_per_step=0,
+                    vmem_limit_bytes=None):
+    """`window` (static): see `_packed_fwd_kernel`; one that holds every
+    key is none, and the call is the unwindowed one.  Forward only: the
+    backward kernels know no window.
+
+    `heads_per_step` (static; 0 = all of them, the trainer's): a grid
+    step holds that many q heads of one kv head's group (blocks of
+    n·D query and D key columns, so head_dim a multiple of 128 and n a
+    divisor of the group): the same body unrolled over n heads in place
+    of H.  The unrolled heads are what a serving process pays for in
+    Python, each time a program is traced and lowered, compile cache or
+    none: 0.24 s a head on the v5e's host, 7.7 s a rung at 32 heads
+    (PERF.md 6, PR 36)."""
     b, sq, hd = q.shape
     sk = k.shape[1]
+    if window >= sk:
+        window = 0
+    assert causal or not window, "a window needs causal attention"
     d = hd // num_heads
     kv_heads = num_kv_heads or num_heads
     hd_kv = kv_heads * d
@@ -527,32 +565,57 @@ def _packed_forward(q, k, v, num_heads, causal, block_q, block_k,
     bq, bk = _fit_block(sq, block_q), _fit_block(sk, block_k)
     assert sq % bq == 0 and sk % bk == 0
     scale = 1.0 / math.sqrt(d)
-    q_spec = pl.BlockSpec((1, bq, hd), lambda b_, iq, ik: (b_, iq, 0))
-    k_spec = pl.BlockSpec((1, bk, hd_kv), lambda b_, iq, ik: (b_, ik, 0))
+    if heads_per_step:
+        # grid axis 0 walks (batch, run of heads); the run's kv head
+        # is the key block's column; lse comes out (B·H/n, S, n)
+        heads_in, kv_in = heads_per_step, 1
+        runs, grp = num_heads // heads_in, num_heads // kv_heads
+        assert grp % heads_in == 0 and d % 128 == 0, (grp, heads_in, d)
+        steps = b * runs
+
+        def q_at(g, i):
+            return (g // runs, i, g % runs)
+
+        def k_at(g, i):
+            return (g // runs, i, g % runs * heads_in // grp)
+    else:
+        steps, heads_in, kv_in = b, num_heads, kv_heads
+
+        def q_at(b_, i):
+            return (b_, i, 0)
+        k_at = q_at
+    q_spec = pl.BlockSpec((1, bq, heads_in * d),
+                          lambda b_, iq, ik: q_at(b_, iq))
+    k_spec = pl.BlockSpec((1, bk, kv_in * d),
+                          lambda b_, iq, ik: k_at(b_, ik))
     out, lse = pl.pallas_call(
-        functools.partial(_packed_fwd_kernel, heads=num_heads,
-                          kv_heads=kv_heads, causal=causal, scale=scale,
-                          bq=bq, bk=bk),
-        grid=(b, sq // bq, sk // bk),
+        functools.partial(_packed_fwd_kernel, heads=heads_in,
+                          kv_heads=kv_in, causal=causal, scale=scale,
+                          bq=bq, bk=bk, window=window,
+                          fold_scale=fold_scale),
+        grid=(steps, sq // bq, sk // bk),
         in_specs=[q_spec, k_spec, k_spec],
         out_specs=[
             q_spec,
-            pl.BlockSpec((1, bq, num_heads),
+            pl.BlockSpec((1, bq, heads_in),
                          lambda b_, iq, ik: (b_, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, sq, num_heads), jnp.float32),
+            jax.ShapeDtypeStruct((steps, sq, heads_in), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, num_heads), jnp.float32),
-            pltpu.VMEM((bq, num_heads), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
+            pltpu.VMEM((bq, heads_in), jnp.float32),
+            pltpu.VMEM((bq, heads_in), jnp.float32),
+            pltpu.VMEM((bq, heads_in * d), jnp.float32),
         ],
-        compiler_params=_packed_params(interpret),
+        compiler_params=_packed_params(interpret, vmem_limit_bytes),
         interpret=interpret,
         name=KERNEL_NAMES[0],
     )(q, k, v)
+    if heads_per_step:
+        lse = lse.reshape(b, runs, sq, heads_in).transpose(
+            0, 2, 1, 3).reshape(b, sq, num_heads)
     return out, lse
 
 
@@ -694,6 +757,84 @@ def _packed_lse_vjp_bwd(num_heads, causal, block_q, block_k, interpret,
 
 flash_attention_packed_lse.defvjp(_packed_lse_vjp_fwd,
                                   _packed_lse_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the serving prefill's call of the forward kernel
+
+# Scoped VMEM the prefill's call states for itself, the trainer's 32 MB
+# (core/trainer.py): the serving programs are compiled without that
+# option.  One head a step needs no more than Mosaic's default of 16 MB;
+# but a stated limit is also what XLA then plans the REST of the program
+# with: it keeps more of its own arrays in VMEM (the held experts' block
+# intermediates among them), and Trinity's 2,048-row rung takes 52.0 ms
+# where it takes 59.4 with nothing stated (v5e; PERF.md 6, PR 36).
+_PREFILL_VMEM_BYTES = 32 * 1024 * 1024
+
+
+def prefill_blocks(s: int, cols: int, cols_kv: int, itemsize: int) -> tuple:
+    """(block_q, block_k) of `flash_prefill` at S rows, where a grid
+    step holds `cols` query and `cols_kv` key columns: (512, 1024),
+    each halved until the q and out blocks (double-buffered) with the
+    f32 accumulator fit an eighth of `_PREFILL_VMEM_BYTES` and the k
+    and v blocks a sixteenth (Mosaic's own temporaries take most of
+    the rest).  A K block of 1,024 rows because the accumulator's
+    rescale and the running max and sum are paid a K block: at 32 / 4
+    heads x 128 and 2,048 rows, 2 heads a step, (512, 1024) takes
+    0.496 ms and (256, 1024) 0.609; 8 heads a step (256, 1024) 0.480,
+    (256, 512) 0.630, (512, 512) 0.557, (128, 512) 0.78 (v5e; PERF.md
+    6, PR 36).  `flash_blocks()` keys on S alone and stays the
+    trainer's."""
+    bq, bk = min(s, 512), min(s, 1024)
+    while bq > 128 and bq * cols * (4 * itemsize + 4) > _PREFILL_VMEM_BYTES // 8:
+        bq //= 2
+    while bk > 128 and 4 * bk * cols_kv * itemsize > _PREFILL_VMEM_BYTES // 16:
+        bk //= 2
+    return bq, bk
+
+
+def flash_prefill(q, k, v, num_heads: int, num_kv_heads: int,
+                  window: int = 0):
+    """Causal self-attention of a whole chunk, forward only: q
+    (B, S, H·D), k / v (B, S, Hkv·D), S a multiple of 128; `window` as
+    `_packed_fwd_kernel` has it (one that holds the chunk is dropped
+    here, so that layers with and without it share a lowering).  The
+    serving prefill's attention (`core.seq_layers.attend_cache`).
+
+    Where a head is whole lane tiles (D a multiple of 128, as every
+    serving configuration's) a grid step holds ONE head
+    (`_packed_forward`'s `heads_per_step`): more heads a step are a
+    faster kernel (K and V are read once a step; at 32 / 4 heads x 128,
+    2,048 rows, (512, 1024): 1 head 0.578 ms, 2 heads 0.496, 4 heads
+    0.450, 8 heads 0.433) and a slower start, 0.24 s a head and rung of
+    every process; one head keeps a Trinity start within 2.4 s of the
+    dense scores' (PERF.md 6, PR 36)."""
+    s, d = q.shape[1], q.shape[-1] // num_heads
+    per_step = 1 if d % 128 == 0 else 0
+    held, held_kv = (1, 1) if per_step else (num_heads, num_kv_heads)
+    bq, bk = prefill_blocks(s, held * d, held_kv * d, q.dtype.itemsize)
+    return singa_flash_prefill(
+        q, k, v, num_heads=num_heads, num_kv_heads=num_kv_heads,
+        window=0 if window >= s else int(window), block_q=bq, block_k=bk,
+        heads_per_step=per_step, interpret=not _on_tpu())
+
+
+# Jitted and named as `singa_paged_decode` is: the layers of one rung
+# share one trace and one Mosaic lowering, and the function's name is
+# the row that holds the kernel's time in a device trace.
+@functools.partial(jax.jit, static_argnames=(
+    "num_heads", "num_kv_heads", "window", "block_q", "block_k",
+    "heads_per_step", "interpret"))
+def singa_flash_prefill(q, k, v, *, num_heads, num_kv_heads, window,
+                        block_q, block_k, heads_per_step, interpret):
+    # the f32 scores are scaled, as the dense scores and the paged
+    # kernel's are: q is not rounded a second time (free on the v5e:
+    # 0.612 ms a layer either way at 32 / 4 heads x 128, 2,048 rows)
+    return _packed_forward(q, k, v, num_heads, True, block_q, block_k,
+                           interpret, num_kv_heads, window,
+                           fold_scale=False,
+                           heads_per_step=heads_per_step,
+                           vmem_limit_bytes=_PREFILL_VMEM_BYTES)[0]
 
 
 def flash_chunk(q, k, v, causal: bool,

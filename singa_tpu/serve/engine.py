@@ -51,6 +51,7 @@ from .. import obs
 from ..obs import perf
 from ..models.generate import (_sample, forward_cached, forward_paged,
                                init_cache, scatter_prefill)
+from ..ops.attention import singa_flash_prefill
 from ..utils import faults
 from ..utils.checkpoint import CheckpointManager
 from .kvcache import init_pools, slot_behind_row
@@ -370,6 +371,9 @@ class InferenceEngine:
         self._prev_params = None
         self._prev_step: Optional[int] = None
         self._compiled: Dict[Tuple[str, int, int], Any] = {}
+        # rungs of the cb prefill ladder whose program holds the flash
+        # forward kernel (read off the lowered program, `_compile_cb`)
+        self.cb_flash_widths: set = set()
         self._compile_lock = threading.Lock()
         # CompileWatch scope: per-engine, so a fleet member (or a
         # fresh autoscaled engine) warming up after its siblings never
@@ -846,8 +850,12 @@ class InferenceEngine:
                     row = jax.ShapeDtypeStruct(
                         (p_len // spec.cb_block_len
                          + int(self._per_slot_state),), jnp.int32)
-                    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-                        p_spec, pools, tok, plen, row, rng).compile()
+                    lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+                        p_spec, pools, tok, plen, row, rng)
+                    # what `attend_cache` took at this rung's shapes
+                    if singa_flash_prefill.__name__ in lowered.as_text():
+                        self.cb_flash_widths.add(p_len)
+                    compiled = lowered.compile()
                 elif which == "decode":
                     fn = self._build_cb_decode()
                     s = spec.cb_slots

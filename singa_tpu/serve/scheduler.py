@@ -661,7 +661,8 @@ class ContinuousScheduler:
                 req.ticket._fail(RuntimeError(f"prefill failed: {e}"))
                 return admitted
             admitted += 1
-            self.stats.observe_cb_prefill(req.plen, width)
+            self.stats.observe_cb_prefill(
+                req.plen, width, width in self.engine.cb_flash_widths)
             self._slot_req[slot] = req
             self._active[slot] = True
             self._ntoks[slot] = req.plen

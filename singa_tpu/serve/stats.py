@@ -34,7 +34,9 @@ handler and tests see the same semantics:
     layers' walk read: what of a context a window still reads);
   * `observe_cb_prefill` feeds `cb_prefill_fill_share` (prompt tokens /
     rows the prefill programs ran: how much of each prompt's rung of
-    the ladder, `ServeSpec.cb_prefill_widths`, was real).
+    the ladder, `ServeSpec.cb_prefill_widths`, was real) and
+    `cb_flash_prefills` (of `cb_prefills`, those whose rung's attention
+    is the flash forward kernel).
 
 `register_into(registry)` additionally exposes every snapshot field
 through an `obs.MetricsRegistry` pull-time collector (the /metrics
@@ -113,6 +115,8 @@ class ServeStats:
         # continuous batching (serve/scheduler.py)
         self.cb_steps = 0             # scheduler iterations run
         self.cb_prefills = 0          # prefills that ran to their end
+        self.cb_flash_prefills = 0    # of them, through a rung whose
+                                      # attention is the flash kernel
         self.cb_prefill_rows = 0      # prompt tokens they held
         self.cb_prefill_width_rows = 0  # rows their programs ran: each
                                         # prompt's rung of the ladder
@@ -244,11 +248,15 @@ class ServeStats:
         if self._hist_ttft is not None:
             self._hist_ttft.observe(seconds)
 
-    def observe_cb_prefill(self, plen: int, width: int) -> None:
+    def observe_cb_prefill(self, plen: int, width: int,
+                           flash: bool = False) -> None:
         """One prefill that ran to its end: `plen` prompt tokens
-        through the program compiled `width` rows wide."""
+        through the program compiled `width` rows wide; `flash`: that
+        program's attention is the flash forward kernel
+        (`InferenceEngine.cb_flash_widths`)."""
         with self._lock:
             self.cb_prefills += 1
+            self.cb_flash_prefills += bool(flash)
             self.cb_prefill_rows += int(plen)
             self.cb_prefill_width_rows += int(width)
 
@@ -435,7 +443,7 @@ class ServeStats:
                     "shed_best_effort", "rejected", "resumed",
                     "generated_tokens", "batches",
                     "batched_requests", "batch_slots", "cb_steps",
-                    "cb_prefills", "cb_prefill_rows",
+                    "cb_prefills", "cb_flash_prefills", "cb_prefill_rows",
                     "cb_prefill_width_rows", "cb_admit_steps",
                     "cb_live_block_steps", "cb_window_block_steps",
                     "cb_routed_layer_steps", "cb_routed_assignments",
@@ -524,6 +532,7 @@ class ServeStats:
                 "batch_slots": self.batch_slots,
                 "cb_steps": self.cb_steps,
                 "cb_prefills": self.cb_prefills,
+                "cb_flash_prefills": self.cb_flash_prefills,
                 "cb_prefill_rows": self.cb_prefill_rows,
                 "cb_prefill_width_rows": self.cb_prefill_width_rows,
                 "cb_admit_steps": self.cb_admit_steps,

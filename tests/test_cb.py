@@ -323,6 +323,8 @@ def test_fill_share_and_span_width_by_hand(ladder_run):
                                       9: 16, 15: 16, 16: 16}
     snap = ladder_run["snapshot"]
     assert snap["cb_prefills"] == 8
+    assert snap["cb_flash_prefills"] == 0      # rungs under a lane tile
+    assert ladder_run["engine"].cb_flash_widths == set()
     assert snap["cb_prefill_rows"] == sum(LADDER_PLENS) == 67
     assert snap["cb_prefill_width_rows"] == 4 + 4 + 8 + 8 + 8 + 16 * 3
     assert snap["cb_prefill_fill_share"] == round(67 / 80, 4)
@@ -356,6 +358,93 @@ def test_build_cb_prefill_without_a_width_is_the_caps_program(ladder_run):
         lowered(engine._build_cb_prefill(cap), cap)
     assert "jit_cb_prefill" in lowered(engine._build_cb_prefill(8), 8)
     assert ServeSpec().cb_prefill_widths == (ServeSpec().cb_prefill_len,)
+
+
+# -- the prefill's attention through the flash forward kernel ------------------
+# Heads of 128, as every serving configuration has; rungs (64, 128, 256):
+# `attend_cache` takes the kernel at whole lane tiles of rows (128 and
+# 256) and the dense scores at 64.  A window of 160 is dropped at the
+# 128 rung (it holds every key) and masks at the 256 rung.
+FLASH_PLENS = (40, 100, 128, 200, 255)
+FLASH_NEW = 5
+
+
+def _flash_model(kind):
+    from singa_tpu.data import discover_input_shapes
+    from singa_tpu.models.transformer import hybrid_lm
+    if kind == "gqa":
+        cfg = transformer_lm(vocab_size=VOCAB, num_layers=2, embed_dim=64,
+                             num_heads=4, head_dim=128, num_kv_heads=1,
+                             seq_len=256, batchsize=1)
+    else:
+        att = {"num_heads": 2, "num_kv_heads": 1, "head_dim": 128,
+               "qk_norm": True, "gate": True, "norm_epsilon": 1e-5,
+               "rope_theta": 1e4}
+        cfg = hybrid_lm(
+            vocab_size=VOCAB, embed_dim=64, seq_len=256, post_norm=True,
+            mixers=[{"attention": {**att, "rope": True, "window": 160}},
+                    {"attention": {**att, "rope": False}}],
+            ffns=[{"dense": {"hidden_dim": 128, "activation": "silu"}}] * 2)
+    net = build_net(cfg, "kTest",
+                    discover_input_shapes(cfg, force_synthetic=True))
+    return net, net.init_params(jax.random.PRNGKey(2))
+
+
+@pytest.fixture(scope="module", params=["windowed_gated_normed", "gqa"])
+def flash_run(request):
+    from singa_tpu.serve import engine as engine_mod
+    from singa_tpu.serve.scheduler import ContinuousScheduler
+    net, params = _flash_model(request.param)
+    spec = ServeSpec(buckets=((1, 256),), max_new_tokens=FLASH_NEW,
+                     temperature=0.0, eos_id=None, request_timeout_s=600.0,
+                     cb="on", cb_slots=2, cb_block_len=16, cb_prompt_cap=256)
+    got = {"net": net, "params": params}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "CB_PREFILL_FLOOR", 64)
+        got["widths"] = spec.cb_prefill_widths
+        engine = InferenceEngine(net, spec, params=params,
+                                 log_fn=lambda s: None)
+        engine.warmup()
+        rng = np.random.default_rng(1)
+        got["prompts"] = [rng.integers(0, VOCAB, n).astype(np.int32)
+                          for n in FLASH_PLENS]
+        sched = ContinuousScheduler(engine, log_fn=lambda s: None).start()
+        try:
+            tickets = [sched.submit(p, max_new=FLASH_NEW)
+                       for p in got["prompts"]]
+            got["served"] = [list(t.wait(timeout=600)["tokens"])
+                             for t in tickets]
+        finally:
+            sched.stop()
+    got["engine"] = engine
+    return got
+
+
+@pytest.mark.parametrize("i", range(len(FLASH_PLENS)),
+                         ids=[f"plen{p}" for p in FLASH_PLENS])
+def test_flash_prefill_tokens_equal_generates(flash_run, i):
+    """`generate()` prefills into a cache longer than its prompt: the
+    dense scores, whatever the length."""
+    prompt = flash_run["prompts"][i]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(generate(flash_run["net"], flash_run["params"],
+                                   prompt[None], FLASH_NEW))[0].tolist()
+    assert flash_run["served"][i] == want, f"plen={prompt.size}"
+
+
+def test_flash_prefills_are_counted_by_rung_and_exported(flash_run):
+    from singa_tpu.obs.metrics import MetricsRegistry
+    engine = flash_run["engine"]
+    assert flash_run["widths"] == (64, 128, 256)
+    assert engine.cb_flash_widths == {128, 256}
+    snap = engine.stats.snapshot()
+    assert snap["cb_prefills"] == len(FLASH_PLENS)
+    assert snap["cb_flash_prefills"] == sum(p > 64 for p in FLASH_PLENS)
+    reg = MetricsRegistry()
+    engine.stats.register_into(reg)
+    text = reg.render_prometheus()
+    assert "singa_serve_cb_flash_prefills_total 4" in text
+    assert "singa_serve_cb_prefills_total 5" in text
 
 
 @pytest.mark.parametrize("on_device", [False, True])

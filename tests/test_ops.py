@@ -368,3 +368,267 @@ def test_maxpool_equality_mask_vjp_ties_match_reference():
         lambda t: ops.max_pool2d(t.transpose(0, 3, 1, 2), 3, 2,
                                  "NCHW").transpose(0, 2, 3, 1), xr)
     np.testing.assert_allclose(vjp_em(cot)[0], vjp_ad(cot)[0], atol=1e-6)
+
+
+# -- the cb prefill's attention through the flash forward kernel ---------------
+# (`core.seq_layers.attend_cache`: a whole chunk at position 0 takes
+# `ops.attention.flash_prefill`; everything else the dense scores)
+
+def _chunk(heads, kv_heads, d, p, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((1, n, p, d)), dtype)
+                 for n in (heads, kv_heads, kv_heads))
+
+
+@pytest.fixture()
+def blocks_128(monkeypatch):
+    """(128, 128) blocks, so that a chunk of 512 rows is a 4 x 4 grid
+    with blocks to skip and blocks to mask."""
+    from singa_tpu.ops import attention
+    monkeypatch.setattr(attention, "prefill_blocks", lambda *a: (128, 128))
+
+
+# (heads, kv heads, head_dim, rows, window): groups of 1 / 4 / 8, two
+# head widths; no window; a window under the chunk (block (3, 0) is
+# skipped and (3, 1) masked in part at 200; 129 leaves one row of a
+# block); a window that holds every key
+PREFILL_CASES = [(4, 4, 16, 256, 0), (8, 2, 16, 512, 0), (8, 1, 32, 512, 0),
+                 (8, 2, 16, 512, 200), (8, 1, 32, 512, 129),
+                 (4, 4, 16, 512, 384), (8, 2, 16, 256, 256),
+                 (8, 2, 32, 256, 1000),
+                 # heads of 128: a grid step holds a kv head's group
+                 (2, 2, 128, 256, 0), (8, 2, 128, 256, 0),
+                 (8, 1, 128, 512, 200)]
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,p,window", PREFILL_CASES)
+def test_prefill_dispatch_equals_the_dense_scores(blocks_128, heads,
+                                                  kv_heads, d, p, window):
+    from singa_tpu.core import seq_layers
+    q, k, v = _chunk(heads, kv_heads, d, p, jnp.float32, seed=p + window)
+    text = str(jax.make_jaxpr(
+        lambda *a: seq_layers.attend_cache(*a, 0, None, window))(q, k, v))
+    assert "singa_flash_fwd" in text and "softmax" not in text
+    got = seq_layers.attend_cache(q, k, v, 0, None, window)
+    want = seq_layers._attend_dense(q, k, v, 0, None, window)
+    assert got.shape == want.shape == (1, p, heads * d)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+
+def test_prefill_dispatch_at_the_blocks_it_picks_itself():
+    from singa_tpu.core import seq_layers
+    q, k, v = _chunk(8, 2, 16, 1024, jnp.float32, seed=5)
+    for window in (0, 300):
+        got = seq_layers.attend_cache(q, k, v, 0, None, window)
+        want = seq_layers._attend_dense(q, k, v, 0, None, window)
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+
+def test_a_block_before_the_window_is_not_computed(blocks_128):
+    """Keys 0..127 hold infinities.  Under a window of 200 the queries
+    of rows 384.. start at key 185: a visited block (3, 0) would put
+    0 x inf = NaN into them through the value matmul; a skipped one
+    leaves them what they are without those keys."""
+    from singa_tpu.core import seq_layers
+    q, k, v = _chunk(8, 2, 16, 512, jnp.float32, seed=9)
+    bad_k, bad_v = k.at[:, :, :128].set(jnp.inf), v.at[:, :, :128].set(jnp.inf)
+    got = seq_layers.attend_cache(q, bad_k, bad_v, 0, None, 200)[:, 384:]
+    want = seq_layers._attend_dense(q, k, v, 0, None, 200)[:, 384:]
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,p,window", [
+    (4, 1, 128, 256, 0), (4, 2, 64, 256, 100), (4, 4, 128, 512, 0)])
+def test_prefill_dispatch_at_bf16_is_no_further_from_float32_than_dense(
+        heads, kv_heads, d, p, window):
+    """bf16 operands, f32 scores scaled in f32, f32 max / sum /
+    accumulator, probabilities rounded to bf16 for the value matmul: the
+    dense path's precision, so no wider a gap to float32 than its own."""
+    from singa_tpu.core import seq_layers
+    q, k, v = _chunk(heads, kv_heads, d, p, jnp.bfloat16, seed=1)
+    exact = seq_layers._attend_dense(
+        *(a.astype(jnp.float32) for a in (q, k, v)), 0, None, window)
+
+    def gaps(out):
+        gap = jnp.abs(out.astype(jnp.float32) - exact)
+        return float(jnp.max(gap)), float(jnp.mean(gap))
+    got = gaps(seq_layers.attend_cache(q, k, v, 0, None, window))
+    dense = gaps(seq_layers._attend_dense(q, k, v, 0, None, window))
+    assert got[0] <= dense[0] and got[1] <= dense[1], (got, dense)
+
+
+@pytest.mark.parametrize("real", [1, 255])
+@pytest.mark.parametrize("window", [0, 100])
+def test_a_right_padded_chunk_keeps_its_real_rows_and_stays_finite(real,
+                                                                   window):
+    """The cb prefill pads a prompt to its rung: the pad rows' K and V
+    (here 50 x the real rows' size) lie after every real query, so the
+    real rows are the unpadded run's; a pad query's row is finite."""
+    from singa_tpu.core import seq_layers
+    q, k, v = _chunk(8, 2, 16, 256, jnp.float32, seed=real)
+    pad = jnp.arange(256)[None, None, :, None] >= real
+    q, k, v = (jnp.where(pad, 50.0 * a, a) for a in (q, k, v))
+    got = seq_layers.attend_cache(q, k, v, 0, None, window)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    alone = seq_layers.attend_cache(
+        *(a[:, :, :real] for a in (q, k, v)), 0, None, window)
+    assert float(jnp.max(jnp.abs(got[:, :real] - alone))) < 2e-5
+
+
+def test_a_gradient_through_the_prefill_dispatch_is_the_dense_scores():
+    """The kernel is forward only; `CCALayer.apply` reaches the dispatch
+    from a training step."""
+    from singa_tpu.core import seq_layers
+    q, k, v = _chunk(4, 2, 16, 128, jnp.float32, seed=4)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.square(fn(*a, 0, None, 50)))
+    got = jax.grad(loss(seq_layers.attend_cache), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(seq_layers._attend_dense), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 1e-4
+
+
+def _digest(fn, *shapes):
+    import hashlib
+    return hashlib.sha256(
+        str(jax.make_jaxpr(fn)(*shapes)).encode()).hexdigest()[:16]
+
+
+def _bf16(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+
+# The programs that must not feel the prefill's kernel, by the digest of
+# their jaxpr's text at commit bc48cc4 (PR 35), before the dispatch and
+# the kernel's window existed: a later change to one of these is a
+# change to the trainer's kernels or to the dense path, and says so here.
+def _trainer_forward(q, k, v):
+    from singa_tpu.ops.attention import flash_attention_packed
+    return flash_attention_packed(q, k, v, 32, True, 256, 512, None, 8)
+
+
+UNTOUCHED = {
+    # the training cell: 32 / 8 heads x 128, S 4096, blocks (256, 512)
+    "trainer_forward": ("e759643c6c983a67", _trainer_forward,
+                        (_bf16(2, 4096, 4096),) + (_bf16(2, 4096, 1024),) * 2),
+    "trainer_backward": (
+        "237e666a2dc56db6",
+        jax.grad(lambda *a: _trainer_forward(*a).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)),
+        (_bf16(2, 4096, 4096),) + (_bf16(2, 4096, 1024),) * 2),
+    # `attend_cache`: a decode token of the static batcher, a chunk at a
+    # traced position, the left-padded batch's mask, a chunk under a
+    # lane tile, a chunk that is not the whole cache
+    "decode_token": (
+        "d678aa0dc867eb8b", lambda q, k, v, pos: _attend(q, k, v, pos),
+        (_bf16(2, 8, 1, 128),) + (_bf16(2, 2, 256, 128),) * 2
+        + (jax.ShapeDtypeStruct((), jnp.int32),)),
+    "traced_pos": (
+        "cab326db8b5925da", lambda q, k, v, pos: _attend(q, k, v, pos),
+        (_bf16(1, 8, 256, 128),) + (_bf16(1, 2, 256, 128),) * 2
+        + (jax.ShapeDtypeStruct((), jnp.int32),)),
+    "kmask": (
+        "68571f57c7b81b84", lambda q, k, v, m: _attend(q, k, v, 0, m),
+        (_bf16(1, 8, 256, 128),) + (_bf16(1, 2, 256, 128),) * 2
+        + (jax.ShapeDtypeStruct((1, 256), jnp.bool_),)),
+    "under_a_lane_tile": (
+        "f1bad6a8ca4454ad", lambda q, k, v: _attend(q, k, v, 0),
+        (_bf16(1, 8, 64, 128),) + (_bf16(1, 2, 64, 128),) * 2),
+    "part_of_the_cache": (
+        "0228ebadd6ea89eb", lambda q, k, v: _attend(q, k, v, 0),
+        (_bf16(1, 8, 128, 128),) + (_bf16(1, 2, 256, 128),) * 2),
+}
+
+
+def _attend(*args):
+    from singa_tpu.core.seq_layers import attend_cache
+    return attend_cache(*args)
+
+
+@pytest.mark.parametrize("name", sorted(UNTOUCHED))
+def test_programs_beside_the_prefill_trace_as_before_it(name):
+    want, fn, shapes = UNTOUCHED[name]
+    assert _digest(fn, *shapes) == want
+
+
+def test_a_window_that_holds_every_key_is_no_window():
+    """`window` is static: 0, and one of at least the chunk's length,
+    trace to the kernel as it was before it knew a window; a window
+    under the chunk adds its compares."""
+    import functools
+    from singa_tpu.ops.attention import _packed_forward
+    q, kv = _bf16(1, 512, 1024), _bf16(1, 512, 256)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(functools.partial(
+            _packed_forward, num_heads=8, causal=True, block_q=128,
+            block_k=128, interpret=True, num_kv_heads=2, **kw))(q, kv, kv))
+    plain = text()
+    assert text(window=0) == plain == text(window=512) == text(window=4096)
+    assert text(window=200).count("gt") > plain.count("gt")
+
+
+# (rows, query columns, key columns a grid step holds) at bf16 -> blocks
+@pytest.mark.parametrize("shape,want", [
+    ((2048, 128, 128), (512, 1024)),      # every serving cell: one head
+    ((512, 128, 128), (512, 512)),
+    ((256, 128, 128), (256, 256)),
+    ((2048, 4096, 1024), (128, 256)),     # 64 / 16 heads of 64, all held
+])
+def test_prefill_blocks_follow_the_geometry_not_the_length_alone(shape, want):
+    from singa_tpu.ops.attention import flash_blocks, prefill_blocks
+    assert prefill_blocks(*shape, 2) == want
+    assert flash_blocks(shape[0]) == ((512, 1024) if shape[0] >= 1024
+                                      else (512, 512))   # the trainer's
+
+
+@pytest.mark.parametrize("d,grid0,cols", [
+    (128, 2 * 8, 128),            # whole lane tiles: one head a step
+    (64, 2, 8 * 64)])             # under a lane tile: all of them
+def test_a_grid_step_holds_one_head_where_a_head_is_whole_lane_tiles(
+        d, grid0, cols):
+    """Batch 2, 8 / 2 heads: at heads of 128 the grid's first axis
+    walks (batch, head) and a q block is the head's columns; at 64 a
+    step holds every head, as the trainer's does."""
+    from singa_tpu.ops.attention import flash_prefill
+    q, kv = _bf16(2, 256, 8 * d), _bf16(2, 256, 2 * d)
+    text = str(jax.make_jaxpr(
+        lambda q, k, v: flash_prefill(q, k, v, 8, 2))(q, kv, kv))
+    assert f"grid=({grid0}, 1, 1)" in text
+    assert f"Blocked(block_size={cols})" in text
+
+
+@pytest.mark.parametrize("per_step", [1, 2, 4])
+def test_heads_a_step_are_the_same_attention(per_step):
+    """8 / 2 heads x 128: whatever run of a kv head's group a grid step
+    holds, out and lse are those of all heads a step."""
+    from singa_tpu.ops.attention import _packed_forward
+    rng = np.random.default_rng(per_step)
+    q = jnp.asarray(rng.standard_normal((2, 256, 8 * 128)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, 256, 2 * 128)), jnp.float32)
+            for _ in range(2))
+    args = (q, k, v, 8, True, 128, 128, True, 2, 100)
+    want = _packed_forward(*args)
+    got = _packed_forward(*args, heads_per_step=per_step)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        assert float(jnp.max(jnp.abs(g - w))) < 1e-6
+
+
+def test_layers_with_a_window_that_holds_the_rung_share_the_lowering():
+    """A window of at least the chunk is dropped before the jitted
+    call: Trinity's 12 windowed and 4 full layers are one trace and one
+    Mosaic lowering a rung."""
+    from singa_tpu.ops.attention import flash_prefill, singa_flash_prefill
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((1, 128, 256)), jnp.float32)
+    kv = jnp.asarray(rng.standard_normal((1, 128, 128)), jnp.float32)
+    flash_prefill(q, kv, kv, 2, 1, 0)
+    before = singa_flash_prefill._cache_size()
+    flash_prefill(q, kv, kv, 2, 1, 128)
+    flash_prefill(q, kv, kv, 2, 1, 4096)
+    assert singa_flash_prefill._cache_size() == before
+    flash_prefill(q, kv, kv, 2, 1, 64)
+    assert singa_flash_prefill._cache_size() == before + 1

@@ -151,6 +151,20 @@ def test_cca_layer_equals_the_reference(lm, i):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
+def test_cca_over_whole_lane_tiles_equals_the_reference(lm):
+    """128 rows at position 0: `attend_cache` takes the flash forward
+    kernel for kCCA as for kAttention (no layer kind tests anything of
+    its own); at 12 rows, above, the dense scores."""
+    net, params, made = lm
+    layer, full = net.layers["cca0"], net._resolve_params(params)
+    x = _x(3, 1, 128, CFG["hidden_size"])
+    text = str(jax.make_jaxpr(lambda x: layer.apply(full, [x], None))(x))
+    assert "singa_flash_fwd" in text
+    want = zaya1.cca(x, _layer_params(made, 0, "cca"), CFG)
+    np.testing.assert_allclose(layer.apply(full, [x], None), want,
+                               rtol=1e-4, atol=1e-5)
+
+
 @pytest.mark.parametrize("i", range(CFG["num_hidden_layers"]))
 def test_expert_layer_equals_the_reference(lm, i):
     """Its two outputs by name: the experts' and the router's state."""

@@ -1,5 +1,6 @@
-"""What `cb_live_block_share` and `cb_prefill_fill_share` read under one
-of the benchmark's serving cells (a builder's tool; the benchmark does
+"""What `cb_live_block_share`, `cb_prefill_fill_share` and the share of
+prefills through a flash-kernel rung read under one of the benchmark's
+serving cells (a builder's tool; the benchmark does
 not report the counters):
 
     python tools/live_block_share.py --workload serve-chat-r80 --seed 1 \\
@@ -25,10 +26,14 @@ def stop(self, *args, **kwargs):
     snap = self.stats.snapshot()
     print(json.dumps({"tool": "live_block_share", **{
         k: snap[k] for k in ("cb_live_block_share", "cb_prefill_fill_share",
-                             "cb_prefills", "cb_prefill_rows",
+                             "cb_prefills", "cb_flash_prefills",
+                             "cb_prefill_rows",
                              "cb_prefill_width_rows", "cb_slot_occupancy",
                              "cb_block_utilization", "cb_steps")},
-        "cb_decode_steps": self.stats.cb_decode_steps}), flush=True)
+        "cb_decode_steps": self.stats.cb_decode_steps,
+        "cb_flash_prefill_share": (
+            snap["cb_flash_prefills"] / snap["cb_prefills"]
+            if snap["cb_prefills"] else None)}), flush=True)
     return _stop(self, *args, **kwargs)
 
 
