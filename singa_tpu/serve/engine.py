@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -387,6 +388,15 @@ class InferenceEngine:
         # healthy — probes pass, requests complete — it is just SLOW,
         # which is exactly the failure mode hedging exists for.
         self.stall_s = 0.0
+        # when the loop thread's last wait on the device began and
+        # ended (`np.asarray` of a step's tokens, `int` of a prefill's
+        # first): the scheduler's stall account reads it after the
+        # call (serve/scheduler.py, `_lap_wait`)
+        self.cb_wait = (0.0, 0.0)
+        # when a decode step's tokens were last read, and when the
+        # steps still in flight were handed over: a step's period
+        self._cb_read_at = 0.0
+        self._cb_flying_at: deque = deque(maxlen=2)
         # reload-poll supervision (server._poll_loop): consecutive
         # unexpected poll deaths — /healthz degrades once the streak
         # crosses degraded_after, because an engine whose poller
@@ -899,14 +909,15 @@ class InferenceEngine:
         width = int(tokens.shape[1])
         compiled = self._compile_cb("prefill", width)
         t0 = time.perf_counter()
-        # host arrays as they are, for the call's own transfer
-        # (`_cb_decode_args`)
-        tok0, pools = compiled(params, pools,
-                               np.asarray(tokens, np.int32),
-                               np.int32(plen),
-                               np.asarray(row, np.int32),
-                               self._next_key())
-        tok0.copy_to_host_async()
+        with obs.span("engine.cb_prefill", width=width):
+            # host arrays as they are, for the call's own transfer
+            # (`_cb_decode_args`)
+            tok0, pools = compiled(params, pools,
+                                   np.asarray(tokens, np.int32),
+                                   np.int32(plen),
+                                   np.asarray(row, np.int32),
+                                   self._next_key())
+            tok0.copy_to_host_async()
         return (tok0, t0, width), pools
 
     def run_cb_prefill_rungs(self, params, pools):
@@ -930,9 +941,12 @@ class InferenceEngine:
     def fetch_cb_prefill(self, flying) -> int:
         """The first sampled token of a dispatched prefill (waits)."""
         tok0, t0, width = flying
-        tok0 = int(tok0)
-        perf.observe_step(self._cb_prefill_name(width),
-                          time.perf_counter() - t0)
+        t = time.perf_counter()
+        with obs.span("engine.cb_prefill_fetch"):
+            tok0 = int(tok0)
+        now = time.perf_counter()
+        self.cb_wait = (t, now)
+        perf.observe_step(self._cb_prefill_name(width), now - t0)
         perf.mark_serving_ready()      # first warm token (latch)
         return tok0
 
@@ -950,9 +964,13 @@ class InferenceEngine:
                 nxt, pools = compiled(params, pools, *args)
                 # on its way to the host as soon as the device has it
                 nxt.copy_to_host_async()
+            t = time.perf_counter()
             with obs.span("engine.fetch"):
                 nxt = self._cb_tokens(np.asarray(nxt))
-        perf.observe_step("cb_decode", time.perf_counter() - t0)
+        now = time.perf_counter()
+        self.cb_wait = (t, now)
+        self._cb_read_at = now
+        perf.observe_step("cb_decode", now - t0)
         return nxt, pools
 
     def _cb_decode_args(self, tokens, ntoks, tables):
@@ -990,6 +1008,7 @@ class InferenceEngine:
         before: then that step's tokens go in unread."""
         self._maybe_stall()
         compiled = self._compile_cb("decode")
+        self._cb_flying_at.append(time.perf_counter())
         with obs.span("engine.cb_decode"):
             with obs.span("engine.upload"):
                 args = self._cb_decode_args(tokens, ntoks, tables)
@@ -999,9 +1018,19 @@ class InferenceEngine:
                 return nxt, pools
 
     def fetch_cb_decode(self, flying) -> np.ndarray:
-        """The (S,) host tokens of a dispatched step (waits)."""
+        """The (S,) host tokens of a dispatched step (waits).  The
+        step's period goes to the step account `run_cb_decode` keeps:
+        from when the tokens before it were read, or from its own
+        hand-over if that was later, to now."""
+        t = time.perf_counter()
         with obs.span("engine.fetch"):
-            return self._cb_tokens(np.asarray(flying))
+            nxt = self._cb_tokens(np.asarray(flying))
+        now = time.perf_counter()
+        self.cb_wait = (t, now)
+        handed = self._cb_flying_at.popleft() if self._cb_flying_at else t
+        perf.observe_step("cb_decode", now - max(handed, self._cb_read_at))
+        self._cb_read_at = now
+        return nxt
 
     def _compile(self, mode: str, batch: int, prompt_len: int):
         key = (mode, batch, prompt_len)

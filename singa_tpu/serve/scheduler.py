@@ -42,6 +42,17 @@ MicroBatcher, with the block pool as the second bounded resource.
 Fault sites: `serve.admit` (shed one submission), `serve.batch` (fail
 one decode step — its active requests fail, the loop and server stay
 up, `consecutive_batch_failures` moves toward the degraded verdict).
+
+The stall account.  The loop thread's time is a row of LAPS, each
+closed by one clock read where the next begins: `rest` (expire, the
+admission's bookkeeping, the walk's counts, the table, the account of
+the step before, the loop's lock), per admitted request `prefill` (its
+hand-over) and `first_token` (the wait for it), the decode step's
+`handover`, its `wait` (the host waiting on the device or the runtime
+for the tokens) and its `emit` loop.  A lap over `STALL_S` is a stall:
+counted (`ServeStats.observe_cb_stall`), and told with the step's
+other laps in one `serve.cb_stall` event.  Always on: a trace of a
+window's last seconds seldom holds the stall that cost the run.
 """
 
 from __future__ import annotations
@@ -64,6 +75,20 @@ from .engine import InferenceEngine
 from .kvcache import PagedKVCache
 from .stats import ServeStats
 from .tenancy import TenantRegistry
+
+
+#: a lap of the loop longer than this is a stall: clear of every
+#: legitimate lap (the longest is one prefill at a 2,048-row rung, some
+#: 55 ms on a v5e; several admissions are several laps; nothing
+#: compiles after warmup)
+STALL_S = 0.5
+#: the laps in which the host waits on the device or the runtime
+WAIT_LAPS = ("wait", "first_token")
+#: what a collect's time before its wait belongs to, by its `why`: 0 =
+#: behind the next step's hand-over, 1 = a slot fell free and nothing
+#: took it, 2 = inside an admission, behind the prefill's hand-over
+COLLECT_BEHIND, COLLECT_DRAIN, COLLECT_ADMIT = 0, 1, 2
+_LAP_BEFORE_COLLECT = ("handover", "rest", "prefill")
 
 
 class StreamTicket:
@@ -236,6 +261,14 @@ class ContinuousScheduler:
         # yet: (its tokens on the device, who held each slot then).
         # Only a step in which every slot was busy is left so
         self._flying: Optional[tuple] = None
+        # the stall account (module docstring): the laps since the step
+        # before was accounted, when the last one closed, whether one
+        # of them was long; and how this step's decode went out
+        self._laps: List[tuple] = []
+        self._lap_at = 0.0
+        self._stalled = False
+        self._step_ahead = 0
+        self._step_drained = 0
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ContinuousScheduler":
@@ -472,24 +505,77 @@ class ContinuousScheduler:
 
     # -- the loop -----------------------------------------------------------
     def _loop(self) -> None:
+        self._lap_at = time.perf_counter()
         while True:
             with self._cv:
+                idle = False
                 while (not self._pending and not self._active.any()
                        and self._flying is None and not self._stop):
-                    self._cv.wait(0.05)
+                    idle = True
+                    # the profiler's alone: an idle server's twenty
+                    # waits a second would fill a session's tracer
+                    with obs.device_span("scheduler.wait", {}):
+                        self._cv.wait(0.05)
                 if self._stop:
                     return
+            if idle:
+                self._lap_at = time.perf_counter()   # no lap of a step
             self._iterate()
+
+    def _lap(self, name: str) -> None:
+        """The loop's time since the last lap closed is `name`'s."""
+        now = time.perf_counter()
+        took = now - self._lap_at
+        self._lap_at = now
+        self._laps.append((name, took))
+        if took > STALL_S:
+            self._stalled = True
+
+    def _lap_wait(self, before: str, wait: str) -> None:
+        """After an engine call that ended in a wait on the device: up
+        to where the wait began the time is `before`'s, the wait itself
+        `wait`'s.  The engine stamped both ends (`cb_wait`); where it
+        did not (a stand-in for the call), the whole is the wait's."""
+        t, now = self.engine.cb_wait
+        if t < self._lap_at:
+            return self._lap(wait)
+        lead, waited = t - self._lap_at, now - t
+        self._lap_at = now
+        self._laps += ((before, lead), (wait, waited))
+        if lead > STALL_S or waited > STALL_S:
+            self._stalled = True
+
+    def _account_laps(self) -> None:
+        """Close the step's laps; tell of each that was long."""
+        if self._stalled:
+            self._stalled = False
+            total: Dict[str, float] = {}
+            for name, took in self._laps:
+                total[name] = total.get(name, 0.0) + took
+            for name, took in self._laps:
+                if took > STALL_S:
+                    self.stats.observe_cb_stall(took, name in WAIT_LAPS)
+                    obs.emit_event(
+                        "serve.cb_stall", lap=name, seconds=round(took, 4),
+                        laps={k: round(v, 4) for k, v in total.items()},
+                        active=int(self._active.sum()),
+                        pending=len(self._pending))
+        self._laps.clear()
 
     def _iterate(self) -> None:
         """One scheduler step: expire, admit, decode, account.  The
-        step, its admissions and its decode are spans
-        (docs/OBSERVABILITY.md, the per-token path): what the host does
-        between two decode programs is read off a device trace by
-        these names."""
-        attrs = ({"active": int(self._active.sum()),
-                  "pending": len(self._pending)}
+        step, its admissions, its decode, each read of a step in
+        flight and each emit loop are spans (docs/OBSERVABILITY.md, the
+        per-token path): what the host does between two decode programs
+        is read off a device trace by these names.  While something
+        records, the step carries the stall account's running values,
+        so that a trace of a run's last seconds holds the whole run's."""
+        st = self.stats
+        attrs = ({"stalls": st.cb_stalls,
+                  "stall_ms": int(1e3 * st.cb_stall_seconds),
+                  "stall_wait_ms": int(1e3 * st.cb_stall_wait_seconds)}
                  if obs.tracing() else {})
+        self._step_ahead = self._step_drained = 0
         with obs.span("scheduler.step", **attrs):
             # ONE params read covers this step's prefills AND decode —
             # the per-step no-tear guarantee (see module docstring)
@@ -500,14 +586,13 @@ class ContinuousScheduler:
                 # an unlocked peek: a submit that lands just after it
                 # is admitted by the next step, as it always was
                 if self._pending and not self._active.all():
-                    with obs.span("scheduler.admit_pending") as sp:
+                    with obs.span("scheduler.admit_pending"):
                         admitted = self._admit_pending(params, step_no)
-                        sp.set(admitted=admitted)     # a session's tracer only
                     if admitted:
                         self.stats.count("cb_admit_steps")
                 if self._flying is not None and not self._active.all():
                     # a slot fell free and nothing took it
-                    self._collect(step_no)
+                    self._collect(step_no, COLLECT_DRAIN)
                 active = int(self._active.sum())
                 walked = {"table": 0, "window": 0}
                 if active:
@@ -520,10 +605,12 @@ class ContinuousScheduler:
             except Exception as e:  # noqa: BLE001 — fail step, keep serving
                 self._fail_step(e)
                 return
+            self._account_laps()
             if self.kv is not None:
                 self.stats.observe_cb_step(
                     int(self._active.sum()), self.kv.blocks_in_use,
-                    walked["table"], walked["window"])
+                    walked["table"], walked["window"],
+                    ahead=self._step_ahead, drained=self._step_drained)
                 self.stats.gauge("cb_blocks_in_use", self.kv.blocks_in_use)
 
     def _expire_pending(self, now: float) -> None:
@@ -631,6 +718,7 @@ class ContinuousScheduler:
             width = spec.cb_prefill_width(req.plen)
             toks = np.zeros((1, width), np.int32)
             toks[0, :req.plen] = req.tokens
+            self._lap("rest")
             try:
                 with obs.span("scheduler.prefill", corr=req.corr,
                               trace=trace_id, parent=parent,
@@ -647,8 +735,9 @@ class ContinuousScheduler:
                         first, self.kv.pools = \
                             self.engine.dispatch_cb_prefill(
                                 params, self.kv.pools, toks, req.plen, row)
-                        self._collect(step_no)
+                        self._collect(step_no, COLLECT_ADMIT)
                         tok0 = self.engine.fetch_cb_prefill(first)
+                    self._lap_wait("prefill", "first_token")
             except Exception as e:  # noqa: BLE001 — fail req, keep going
                 # the slot is not in _slot_req yet: clean it here so
                 # the blocks cannot leak, fail only this request
@@ -674,24 +763,32 @@ class ContinuousScheduler:
             self._maybe_retire(slot, tok0, step_no, now)
 
     def _decode_step(self, params, step_no: int, active: int) -> None:
-        with obs.span("scheduler.decode", active=active):
+        full = active == len(self._active)
+        # ahead: it goes to the device before the step before it is read
+        self._step_ahead = int(full and self._flying is not None)
+        with obs.span("scheduler.decode", active=active,
+                      ahead=self._step_ahead):
             faults.maybe_fault("serve.batch")
-            if active == len(self._active):
+            if full:
                 self._decode_ahead(params, step_no)
                 return
+            tables = self.kv.table_array()
+            self._lap("rest")
             nxt, self.kv.pools = self.engine.run_cb_decode(
-                params, self.kv.pools, self._last, self._ntoks,
-                self.kv.table_array())
+                params, self.kv.pools, self._last, self._ntoks, tables)
+            self._lap_wait("handover", "wait")
             now = time.monotonic()
-            for slot in np.flatnonzero(self._active):
-                slot = int(slot)
-                self._ntoks[slot] += 1
-                tok = int(nxt[slot])
-                self._last[slot] = tok
-                req = self._slot_req[slot]
-                req.produced.append(tok)
-                req.ticket._emit(tok)
-                self._maybe_retire(slot, tok, step_no, now)
+            with obs.span("scheduler.emit", slots=active):
+                for slot in np.flatnonzero(self._active):
+                    slot = int(slot)
+                    self._ntoks[slot] += 1
+                    tok = int(nxt[slot])
+                    self._last[slot] = tok
+                    req = self._slot_req[slot]
+                    req.produced.append(tok)
+                    req.ticket._emit(tok)
+                    self._maybe_retire(slot, tok, step_no, now)
+            self._lap("emit")
 
     def _decode_ahead(self, params, step_no: int) -> None:
         """Every slot is busy: nothing can be admitted before one
@@ -704,30 +801,41 @@ class ContinuousScheduler:
         overwrites."""
         before = self._flying
         # copies: the host's arrays change before the device has run
+        tokens = self._last.copy() if before is None else before[0]
+        ntoks, tables = self._ntoks.copy(), self.kv.table_array()
+        self._lap("rest")
         nxt, self.kv.pools = self.engine.dispatch_cb_decode(
-            params, self.kv.pools,
-            self._last.copy() if before is None else before[0],
-            self._ntoks.copy(), self.kv.table_array())
+            params, self.kv.pools, tokens, ntoks, tables)
         self._ntoks += 1
         self._flying = (nxt, list(self._slot_req))
         if before is not None:
-            self._collect(step_no, before)
+            self._collect(step_no, COLLECT_BEHIND, before)
+        else:
+            self._lap("handover")
 
-    def _collect(self, step_no: int, flying: Optional[tuple] = None) -> None:
+    def _collect(self, step_no: int, why: int,
+                 flying: Optional[tuple] = None) -> None:
         """Read a dispatched step's tokens and hand them out: to the
-        requests that held their slots then and still do."""
-        if flying is None:
-            flying, self._flying = self._flying, None
-        nxt = self.engine.fetch_cb_decode(flying[0])
-        now = time.monotonic()
-        for slot, req in enumerate(flying[1]):
-            if req is not self._slot_req[slot]:
-                continue               # retired since: a token too many
-            tok = int(nxt[slot])
-            self._last[slot] = tok
-            req.produced.append(tok)
-            req.ticket._emit(tok)
-            self._maybe_retire(slot, tok, step_no, now)
+        requests that held their slots then and still do.  `why`: one
+        of the `COLLECT_` three; but for the first the step in flight
+        is taken down with none handed over behind it."""
+        with obs.span("scheduler.collect", why=why):
+            if flying is None:
+                flying, self._flying = self._flying, None
+                self._step_drained += 1
+            nxt = self.engine.fetch_cb_decode(flying[0])
+            self._lap_wait(_LAP_BEFORE_COLLECT[why], "wait")
+            now = time.monotonic()
+            with obs.span("scheduler.emit", slots=len(flying[1])):
+                for slot, req in enumerate(flying[1]):
+                    if req is not self._slot_req[slot]:
+                        continue       # retired since: a token too many
+                    tok = int(nxt[slot])
+                    self._last[slot] = tok
+                    req.produced.append(tok)
+                    req.ticket._emit(tok)
+                    self._maybe_retire(slot, tok, step_no, now)
+            self._lap("emit")
 
     def _maybe_retire(self, slot: int, tok: int, step_no: int,
                       now: float) -> None:
@@ -786,6 +894,7 @@ class ContinuousScheduler:
         story)."""
         n = int(self._active.sum())
         self._flying = None
+        self._account_laps()
         self.stats.count("failed", n)
         self.stats.observe_batch_failure()
         self.log(f"warning: cb decode step failed "

@@ -32,6 +32,16 @@ handler and tests see the same semantics:
     touch this traffic keeps live), and `cb_window_block_share` (ring
     blocks the windowed layers' walk read / table blocks the growing
     layers' walk read: what of a context a window still reads);
+  * `observe_cb_step` also counts how a step went to the device:
+    `cb_steps_ahead` (decode steps handed over before the step before
+    them was read) and `cb_collects_drained` (steps in flight read
+    with none handed over behind them: a slot fell free, or an
+    admission took it);
+  * `observe_cb_stall` is the loop thread's stall account: a LAP of a
+    scheduler step (`serve/scheduler.py`, `STALL_S`) that took longer
+    than any legitimate one adds to `cb_stalls`, its seconds to
+    `cb_stall_seconds`, and to `cb_stall_wait_seconds` too where the
+    lap was a wait on the device or the runtime;
   * `observe_cb_prefill` feeds `cb_prefill_fill_share` (prompt tokens /
     rows the prefill programs ran: how much of each prompt's rung of
     the ladder, `ServeSpec.cb_prefill_widths`, was real) and
@@ -123,6 +133,15 @@ class ServeStats:
         self.cb_admit_steps = 0       # iterations that admitted >= 1:
                                       # each held every slot for its
                                       # prefills before decoding
+        self.cb_steps_ahead = 0       # decode steps handed over before
+                                      # the step before them was read
+        self.cb_collects_drained = 0  # steps in flight read with none
+                                      # handed over behind them
+        # the loop thread's stall account (observe_cb_stall)
+        self.cb_stalls = 0            # laps of a step over STALL_S
+        self.cb_stall_seconds = 0.0   # their seconds
+        self.cb_stall_wait_seconds = 0.0  # of them, inside waits on
+                                          # the device or the runtime
         self.cb_active_slot_steps = 0  # sum of active slots per step
         self.cb_block_use_steps = 0    # sum of blocks in use per step
         self.cb_decode_steps = 0       # iterations that ran a decode
@@ -262,12 +281,18 @@ class ServeStats:
 
     def observe_cb_step(self, active_slots: int, blocks_in_use: int,
                         live_blocks: int = 0,
-                        window_blocks: int = 0) -> None:
+                        window_blocks: int = 0, ahead: int = 0,
+                        drained: int = 0) -> None:
         """`live_blocks`: table blocks the step's decode program walked
         (0 for a step that ran none); `window_blocks`: ring blocks its
-        windowed layers walked (`PagedKVCache.walked_blocks`)."""
+        windowed layers walked (`PagedKVCache.walked_blocks`);
+        `ahead`: 1 where its decode step went to the device before the
+        one before it was read; `drained`: steps in flight it read with
+        none handed over behind them."""
         with self._lock:
             self.cb_steps += 1
+            self.cb_steps_ahead += ahead
+            self.cb_collects_drained += drained
             self.cb_active_slot_steps += int(active_slots)
             self.cb_block_use_steps += int(blocks_in_use)
             if live_blocks:
@@ -275,6 +300,16 @@ class ServeStats:
                 self.cb_live_block_steps += int(live_blocks)
                 self.cb_window_block_steps += int(window_blocks)
             self._cb_t.append((time.monotonic(), int(active_slots)))
+
+    def observe_cb_stall(self, seconds: float, waited: bool) -> None:
+        """One lap of a scheduler step that took `seconds`, longer than
+        any legitimate lap; `waited`: the lap was a wait on the device
+        or the runtime, not the host's own work."""
+        with self._lock:
+            self.cb_stalls += 1
+            self.cb_stall_seconds += float(seconds)
+            if waited:
+                self.cb_stall_wait_seconds += float(seconds)
 
     def observe_routing(self, assignments: int, experts_touched: int,
                         layers: int, max_load: int = 0) -> None:
@@ -445,6 +480,9 @@ class ServeStats:
                     "batched_requests", "batch_slots", "cb_steps",
                     "cb_prefills", "cb_flash_prefills", "cb_prefill_rows",
                     "cb_prefill_width_rows", "cb_admit_steps",
+                    "cb_steps_ahead", "cb_collects_drained",
+                    "cb_stalls", "cb_stall_seconds",
+                    "cb_stall_wait_seconds",
                     "cb_live_block_steps", "cb_window_block_steps",
                     "cb_routed_layer_steps", "cb_routed_assignments",
                     "cb_routed_experts_touched", "cb_routed_max_load",
@@ -536,6 +574,12 @@ class ServeStats:
                 "cb_prefill_rows": self.cb_prefill_rows,
                 "cb_prefill_width_rows": self.cb_prefill_width_rows,
                 "cb_admit_steps": self.cb_admit_steps,
+                "cb_steps_ahead": self.cb_steps_ahead,
+                "cb_collects_drained": self.cb_collects_drained,
+                "cb_stalls": self.cb_stalls,
+                "cb_stall_seconds": round(self.cb_stall_seconds, 6),
+                "cb_stall_wait_seconds":
+                    round(self.cb_stall_wait_seconds, 6),
                 "cb_blocks_in_use": self.cb_blocks_in_use,
                 "cb_blocks_total": self.cb_blocks_total,
                 "cb_slot_state_bytes": self.cb_slot_state_bytes,

@@ -219,12 +219,15 @@ def test_one_step_is_spanned_from_inside(cb_served):
     admitting = [s for s in steps if "scheduler.admit_pending" in names(s)]
     assert len(admitting) == 1 and len(steps) >= 5
     step = admitting[0]
-    assert step["args"]["active"] == 0 and step["args"]["pending"] == 1
+    # the stall account's running values, and nothing no metric reads
+    assert {k: step["args"][k] for k in step["args"]
+            if k not in ("trace", "span_id", "parent_id", "corr")} == {
+        "stalls": 0, "stall_ms": 0, "stall_wait_ms": 0}
     assert names(step) == ["scheduler.admit_pending", "scheduler.decode"]
     one = lambda e, n: [k for k in kids[e["args"]["span_id"]]  # noqa: E731
                         if k["name"] == n][0]
     admit = one(step, "scheduler.admit_pending")
-    assert admit["args"]["admitted"] == 1
+    assert "admitted" not in admit["args"]     # nothing read it: gone
     # the prefill is anchored in the REQUEST's trace (its link), so it
     # is the request's child, and lies inside the admission by time
     prefill = [e for e in events if e["name"] == "scheduler.prefill"][0]
@@ -234,10 +237,13 @@ def test_one_step_is_spanned_from_inside(cb_served):
     assert prefill["args"]["plen"] == 5 and prefill["args"]["slot"] == 0
     assert admit["ts"] <= prefill["ts"] and \
         prefill["ts"] + prefill["dur"] <= admit["ts"] + admit["dur"] + 1
-    assert names(prefill) == []          # one program, one span
+    # the hand-over, then the wait for the first token
+    assert names(prefill) == ["engine.cb_prefill", "engine.cb_prefill_fetch"]
+    assert one(prefill, "engine.cb_prefill")["args"]["width"] == SEQ
     decode = one(step, "scheduler.decode")
-    assert decode["args"]["active"] == 1
-    assert names(decode) == ["engine.cb_decode"]
+    assert decode["args"]["active"] == 1 and decode["args"]["ahead"] == 0
+    assert names(decode) == ["engine.cb_decode", "scheduler.emit"]
+    assert one(decode, "scheduler.emit")["args"]["slots"] == 1
     assert names(one(decode, "engine.cb_decode")) == [
         "engine.dispatch", "engine.fetch", "engine.upload"]
     # a step that admits nothing opens no admission span
@@ -248,6 +254,7 @@ def test_one_step_is_spanned_from_inside(cb_served):
         ("scheduler.", "engine."))} == {
         "scheduler.admit", "scheduler.queue", "scheduler.step",
         "scheduler.admit_pending", "scheduler.prefill", "scheduler.decode",
+        "scheduler.emit", "engine.cb_prefill", "engine.cb_prefill_fetch",
         "engine.cb_decode", "engine.upload", "engine.dispatch",
         "engine.fetch"}
     # the request's wait, recorded when it ended, in the request's trace
@@ -274,11 +281,15 @@ def test_tokens_identical_with_spans_on_off_and_profiled(cb_served,
     assert events["scheduler.prefill"][0]["plen"] == 5
     assert events["scheduler.prefill"][0]["queue_ms"] >= 0
     assert len(events["engine.cb_decode"]) == 5
-    assert events["scheduler.step"][0]["pending"] == 1
-    assert events["scheduler.step"][0]["active"] == 0
+    assert events["scheduler.step"][0] == {
+        "stalls": 0, "stall_ms": 0, "stall_wait_ms": 0}
     assert [e["active"] for e in events["scheduler.decode"]] == [1] * 5
+    assert [e["ahead"] for e in events["scheduler.decode"]] == [0] * 5
+    assert [e["slots"] for e in events["scheduler.emit"]] == [1] * 5
+    assert events["engine.cb_prefill"] == [{"width": SEQ}]
     for name in ("scheduler.admit_pending", "engine.upload",
-                 "engine.dispatch", "engine.fetch"):
+                 "engine.dispatch", "engine.fetch",
+                 "engine.cb_prefill_fetch"):
         assert name in events, name
 
 
@@ -323,8 +334,8 @@ def test_cb_programs_are_named(cb_served, which):
 
 def test_step_attributes_cost_nothing_while_nothing_records(cb_served,
                                                             monkeypatch):
-    """`active` / `pending` of `scheduler.step` are a reduction over the
-    slots: computed only under a session or a running profiler."""
+    """The stall account's values on `scheduler.step` are read and
+    scaled only under a session or a running profiler."""
     engine, server = cb_served
     seen = []
     real = obs.span
@@ -341,7 +352,8 @@ def test_step_attributes_cost_nothing_while_nothing_records(cb_served,
     with obs.session(obs.ObsSpec()):
         server.generate(PROMPT)
     steps = [kw for name, kw in seen if name == "scheduler.step"]
-    assert steps and all(set(kw) == {"active", "pending"} for kw in steps)
+    assert steps and all(set(kw) == {"stalls", "stall_ms", "stall_wait_ms"}
+                         for kw in steps)
 
 
 # -- what an operator lacks at /metrics ---------------------------------------
