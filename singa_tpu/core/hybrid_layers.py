@@ -48,7 +48,7 @@ from ..ops.attention import NEG_INF
 from ..ops.paged_attention import paged_decode_attention
 from .layers import Layer, LayerError, ParamSpec, register_layer
 from .seq_layers import (AttentionLayer, _declare_with_default, attend_cache,
-                         write_token)
+                         paged_rows, write_token)
 
 
 def _dot(x, w):
@@ -397,7 +397,7 @@ class MLALayer(Layer):
         pool = pool.at[bidx].set(blocks)
         o_lat = paged_decode_attention(
             self._absorb_query(params, q[:, 0], self.pool_row),
-            pool[:, None], None, tables, ntoks, value_dim=self.rank,
+            pool[:, None], tables, ntoks, value_dim=self.rank,
             scale=1.0 / math.sqrt(self.nope + self.rope))
         o = self._expand_output(params, o_lat)
         return _dot(o, params[self.wo]).astype(x.dtype)[None], {"c": pool}
@@ -606,13 +606,14 @@ class CCALayer(Layer):
 
     def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
                   dtype):
-        """K and V in paged blocks, (num_blocks, Hkv, block_len, D) a
-        side, and the tails of each of `num_slots` slots."""
+        """K and V in paged blocks of one pool, (num_blocks, 2 * Hkv,
+        block_len, D) as kAttention's, and the tails of each of
+        `num_slots` slots."""
         if num_slots < 1:
             raise ValueError(f"{self.name}: a tail per slot needs "
                              f"num_slots >= 1")
-        shape = (num_blocks, self.kv_heads, block_len, self.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+        return {"kv": jnp.zeros((num_blocks, 2 * self.kv_heads, block_len,
+                                 self.head_dim), dtype),
                 **self._tails(num_slots, dtype)}
 
     def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
@@ -633,21 +634,20 @@ class CCALayer(Layer):
 
     def apply_paged(self, params, x, entry, tables, ntoks):
         """x (1, S, E): slot s's token from slot s's tails, its K and V
-        rows written at position ntoks[s] (whole blocks, as kAttention
-        writes them), then the paged kernel over the slot's live
-        blocks.  A slot that is not in use keeps its tails."""
-        s, bl = x.shape[1], entry["k"].shape[2]
+        rows written at position ntoks[s] (one whole block a slot, as
+        kAttention writes them), then the paged kernel over the slot's
+        live blocks.  A slot that is not in use keeps its tails."""
+        s, bl = x.shape[1], entry["kv"].shape[2]
         q, k, v, tails = self._qkv(params, x[0][:, None, :], entry, None,
                                    ntoks[:, None])
         busy = (ntoks > 0)[:, None, None]
         tails = {n: jnp.where(busy, a, entry[n]) for n, a in tails.items()}
         bidx = tables[jnp.arange(s), ntoks // bl]
-        k_pool = write_token(entry["k"], bidx, ntoks % bl, k[:, :, 0])
-        v_pool = write_token(entry["v"], bidx, ntoks % bl, v[:, :, 0])
-        o = paged_decode_attention(q[:, :, 0], k_pool, v_pool, tables, ntoks)
+        pool = write_token(entry["kv"], bidx, ntoks % bl,
+                           paged_rows(k, v)[:, :, 0])
+        o = paged_decode_attention(q[:, :, 0], pool, tables, ntoks)
         out = _dot(o.reshape(s, -1).astype(x.dtype), params[self.wo])
-        return (out.astype(x.dtype)[None],
-                {"k": k_pool, "v": v_pool, **tails})
+        return out.astype(x.dtype)[None], {"kv": pool, **tails}
 
     @staticmethod
     def scatter_prefill(pool, cache, table_row, slot):

@@ -154,20 +154,28 @@ DECODE_CTX = Context(batch={}, train=False, rng=None, layer_index=0,
 
 
 def write_token(pool, bidx, off, new):
-    """Row `off[s]` of pool block `bidx[s]` becomes `new[s]` (Hkv, D),
-    for every slot s; inactive slots all write the null block.
+    """Row `off[s]` of pool block `bidx[s]` becomes `new[s]` (heads, D),
+    for every slot s; inactive slots all write the null block.  A K/V
+    pool's `new` is the token's key heads and then its value heads
+    (`paged_rows`): one patched block, one scatter, for both.
 
     Whole blocks are read, patched and scattered back, so the scatter's
-    window is the pool's trailing (Hkv, block_len, D) dims.  The direct
-    form, `pool.at[bidx, :, off].set(new)`, has the window (Hkv, D)
-    around the scattered block_len axis; XLA:TPU gives that scatter's
-    operand another layout than the pool arrives and leaves in, and
-    copies the WHOLE pool there and back, for each side of each layer
-    of every decode step."""
+    window is the pool's trailing (heads, block_len, D) dims.  The
+    direct form, `pool.at[bidx, :, off].set(new)`, has the window
+    (heads, D) around the scattered block_len axis; XLA:TPU gives that
+    scatter's operand another layout than the pool arrives and leaves
+    in, and copies the WHOLE pool there and back, for each layer of
+    every decode step."""
     rows = jnp.arange(pool.shape[2])[None, None, :, None]
     blocks = jnp.where(rows == off[:, None, None, None],
                        new.astype(pool.dtype)[:, :, None, :], pool[bidx])
     return pool.at[bidx].set(blocks)
+
+
+def paged_rows(k, v):
+    """Keys and values (..., Hkv, n, D) as a K/V pool holds them: the
+    key heads and then the value heads, (..., 2 * Hkv, n, D)."""
+    return jnp.concatenate([k, v], axis=-3)
 
 
 def attend_cache(q, k_cache, v_cache, pos, kmask=None, window=0):
@@ -460,23 +468,23 @@ class AttentionLayer(Layer):
 
     def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
                   dtype):
-        """Paged K/V: (num_blocks, Hkv, block_len, D) per side."""
-        shape = (num_blocks, self.kv_heads, block_len, self.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        """Paged K/V, one pool: (num_blocks, 2 * Hkv, block_len, D), a
+        block's key heads and then its value heads, so that the paged
+        kernel brings a block in one copy."""
+        return {"kv": jnp.zeros((num_blocks, 2 * self.kv_heads, block_len,
+                                 self.head_dim), dtype)}
 
     @staticmethod
     def scatter_prefill(pool, cache, table_row, slot=None):
-        """A batch-1 contiguous prefill cache ((1, Hkv, P, D), P a
-        block_len multiple) into the pool blocks `table_row` names."""
-        bl = pool["k"].shape[2]
-        hkv, p, d = cache["k"].shape[1:]
-        nb = p // bl
-        kb = cache["k"][0].transpose(1, 0, 2).reshape(
-            nb, bl, hkv, d).transpose(0, 2, 1, 3)   # (nb, Hkv, bl, D)
-        vb = cache["v"][0].transpose(1, 0, 2).reshape(
-            nb, bl, hkv, d).transpose(0, 2, 1, 3)
-        return {"k": pool["k"].at[table_row].set(kb.astype(pool["k"].dtype)),
-                "v": pool["v"].at[table_row].set(vb.astype(pool["v"].dtype))}
+        """A batch-1 contiguous prefill cache ((1, Hkv, P, D) a side, P
+        a block_len multiple) into the pool blocks `table_row` names:
+        one scatter of whole blocks."""
+        kv = pool["kv"]
+        bl = kv.shape[2]
+        rows = paged_rows(cache["k"][0], cache["v"][0])     # (2 Hkv, P, D)
+        heads, p, d = rows.shape
+        blocks = rows.reshape(heads, p // bl, bl, d).transpose(1, 0, 2, 3)
+        return {"kv": kv.at[table_row].set(blocks.astype(kv.dtype))}
 
     # a windowed layer's serving state: a ring of blocks per slot.  It
     # needs the last `window` positions of a slot and no more, so slot
@@ -489,8 +497,8 @@ class AttentionLayer(Layer):
         """Paged K/V of `num_slots` rings (and the null block):
         `num_blocks`, the table layers' pool size, plays no part."""
         blocks = num_slots * ring_blocks(self.window, block_len) + 1
-        shape = (blocks, self.kv_heads, block_len, self.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        return AttentionLayer.init_pool(self, num_slots, blocks, block_len,
+                                        dtype)
 
     @staticmethod
     def ring_tables(slots: int, ring: int):
@@ -507,7 +515,7 @@ class AttentionLayer(Layer):
         if slot is None:
             raise LayerError(f"{self.name}: a windowed layer's prefill "
                              f"has to be told its slot")
-        bl = pool["k"].shape[2]
+        bl = pool["kv"].shape[2]
         ring = ring_blocks(self.window, bl)
         nb = cache["k"].shape[2] // bl
         block = jnp.arange(nb, dtype=jnp.int32)
@@ -565,14 +573,16 @@ class AttentionLayer(Layer):
         per-slot key-visibility horizon.  The slots never attend each
         other: attention below is per-slot against that slot's own blocks.
 
-        `entry` holds the layer's {"k","v"} pools, each (num_blocks, Hkv,
-        block_len, D); `tables` (S, T) int32 maps slot s's logical block t
+        `entry` holds the layer's pool {"kv"}, (num_blocks, 2 * Hkv,
+        block_len, D), a block's key heads and then its value heads;
+        `tables` (S, T) int32 maps slot s's logical block t
         to a pool index (block 0 = null: inactive slots and table tails
         point there).  Token position p of slot s lives at
         pool[tables[s, p // bl], :, p % bl].
 
         Write-before-read: the new K/V is scattered at position ntoks[s]
-        first, then `ops.paged_attention.paged_decode_attention` attends
+        first (one patched block a slot for both), then
+        `ops.paged_attention.paged_decode_attention` attends
         positions `<= ntoks[s]` — the same self-inclusive causal horizon as
         `_attn_cached` at T=1 — walking ntoks[s] // bl + 1 blocks of the
         slot's table row and no more (an inactive slot: the null block).
@@ -588,7 +598,7 @@ class AttentionLayer(Layer):
         only."""
         assert self.causal, f"{self.name}: decode requires causal attention"
         _, s, _ = x.shape
-        bl = entry["k"].shape[2]
+        bl = entry["kv"].shape[2]
         q, k, v = self.qkv(params, x, ntoks, DECODE_CTX)    # (1,H,S,D)/(1,Hkv,S,D)
 
         if self.window:
@@ -600,16 +610,13 @@ class AttentionLayer(Layer):
         else:
             bidx = tables[jnp.arange(s), ntoks // bl]  # (S,) pool block
         off = ntoks % bl                               # (S,) offset in block
-        k_new = k[0].transpose(1, 0, 2)                # (S, Hkv, D)
-        v_new = v[0].transpose(1, 0, 2)
-        k_pool = write_token(entry["k"], bidx, off, k_new)
-        v_pool = write_token(entry["v"], bidx, off, v_new)
+        new = paged_rows(k[0], v[0]).transpose(1, 0, 2)    # (S, 2 Hkv, D)
+        pool = write_token(entry["kv"], bidx, off, new)
 
-        out = paged_decode_attention(q[0].transpose(1, 0, 2), k_pool, v_pool,
-                                     tables, ntoks,
-                                     window=self.window)   # (S, H, D)
+        out = paged_decode_attention(q[0].transpose(1, 0, 2), pool, tables,
+                                     ntoks, window=self.window)  # (S, H, D)
         out = self._out(params, x, out.reshape(1, s, -1), DECODE_CTX)
-        return out, {"k": k_pool, "v": v_pool}
+        return out, {"kv": pool}
 
 
 @register_layer("kFeedForward")

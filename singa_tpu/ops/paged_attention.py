@@ -2,8 +2,9 @@
 slot's blocks of a paged KV pool, as a Pallas kernel that reads the
 live blocks only.
 
-The pool of one attention layer is (num_blocks, Hkv, block_len, D) per
-side (serve/kvcache.py); slot s's logical block t is pool block
+The pool of one attention layer is (num_blocks, 2 * Hkv, block_len, D)
+(serve/kvcache.py): a block holds its Hkv key heads and then its Hkv
+value heads, contiguous in HBM.  Slot s's logical block t is pool block
 `tables[s, t]`, and token position p lives at
 pool[tables[s, p // block_len], :, p % block_len].  A slot that has
 written `ntoks[s]` tokens attends positions 0..ntoks[s] (its newest
@@ -11,27 +12,28 @@ token was written just before the call), which is
 ntoks[s] // block_len + 1 blocks of its table row; the rest of the row
 (null-block tail, reserved-but-unwritten blocks) is never read.
 
-`paged_decode_attention` is the kernel: the pools stay in HBM, the block
+`paged_decode_attention` is the kernel: the pool stays in HBM, the block
 table and the lengths are scalar-prefetched, and each slot's live blocks
 are copied HBM -> VMEM a chunk at a time, double buffered, the next
 slot's first chunk in flight while this slot's last one is computed.
 Scores and the online softmax are f32; GQA is done in the kernel (q
-grouped (Hkv, G, D), no expanded K/V).  Three callers, one body, told
-apart by their arguments' shapes: `kAttention` (separate key and value
-pools, 1 / sqrt(D)), `kCCA` (the same with few, narrow heads) and
-`kMLA`'s absorbed decode step (one pool of latent rows shared by all
-heads, Hkv 1, whose leading columns are the value, so a block is copied
-once; its own scale).
+grouped (Hkv, G, D), no expanded K/V).  Every caller hands ONE pool, so
+a block is one copy: `kAttention` and `kCCA` (few, narrow heads) a pool
+whose blocks hold keys and then values, 1 / sqrt(D); `kMLA`'s absorbed
+decode step a pool of latent rows shared by all heads (Hkv 1) whose
+leading `value_dim` columns are the value, its own scale.  One body,
+the two told apart by the pool's heads beside the output's.
 
 The copy schedule follows the pool's shape.  Beside its transfer a live
-byte costs scalar work a block (a table read, a descriptor and an issue
-a pool) and fixed work a chunk, and neither shrinks with the block: a
-block of (8, 16, 128) bf16 is a 32 KB copy that hides them, one of
-(2, 16, 128) an 8 KB copy that does not.  So a chunk is a number of
-bytes (`chunk_positions`); a chunk's copies of one pool signal one DMA
-semaphore, which counts bytes, and are waited for together, by size,
-with no second walk of the table; and `start` issues a group of blocks
-in straight-line code before it loops.
+byte costs scalar work a block (a table read, a descriptor and its
+issue) and fixed work a chunk, and neither shrinks with the block: a
+block of (2 x 8, 16, 128) bf16 is a 64 KB copy that hides them, one of
+(2 x 2, 16, 128) a 16 KB copy that does not (and was two copies of 8
+KB while keys and values lay in two pools: PERF.md 6, PR 38).  So a
+chunk is a number of bytes (`chunk_positions`); a chunk's copies signal
+one DMA semaphore, which counts bytes, and are waited for together, by
+size, with no second walk of the table; and `start` issues a group of
+blocks in straight-line code before it loops.
 
 A WINDOWED call (`window` W > 0, a sliding-window `kAttention` layer)
 attends positions max(0, ntoks[s] - W + 1)..ntoks[s] and its table row
@@ -44,7 +46,7 @@ kernel it lowered to before there was one.
 
 `paged_attention_reference` is the plain-jnp gather of every slot's
 whole table, the formulation the serving engine ran before the kernel:
-it materialises (S, T, Hkv, block_len, D) per side and is kept only as
+it materialises (S, T, 2 * Hkv, block_len, D) and is kept only as
 the oracle the tests compare the kernel against (kMLA's is its own
 `MLALayer._attend_absorbed` over the gathered rows) and as the row
 `tools/paged_kernel_bench.py` times the kernel beside.
@@ -65,19 +67,21 @@ from . import attention as _attention
 #: Stable name of the Mosaic custom call in compiled modules.
 KERNEL_NAME = "singa_paged_decode_kernel"
 
-# Bytes of one pool that a chunk (one trip of a slot's loop) brings in.
-# A trip's fixed work (the loop's carry, the softmax's rescale, a wait a
-# pool) is paid a chunk and a copy's scalar work a block, whatever the
-# bytes they move, so the chunk is a number of bytes and its positions
-# follow the pool's row: 256 at the dense cells' 8 heads x 128 bf16,
-# 512 at a latent pool's 1 x 640, 1,024 at kCCA's 2 x 128.  On the v5e
-# (tools/paged_kernel_bench.py; PERF.md 6, PR 34), at 128 / 256 / 512 /
-# 1,024 positions a chunk, us a layer: 32 slots x 80 blocks of
-# (8, 16, 128) 161 / 141 / 139 / 140 with 57 % of the table live and
-# 37 / 34 / 42 / 63 with 8 % live (the masked tail of a slot's last
-# chunk is computed whole); 96 x 128 blocks of (1, 16, 640), 23 % live,
-# 242 / 182 / 157 / 166; 64 x 256 blocks of (2, 16, 128), 26 % live,
-# 296 / 219 / 191 / 181 (2,048: slower again).
+# Bytes of KEY rows that a chunk (one trip of a slot's loop) brings in;
+# where a block holds its values behind its keys the chunk brings as
+# many again.  A trip's fixed work (the loop's carry, the softmax's
+# rescale, a wait) is paid a chunk and a copy's scalar work a block,
+# whatever the bytes they move, so the chunk is a number of bytes and
+# its positions follow the keys' row: 256 at the dense cells' 8 heads x
+# 128 bf16, 512 at Trinity's 4 x 128 and at a latent pool's 1 x 640,
+# 1,024 at kCCA's 2 x 128.  On the v5e (tools/paged_kernel_bench.py;
+# PERF.md 6, PR 34: keys and values in two pools then, a copy each), at
+# 128 / 256 / 512 / 1,024 positions a chunk, us a layer: 32 slots x 80
+# blocks of (8, 16, 128) 161 / 141 / 139 / 140 with 57 % of the table
+# live and 37 / 34 / 42 / 63 with 8 % live (the masked tail of a slot's
+# last chunk is computed whole); 96 x 128 blocks of (1, 16, 640), 23 %
+# live, 242 / 182 / 157 / 166; 64 x 256 blocks of (2, 16, 128), 26 %
+# live, 296 / 219 / 191 / 181 (2,048: slower again).
 _CHUNK_BYTES = 512 * 1024
 
 # Blocks whose copies `start` issues in straight-line code (the table
@@ -90,11 +94,24 @@ _CHUNK_BYTES = 512 * 1024
 _ISSUE_GROUP = 8
 
 
-def chunk_positions(pool_shape, dtype):
-    """Key positions a chunk holds of a (num_blocks, Hkv, bl, D) pool:
-    the power of two whose rows come nearest `_CHUNK_BYTES`."""
-    _, hkv, _, d = pool_shape
-    row = hkv * d * jnp.dtype(dtype).itemsize
+def key_heads(pool_shape, value_dim=None) -> int:
+    """Hkv of a (num_blocks, heads, bl, D) pool: half its heads where a
+    block holds values behind keys (`value_dim` None), all of them
+    where a row's leading `value_dim` columns are its value."""
+    heads = pool_shape[1]
+    if value_dim is not None:
+        return heads
+    if heads % 2:
+        raise ValueError(f"a pool of shape {tuple(pool_shape)} holds no "
+                         f"value heads behind its key heads")
+    return heads // 2
+
+
+def chunk_positions(pool_shape, dtype, value_dim=None):
+    """Key positions a chunk holds of a (num_blocks, heads, bl, D) pool:
+    the power of two whose KEY rows come nearest `_CHUNK_BYTES`."""
+    row = (key_heads(pool_shape, value_dim) * pool_shape[3]
+           * jnp.dtype(dtype).itemsize)
     return 2 ** round(math.log2(_CHUNK_BYTES / row))
 
 
@@ -105,24 +122,24 @@ def ring_blocks(window: int, block_len: int) -> int:
     return -(-(int(window) - 1) // int(block_len)) + 1
 
 
-def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
-                              scale=None, value_dim=None, window=0):
+def paged_attention_reference(q, pool, tables, ntoks, *, scale=None,
+                              value_dim=None, window=0):
     """Gather formulation, `paged_decode_attention`'s arguments.  q
-    (S, H, D); pools (num_blocks, Hkv, bl, D); tables (S, T) int32;
-    ntoks (S,) int32.  Returns (S, H, D) in q's dtype: softmax(q k^T /
-    sqrt(D)) v over positions <= ntoks[s], and with `window` over the
-    last `window` of them, the table row read as a ring."""
+    (S, H, D); pool (num_blocks, 2 * Hkv, bl, D), or (num_blocks, Hkv,
+    bl, D) with `value_dim`; tables (S, T) int32; ntoks (S,) int32.
+    Returns (S, H, D) in q's dtype: softmax(q k^T / sqrt(D)) v over
+    positions <= ntoks[s], and with `window` over the last `window` of
+    them, the table row read as a ring."""
     s, h, d = q.shape
-    _, hkv, bl, _ = k_pool.shape
+    bl = pool.shape[2]
+    hkv = key_heads(pool.shape, value_dim)
     t = tables.shape[1]
     groups = h // hkv
-
-    def flat(pool):                               # (S, Hkv, T*bl, D)
-        return pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            s, hkv, t * bl, d).astype(q.dtype)
-
-    kk = flat(k_pool)
-    vv = kk[..., :value_dim] if v_pool is None else flat(v_pool)
+    # (S, heads, T*bl, D)
+    rows = pool[tables].transpose(0, 2, 1, 3, 4).reshape(
+        s, pool.shape[1], t * bl, d).astype(q.dtype)
+    kk = rows[:, :hkv]
+    vv = rows[:, hkv:] if value_dim is None else kk[..., :value_dim]
     if window:
         # column j holds the newest logical block b <= ntoks // bl with
         # b % T == j (an older one it held has been overwritten)
@@ -147,20 +164,17 @@ def paged_attention_reference(q, k_pool, v_pool, tables, ntoks, *,
     return out.reshape(s, h, vv.shape[-1])
 
 
-def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
-            group, window):
-    """Grid step s attends slot s.  `refs` is the `sides` pools in HBM
-    (keys, then values; one pool where the values are columns of the key
-    rows), the output, a buffer a pool, the copies' semaphores and
-    `base_ref`.  A buffer is (2, Hkv, cb*bl, D): two chunks of cb blocks
-    each, a block's (Hkv, bl, D) slab copied to rows [c*bl, (c+1)*bl)
-    of every head.  The buffer that holds a slot's first chunk
-    alternates with the number of chunks walked so far (`base_ref`),
-    because the copy of slot s+1's first chunk is started under slot
-    s's last.  With a `window` the walk starts at the window's first
-    block and the table row is a ring of `tw` columns."""
-    pools, o_ref, bufs = refs[:sides], refs[sides], refs[sides + 1:-2]
-    sems, base_ref = refs[-2:]
+def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
+            *, bl, cb, tw, scale, group, window):
+    """Grid step s attends slot s.  `pool` lies in HBM; `into` is the
+    buffer (2, heads, cb*bl, D): two chunks of cb blocks each, a
+    block's (heads, bl, D) slab, its key heads and behind them its
+    value heads, copied to rows [c*bl, (c+1)*bl) of every head.  The
+    buffer that holds a slot's first chunk alternates with the number
+    of chunks walked so far (`base_ref`), because the copy of slot
+    s+1's first chunk is started under slot s's last.  With a `window`
+    the walk starts at the window's first block and the table row is a
+    ring of `tw` columns."""
     s = pl.program_id(0)
     slots = pl.num_programs(0)
     span = cb * bl
@@ -190,8 +204,8 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
 
     def start(slot, chunk, buf, walked):
         """Start the copies of the chunk's live blocks, the slot's walk
-        being `walked` (its first block, its count), one a pool and
-        block; a pool's all signal its semaphore of `buf`."""
+        being `walked` (its first block, its count), one a block; all
+        signal the semaphore of `buf`."""
         first, of = walked
         live = jnp.minimum(of - chunk * cb, cb)
         if window:
@@ -209,9 +223,8 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
             else:
                 blk = tables_ref[entry + c]
             rows = pl.ds(pl.multiple_of(c * bl, bl), bl)
-            for side, (hbm, into) in enumerate(zip(pools, bufs)):
-                pltpu.make_async_copy(hbm.at[blk], into.at[buf, :, rows, :],
-                                      sems.at[side, buf]).start()
+            pltpu.make_async_copy(pool.at[blk], into.at[buf, :, rows, :],
+                                  sems.at[buf]).start()
 
         def issue(width):
             # `width` blocks a trip in straight-line code: the table
@@ -228,21 +241,19 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
             jax.lax.fori_loop(whole * group, live, issue(1), None)
 
     def wait(buf, count):
-        """Wait for `count` (static) blocks a pool.  A DMA semaphore
-        counts bytes, so one descriptor of the copies' size together
-        stands for them all, whatever blocks they brought: no wait
-        reads the table."""
-        rows = pl.ds(0, count * bl)
-        for side, into in enumerate(bufs):
-            landed = into.at[buf, :, rows, :]
-            pltpu.make_async_copy(landed, landed, sems.at[side, buf]).wait()
+        """Wait for `count` (static) blocks.  A DMA semaphore counts
+        bytes, so one descriptor of the copies' size together stands
+        for them all, whatever blocks they brought: no wait reads the
+        table."""
+        landed = into.at[buf, :, pl.ds(0, count * bl), :]
+        pltpu.make_async_copy(landed, landed, sems.at[buf]).wait()
 
     def attend(chunk, buf, carry, last):
         m, l, acc = carry
         q = q_ref[0]                                    # (Hkv, G, D)
-        k = bufs[0][buf].astype(q.dtype)                # (Hkv, span, D)
-        # one pool: a row's leading columns are its value
-        v = (bufs[1][buf].astype(q.dtype) if sides == 2
+        k = into[buf, :hkv].astype(q.dtype)             # (Hkv, span, D)
+        # whole heads behind the keys, or a row's leading columns
+        v = (into[buf, hkv:].astype(q.dtype) if into.shape[1] > hkv
              else k[..., :vd])                          # (Hkv, span, Dv)
         sc = jnp.einsum("hgd,htd->hgt", q, k,
                         preferred_element_type=jnp.float32) * scale
@@ -307,32 +318,33 @@ def _kernel(ntoks_ref, tables_ref, q_ref, *refs, bl, cb, tw, scale, sides,
     base_ref[0] = 1 - buf
 
 
-def _check_tiling(q, k_pool, value_dim):
+def _check_tiling(q, pool, value_dim):
     """Mosaic copies whole (sublane, lane) tiles: a block's (bl, D) face
     has to be made of them, and so has the part of a row that is its
     value.  Interpreted kernels take any shape."""
-    _, _, bl, d = k_pool.shape
-    sublanes = 32 // jnp.dtype(k_pool.dtype).itemsize
+    _, _, bl, d = pool.shape
+    sublanes = 32 // jnp.dtype(pool.dtype).itemsize
     if bl % sublanes or d % 128 or value_dim % 128:
         raise ValueError(
-            f"paged_decode_attention cannot tile a {k_pool.dtype} pool of "
-            f"shape {k_pool.shape} (q {q.shape}, values of {value_dim}) on "
+            f"paged_decode_attention cannot tile a {pool.dtype} pool of "
+            f"shape {pool.shape} (q {q.shape}, values of {value_dim}) on "
             f"the TPU: block_len must be a multiple of {sublanes}, "
             f"head_dim and the value's width of 128")
 
 
-def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
+def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
                            value_dim=None, window=0):
-    """q (S, H, D) against pools (num_blocks, Hkv, bl, D) through
-    `tables` (S, T) int32 and `ntoks` (S,) int32.  Returns (S, H, D) in
-    q's dtype, equal to `paged_attention_reference` up to the order of
-    the f32 sums.  Reads ntoks[s] // bl + 1 blocks of slot s's row and
-    nothing else of the pools.  `scale` multiplies the f32 scores
-    (default 1 / sqrt(D)).
+    """q (S, H, D) against a pool (num_blocks, 2 * Hkv, bl, D), a
+    block's key heads and then its value heads, through `tables` (S, T)
+    int32 and `ntoks` (S,) int32.  Returns (S, H, D) in q's dtype,
+    equal to `paged_attention_reference` up to the order of the f32
+    sums.  Reads ntoks[s] // bl + 1 blocks of slot s's row, each in one
+    copy, and nothing else of the pool.  `scale` multiplies the f32
+    scores (default 1 / sqrt(D)).
 
-    `v_pool` None: one pool holds both sides, a row's first `value_dim`
-    columns (default all D) being its value, and every block is copied
-    once; the result is (S, H, value_dim).  That is a latent (MLA)
+    `value_dim`: the pool is (num_blocks, Hkv, bl, D) and holds both
+    sides in the same rows, a row's first `value_dim` columns being its
+    value; the result is (S, H, value_dim).  That is a latent (MLA)
     cache under an absorbed query: Hkv 1, one row a token shared by all
     heads, scale 1 / sqrt(nope + rope).
 
@@ -343,27 +355,25 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
 
     Compiled by Mosaic on the TPU, interpreted elsewhere
     (`ops.attention._on_tpu`)."""
-    if window and tables.shape[1] < ring_blocks(window, k_pool.shape[2]):
+    if window and tables.shape[1] < ring_blocks(window, pool.shape[2]):
         raise ValueError(
             f"a window of {window} positions touches up to "
-            f"{ring_blocks(window, k_pool.shape[2])} blocks of "
-            f"{k_pool.shape[2]}; the ring has {tables.shape[1]}")
-    if q.shape[1] % k_pool.shape[1]:
+            f"{ring_blocks(window, pool.shape[2])} blocks of "
+            f"{pool.shape[2]}; the ring has {tables.shape[1]}")
+    hkv = key_heads(pool.shape, value_dim)
+    if q.shape[1] % hkv:
         raise ValueError(f"{q.shape[1]} query heads over "
-                         f"{k_pool.shape[1]} key/value heads")
+                         f"{hkv} key/value heads")
     d = q.shape[-1]
-    if value_dim is None:
-        value_dim = d
-    if not 0 < value_dim <= d or (v_pool is not None and value_dim != d):
-        raise ValueError(f"values of {value_dim} columns from "
-                         f"{'the key' if v_pool is None else 'value'} rows "
-                         f"of {d}")
+    if value_dim is not None and not 0 < value_dim <= d:
+        raise ValueError(f"values of {value_dim} columns from the key "
+                         f"rows of {d}")
     interpret = not _attention._on_tpu()
     if not interpret:
-        _check_tiling(q, k_pool, value_dim)
+        _check_tiling(q, pool, d if value_dim is None else value_dim)
     return singa_paged_decode(
-        q, k_pool, v_pool, tables, ntoks, interpret=interpret,
-        chunk=chunk_positions(k_pool.shape, k_pool.dtype),
+        q, pool, tables, ntoks, interpret=interpret,
+        chunk=chunk_positions(pool.shape, pool.dtype, value_dim),
         group=1 if interpret else _ISSUE_GROUP, value_dim=value_dim,
         scale=1.0 / math.sqrt(d) if scale is None else float(scale),
         window=int(window))
@@ -375,39 +385,36 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ntoks, *, scale=None,
 # of the row, that holds the kernel's time in a device trace.
 @functools.partial(jax.jit, static_argnames=("interpret", "chunk", "group",
                                              "scale", "value_dim", "window"))
-def singa_paged_decode(q, k_pool, v_pool, tables, ntoks, *, interpret,
-                       chunk, scale, value_dim, group=_ISSUE_GROUP,
-                       window=0):
+def singa_paged_decode(q, pool, tables, ntoks, *, interpret, chunk, scale,
+                       value_dim=None, group=_ISSUE_GROUP, window=0):
     s, h, d = q.shape
-    _, hkv, bl, _ = k_pool.shape
+    _, heads, bl, _ = pool.shape
+    hkv = key_heads(pool.shape, value_dim)
     tw = tables.shape[1]
     cb = max(1, min(chunk // bl, tw))
     groups = h // hkv
-    pools = [k_pool] if v_pool is None else [k_pool, v_pool]
+    vd = d if value_dim is None else value_dim
 
-    def heads(width):
+    def of_slot(width):
         return pl.BlockSpec((1, hkv, groups, width),
                             lambda i, *_: (i, 0, 0, 0))
 
     out = pl.pallas_call(
         functools.partial(_kernel, bl=bl, cb=cb, tw=tw, scale=scale,
-                          sides=len(pools), group=group, window=window),
+                          group=group, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),
-            in_specs=[heads(d)] + [pl.BlockSpec(memory_space=pl.ANY)
-                                   for _ in pools],
-            out_specs=heads(value_dim),
-            scratch_shapes=[pltpu.VMEM((2, hkv, cb * bl, d), pool.dtype)
-                            for pool in pools]
-            + [pltpu.SemaphoreType.DMA((len(pools), 2)),
-               pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((s, hkv, groups, value_dim),
-                                       q.dtype),
+            in_specs=[of_slot(d), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=of_slot(vd),
+            scratch_shapes=[pltpu.VMEM((2, heads, cb * bl, d), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((s, hkv, groups, vd), q.dtype),
         compiler_params=(None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))),
         interpret=interpret,
         name=KERNEL_NAME,
     )(ntoks.astype(jnp.int32), tables.astype(jnp.int32).reshape(-1),
-      q.reshape(s, hkv, groups, d), *pools)
-    return out.reshape(s, h, value_dim)
+      q.reshape(s, hkv, groups, d), pool)
+    return out.reshape(s, h, vd)

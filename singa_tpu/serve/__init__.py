@@ -14,7 +14,7 @@ checkpoints via atomic hot-reload.  Layers:
                  shedding, deadline expiry, smallest-admissible-bucket
                  coalescing with left-pad masking (the static path;
                  predict always rides here)
-    kvcache.py   PagedKVCache: fixed pool of (block, Hkv, block_len,
+    kvcache.py   PagedKVCache: fixed pool of (block, 2 Hkv, block_len,
                  D) KV blocks, per-slot block tables, refcounts, null
                  block 0 — slot memory O(active tokens)
     scheduler.py ContinuousScheduler + StreamTicket: admit a request

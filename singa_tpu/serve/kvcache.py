@@ -10,7 +10,9 @@ together, counts each part where it belongs (`state_bytes`) and hands
 the prefill program the table row and the slot behind it:
 
   * rows per token in PAGED BLOCKS THAT GROW — K and V of a `kAttention`
-    layer without a window, (num_blocks, Hkv, block_len, D) per side;
+    layer without a window in ONE pool, (num_blocks, 2 * Hkv, block_len,
+    D), a block's key heads and then its value heads, so that the paged
+    kernel brings a block in one copy (`block_copy` of `state_bytes`);
     the latent row of a `kMLA` layer, (num_blocks, block_len, rank +
     rope).  A slot holds an ordered list of block indices (its *block
     table* row), one table for all layers of this kind, and retiring a
@@ -22,7 +24,7 @@ the prefill program the table row and the slot behind it:
     `kAttention` layer with a window W: it needs a slot's last W
     positions and no more, so slot s owns `ring_blocks` = W / block_len
     + 1 blocks of that layer's pool for good (blocks 1 + s R ..
-    (s + 1) R of (num_slots R + 1, Hkv, block_len, D)) and position p
+    (s + 1) R of (num_slots R + 1, 2 * Hkv, block_len, D)) and position p
     lives in ring column (p // block_len) % R.  A column is used again
     every R blocks; nothing is allocated at admission, nothing freed
     at retirement, and the table of growing blocks plays no part.  A
@@ -114,29 +116,42 @@ def state_bytes(net, block_len: int, dtype=jnp.float32) -> Dict[str, int]:
     the fixed per-slot states of all layers, `block` the bytes one more
     growing block adds over all layers that keep a table, `window_block`
     the bytes of one ring block over all windowed layers (a slot holds
-    `ring_blocks` of them whatever its length).  Read off each layer's
-    pool shapes at two sizes, so a layer whose entry holds two kinds
-    adds to both."""
+    `ring_blocks` of them whatever its length).  And what ONE COPY of
+    the paged kernel moves, a block of one layer's pool: `block_copy`
+    under a table, `window_block_copy` in a ring (the widest layer's,
+    should they differ).  Read off each layer's pool shapes at two
+    sizes, so a layer whose entry holds two kinds adds to both."""
     import jax
     from ..ops.paged_attention import ring_blocks
 
-    def size(layer, slots, blocks):
-        shapes = jax.eval_shape(
-            lambda: layer.init_pool(slots, blocks, block_len, dtype))
-        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                   for a in jax.tree_util.tree_leaves(shapes))
+    def nbytes(a):
+        return int(np.prod(a.shape)) * a.dtype.itemsize
 
-    out = {"slot": 0, "block": 0, "window_block": 0}
+    def arrays(layer, slots, blocks):
+        return jax.tree_util.tree_leaves(jax.eval_shape(
+            lambda: layer.init_pool(slots, blocks, block_len, dtype)))
+
+    def a_block_of(small, grown):
+        """Bytes of one block of the widest array that grew."""
+        return max((nbytes(a) // a.shape[0] for a, b in zip(small, grown)
+                    if b.shape[0] > a.shape[0]), default=0)
+
+    out = {"slot": 0, "block": 0, "window_block": 0, "block_copy": 0,
+           "window_block_copy": 0}
     for _, layer in _stateful(net):
-        base = size(layer, 1, 1)
-        a_slot, a_block = (size(layer, 2, 1) - base,
-                           size(layer, 1, 2) - base)
+        base, slots, blocks = (arrays(layer, 1, 1), arrays(layer, 2, 1),
+                               arrays(layer, 1, 2))
+        size = sum(map(nbytes, base))
+        a_slot = sum(map(nbytes, slots)) - size
         window = getattr(layer, "window", 0)
         if window:
             out["window_block"] += a_slot // ring_blocks(window, block_len)
+            out["window_block_copy"] = max(out["window_block_copy"],
+                                           a_block_of(base, slots))
         else:
             out["slot"] += a_slot
-        out["block"] += a_block
+        out["block"] += sum(map(nbytes, blocks)) - size
+        out["block_copy"] = max(out["block_copy"], a_block_of(base, blocks))
     return out
 
 

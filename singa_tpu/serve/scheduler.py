@@ -309,6 +309,9 @@ class ContinuousScheduler:
             self.stats.gauge("cb_slot_state_bytes", per["slot"])
             self.stats.gauge("cb_block_bytes", per["block"])
             self.stats.gauge("cb_window_block_bytes", per["window_block"])
+            self.stats.gauge("cb_block_copy_bytes", per["block_copy"])
+            self.stats.gauge("cb_window_block_copy_bytes",
+                             per["window_block_copy"])
             self.stats.gauge("cb_ring_blocks", self.kv.ring_blocks)
             # no request's first token waits on a program's first run
             self.kv.pools = self.engine.run_cb_prefill_rungs(

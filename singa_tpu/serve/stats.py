@@ -159,6 +159,10 @@ class ServeStats:
         self.cb_block_bytes = 0        # gauge: a growing block
         self.cb_window_block_bytes = 0  # gauge: a ring block, over the
                                         # layers with a window
+        # what ONE copy of the paged kernel moves: a block of one
+        # layer's pool, keys and values together (0: no such layer)
+        self.cb_block_copy_bytes = 0         # gauge: under the table
+        self.cb_window_block_copy_bytes = 0  # gauge: in a ring
         self.cb_ring_blocks = 0        # gauge: ring blocks a slot
         # routing of the experts held here, summed over decode steps
         # and routed layers, busy slots only (engine.run_cb_decode)
@@ -504,6 +508,7 @@ class ServeStats:
                   "cb_blocks_total",
                   "cb_slot_state_bytes", "cb_block_bytes",
                   "cb_window_block_bytes", "cb_ring_blocks",
+                  "cb_block_copy_bytes", "cb_window_block_copy_bytes",
                   "cb_window_block_share")
 
         def collect():
@@ -585,6 +590,9 @@ class ServeStats:
                 "cb_slot_state_bytes": self.cb_slot_state_bytes,
                 "cb_block_bytes": self.cb_block_bytes,
                 "cb_window_block_bytes": self.cb_window_block_bytes,
+                "cb_block_copy_bytes": self.cb_block_copy_bytes,
+                "cb_window_block_copy_bytes":
+                    self.cb_window_block_copy_bytes,
                 "cb_ring_blocks": self.cb_ring_blocks,
                 "cb_live_block_steps": self.cb_live_block_steps,
                 "cb_window_block_steps": self.cb_window_block_steps,

@@ -267,6 +267,12 @@ def test_reused_slots_carry_nothing_of_their_last_tenant(lm):
     per = state_bytes(net, BL, jnp.float32)
     assert st.cb_slot_state_bytes == per["slot"] > 0
     assert st.cb_block_bytes == per["block"] > 0
+    # a copy of the paged kernel moves one kMLA layer's block of latent
+    # rows; no layer of this model keeps a ring
+    mla = [net.layers[n] for n in net.topo if net.layers[n].cfg.type == "kMLA"]
+    assert st.cb_block_copy_bytes == per["block_copy"] == (
+        BL * mla[0].pool_row * 4) == per["block"] // len(mla)
+    assert st.cb_window_block_copy_bytes == per["window_block_copy"] == 0
 
 
 def test_cb_greedy_tokens_equal_generates(lm):

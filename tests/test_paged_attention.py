@@ -1,7 +1,8 @@
 """Paged decode attention (ops/paged_attention.py): the kernel against
 the plain gather reference on poisoned pools, in kAttention's geometry
-(a key and a value pool) and in kMLA's (one pool of latent rows shared
-by all heads, its own scale), the compiled cb decode program's freedom
+(one pool whose blocks hold their key heads and then their value heads)
+and in kMLA's (one pool of latent rows shared by all heads, its own
+scale), the one copy a block, the compiled cb decode program's freedom
 from the materialised gather, and the scheduler's `cb_live_block_share`
 counter.
 
@@ -53,7 +54,8 @@ def _narrow_lengths(dtype):
     """ntoks a slot: the newest token one below, on and one past the
     last position of a chunk, the same one chunk on, the table's full
     256 blocks, no token, one whole block, an inactive slot."""
-    w = chunk_positions((1, NARROW["hkv"], NARROW["bl"], NARROW["d"]), dtype)
+    w = chunk_positions((1, 2 * NARROW["hkv"], NARROW["bl"], NARROW["d"]),
+                        dtype)
     full = NARROW["t"] * NARROW["bl"] - 1
     return {"one_chunk": [w - 2, w - 1, w, None],
             "two_chunks": [2 * w - 2, 2 * w - 1, 2 * w, 0],
@@ -64,25 +66,34 @@ _dense_kernel = paged_decode_attention        # jitted inside
 _dense_reference = jax.jit(paged_attention_reference)
 
 
-def _narrow_kernel(q, k_pool, v_pool, tables, ntoks):
+def _narrow_kernel(q, pool, tables, ntoks):
     """The schedule the chip runs: the interpreter is given groups of
     one block (`paged_decode_attention`), Mosaic the default."""
     return singa_paged_decode(
-        q, k_pool, v_pool, tables, ntoks, interpret=True,
-        chunk=chunk_positions(k_pool.shape, k_pool.dtype),
-        scale=1.0 / np.sqrt(NARROW["d"]), value_dim=NARROW["d"])
+        q, pool, tables, ntoks, interpret=True,
+        chunk=chunk_positions(pool.shape, pool.dtype),
+        scale=1.0 / np.sqrt(NARROW["d"]))
+
+
+def _one_pool(sides):
+    """Keys and values (nb, Hkv, bl, D) as the pool holds them: a
+    block's key heads and then its value heads.  A latent pool has one
+    side and is itself."""
+    return np.concatenate(sides, axis=1)
 
 
 def _case(lengths, groups, dtype, seed, hkv=HKV, d=D, sides=2, bl=BL,
           t=T):
-    """q, a clean and a poisoned copy of the `sides` pools, tables,
-    ntoks, a slot a length.  The table is a shuffled (non-monotone)
-    draw of the pool's blocks; a slot's reservation ends somewhere at
-    or after its last live block and the row's tail is the null block.
-    In the poisoned copy every position no slot may see is nan (K, or
-    the one pool of both sides) or inf (V): blocks no live table entry
-    names, the tail of each last live block, and the null block past
-    its position 0 (which an inactive slot attends)."""
+    """q, a clean and a poisoned copy of the pool (`sides` 2: a block's
+    key heads and then its value heads; 1: latent rows), tables, ntoks,
+    a slot a length.  The table is a shuffled (non-monotone) draw of
+    the pool's blocks; a slot's reservation ends somewhere at or after
+    its last live block and the row's tail is the null block.  In the
+    poisoned copy every position no slot may see is nan (the key half,
+    or the latent rows of both sides) or inf (the value half): blocks
+    no live table entry names, the tail of each last live block, and
+    the null block past its position 0 (which an inactive slot
+    attends)."""
     rng = np.random.default_rng(seed)
     slots = len(lengths)
     nb = slots * t + 1
@@ -104,12 +115,12 @@ def _case(lengths, groups, dtype, seed, hkv=HKV, d=D, sides=2, bl=BL,
         at = np.arange(n + 1)
         seen[tables[s, at // bl], at % bl] = True
     hide = ~seen[:, None, :, None]
-    clean = [np.where(hide, 0.0, a) for a in pools]
-    poisoned = [np.where(hide, bad, a)
-                for a, bad in zip(pools, (np.nan, np.inf))]
+    clean = _one_pool([np.where(hide, 0.0, a) for a in pools])
+    poisoned = _one_pool([np.where(hide, bad, a)
+                          for a, bad in zip(pools, (np.nan, np.inf))])
     to = lambda a: jnp.asarray(a, dtype)                      # noqa: E731
-    return (to(q), [to(a) for a in clean], [to(a) for a in poisoned],
-            jnp.asarray(tables), jnp.asarray(ntoks))
+    return (to(q), to(clean), to(poisoned), jnp.asarray(tables),
+            jnp.asarray(ntoks))
 
 
 # kMLA's decode step at a tiny size: 3 heads over one latent row a
@@ -147,7 +158,7 @@ def _latent_kernel(layer, params, q, pool, tables, ntoks):
     """`MLALayer.apply_paged`'s middle: the kernel between the two
     halves of Wkvb."""
     o_lat = paged_decode_attention(
-        layer._absorb_query(params, q, pool.shape[-1]), pool, None, tables,
+        layer._absorb_query(params, q, pool.shape[-1]), pool, tables,
         ntoks, value_dim=layer.rank,
         scale=1.0 / np.sqrt(layer.nope + layer.rope))
     assert o_lat.shape == (len(ntoks), layer.heads, layer.rank)
@@ -181,22 +192,22 @@ def test_kernel_matches_gather_reference_on_poisoned_pools(name, groups,
         q, clean, poisoned, tables, ntoks = _case(
             LENGTHS[name], groups, dtype, seed=len(name) + groups)
         _reference, _kernel = _dense_reference, _dense_kernel
-    want = np.asarray(_reference(q, *clean, tables, ntoks), np.float32)
-    got = np.asarray(_kernel(q, *poisoned, tables, ntoks), np.float32)
+    want = np.asarray(_reference(q, clean, tables, ntoks), np.float32)
+    got = np.asarray(_kernel(q, poisoned, tables, ntoks), np.float32)
     assert np.isfinite(got).all(), "the kernel read past a slot's horizon"
     # Wkvb's value half (unit normal here) scales the latent case's output
     size = np.max(np.abs(want)) if groups == "latent" else 1.0
     assert np.max(np.abs(got - want)) <= tol * size
     # the table's tail and the unseen blocks do not reach the result
     # of the reference either: it is a fair oracle on the same pools
-    same = np.asarray(_reference(q, *poisoned, tables, ntoks), np.float32)
+    same = np.asarray(_reference(q, poisoned, tables, ntoks), np.float32)
     assert np.array_equal(same, want)
 
 
 # -- the windowed walk over a ring of blocks per slot -------------------------
 
-# (Hkv, G, D, block_len, window, pools, value columns): the Trinity
-# cell's geometry (two pools of (4, 16, 128) under 32 query heads, a
+# (Hkv, G, D, block_len, window, sides, value columns): the Trinity
+# cell's geometry (a pool of (2 x 4, 16, 128) under 32 query heads, a
 # window of 2,048: a ring of 129 blocks, four chunks of 512 positions
 # and a fifth of one block), and a window over each of the three
 # geometries the kernel already served.  `tiny` walks several chunks of
@@ -237,19 +248,20 @@ def _ring_case(lengths, hkv, groups, d, bl, window, sides, dtype, seed):
         at = np.arange(max(0, n - window + 1), n + 1)
         seen[tables[s, (at // bl) % ring], at % bl] = True
     hide = ~seen[:, None, :, None]
-    clean = [np.where(hide, 0.0, a) for a in pools]
-    poisoned = [np.where(hide, bad, a)
-                for a, bad in zip(pools, (np.nan, np.inf))]
+    clean = _one_pool([np.where(hide, 0.0, a) for a in pools])
+    poisoned = _one_pool([np.where(hide, bad, a)
+                          for a, bad in zip(pools, (np.nan, np.inf))])
     to = lambda a: jnp.asarray(a, dtype)                      # noqa: E731
-    return (to(q), [to(a) for a in clean], [to(a) for a in poisoned],
-            jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+    return (to(q), to(clean), to(poisoned), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
 
 
-def _window_oracle(q, pools, tables, ntoks, window, bl, vd):
+def _window_oracle(q, pool, sides, tables, ntoks, window, bl, vd):
     """The window's rows gathered position by position, in numpy."""
     q = np.asarray(q, np.float32)
-    k = np.asarray(pools[0], np.float32)
-    v = np.asarray(pools[-1], np.float32)[..., :vd]
+    pool = np.asarray(pool, np.float32)
+    hkv = pool.shape[1] // sides
+    k, v = pool[:, :hkv], pool[:, -hkv:, :, :vd]
     slots, h, d = q.shape
     groups, ring = h // k.shape[1], tables.shape[1]
     out = np.zeros((slots, h, vd), np.float32)
@@ -274,35 +286,187 @@ def test_windowed_walk_reads_the_ring_and_nothing_outside_the_window(
     lengths = _ring_lengths(window, bl)
     q, clean, poisoned, tables, ntoks = _ring_case(
         lengths, hkv, groups, d, bl, window, sides, dtype, seed=len(cell))
-    v_of = lambda pools: pools[1] if sides == 2 else None     # noqa: E731
-    kw = dict(window=window, value_dim=vd)
+    kw = dict(window=window, value_dim=vd if sides == 1 else None)
     if cell == "tiny":
-        def kernel(q, k, v, tables, ntoks):
+        def kernel(q, pool, tables, ntoks):
             return singa_paged_decode(
-                q, k, v, tables, ntoks, interpret=True, chunk=2 * bl,
+                q, pool, tables, ntoks, interpret=True, chunk=2 * bl,
                 group=2, scale=1.0 / np.sqrt(d), **kw)
     else:
         kernel = functools.partial(paged_decode_attention, **kw)
     want = np.asarray(paged_attention_reference(
-        q, clean[0], v_of(clean), tables, ntoks, **kw), np.float32)
+        q, clean, tables, ntoks, **kw), np.float32)
     if dtype == jnp.float32:
         # the gather itself against a position-by-position reading
-        oracle = _window_oracle(q, clean, tables, ntoks, window, bl, vd)
+        oracle = _window_oracle(q, clean, sides, tables, ntoks, window, bl,
+                                vd)
         assert np.max(np.abs(want - oracle)) <= tol
-    got = np.asarray(kernel(q, poisoned[0], v_of(poisoned), tables, ntoks),
-                     np.float32)
+    got = np.asarray(kernel(q, poisoned, tables, ntoks), np.float32)
     assert np.isfinite(got).all(), "the kernel read outside a window"
     assert np.max(np.abs(got - want)) <= tol
     same = np.asarray(paged_attention_reference(
-        q, poisoned[0], v_of(poisoned), tables, ntoks, **kw), np.float32)
+        q, poisoned, tables, ntoks, **kw), np.float32)
     assert np.array_equal(same, want)
+
+
+# -- a block's two halves -----------------------------------------------------
+
+# chunks of 2 blocks of 4 positions: a slot's newest token around the
+# edges of the first and second chunk, a table's (or a window's) whole
+# width, no token
+EDGES = [0, 2 * BL - 2, 2 * BL - 1, 2 * BL, 4 * BL - 1, 4 * BL, FULL]
+HALVES_WINDOW = 3 * BL + 2
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("window", [0, HALVES_WINDOW], ids=["table", "ring"])
+def test_each_half_of_a_block_is_read_as_what_it_is(window, dtype, tol):
+    """The halves of the one pool poisoned DIFFERENTLY: the value half
+    inf at every position a slot does not attend (beyond its horizon,
+    before its window), the key half nan in every block the walk must
+    not read and LARGE but finite in the rows of a walked block that
+    the mask hides.  A kernel that took a value for a key, a key for a
+    value, or waited for one half's bytes only, reads one of them."""
+    rng = np.random.default_rng(11)
+    slots = len(EDGES)
+    width = ring_blocks(window, BL) if window else T
+    nb = slots * width + 1
+    q = rng.standard_normal((slots, HKV * 2, D)).astype(np.float32)
+    k, v = (rng.standard_normal((nb, HKV, BL, D)).astype(np.float32)
+            for _ in range(2))
+    tables = (1 + rng.permutation(nb - 1)).reshape(slots, width).astype(
+        np.int32)
+    seen = np.zeros((nb, BL), bool)
+    for s, n in enumerate(EDGES):
+        at = np.arange(max(0, n - window + 1) if window else 0, n + 1)
+        seen[tables[s, (at // BL) % width], at % BL] = True
+    walked = seen.any(axis=1)[:, None, None, None]
+    hide = ~seen[:, None, :, None]
+    clean = np.concatenate([np.where(hide, 0.0, k), np.where(hide, 0.0, v)], 1)
+    poisoned = np.concatenate(
+        [np.where(walked, np.where(hide, 1e4, k), np.nan),
+         np.where(hide, np.inf, v)], 1)
+    to = lambda a: jnp.asarray(a, dtype)                      # noqa: E731
+    args = jnp.asarray(tables), jnp.asarray(EDGES, jnp.int32)
+    got = np.asarray(singa_paged_decode(
+        to(q), to(poisoned), *args, interpret=True, chunk=2 * BL, group=2,
+        scale=1.0 / np.sqrt(D), window=window), np.float32)
+    want = np.asarray(paged_attention_reference(
+        to(q), to(clean), *args, window=window), np.float32)
+    assert np.isfinite(got).all()
+    assert np.max(np.abs(got - want)) <= tol
+
+
+# -- the writers of the one pool against K and V written apart ----------------
+
+def _attention_layer(window=0):
+    from singa_tpu.config.schema import AttentionConfig
+    from singa_tpu.core.seq_layers import AttentionLayer
+    layer = AttentionLayer(LayerConfig(
+        name="attn", type="kAttention", attention_param=AttentionConfig(
+            num_heads=2 * HKV, num_kv_heads=HKV, head_dim=D, window=window)))
+    layer.setup([(1, 1, 2 * HKV * D)])
+    return layer
+
+
+def _cca_layer():
+    from singa_tpu.config.schema import CCAConfig
+    from singa_tpu.core.hybrid_layers import CCALayer
+    layer = CCALayer(LayerConfig(name="cca", type="kCCA", cca_param=CCAConfig(
+        num_heads=2 * HKV, num_kv_heads=HKV, head_dim=D)))
+    layer.setup([(1, 1, 2 * HKV * D)])
+    return layer
+
+
+def _apart(pool, hkv=HKV):
+    """The K and the V pool a block's two halves would be."""
+    pool = np.asarray(pool)
+    assert pool.shape[1] == 2 * hkv
+    return pool[:, :hkv], pool[:, hkv:]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_write_token_patches_one_block_with_both_rows(dtype):
+    """`write_token` over the one pool with a token's key heads and
+    then its value heads is the K pool and the V pool each written
+    with its own rows; slots that share the null block aside, no other
+    row of the pool changes."""
+    from singa_tpu.core.seq_layers import paged_rows, write_token
+    rng = np.random.default_rng(3)
+    nb, slots = 9, 4
+    pool = jnp.asarray(rng.standard_normal((nb, 2 * HKV, BL, D)), dtype)
+    k_new, v_new = (jnp.asarray(rng.standard_normal((slots, HKV, D)), dtype)
+                    for _ in range(2))
+    bidx = jnp.asarray([3, 7, 1, 5], jnp.int32)
+    off = jnp.asarray([0, BL - 1, 2, 1], jnp.int32)
+    new = paged_rows(k_new[:, :, None], v_new[:, :, None])[:, :, 0]
+    got_k, got_v = _apart(write_token(pool, bidx, off, new))
+    want_k, want_v = _apart(pool)
+    want_k, want_v = want_k.copy(), want_v.copy()
+    want_k[np.asarray(bidx), :, np.asarray(off)] = np.asarray(k_new)
+    want_v[np.asarray(bidx), :, np.asarray(off)] = np.asarray(v_new)
+    assert np.array_equal(got_k, want_k) and np.array_equal(got_v, want_v)
+
+
+def _prefill_apart(cache, nb, table_row):
+    """The parent's two scatters: each side's rows cut into blocks and
+    set at `table_row`, in a pool of its own."""
+    out = []
+    for side in ("k", "v"):
+        rows = np.asarray(cache[side])[0]               # (Hkv, P, D)
+        hkv, p, d = rows.shape
+        blocks = rows.reshape(hkv, p // BL, BL, d).transpose(1, 0, 2, 3)
+        pool = np.zeros((nb, hkv, BL, d), rows.dtype)
+        for block, at in zip(blocks, np.asarray(table_row)):
+            pool[at] = block              # in order: the last write wins
+        out.append(pool)
+    return out
+
+
+@pytest.mark.parametrize("kind", ["table", "ring", "cca"])
+def test_scatter_prefill_writes_a_prompts_blocks_with_both_halves(kind):
+    """The three `scatter_prefill`s (kAttention under a table, under a
+    ring, kCCA) against K and V scattered apart: the pool's key half is
+    the K pool, its value half the V pool, block for block."""
+    rng = np.random.default_rng(5)
+    layer = {"table": _attention_layer, "cca": _cca_layer,
+             "ring": lambda: _attention_layer(window=2 * BL + 1)}[kind]()
+    slots, nb, plen = 3, 12, 6 * BL
+    pool = layer.init_pool(slots, nb, BL, jnp.float32)
+    cache = layer.init_cache(1, plen, jnp.float32)
+    for side in ("k", "v"):
+        cache[side] = jnp.asarray(
+            rng.standard_normal(cache[side].shape), jnp.float32)
+    row = jnp.asarray([4, 9, 2, 11, 0, 0], jnp.int32)    # 4 real blocks
+    slot = 1
+    if kind == "ring":
+        # 14 real rows: blocks 0..3, of which the ring keeps the last
+        # `ring_blocks` = 3, each in its column; the rest go to null
+        cache["rows"] = jnp.asarray(3 * BL + 2, jnp.int32)
+        ring = ring_blocks(layer.window, BL)
+        assert ring == 3
+        want_row = [0, 1 + slot * ring + 1, 1 + slot * ring + 2,
+                    1 + slot * ring + 0, 0, 0]
+    else:
+        want_row = row
+    out = layer.scatter_prefill(pool, cache, row, slot)
+    assert set(out) == set(pool)
+    got_k, got_v = _apart(out["kv"])
+    want_k, want_v = _prefill_apart(cache, out["kv"].shape[0], want_row)
+    # the null block takes whatever pad blocks were sent there
+    assert np.array_equal(got_k[1:], want_k[1:])
+    assert np.array_equal(got_v[1:], want_v[1:])
+    assert not np.array_equal(got_k[1:], got_v[1:])
 
 
 def test_a_ring_narrower_than_its_window_is_refused():
     q, clean, _, tables, ntoks = _ring_case(
         [5, 40], 2, 2, 8, 4, 10, 2, jnp.float32, seed=1)
     with pytest.raises(ValueError, match="the ring has 3"):
-        paged_decode_attention(q, *clean, tables[:, :3], ntoks, window=10)
+        paged_decode_attention(q, clean, tables[:, :3], ntoks, window=10)
 
 
 def test_an_unwindowed_call_lowers_without_a_trace_of_the_window():
@@ -311,40 +475,86 @@ def test_an_unwindowed_call_lowers_without_a_trace_of_the_window():
     mask), so the cells that have no window do not pay for it."""
     q, clean, _, tables, ntoks = _case(LENGTHS["mixed"], 4, jnp.float32, 3)
     plain = str(jax.make_jaxpr(paged_decode_attention)(
-        q, *clean, tables, ntoks))
+        q, clean, tables, ntoks))
     ring = _ring_case([5, 40], HKV, 4, D, BL, 10, 2, jnp.float32, seed=1)
     windowed = str(jax.make_jaxpr(functools.partial(
         paged_decode_attention, window=10))(
-            ring[0], *ring[1], ring[3], ring[4]))
+            ring[0], ring[1], ring[3], ring[4]))
     # the lower mask's compares and the ring's column are the windowed
     # call's alone
     assert plain.count(" gt ") < windowed.count(" gt ")
     assert plain.count("remainder") < windowed.count("remainder")
     again = str(jax.make_jaxpr(functools.partial(
-        paged_decode_attention, window=0))(q, *clean, tables, ntoks))
+        paged_decode_attention, window=0))(q, clean, tables, ntoks))
     assert again == plain
 
 
-# (Hkv, block_len, D) of a pool -> positions a chunk: the three serving
-# cells' pools, and the float32 pools `chip_smoke.py`'s serve leg and
-# the f32 tests run.  A chunk is a number of bytes, so it follows the
-# pool's row; at the dense cells' shape it has to stay 256 (512 there
-# read 25 % slower at chat's short rows: PERF.md 6, PR 30).
+# (heads, block_len, D) of a pool, the value's columns where its rows
+# hold both sides -> positions a chunk: the four serving cells' pools
+# (Mistral's, Trinity's and ZAYA's keys and values, 2 x Hkv heads a
+# block; Kimi's latent rows), and the float32 pools `chip_smoke.py`'s
+# serve leg and the f32 tests run.  A chunk is a number of bytes of KEY
+# rows, so it follows one side's row and is what it was while the
+# values lay in a pool of their own; at the dense cells' shape it has
+# to stay 256 (512 there read 25 % slower at chat's short rows:
+# PERF.md 6, PR 30).
 CHUNKS = {
-    "dense": ((8, 16, 128), {"bfloat16": 256, "float32": 128}),
-    "latent": ((1, 16, 640), {"bfloat16": 512, "float32": 256}),
-    "narrow": ((2, 16, 128), {"bfloat16": 1024, "float32": 512}),
+    "dense": ((16, 16, 128), None, {"bfloat16": 256, "float32": 128}),
+    "trinity": ((8, 16, 128), None, {"bfloat16": 512, "float32": 256}),
+    "narrow": ((4, 16, 128), None, {"bfloat16": 1024, "float32": 512}),
+    "latent": ((1, 16, 640), 512, {"bfloat16": 512, "float32": 256}),
 }
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("cell", list(CHUNKS))
 def test_chunk_follows_the_pools_row_bytes(cell, dtype):
-    shape, want = CHUNKS[cell]
-    assert chunk_positions((4097,) + shape, jnp.dtype(dtype)) == want[dtype]
+    shape, value_dim, want = CHUNKS[cell]
+    assert chunk_positions((4097,) + shape, jnp.dtype(dtype),
+                           value_dim) == want[dtype]
 
 
-@pytest.mark.parametrize("sides", [2, 1], ids=["two_pools", "one_pool"])
+def _copies(jaxpr, found=None):
+    """Every `dma_start` of a jaxpr and of the jaxprs inside it."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dma_start":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _copies(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("window", [0, 10], ids=["table", "ring"])
+@pytest.mark.parametrize("sides", [2, 1], ids=["keys_and_values", "latent"])
+@pytest.mark.parametrize("group", [1, 4])
+def test_the_kernel_starts_one_copy_a_block(group, sides, window):
+    """`start` issues `group` blocks a trip in straight-line code and
+    the rest one a trip, and is traced at three places (slot 0's first
+    chunk, the next chunk under this one, the next slot's first): with
+    one copy a block that is 3 x (group + 1) `dma_start`s in the
+    kernel's jaxpr (group 1: 3), each of a whole block of the pool,
+    key heads and value heads together.  Two pools traced twice as
+    many, each of half a block."""
+    case = (_ring_case([5, 40], HKV, 2, D, BL, window, sides, jnp.float32, 1)
+            if window else _case(LENGTHS["mixed"], 2, jnp.float32, 1,
+                                 sides=sides))
+    q, pool, _, tables, ntoks = case
+    jaxpr = jax.make_jaxpr(functools.partial(
+        singa_paged_decode, interpret=True, chunk=2 * BL, group=group,
+        scale=0.3, value_dim=D if sides == 1 else None, window=window))(
+            q, pool, tables, ntoks)
+    starts = _copies(jaxpr.jaxpr)
+    assert len(starts) == 3 * (group + (group > 1))
+    for eqn in starts:               # each from the one pool in HBM
+        src = eqn.invars[0].aval
+        assert src.shape == pool.shape and src.dtype == pool.dtype
+
+
+@pytest.mark.parametrize("sides", [2, 1], ids=["keys_and_values", "latent"])
 def test_waits_cover_the_copies_under_the_dma_model(sides):
     """One wait stands for a whole chunk's copies, and a few for the
     live blocks of a slot's last chunk.  Under JAX's model of the TPU's
@@ -360,32 +570,35 @@ def test_waits_cover_the_copies_under_the_dma_model(sides):
     lengths = [1, 2 * BL - 1, 3 * BL - 1, 3 * BL, 5 * BL + 1, FULL, None]
     q, clean, poisoned, tables, ntoks = _case(lengths, 4, jnp.float32, 5,
                                               sides=sides)
-    pad = [None] * (2 - sides)
+    value_dim = D if sides == 1 else None
     got = singa_paged_decode(
-        q, *poisoned, *pad, tables, ntoks, chunk=3 * BL, group=2, scale=0.3,
-        value_dim=D, interpret=pltpu.InterpretParams(
+        q, poisoned, tables, ntoks, chunk=3 * BL, group=2, scale=0.3,
+        value_dim=value_dim, interpret=pltpu.InterpretParams(
             dma_execution_mode="on_wait", detect_races=True))
-    want = paged_attention_reference(q, *clean, *pad, tables, ntoks,
-                                     scale=0.3)
+    want = paged_attention_reference(q, clean, tables, ntoks, scale=0.3,
+                                     value_dim=value_dim)
     assert np.max(np.abs(np.asarray(got) - np.asarray(want))) <= 2e-6
 
 
 @pytest.mark.parametrize("bad", [{"value_dim": 0}, {"value_dim": D + 1},
-                                 {"value_dim": D // 2, "both": True}],
-                         ids=["none", "wider_than_a_row", "of_a_value_pool"])
+                                 {"heads": 3}],
+                         ids=["none", "wider_than_a_row",
+                              "of_no_heads_behind_the_keys"])
 def test_kernel_refuses_values_that_are_no_part_of_a_row(bad):
+    """A latent pool's value is some leading columns of its rows; a
+    pool without `value_dim` holds as many value heads as key heads."""
     q, clean, _, tables, ntoks = _case(LENGTHS["mixed"], 1, jnp.float32, 0)
-    v_pool = clean[1] if bad.pop("both", False) else None
-    with pytest.raises(ValueError, match="values of"):
-        paged_decode_attention(q, clean[0], v_pool, tables, ntoks, **bad)
+    pool = clean[:, :bad.pop("heads", HKV)]
+    with pytest.raises(ValueError, match="values of|no value heads"):
+        paged_decode_attention(q, pool, tables, ntoks, **bad)
 
 
 def test_kernel_refuses_a_shape_it_cannot_tile_on_the_chip(monkeypatch):
     from singa_tpu.ops import attention
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     q, clean, _, tables, ntoks = _case(LENGTHS["mixed"], 1, jnp.float32, 0)
-    with pytest.raises(ValueError, match=r"\(25, 2, 4, 8\)"):
-        paged_decode_attention(q, *clean, tables, ntoks)
+    with pytest.raises(ValueError, match=r"\(25, 4, 4, 8\)"):
+        paged_decode_attention(q, clean, tables, ntoks)
 
 
 # -- the compiled decode program ---------------------------------------------
@@ -409,27 +622,32 @@ def _engine(**spec):
 
 
 def test_decode_program_never_materialises_the_gathered_tables():
-    """What `_attn_paged` used to build per layer and side, (S, T, Hkv,
-    bl, D) and its (S, Hkv, T*bl, D) transpose, is in no value of the
+    """What `_attn_paged` used to build per layer, (S, T, 2 Hkv, bl, D)
+    and its (S, 2 Hkv, T*bl, D) transpose, is in no value of the
     lowered cb decode program; the reference's lowering, the same
     search's control, has both."""
     engine = _engine(max_new_tokens=8, cb_slots=3)
     spec = engine.spec
     s, t, bl = spec.cb_slots, spec.cb_blocks_per_slot, spec.cb_block_len
-    hkv, d = LM_KV_HEADS, LM_HEAD_DIM
-    gathered = (f"tensor<{s}x{t}x{hkv}x{bl}x{d}x",
-                f"tensor<{s}x{hkv}x{t * bl}x{d}x")
+    heads, d = 2 * LM_KV_HEADS, LM_HEAD_DIM
+    gathered = (f"tensor<{s}x{t}x{heads}x{bl}x{d}x",
+                f"tensor<{s}x{heads}x{t * bl}x{d}x")
     shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.int32)  # noqa: E731
+    # one pool a layer goes in (and, donated, comes out): its keys and
+    # values together
+    for entry in engine._pools_spec().values():
+        assert set(entry) == {"kv"}
+        assert entry["kv"].shape == (spec.cb_pool_blocks, heads, bl, d)
     text = jax.jit(engine._build_cb_decode(), donate_argnums=(1,)).lower(
         engine.params, engine._pools_spec(), shape(s), shape(s),
         shape(s, t), jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
     assert "while" in text                     # the interpreted kernel
     for needle in gathered:
         assert needle not in text, needle
-    pool = jax.ShapeDtypeStruct((spec.cb_pool_blocks, hkv, bl, d),
+    pool = jax.ShapeDtypeStruct((spec.cb_pool_blocks, heads, bl, d),
                                 jnp.float32)
     control = jax.jit(paged_attention_reference).lower(
-        jax.ShapeDtypeStruct((s, LM_HEADS, d), jnp.float32), pool, pool,
+        jax.ShapeDtypeStruct((s, LM_HEADS, d), jnp.float32), pool,
         shape(s, t), shape(s)).as_text()
     for needle in gathered:
         assert needle in control, needle

@@ -146,7 +146,7 @@ def test_the_ring_holds_the_window_and_the_table_every_row(lm):
                                             30).astype(np.int32)
     _, pools = _prefill_then_decode(net, params, seq, 5, slot=2,
                                     poison=False)
-    ring, table = pools["attention0"]["k"], pools["attention1"]["k"]
+    ring, table = pools["attention0"]["kv"], pools["attention1"]["kv"]
     assert ring.shape[0] == 3 * RING + 1 and table.shape[0] == 8 + 1
     # slot 2's ring is blocks 7..9; slots 0 and 1 decoded nothing but
     # their idle writes at position 0
@@ -187,8 +187,8 @@ def test_the_allocator_reserves_per_kind(lm):
                       num_blocks=3 * 14 + 1, block_len=BL)
     assert (kv.window, kv.ring_blocks, kv.per_slot_state) == (WINDOW, RING,
                                                               True)
-    assert kv.pools["attention0"]["k"].shape[0] == 3 * RING + 1
-    assert kv.pools["attention1"]["k"].shape[0] == 3 * 14 + 1
+    assert kv.pools["attention0"]["kv"].shape[0] == 3 * RING + 1
+    assert kv.pools["attention1"]["kv"].shape[0] == 3 * 14 + 1
     # a request of 50 tokens: 13 growing blocks from the free list, and
     # of the ring what it always holds
     assert (kv.blocks_for(50), kv.ring_blocks) == (13, RING)
@@ -219,7 +219,10 @@ def test_state_bytes_by_kind(lm):
     net, _, _ = lm
     per = state_bytes(net, BL, jnp.float32)
     block = 2 * CFG["num_key_value_heads"] * BL * CFG["head_dim"] * 4
-    assert per == {"slot": 0, "block": 2 * block, "window_block": 2 * block}
+    # two layers of each kind; a copy of the kernel moves ONE layer's
+    # block, its keys and values together
+    assert per == {"slot": 0, "block": 2 * block, "window_block": 2 * block,
+                   "block_copy": block, "window_block_copy": block}
     total = pool_bytes(net, 3 * 14 + 1, BL, jnp.float32, 3)
     counts = 3 * 3 * 4                 # three sparse layers' routing counts
     assert total == (2 * block * (3 * 14 + 1) + 2 * block * (3 * RING + 1)
@@ -348,6 +351,8 @@ def test_cb_tokens_are_generates_through_rings_that_wrap(lm):
     snap = engine.stats.snapshot()
     assert snap["cb_ring_blocks"] == RING
     assert snap["cb_window_block_bytes"] == snap["cb_block_bytes"] > 0
+    assert (snap["cb_block_copy_bytes"] == snap["cb_window_block_copy_bytes"]
+            == snap["cb_block_bytes"] // 2)        # two layers a kind
     assert 0 < snap["cb_window_block_steps"] < snap["cb_live_block_steps"]
     assert 0 < snap["cb_window_block_share"] < 1
     assert snap["cb_routed_max_load"] > 0
@@ -359,6 +364,8 @@ def test_serve_stats_exports_the_window_counters():
     st = ServeStats()
     st.gauge("cb_ring_blocks", 129)
     st.gauge("cb_window_block_bytes", 393216)
+    st.gauge("cb_block_copy_bytes", 32768)
+    st.gauge("cb_window_block_copy_bytes", 32768)
     st.observe_cb_step(64, 1000, 10000, 6400)
     st.observe_cb_step(64, 1000, 10400, 6500)
     st.observe_cb_step(0, 0)                  # a step that decoded nothing
@@ -371,6 +378,8 @@ def test_serve_stats_exports_the_window_counters():
     text = registry.render_prometheus()
     assert "singa_serve_cb_window_block_steps_total 12900" in text
     assert "singa_serve_cb_ring_blocks 129" in text
+    assert "singa_serve_cb_block_copy_bytes 32768" in text
+    assert "singa_serve_cb_window_block_copy_bytes 32768" in text
     plain = ServeStats()
     plain.observe_cb_step(4, 10, 40)
     assert plain.snapshot()["cb_window_block_share"] is None
@@ -403,19 +412,20 @@ def _cached_before(layer, params, x, entry, pos):
 
 
 def _paged_before(layer, params, x, entry, tables, ntoks):
-    """`AttentionLayer.apply_paged` as it stood before them."""
+    """`AttentionLayer.apply_paged` as it stood before them (over the
+    one pool of keys and values, PR 38)."""
     _, s, _ = x.shape
-    bl = entry["k"].shape[2]
+    bl = entry["kv"].shape[2]
     q, k, v = layer.qkv(params, x, ntoks, DECODE_CTX)
     bidx = tables[jnp.arange(s), ntoks // bl]
     off = ntoks % bl
-    k_pool = write_token(entry["k"], bidx, off, k[0].transpose(1, 0, 2))
-    v_pool = write_token(entry["v"], bidx, off, v[0].transpose(1, 0, 2))
-    out = paged_decode_attention(q[0].transpose(1, 0, 2), k_pool, v_pool,
-                                 tables, ntoks)
+    new = jnp.concatenate([k[0], v[0]], 0).transpose(1, 0, 2)
+    pool = write_token(entry["kv"], bidx, off, new)
+    out = paged_decode_attention(q[0].transpose(1, 0, 2), pool, tables,
+                                 ntoks)
     out = out.reshape(1, s, -1)
     out = layer._proj(params, layer.wo, out.astype(x.dtype), DECODE_CTX)
-    return out, {"k": k_pool, "v": v_pool}
+    return out, {"kv": pool}
 
 
 def test_a_layer_without_the_options_is_bit_identical_to_the_one_before():
@@ -429,7 +439,8 @@ def test_a_layer_without_the_options_is_bit_identical_to_the_one_before():
     assert sorted(s.name for s in layer.param_specs) == [
         "attn0/wk", "attn0/wo", "attn0/wq", "attn0/wv"]
     assert set(layer.init_cache(1, 16, jnp.float32)) == {"k", "v"}
-    assert layer.init_pool(4, 9, 4, jnp.float32)["k"].shape[0] == 9
+    assert layer.init_pool(4, 9, 4, jnp.float32)["kv"].shape[:2] == (
+        9, 2 * layer.kv_heads)
     assert state_bytes(net, 4)["window_block"] == 0
     full = net._resolve_params(params)
     rng = np.random.default_rng(2)
@@ -450,8 +461,8 @@ def test_a_layer_without_the_options_is_bit_identical_to_the_one_before():
     got, new = layer.apply_paged(full, xs, pool, tables, ntoks)
     want, new0 = _paged_before(layer, full, xs, pool, tables, ntoks)
     assert np.array_equal(np.asarray(got), np.asarray(want))
-    for side in ("k", "v"):
-        assert np.array_equal(np.asarray(new[side]), np.asarray(new0[side]))
+    assert set(new) == set(new0) == {"kv"}
+    assert np.array_equal(np.asarray(new["kv"]), np.asarray(new0["kv"]))
     # and the programs: the walkers trace to the same equations with the
     # new options named and switched off as with none named
     named = build_net(_named_off(cfg), "kTrain", discover_input_shapes(

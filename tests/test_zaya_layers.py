@@ -277,13 +277,15 @@ def test_one_entry_holds_paged_rows_and_slot_tails_and_both_are_counted(lm):
                 CFG["head_dim"])
     pools = init_pools(net, 7, BL, jnp.float32, 3)
     entry = pools["cca0"]
-    assert entry["k"].shape == entry["v"].shape == (7, hk, BL, d)
+    assert entry["kv"].shape == (7, 2 * hk, BL, d)
     assert entry["conv"].shape == entry["mix"].shape == (3, 1, (h + hk) * d)
     assert entry["vprev"].shape == (3, 1, d)
     assert pools["zaya_moe0"]["routed"].shape == (3,)
     layers = CFG["num_hidden_layers"]
     per = state_bytes(net, BL, jnp.float32)
     assert per["block"] == layers * 2 * hk * BL * d * 4
+    assert per["block_copy"] == 2 * hk * BL * d * 4     # one layer's block
+    assert per["window_block_copy"] == 0
     assert per["slot"] == layers * (2 * (h + hk) * d + d) * 4
     assert pool_bytes(net, 7, BL, jnp.float32, 3) == (
         7 * per["block"] + 3 * per["slot"] + layers * 3 * 4)

@@ -1,7 +1,8 @@
-"""What `cb_live_block_share`, `cb_prefill_fill_share` and the share of
-prefills through a flash-kernel rung read under one of the benchmark's
-serving cells (a builder's tool; the benchmark does
-not report the counters):
+"""What `cb_live_block_share`, `cb_prefill_fill_share`, the share of
+prefills through a flash-kernel rung and the bytes one copy of the paged
+kernel moves (`cb_block_copy_bytes`, a ring's `cb_window_block_copy_bytes`)
+read under one of the benchmark's serving cells (a builder's tool; the
+benchmark does not report the counters):
 
     python tools/live_block_share.py --workload serve-chat-r80 --seed 1 \\
         --seconds 45
@@ -29,7 +30,9 @@ def stop(self, *args, **kwargs):
                              "cb_prefills", "cb_flash_prefills",
                              "cb_prefill_rows",
                              "cb_prefill_width_rows", "cb_slot_occupancy",
-                             "cb_block_utilization", "cb_steps")},
+                             "cb_block_utilization", "cb_steps",
+                             "cb_block_bytes", "cb_block_copy_bytes",
+                             "cb_window_block_copy_bytes")},
         "cb_decode_steps": self.stats.cb_decode_steps,
         "cb_flash_prefill_share": (
             snap["cb_flash_prefills"] / snap["cb_prefills"]

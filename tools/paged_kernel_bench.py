@@ -1,12 +1,13 @@
 """Microbenchmark of the paged decode attention kernel on the chip (a
 builder's tool, not part of the benchmark): the kernel alone at the
-serving geometries (the dense cells' key and value pools, the Kimi
-cell's one pool of latent rows, the ZAYA cell's narrow key and value
-pools, the Trinity cell's pools under a growing table and under a ring
-read through a window), each under its slot mixes, against the gather formulation, as
+serving geometries (the dense cells' pool of keys and values, the Kimi
+cell's pool of latent rows, the ZAYA cell's narrow pool, the Trinity
+cell's pool under a growing table and under a ring read through a
+window), each under its slot mixes, against the gather formulation, as
 seconds a layer and as a share of the live bytes' time at the memory
-roofline, at the chunk the kernel's rule gives and at fixed ones beside
-it.  `python tools/paged_kernel_bench.py [geometry ...]` prints one JSON
+roofline, at the chunk and the issue group the kernel's rule gives and
+at fixed ones beside them, the bytes one copy moves on every row.
+`python tools/paged_kernel_bench.py [geometry ...]` prints one JSON
 line a measurement; fails off the TPU."""
 import functools
 import json
@@ -26,9 +27,10 @@ HBM_BYTES_S = 819e9
 
 
 class Geometry(NamedTuple):
-    """One caller's arguments: `sides` pools of (slots x table + 1, hkv,
-    bl, d) bf16, the calls a decode step makes, and what the caller
-    passes beside the arrays."""
+    """One caller's arguments: a pool of (slots x table + 1, sides x
+    hkv, bl, d) bf16 (`sides` 2: a block's keys and then its values; 1:
+    latent rows whose leading columns are the value), the calls a
+    decode step makes, and what the caller passes beside the arrays."""
     slots: int
     heads: int
     hkv: int
@@ -94,9 +96,9 @@ def timed(fn, args, layers, reps=20):
 
 def chain(attn, layers):
     """`layers` calls in one program, each fed the one before."""
-    def run(q, pools, tables, ntoks):
+    def run(q, pool, tables, ntoks):
         def body(_, x):
-            out = attn(x, *pools, tables, ntoks).astype(x.dtype)
+            out = attn(x, pool, tables, ntoks).astype(x.dtype)
             return jnp.pad(out, ((0, 0), (0, 0),
                                  (0, x.shape[-1] - out.shape[-1])))
         return jax.lax.fori_loop(0, layers, body, q)
@@ -108,17 +110,19 @@ def bench(name, g):
     nb = g.slots * g.table + 1
     dt = jnp.bfloat16
     q = jnp.asarray(rng.standard_normal((g.slots, g.heads, g.d)), dt)
-    pools = [jnp.asarray(rng.standard_normal((nb, g.hkv, g.bl, g.d)), dt)
-             for _ in range(g.sides)]
-    pools += [None] * (2 - g.sides)
+    pool = jnp.asarray(
+        rng.standard_normal((nb, g.sides * g.hkv, g.bl, g.d)), dt)
+    copy_bytes = pool[0].size * pool.dtype.itemsize
     tables = jnp.asarray(rng.permutation(np.arange(1, nb))
                          .reshape(g.slots, g.table).astype(np.int32))
-    how = {"scale": g.scale, "value_dim": g.value_dim}
+    how = {"scale": g.scale}
+    if g.sides == 1:
+        how["value_dim"] = g.value_dim
     if g.window:
         how["window"] = g.window
     gather = functools.partial(pa.paged_attention_reference, **how)
     # float32 pools (chip_smoke.py's serve leg serves them): parity only
-    f32 = [a if a is None else a.astype(jnp.float32) for a in [q] + pools]
+    f32 = [q.astype(jnp.float32), pool.astype(jnp.float32)]
     last = list(mixes(name, g, np.random.default_rng(1)).values())[0]
     err = jnp.max(jnp.abs(
         pa.paged_decode_attention(*f32, tables, jnp.asarray(last), **how)
@@ -129,28 +133,34 @@ def bench(name, g):
         nt = jnp.asarray(ntoks)
         first = np.maximum(ntoks - g.window + 1, 0) // g.bl if g.window else 0
         live = int(np.sum(ntoks // g.bl - first + 1))
-        need = live * g.sides * g.hkv * g.bl * g.d * 2 / HBM_BYTES_S
-        ref = gather(q, *pools, tables, nt)
-        ruled = pa.chunk_positions(pools[0].shape, pools[0].dtype)
-        rows = {"gather": (gather, None),
+        need = live * copy_bytes / HBM_BYTES_S
+        ref = gather(q, pool, tables, nt)
+        ruled = pa.chunk_positions(pool.shape, pool.dtype,
+                                   how.get("value_dim"))
+        fixed = functools.partial(pa.singa_paged_decode, interpret=False,
+                                  **how)
+        rows = {"gather": (gather, None, None),
                 "kernel": (functools.partial(pa.paged_decode_attention,
-                                             **how), ruled)}
+                                             **how), ruled, pa._ISSUE_GROUP)}
         for pos in (128, 256, 512, 1024):
-            rows[f"kernel_{pos}"] = (functools.partial(
-                pa.singa_paged_decode, interpret=False, chunk=pos, **how),
-                pos)
-        for label, (fn, chunk) in rows.items():
-            one = jax.jit(fn)(q, *pools, tables, nt)
+            rows[f"kernel_{pos}"] = (functools.partial(fixed, chunk=pos),
+                                     pos, pa._ISSUE_GROUP)
+        for group in (4, 8, 16):
+            rows[f"kernel_group_{group}"] = (functools.partial(
+                fixed, chunk=ruled, group=group), ruled, group)
+        for label, (fn, chunk, group) in rows.items():
+            one = jax.jit(fn)(q, pool, tables, nt)
             err = float(jnp.max(jnp.abs(one.astype(jnp.float32)
                                         - ref.astype(jnp.float32))))
-            sec = timed(chain(fn, g.layers), (q, pools, tables, nt),
+            sec = timed(chain(fn, g.layers), (q, pool, tables, nt),
                         g.layers)
             print(json.dumps({
                 "geometry": name, "mix": mix, "what": label,
                 "live_blocks": live,
                 "live_block_share": live / (g.slots * g.table),
-                "chunk_positions": chunk,
-                "copies_a_call": live * g.sides,
+                "chunk_positions": chunk, "issue_group": group,
+                "copy_bytes": copy_bytes,
+                "copies_a_call": live,
                 "us_a_layer": sec * 1e6,
                 f"ms_a_step_{g.layers}_layers": sec * g.layers * 1e3,
                 "roofline_share": need / sec,
