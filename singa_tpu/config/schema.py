@@ -254,13 +254,26 @@ class KDAConfig:
 
 @dataclass
 class MLAConfig:
-    """kMLA: multi-head latent attention without positions (NoPE)."""
+    """kMLA: multi-head latent attention.  Without positions (NoPE) as
+    it stands; `rope_theta` > 0 rotates the shared key's rope dims and
+    every query head's; `q_lora_rank` > 0 makes the query through a
+    bottleneck of that rank with an RMSNorm of its own."""
     num_heads: int = 8
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     kv_lora_rank: int = 512
-    epsilon: float = 1e-5        # of the latent's RMSNorm
+    epsilon: float = 1e-5        # of the latent's (and the query's) RMSNorm
+    q_lora_rank: int = 0         # 0 = a full-rank wq
+    rope_theta: float = 0.0      # 0 = NoPE
+
+
+@dataclass
+class MTPConfig:
+    """kMTP: the entry of a multi-token prediction module (the width of
+    the draft distribution it keeps per serving slot)."""
+    vocab_size: int = 0
+    epsilon: float = 1e-5        # of its two RMSNorms
 
 
 @dataclass
@@ -390,6 +403,7 @@ class LayerConfig:
     routed_moe_param: Optional[RoutedMoEConfig] = _msg(RoutedMoEConfig)
     kda_param: Optional[KDAConfig] = _msg(KDAConfig)
     mla_param: Optional[MLAConfig] = _msg(MLAConfig)
+    mtp_param: Optional[MTPConfig] = _msg(MTPConfig)
     cca_param: Optional[CCAConfig] = _msg(CCAConfig)
     zaya_moe_param: Optional[ZayaMoEConfig] = _msg(ZayaMoEConfig)
     embed_param: Optional[EmbedConfig] = _msg(EmbedConfig)

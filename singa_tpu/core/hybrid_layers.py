@@ -4,9 +4,13 @@ sparse LMs:
   kKDA        Kimi Delta Attention: a gated delta rule whose state is a
               fixed (H, Dk, Dv) float32 matrix per sequence plus the
               short convolution's tail (ops/kda.py)
-  kMLA        multi-head latent attention without positions: what is
-              cached per token is one latent row (kv_lora_rank + rope
-              dims) shared by every head
+  kMLA        multi-head latent attention, without positions or with
+              its rope dims rotated, the query full-rank or through a
+              bottleneck: what is cached per token is one latent row
+              (kv_lora_rank + rope dims) shared by every head
+  kMTP        the entry of a multi-token prediction module: the main
+              stack's output beside the NEXT token's embedding, and per
+              serving slot the draft the module last made
   kRoutedMoE  sigmoid router with a selection bias over ALL routed
               experts, the top k renormalised and scaled, a shared
               expert on every token, and only the experts this process
@@ -223,15 +227,25 @@ class KDALayer(Layer):
 
 # ---------------------------------------------------------------------------
 
+# Query heads `_attend_expanded` scores at a time: a chunk's f32 scores
+# are heads x rows x rows (32 heads at a prefill of 1,024 rows 134 MB,
+# the 128 of a wide model 537 MB at once).
+_HEAD_CHUNK = 32
+
+
 @register_layer("kMLA")
 class MLALayer(Layer):
-    """Multi-head latent attention, NoPE, over (B, S, E).
+    """Multi-head latent attention over (B, S, E).
 
-    q = x Wq -> H x (nope + rope dims); x Wkva -> rank + rope dims: the
-    first `rank` through RMSNorm are the latent c, the rest k_pe, one
-    row shared by all heads and never rotated; [k_nope | v] = c Wkvb
-    per head; scores (q_nope . k_nope + q_pe . k_pe) / sqrt(nope +
-    rope), causal softmax, Wo.  What is cached per token is [c | k_pe].
+    q = x Wq -> H x (nope + rope dims), or with `q_lora_rank`
+    q = RMSNorm(x Wqa) Wqb; x Wkva -> rank + rope dims: the first
+    `rank` through RMSNorm are the latent c, the rest k_pe, one row
+    shared by all heads; [k_nope | v] = c Wkvb per head; scores (q_nope
+    . k_nope + q_pe . k_pe) / sqrt(nope + rope), causal softmax, Wo.
+    With `rope_theta` k_pe and every head's q_pe are rotated at the
+    token's position (half against half inside the rope dims), without
+    it nothing is (NoPE).  What is cached per token is [c | k_pe], k_pe
+    as it is scored (rotated).
 
     A chunk of tokens expands the cached rows to per-head keys and
     values; a decode step absorbs Wkvb into the query and the output
@@ -248,11 +262,14 @@ class MLALayer(Layer):
         self.nope, self.rope = p.qk_nope_head_dim, p.qk_rope_head_dim
         self.vdim, self.rank, self.eps = (p.v_head_dim, p.kv_lora_rank,
                                           p.epsilon)
+        self.q_rank, self.theta = p.q_lora_rank, p.rope_theta
         self.causal = True
         self.out_shape = (b, s, e)
         h, se = self.heads, 1.0 / math.sqrt(e)
         dec = _declare_with_default
-        self.wq = dec(self, 0, "wq", (e, h * (self.nope + self.rope)), se, 1)
+        q_in = self.q_rank or e
+        self.wq = dec(self, 0, "wq", (q_in, h * (self.nope + self.rope)),
+                      1.0 / math.sqrt(q_in), 1)
         self.w_kva = dec(self, 1, "w_kva", (e, self.rank + self.rope), se)
         self.w_kvb = dec(self, 2, "w_kvb",
                          (self.rank, h * (self.nope + self.vdim)),
@@ -260,6 +277,9 @@ class MLALayer(Layer):
         self.wo = dec(self, 3, "wo", (h * self.vdim, e),
                       1.0 / math.sqrt(h * self.vdim), 0)
         self.kv_norm = _declare_const(self, "kv_norm", (self.rank,), 1.0)
+        if self.q_rank:
+            self.wq_a = dec(self, 4, "wq_a", (e, self.q_rank), se)
+            self.q_norm = _declare_const(self, "q_norm", (self.q_rank,), 1.0)
 
     @property
     def latent_dim(self) -> int:
@@ -275,16 +295,30 @@ class MLALayer(Layer):
         and gather (0.75 ms each way a layer at 12 k blocks)."""
         return -(-self.latent_dim // 128) * 128
 
-    def _project(self, params, x):
-        """x (B, T, E) -> q (B, T, H, nope + rope), latent rows
-        (B, T, rank + rope), both in x's dtype."""
+    def _project(self, params, x, positions=None):
+        """x (B, T, E) at `positions` (T,) or (B, T), 0 .. T - 1 where
+        not given -> q (B, T, H, nope + rope), latent rows (B, T, rank +
+        rope), both in x's dtype."""
         b, t, _ = x.shape
-        q = _dot(x, params[self.wq]).astype(x.dtype).reshape(
+        if positions is None:
+            positions = jnp.arange(t)
+        cq = x
+        if self.q_rank:
+            cq = _rms(_dot(x, params[self.wq_a]), params[self.q_norm],
+                      self.eps).astype(x.dtype)
+        q = _dot(cq, params[self.wq]).reshape(
             b, t, self.heads, self.nope + self.rope)
         kva = _dot(x, params[self.w_kva])
         c = _rms(kva[..., :self.rank], params[self.kv_norm], self.eps)
-        lat = jnp.concatenate([c, kva[..., self.rank:]], -1)
-        return q, lat.astype(x.dtype)
+        k_pe = kva[..., self.rank:]
+        if self.theta:
+            turn = lambda a: cca_ops.partial_rope(           # noqa: E731
+                a, positions, self.rope, self.theta)
+            q = jnp.concatenate(
+                [q[..., :self.nope], turn(q[..., self.nope:])], -1)
+            k_pe = turn(k_pe[:, :, None])[:, :, 0]
+        lat = jnp.concatenate([c, k_pe], -1)
+        return q.astype(x.dtype), lat.astype(x.dtype)
 
     def _wkvb(self, params):
         return params[self.w_kvb].reshape(self.rank, self.heads,
@@ -292,10 +326,24 @@ class MLALayer(Layer):
 
     def _attend_expanded(self, params, q, lat, allowed):
         """q (B, T, H, .) against latent rows lat (B, L, .) expanded to
-        per-head keys and values; allowed (B or 1, T, L) bool."""
+        per-head keys and values; allowed (B or 1, T, L) bool.  More
+        than `_HEAD_CHUNK` heads go a chunk of them at a time."""
+        b, t, h = q.shape[:3]
+        wkvb = self._wkvb(params)
+        if h <= _HEAD_CHUNK or h % _HEAD_CHUNK or t == 1:
+            return self._attend_heads(q, lat, wkvb, allowed)
+        n = h // _HEAD_CHUNK
+        o = jax.lax.map(
+            lambda a: self._attend_heads(a[0], lat, a[1], allowed),
+            (jnp.moveaxis(q.reshape(b, t, n, _HEAD_CHUNK, -1), 2, 0),
+             jnp.moveaxis(wkvb.reshape(self.rank, n, _HEAD_CHUNK, -1), 1, 0)))
+        return jnp.moveaxis(o, 0, 2).reshape(b, t, -1)
+
+    def _attend_heads(self, q, lat, wkvb, allowed):
+        """`_attend_expanded` for the heads q and wkvb (rank, heads, nope
+        + vdim) hold."""
         b, t = q.shape[:2]
-        kv = jnp.einsum("blr,rhd->blhd", lat[..., :self.rank],
-                        self._wkvb(params),
+        kv = jnp.einsum("blr,rhd->blhd", lat[..., :self.rank], wkvb,
                         preferred_element_type=jnp.float32).astype(q.dtype)
         k_nope, v = kv[..., :self.nope], kv[..., self.nope:]
         sc = (jnp.einsum("bthd,blhd->bhtl", q[..., :self.nope], k_nope,
@@ -366,7 +414,7 @@ class MLALayer(Layer):
 
     def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
         t = x.shape[1]
-        q, lat = self._project(params, x)
+        q, lat = self._project(params, x, pos + jnp.arange(t))
         cache = jax.lax.dynamic_update_slice(
             entry["c"], lat.astype(entry["c"].dtype), (0, pos, 0))
         qpos = pos + jnp.arange(t)[:, None]
@@ -384,22 +432,35 @@ class MLALayer(Layer):
         the pool itself, one key row a token shared by all heads whose
         first `rank` columns are the value, so the paged kernel attends
         it and reads blocks 0 .. ntoks[s] // bl of slot s's table row
-        only; Wkvb goes into the query and the output here, in XLA."""
+        only; Wkvb goes into the query and the output here, in XLA.
+
+        x (1, S * R, E), R > 1 (a verify step, models/generate.py): slot
+        s's R tokens one after another, at positions ntoks[s] ..
+        ntoks[s] + R - 1.  Their rows are written one after another too
+        (they may lie in two blocks), then all R attend in one call,
+        row j up to its own position."""
         pool = entry["c"]
-        s, bl = x.shape[1], pool.shape[1]
-        q, lat = self._project(params, x[0][:, None, :])
+        s, bl = ntoks.shape[0], pool.shape[1]
+        r = x.shape[1] // s
+        q, lat = self._project(params, x[0].reshape(s, r, -1),
+                               ntoks[:, None] + jnp.arange(r))
         lat = jnp.pad(lat, ((0, 0), (0, 0),
                             (0, self.pool_row - self.latent_dim)))
-        bidx = tables[jnp.arange(s), ntoks // bl]
         rows = jnp.arange(bl)[None, :, None]
-        blocks = jnp.where(rows == (ntoks % bl)[:, None, None],
-                           lat.astype(pool.dtype), pool[bidx])
-        pool = pool.at[bidx].set(blocks)
+        for j in range(r):
+            at = ntoks + j
+            bidx = tables[jnp.arange(s), at // bl]
+            blocks = jnp.where(rows == (at % bl)[:, None, None],
+                               lat[:, j:j + 1].astype(pool.dtype), pool[bidx])
+            pool = pool.at[bidx].set(blocks)
+        q = self._absorb_query(params, q.reshape(s * r, self.heads, -1),
+                               self.pool_row)
         o_lat = paged_decode_attention(
-            self._absorb_query(params, q[:, 0], self.pool_row),
-            pool[:, None], tables, ntoks, value_dim=self.rank,
+            q.reshape(s, r * self.heads, -1), pool[:, None], tables, ntoks,
+            value_dim=self.rank, rows=r,
             scale=1.0 / math.sqrt(self.nope + self.rope))
-        o = self._expand_output(params, o_lat)
+        o = self._expand_output(
+            params, o_lat.reshape(s * r, self.heads, self.rank))
         return _dot(o, params[self.wo]).astype(x.dtype)[None], {"c": pool}
 
     @staticmethod
@@ -499,7 +560,10 @@ class RoutedMoELayer(Layer):
         return out.reshape(b, t, e), entry
 
     def apply_paged(self, params, x, entry, tables, ntoks):
-        out, counts = self._ffn(params, x[0], ntoks > 0)
+        busy = ntoks > 0
+        rows = x.shape[1] // busy.shape[0]     # of a slot (a verify step)
+        out, counts = self._ffn(params, x[0],
+                                jnp.repeat(busy, rows) if rows > 1 else busy)
         return out[None], {"routed": counts}
 
     @staticmethod
@@ -784,6 +848,56 @@ class ZayaMoELayer(Layer):
     @staticmethod
     def scatter_prefill(pool, cache, table_row, slot=None):
         return pool
+
+
+@register_layer("kMTP")
+class MTPLayer(Layer):
+    """The entry of a multi-token prediction module over (B, S, E).
+    Sources: the embedding and the main stack's output h (after its
+    final norm).  For position i, with the token AFTER it,
+
+        z_i = [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)] W_eh
+
+    (2 E -> E); the module's block, its final norm and the main
+    model's head follow and give the distribution of t_{i+2}.  `apply`
+    sees one sequence, so it shifts the embedding by a token itself
+    (the last row gets zeros); the decode walkers hand `combine` the
+    next tokens' embedding as they have it (models/generate.py).
+
+    Serving state, per slot: the draft the module last made for the
+    slot's next step and the distribution it was drawn from (what the
+    verify step's accept-or-resample rule needs of it)."""
+
+    def setup(self, src_shapes):
+        p = self.cfg.mtp_param
+        if p is None:
+            raise LayerError(f"{self.name}: mtp_param required")
+        b, s, e = tuple(src_shapes[0])
+        self.vocab, self.eps = p.vocab_size, p.epsilon
+        self.out_shape = (b, s, e)
+        self.w_eh = _declare_with_default(self, 0, "w_eh", (2 * e, e),
+                                          1.0 / math.sqrt(2 * e))
+        self.e_norm = _declare_const(self, "e_norm", (e,), 1.0)
+        self.h_norm = _declare_const(self, "h_norm", (e,), 1.0)
+
+    def combine(self, params, e_next, h):
+        z = jnp.concatenate(
+            [_rms(e_next, params[self.e_norm], self.eps),
+             _rms(h, params[self.h_norm], self.eps)], -1).astype(h.dtype)
+        return _dot(z, params[self.w_eh]).astype(h.dtype)
+
+    def apply(self, params, srcs, ctx):
+        e, h = srcs
+        e_next = jnp.concatenate([e[:, 1:], jnp.zeros_like(e[:, :1])], 1)
+        return self.combine(params, e_next, h)
+
+    def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
+                  dtype):
+        if num_slots < 1:
+            raise ValueError(f"{self.name}: a draft per slot needs "
+                             f"num_slots >= 1")
+        return {"draft": jnp.zeros((num_slots,), jnp.int32),
+                "q": jnp.zeros((num_slots, self.vocab), jnp.float32)}
 
 
 @register_layer("kScaledResidual")

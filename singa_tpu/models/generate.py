@@ -25,7 +25,7 @@ re-used here only for its projection weight).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +58,15 @@ Cache = Dict[str, CacheEntry]         # layer name -> its entry
 # step's routing counts) and kZayaMoE (the same; its second source and
 # second output are the router's state, an edge from expert layer to
 # expert layer) implement it.
+#
+# A net with a kMTP layer (`hybrid_lm(mtp=...)`) holds a multi-token
+# prediction MODULE behind it: a second, small stack with a latent
+# cache of its own, fed by the main stack's output and the NEXT
+# token's embedding, under the main model's embedding and head.  The
+# walkers run one part a call: the main stack as ever (the module's
+# layers are left out), or, handed the main stack's output `hidden`,
+# the module (`draft_cached`, `draft_paged`).  The serving engine's
+# verify-and-draft step is made of both (serve/engine.py).
 
 
 def keeps_state(layer) -> bool:
@@ -72,48 +81,103 @@ def init_cache(net: NeuralNet, batchsize: int, max_len: int,
 
 
 
-def _walk(net: NeuralNet, params, tokens, state: Cache, step):
+_HEADS = ("kLMHead", "kLMHeadLoss", "kSoftmaxLoss")
+
+
+class MTPModule(NamedTuple):
+    """A net's multi-token prediction module: its entry layer (kMTP),
+    the names of its layers, the last of them (what the head projects
+    for it), and the entry's two sources (the embedding, the main
+    stack's output)."""
+    entry: str
+    layers: frozenset
+    last: str
+    embed: str
+    hidden: str
+
+
+def mtp_module(net: NeuralNet) -> Optional[MTPModule]:
+    """The net's module, None where it has none."""
+    entries = [n for n in net.topo if net.layers[n].cfg.type == "kMTP"]
+    if not entries:
+        return None
+    if len(entries) > 1:
+        raise ValueError(f"several kMTP layers {entries}: one module a net")
+    entry = entries[0]
+    inside, last = {entry}, entry
+    for name in net.topo:
+        cfg = net.layers[name].cfg
+        if cfg.type not in _HEADS and inside & set(cfg.srclayers):
+            inside.add(name)
+            last = name
+    embed, hidden = net.layers[entry].cfg.srclayers
+    return MTPModule(entry, frozenset(inside), last, embed, hidden)
+
+
+def _walk(net: NeuralNet, params, tokens, state: Cache, step, hidden=None):
     """The LM over `tokens` with `step(layer, full, x, entry)` ->
     (out, entry) at every layer that keeps state; every other layer
-    runs its normal `apply`.  Returns (logits float32, new state)."""
+    runs its normal `apply`.  Returns (logits float32, new state, the
+    main stack's output where the net has a module).
+
+    With `hidden` the walk is the MODULE's: `tokens` are, for every
+    position, the token AFTER it; their embedding and `hidden` go into
+    the module's entry, its layers run, and the head projects the last
+    of them."""
     full = net._resolve_params(params)
     outputs: Dict[str, Any] = {}
     new_state: Cache = dict(state)
-    logits = None
+    head = head_src = None
+    module = mtp_module(net)
+    drafting = hidden is not None
+    if drafting:
+        outputs[module.hidden] = hidden
     for idx, name in enumerate(net.topo):
         layer = net.layers[name]
         ltype = layer.cfg.type
-        srcs = [net._src_out(outputs, s, name) for s in layer.cfg.srclayers]
         if ltype == "kSequenceData":
             outputs[name] = {"input": tokens, "target": tokens}
-        elif ltype == "kSeqLabel":
+            continue
+        if ltype == "kSeqLabel":
             outputs[name] = tokens
+            continue
+        if module is not None and ltype not in _HEADS:
+            mine = name in module.layers or (drafting
+                                             and name == module.embed)
+            if mine != drafting:
+                continue       # the other part's layer
+        srcs = [net._src_out(outputs, s, name) for s in layer.cfg.srclayers]
+        if ltype == "kMTP":
+            outputs[name] = layer.combine(full, *srcs)
         elif keeps_state(layer):
             outputs[name], new_state[name] = step(
                 layer, full, srcs[0] if len(srcs) == 1 else srcs,
                 state[name])
-        elif ltype == "kLMHead":
-            outputs[name] = layer.apply(full, srcs, DECODE_CTX)
-            logits = outputs[name]
-        elif ltype == "kLMHeadLoss":
-            # reuse the fused loss layer's projection to emit logits
-            logits = layer.project_logits(full, srcs[0])
-            outputs[name] = logits
+        elif ltype in ("kLMHead", "kLMHeadLoss"):
+            head, head_src = layer, srcs[0]    # after the walk: the
+            outputs[name] = None               # module's end may follow
         elif ltype == "kSoftmaxLoss":
             outputs[name] = None     # no loss at decode
         else:
             ctx = Context(batch={}, train=False, rng=None, layer_index=idx,
                           mesh=None, compute_dtype=None)
             outputs[name] = layer.apply(full, srcs, ctx)
-    if logits is None:
+    if head is None:
         raise ValueError("net has no kLMHead/kLMHeadLoss layer")
-    return logits.astype(jnp.float32), new_state
+    if drafting:
+        head_src = outputs[module.last]
+    # the fused loss layer's projection is reused to emit logits
+    logits = (head.apply(full, [head_src], DECODE_CTX)
+              if head.cfg.type == "kLMHead"
+              else head.project_logits(full, head_src))
+    return (logits.astype(jnp.float32), new_state,
+            None if module is None or drafting else outputs[module.hidden])
 
 
 def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
                    cache: Cache, pos,
                    kmask: Optional[jnp.ndarray] = None,
-                   plen=None) -> Tuple[jnp.ndarray, Cache]:
+                   plen=None, with_hidden: bool = False):
     """Run the LM over a (B, T) token chunk at absolute offset `pos`.
     Returns (logits (B, T, V) float32, updated cache).  `kmask`
     (B, max_len) bool marks per-sequence attendable key positions
@@ -122,24 +186,55 @@ def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
     only the chunk's first `plen` rows are real (the cb prefill's right
     padding): attention needs no telling, its causal mask hides what
     follows, but a recurrence must stop its state at the last real
-    row."""
-    return _walk(net, params, tokens, cache,
+    row.  `with_hidden` hands the main stack's output (B, T, E) back
+    third: what a net's MTP module takes (`draft_cached`)."""
+    out = _walk(net, params, tokens, cache,
+                lambda layer, full, x, entry: layer.apply_cached(
+                    full, x, entry, pos, kmask=kmask, plen=plen))
+    return out if with_hidden else out[:2]
+
+
+def draft_cached(net: NeuralNet, params, hidden, next_tokens, cache: Cache,
+                 pos, kmask=None, plen=None) -> Tuple[jnp.ndarray, Cache]:
+    """The net's MTP module over a chunk: `hidden` (B, T, E) the main
+    stack's output at the chunk's positions, `next_tokens` (B, T) the
+    token after each.  Returns (logits (B, T, V) float32: position i's
+    are the module's for token i + 2; the cache with the module's
+    entries updated)."""
+    return _walk(net, params, next_tokens, cache,
                  lambda layer, full, x, entry: layer.apply_cached(
-                     full, x, entry, pos, kmask=kmask, plen=plen))
+                     full, x, entry, pos, kmask=kmask, plen=plen),
+                 hidden=hidden)[:2]
 
 
 def forward_paged(net: NeuralNet, params, tokens: jnp.ndarray,
-                  pools: Cache, tables, ntoks
-                  ) -> Tuple[jnp.ndarray, Cache]:
+                  pools: Cache, tables, ntoks, with_hidden: bool = False):
     """One decode step for S slots against the serving state.
     `tokens` (1, S) int32 — slot s's last sampled token on the seq
     axis; `tables` (S, T) int32 block tables; `ntoks` (S,) int32
     tokens already written per slot (= the incoming token's absolute
     position; 0 for a slot that is not in use).  Returns (logits
-    (1, S, V) float32, updated pools)."""
-    return _walk(net, params, tokens, pools,
+    (1, S, V) float32, updated pools).
+
+    `tokens` (1, S * R): R tokens a slot, one after another, at
+    positions ntoks[s] .. ntoks[s] + R - 1 (a verify step: the last
+    token and R - 1 drafted ones); every layer of the net that keeps
+    rows per token has to take them (kMLA does).  `with_hidden` as
+    `forward_cached`'s."""
+    out = _walk(net, params, tokens, pools,
+                lambda layer, full, x, entry: layer.apply_paged(
+                    full, x, entry, tables, ntoks))
+    return out if with_hidden else out[:2]
+
+
+def draft_paged(net: NeuralNet, params, hidden, next_tokens, pools: Cache,
+                tables, ntoks) -> Tuple[jnp.ndarray, Cache]:
+    """`draft_cached` against the serving state: `hidden` (1, S * R, E)
+    and `next_tokens` (1, S * R) at positions ntoks[s] .. ntoks[s] +
+    R - 1 of every slot."""
+    return _walk(net, params, next_tokens, pools,
                  lambda layer, full, x, entry: layer.apply_paged(
-                     full, x, entry, tables, ntoks))
+                     full, x, entry, tables, ntoks), hidden=hidden)[:2]
 
 
 def scatter_prefill(pools: Cache, cache: Cache, table_row,
@@ -159,14 +254,13 @@ def scatter_prefill(pools: Cache, cache: Cache, table_row,
     return out
 
 
-def _sample(logits: jnp.ndarray, key, temperature: float,
-            top_k: int, top_p: float) -> jnp.ndarray:
-    """logits: (B, V) -> (B,) int32.  temperature 0 = greedy."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+def _filtered(logits: jnp.ndarray, temperature: float, top_k: int,
+              top_p: float) -> jnp.ndarray:
+    """(..., V) logits over the temperature (> 0), those the top-k and
+    nucleus filters drop at -1e30: what a token is drawn from."""
     logits = logits / temperature
     if top_k > 0 and top_k < logits.shape[-1]:
-        kth = jax.lax.top_k(logits, top_k)[0][:, -1:]
+        kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
         logits = jnp.where(logits < kth, -1e30, logits)
     if 0.0 < top_p < 1.0:
         # nucleus: keep the smallest prefix of descending-prob tokens
@@ -179,7 +273,74 @@ def _sample(logits: jnp.ndarray, key, temperature: float,
         kth = jnp.min(jnp.where(before < top_p, desc, jnp.inf),
                       axis=-1, keepdims=True)
         logits = jnp.where(logits < kth, -1e30, logits)
-    return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
+    return logits
+
+
+def _sample(logits: jnp.ndarray, key, temperature: float,
+            top_k: int, top_p: float) -> jnp.ndarray:
+    """logits: (B, V) -> (B,) int32.  temperature 0 = greedy."""
+    if temperature == 0.0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jax.random.categorical(
+        key, _filtered(logits, temperature, top_k, top_p),
+        axis=-1).astype(jnp.int32)
+
+
+def _at(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+    """table (..., V) at ids (...)."""
+    return jnp.take_along_axis(table, ids[..., None], axis=-1)[..., 0]
+
+
+def sample_logprob(logits: jnp.ndarray, key, temperature: float, top_k: int,
+                   top_p: float):
+    """logits (B, V) -> (token (B,) int32, its log-probability (B,)
+    float32, the distribution (B, V) float32 it was drawn from).  At
+    temperature 0 the token is the largest logit's, the log-probability
+    the plain softmax's and no distribution is handed back (None)."""
+    if temperature == 0.0:
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return tok, _at(jax.nn.log_softmax(logits, axis=-1), tok), None
+    z = _filtered(logits, temperature, top_k, top_p)
+    tok = jax.random.categorical(key, z, axis=-1).astype(jnp.int32)
+    logp = jax.nn.log_softmax(z, axis=-1)
+    return tok, _at(logp, tok), jnp.exp(logp)
+
+
+def verify_draft(logits: jnp.ndarray, draft: jnp.ndarray, q, key,
+                 temperature: float, top_k: int, top_p: float):
+    """The accept-or-resample rule of speculative sampling, one draft a
+    sequence.  logits (S, 2, V): the main model's at the row of the
+    last token (the distribution p of the token the draft stands for)
+    and at the draft's row; draft (S,) int32, drawn from q (S, V).
+    The draft is accepted with probability min(1, p(d) / q(d)); else
+    the token is drawn from norm(max(0, p - q)); behind an accepted
+    draft a bonus token is drawn from the second row.  Every emitted
+    token is distributed as the main model alone would have drawn it.
+    At temperature 0 (q is not read) a draft is accepted where it IS the
+    largest logit's token, and the tokens are the largest logits'.
+
+    Returns (first token, bonus token, accepted, and the log-probability
+    of each of the two under the row it came from): (S,) each; the
+    bonus counts only where `accepted`."""
+    if temperature == 0.0:
+        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        lp = _at(jax.nn.log_softmax(logits, axis=-1), toks)
+        return toks[:, 0], toks[:, 1], draft == toks[:, 0], lp[:, 0], lp[:, 1]
+    z = _filtered(logits, temperature, top_k, top_p)
+    logp = jax.nn.log_softmax(z, axis=-1)
+    p = jnp.exp(logp[:, 0])
+    k_accept, k_again, k_bonus = jax.random.split(key, 3)
+    accepted = (jax.random.uniform(k_accept, draft.shape) * _at(q, draft)
+                < _at(p, draft))
+    left = jnp.maximum(p - q, 0.0)
+    # p == q to the last bit leaves nothing; then every draft is accepted
+    left = jnp.where(left.sum(-1, keepdims=True) > 0, left, p)
+    again = jax.random.categorical(k_again, jnp.log(left), axis=-1)
+    first = jnp.where(accepted, draft, again).astype(jnp.int32)
+    bonus = jax.random.categorical(k_bonus, z[:, 1], axis=-1).astype(
+        jnp.int32)
+    return (first, bonus, accepted, _at(logp[:, 0], first),
+            _at(logp[:, 1], bonus))
 
 
 @partial(jax.jit, static_argnums=(0, 3, 5, 6, 7, 8, 9))

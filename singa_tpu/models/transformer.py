@@ -150,7 +150,8 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
               train_steps: int = 1000,
               learning_rate: float = 3e-4, tie_head: bool = False,
               scaled_residual: bool = False, post_norm: bool = False,
-              embed_scale: float = 0.0) -> ModelConfig:
+              embed_scale: float = 0.0,
+              mtp: Optional[Dict] = None) -> ModelConfig:
     """A decoder whose layers differ: block i is
 
         x += mixer_i(rmsnorm(x));  x += ffn_i(rmsnorm(x))
@@ -172,7 +173,15 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
     RMSNorm and a fused head, tied to the embedding with `tie_head`.
     Layer names follow `transformer_lm`'s: ln{i}a, <kind>{i}, res{i}a,
     ln{i}b, ffn{i} or <kind>{i}, res{i}b, ln_f, loss; a norm after a
-    sublayer is pn{i}a / pn{i}b."""
+    sublayer is pn{i}a / pn{i}b.
+
+    `mtp` = {"mixer": {...}, "ffn": {...}} adds a multi-token
+    prediction module: the entry `mtp` (kMTP: ln_f's output beside the
+    next token's embedding), one block as the others numbered after
+    them (ln{n}a ... res{n}b, n = len(mixers)) and a final norm of its
+    own, `mtp_ln_f`; embedding and head are the main model's.  The
+    serving engine drafts with it (docs/SERVING.md); training does not
+    count its loss yet."""
     if len(mixers) != len(ffns):
         raise ValueError(f"{len(mixers)} mixers for {len(ffns)} ffns")
     kinds = {"kda": ("kKDA", "kda_param"), "mla": ("kMLA", "mla_param"),
@@ -193,7 +202,18 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
                          "scale": embed_scale}},
     ]
     src, router = "embed", None      # the last layer with a router state
-    for i, (mixer, ffn) in enumerate(zip(mixers, ffns)):
+    blocks = list(zip(mixers, ffns))
+    if mtp is not None:
+        blocks.append((mtp["mixer"], mtp["ffn"]))
+    for i, (mixer, ffn) in enumerate(blocks):
+        if mtp is not None and i == len(mixers):
+            layers += [
+                {"name": "ln_f", "type": "kRMSNorm", "srclayers": src,
+                 **norm},
+                {"name": "mtp", "type": "kMTP", "srclayers": ["embed", "ln_f"],
+                 "mtp_param": {"vocab_size": vocab_size,
+                               "epsilon": epsilon}}]
+            src = "mtp"
         for half, spec, want in (
                 ("a", mixer, ("kda", "mla", "attention", "cca")),
                 ("b", ffn, ("dense", "moe", "zaya_moe"))):
@@ -226,9 +246,13 @@ def hybrid_lm(vocab_size: int, embed_dim: int, mixers: List[Dict],
             "softmaxloss_param": {"topk": 1}}
     if tie_head:
         head.update(share_param=["embed/embedding"], param=[{"name": "w"}])
-    layers += [
-        {"name": "ln_f", "type": "kRMSNorm", "srclayers": src, **norm},
-        head]
+    if mtp is None:
+        layers.append({"name": "ln_f", "type": "kRMSNorm", "srclayers": src,
+                       **norm})
+    else:
+        layers.append({"name": "mtp_ln_f", "type": "kRMSNorm",
+                       "srclayers": src, **norm})
+    layers.append(head)
     return model_config_from_dict({
         "name": f"hybrid-lm-{len(mixers)}L{embed_dim}E",
         "train_steps": train_steps, "display_frequency": 50,

@@ -123,23 +123,25 @@ def ring_blocks(window: int, block_len: int) -> int:
 
 
 def paged_attention_reference(q, pool, tables, ntoks, *, scale=None,
-                              value_dim=None, window=0):
+                              value_dim=None, window=0, rows=1):
     """Gather formulation, `paged_decode_attention`'s arguments.  q
     (S, H, D); pool (num_blocks, 2 * Hkv, bl, D), or (num_blocks, Hkv,
     bl, D) with `value_dim`; tables (S, T) int32; ntoks (S,) int32.
     Returns (S, H, D) in q's dtype: softmax(q k^T / sqrt(D)) v over
     positions <= ntoks[s], and with `window` over the last `window` of
-    them, the table row read as a ring."""
+    them, the table row read as a ring.  With `rows` R > 1 q holds R
+    query rows a slot, row j's heads attending positions <= ntoks[s] +
+    j."""
     s, h, d = q.shape
     bl = pool.shape[2]
     hkv = key_heads(pool.shape, value_dim)
     t = tables.shape[1]
     groups = h // hkv
     # (S, heads, T*bl, D)
-    rows = pool[tables].transpose(0, 2, 1, 3, 4).reshape(
+    lines = pool[tables].transpose(0, 2, 1, 3, 4).reshape(
         s, pool.shape[1], t * bl, d).astype(q.dtype)
-    kk = rows[:, :hkv]
-    vv = rows[:, hkv:] if value_dim is None else kk[..., :value_dim]
+    kk = lines[:, :hkv]
+    vv = lines[:, hkv:] if value_dim is None else kk[..., :value_dim]
     if window:
         # column j holds the newest logical block b <= ntoks // bl with
         # b % T == j (an older one it held has been overwritten)
@@ -150,12 +152,19 @@ def paged_attention_reference(q, pool, tables, ntoks, *, scale=None,
                    & (pos > ntoks[:, None] - window))
     else:
         allowed = jnp.arange(t * bl)[None, :] <= ntoks[:, None]  # (S, T*bl)
+    if rows > 1:
+        # (S, 1, G, T*bl): the heads of query row j see j positions more
+        ahead = jnp.repeat(jnp.arange(rows), groups // rows)
+        seen = (jnp.arange(t * bl)[None, None, :]
+                <= ntoks[:, None, None] + ahead[None, :, None])[:, None]
+        allowed = allowed | seen[:, 0].any(1)         # values: the last row's
     qg = q.reshape(s, hkv, groups, d)
     scores = jnp.einsum("shgd,shkd->shgk", qg, kk,
                         preferred_element_type=jnp.float32)
     scores = (scores / jnp.sqrt(jnp.float32(d)) if scale is None
               else scores * scale)
-    scores = jnp.where(allowed[:, None, None], scores, _attention.NEG_INF)
+    scores = jnp.where(seen if rows > 1 else allowed[:, None, None], scores,
+                       _attention.NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # a masked lane's probability is an exact zero, but 0 * (inf | nan)
     # is nan: values the mask hides must not reach the product
@@ -165,7 +174,7 @@ def paged_attention_reference(q, pool, tables, ntoks, *, scale=None,
 
 
 def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
-            *, bl, cb, tw, scale, group, window):
+            *, bl, cb, tw, scale, group, window, qrows=1):
     """Grid step s attends slot s.  `pool` lies in HBM; `into` is the
     buffer (2, heads, cb*bl, D): two chunks of cb blocks each, a
     block's (heads, bl, D) slab, its key heads and behind them its
@@ -174,7 +183,9 @@ def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
     of chunks walked so far (`base_ref`), because the copy of slot
     s+1's first chunk is started under slot s's last.  With a `window`
     the walk starts at the window's first block and the table row is a
-    ring of `tw` columns."""
+    ring of `tw` columns.  With `qrows` R > 1 the slot's query heads are
+    R rows of heads, row j at position ntoks + j: the walk goes to the
+    last row's horizon and row j's scores stop at its own."""
     s = pl.program_id(0)
     slots = pl.num_programs(0)
     span = cb * bl
@@ -186,7 +197,10 @@ def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
         index read from beyond the table."""
         if window:
             return ntoks_ref[slot]
-        return jnp.minimum(ntoks_ref[slot], tw * bl - 1)
+        last = ntoks_ref[slot]
+        if qrows > 1:
+            last = last + (qrows - 1)
+        return jnp.minimum(last, tw * bl - 1)
 
     def walk(upto):
         """(the first block a walk up to position `upto` reads, how
@@ -274,7 +288,14 @@ def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
         if last:
             # the only chunk with positions past the slot's horizon:
             # rows no copy wrote hold whatever the buffer held before
-            sc = jnp.where(lane <= n, sc, _attention.NEG_INF)
+            upto = n
+            if qrows > 1:
+                # query row j ends qrows - 1 - j before the last; the
+                # heads of one row lie together
+                of_head = jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1)
+                upto = n - sum((of_head < j * (g // qrows)).astype(jnp.int32)
+                               for j in range(1, qrows))
+            sc = jnp.where(lane <= upto, sc, _attention.NEG_INF)
             v = jnp.where(rows() <= n, v, jnp.zeros_like(v))
         m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -333,7 +354,7 @@ def _check_tiling(q, pool, value_dim):
 
 
 def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
-                           value_dim=None, window=0):
+                           value_dim=None, window=0, rows=1):
     """q (S, H, D) against a pool (num_blocks, 2 * Hkv, bl, D), a
     block's key heads and then its value heads, through `tables` (S, T)
     int32 and `ntoks` (S,) int32.  Returns (S, H, D) in q's dtype,
@@ -353,6 +374,12 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
     (position p in column (p // bl) % T, T >= `ring_blocks(W, bl)`);
     the walk starts at the window's first block.
 
+    `rows` R > 1 (a verify step: a slot's last token and its drafts):
+    q is (S, R * H, D), a slot's R query rows one after another, each
+    H heads; row j stands at position ntoks[s] + j and attends
+    positions 0..ntoks[s] + j, all R rows written before the call.  A
+    block is still read once.  Not with a window.
+
     Compiled by Mosaic on the TPU, interpreted elsewhere
     (`ops.attention._on_tpu`)."""
     if window and tables.shape[1] < ring_blocks(window, pool.shape[2]):
@@ -361,9 +388,12 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
             f"{ring_blocks(window, pool.shape[2])} blocks of "
             f"{pool.shape[2]}; the ring has {tables.shape[1]}")
     hkv = key_heads(pool.shape, value_dim)
-    if q.shape[1] % hkv:
-        raise ValueError(f"{q.shape[1]} query heads over "
+    if q.shape[1] % (hkv * rows):
+        raise ValueError(f"{q.shape[1]} query heads in {rows} rows over "
                          f"{hkv} key/value heads")
+    if rows > 1 and (window or hkv > 1):
+        raise ValueError("several query rows a slot are attended over one "
+                         "shared key head and no window")
     d = q.shape[-1]
     if value_dim is not None and not 0 < value_dim <= d:
         raise ValueError(f"values of {value_dim} columns from the key "
@@ -376,7 +406,7 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
         chunk=chunk_positions(pool.shape, pool.dtype, value_dim),
         group=1 if interpret else _ISSUE_GROUP, value_dim=value_dim,
         scale=1.0 / math.sqrt(d) if scale is None else float(scale),
-        window=int(window))
+        window=int(window), rows=int(rows))
 
 
 # Jitted, so that the layers of one program share one trace and one
@@ -384,9 +414,10 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
 # named as the kernel: the function's name is the name of the op, and so
 # of the row, that holds the kernel's time in a device trace.
 @functools.partial(jax.jit, static_argnames=("interpret", "chunk", "group",
-                                             "scale", "value_dim", "window"))
+                                             "scale", "value_dim", "window",
+                                             "rows"))
 def singa_paged_decode(q, pool, tables, ntoks, *, interpret, chunk, scale,
-                       value_dim=None, group=_ISSUE_GROUP, window=0):
+                       value_dim=None, group=_ISSUE_GROUP, window=0, rows=1):
     s, h, d = q.shape
     _, heads, bl, _ = pool.shape
     hkv = key_heads(pool.shape, value_dim)
@@ -401,7 +432,8 @@ def singa_paged_decode(q, pool, tables, ntoks, *, interpret, chunk, scale,
 
     out = pl.pallas_call(
         functools.partial(_kernel, bl=bl, cb=cb, tw=tw, scale=scale,
-                          group=group, window=window),
+                          group=group, window=window,
+                          **({"qrows": rows} if rows > 1 else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),

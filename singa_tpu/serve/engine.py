@@ -42,7 +42,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +50,10 @@ import numpy as np
 
 from .. import obs
 from ..obs import perf
-from ..models.generate import (_sample, forward_cached, forward_paged,
-                               init_cache, scatter_prefill)
+from ..models.generate import (_sample, draft_cached, draft_paged,
+                               forward_cached, forward_paged, init_cache,
+                               mtp_module, sample_logprob, scatter_prefill,
+                               verify_draft)
 from ..ops.attention import singa_flash_prefill
 from ..utils import faults
 from ..utils.checkpoint import CheckpointManager
@@ -301,6 +303,46 @@ def _left_pad_mask(prompt_len: int, max_len: int,
     return kpos >= (prompt_len - plens)[:, None]
 
 
+class FirstToken(NamedTuple):
+    """What the prefill of a model that drafts hands the scheduler: the
+    first token and the main model's log-probability of it, the first
+    draft and the module's of that."""
+    token: int
+    logprob: float
+    draft: int
+    draft_logprob: float
+
+
+class StepTokens(NamedTuple):
+    """A fetched verify-and-draft step, (S,) or (S, 2) host arrays: the
+    one or two tokens each slot yielded (`count` of them) with the main
+    model's log-probabilities, the rows each slot holds after the step,
+    and the draft made for its next step with the module's
+    log-probability of it."""
+    tokens: np.ndarray
+    count: np.ndarray
+    logprobs: np.ndarray
+    ntoks: np.ndarray
+    draft: np.ndarray
+    draft_logprob: np.ndarray
+
+
+# rows of a verify-and-draft step's (STEP_ROWS * S + tail,) int32 result,
+# S wide each: the token to step from next and the rows held then (what
+# the NEXT step takes as they lie on the device), the two tokens, how
+# many of them count, the draft; and as float32 bits the two tokens'
+# log-probabilities and the draft's
+STEP_ROWS = 9
+
+
+#: the name the module's part of the two cb programs is traced under
+MTP_SCOPE = "mtp_module"
+
+
+def _bits(x):
+    return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+
+
 def _tree_spec(tree):
     return jax.tree_util.tree_map(
         lambda a: (tuple(a.shape), str(jnp.asarray(a).dtype)), tree)
@@ -338,6 +380,10 @@ class InferenceEngine:
         # assignments, experts touched and, where a layer counts it,
         # the busiest expert's assignments
         self._cb_tail = max(routed.values(), default=0)
+        # a model with a multi-token prediction module drafts with it:
+        # the prefill hands back a first draft, the decode program is
+        # the verify-and-draft step (two rows a slot, one or two tokens)
+        self._mtp = mtp_module(net)
         self.stats = stats if stats is not None else ServeStats()
         self.log = log_fn
         self.ckpt = (CheckpointManager(workspace, log_fn=log_fn)
@@ -402,6 +448,13 @@ class InferenceEngine:
         # crosses degraded_after, because an engine whose poller
         # cannot stay alive is quietly going stale
         self._poll_death_streak = 0
+
+    @property
+    def drafts(self) -> bool:
+        """Whether a decode step verifies a draft and makes the next (the
+        net has an MTP module): a slot then yields one or two tokens a
+        step, with their log-probabilities (docs/SERVING.md)."""
+        return self._mtp is not None
 
     def note_poll_death(self) -> int:
         self._poll_death_streak += 1
@@ -740,6 +793,41 @@ class InferenceEngine:
             tok0 = _sample(last, key, temperature, top_k, top_p)[0]
             return tok0, scatter_prefill(pools, cache, row, slot, net)
 
+        mtp = self._mtp
+
+        def prefill_and_draft(params, pools, tokens, plen, row, slot, key):
+            """Both caches filled: the main stack's as `prefill` fills
+            it, the module's from the main stack's output beside the
+            tokens shifted by one, the first token behind the prompt's
+            last.  Returns ([first token, first draft, the bits of their
+            two log-probabilities], pools)."""
+            dtype = jax.tree_util.tree_leaves(params)[0].dtype
+            cache = init_cache(net, 1, p_len, dtype)
+            logits, cache, hidden = forward_cached(
+                net, params, tokens, cache, 0, plen=plen, with_hidden=True)
+            at_last = lambda a: jax.lax.dynamic_index_in_dim(  # noqa: E731
+                a[0], plen - 1, axis=0, keepdims=True)
+            k_tok, k_draft = jax.random.split(key)
+            tok0, lp0, _ = sample_logprob(at_last(logits), k_tok,
+                                          temperature, top_k, top_p)
+            nxt = jax.lax.dynamic_update_slice(
+                jnp.roll(tokens, -1, axis=1), tok0[None], (0, plen - 1))
+            with jax.named_scope(MTP_SCOPE):
+                logits, cache = draft_cached(net, params, hidden, nxt, cache,
+                                             0, plen=plen)
+            draft, lq, q = sample_logprob(at_last(logits), k_draft,
+                                          temperature, top_k, top_p)
+            pools = scatter_prefill(pools, cache, row, slot, net)
+            state = dict(pools[mtp.entry])
+            state["draft"] = state["draft"].at[slot].set(draft[0])
+            if q is not None:
+                state["q"] = state["q"].at[slot].set(q[0])
+            pools[mtp.entry] = state
+            return jnp.concatenate([tok0, draft, _bits(lp0), _bits(lq)]), pools
+
+        if mtp is not None:
+            prefill = prefill_and_draft
+
         # the function's name is the program's in a device trace
         # (`jit_cb_prefill`, every rung).  A state per slot goes to the
         # slot's own place: there `row` carries the slot's index behind
@@ -770,6 +858,8 @@ class InferenceEngine:
             nxt = _sample(logits[0], key, temperature, top_k, top_p)
             return nxt, pools
 
+        if self._mtp is not None:
+            return self._build_cb_verify()
         if not self._routed_layers:
             return cb_decode
         step = cb_decode
@@ -780,13 +870,68 @@ class InferenceEngine:
         def cb_decode(params, pools, tokens, ntoks, tables, key):  # noqa: F811
             nxt, pools = step(params, pools, tokens[:spec.cb_slots], ntoks,
                               tables, key)
-            tail = self._cb_tail
-            routed = sum(
-                c if c.shape[0] == tail else jnp.pad(
-                    c, (0, tail - c.shape[0]))
-                for c in (pools[name]["routed"]
-                          for name in self._routed_layers))
-            return jnp.concatenate([nxt, routed]), pools
+            return jnp.concatenate([nxt, self._routed_counts(pools)]), pools
+
+        return cb_decode
+
+    def _routed_counts(self, pools):
+        """The step's routing counts, summed over the routed layers."""
+        tail = self._cb_tail
+        return sum(c if c.shape[0] == tail else jnp.pad(
+            c, (0, tail - c.shape[0]))
+            for c in (pools[name]["routed"] for name in self._routed_layers))
+
+    def _build_cb_verify(self):
+        """The decode step of a model that drafts: ONE compiled program
+        at fixed slot count S, two rows a slot.  Slot s holds its last
+        token t_n (not yet cached, n = ntoks[s]) and, on the device,
+        the draft d for t_{n+1} with the distribution q it was drawn
+        from.  The main stack runs [t_n @ n, d @ n + 1];
+        `verify_draft` accepts d or draws t_{n+1} anew and, behind an
+        accepted draft, draws t_{n+2} from the second row; the module
+        then runs rows n and n + 1 (beside t_{n+1} and t_{n+2}) and
+        drafts from the last row that counts.  A slot advances by 1 or
+        2; a rejected draft's rows (the main stack's and the module's at
+        n + 1) stay in the pool and are overwritten by the next step,
+        which starts there.
+
+        `carry` is a step's result as it lies on the device, or the
+        host's (last tokens, rows held) in its first two rows: how far
+        a slot advanced need not come to the host before the next step
+        goes out."""
+        net, spec, mtp = self.net, self.spec, self._mtp
+        temperature, top_k, top_p = (float(spec.temperature),
+                                     int(spec.top_k), float(spec.top_p))
+        s = int(spec.cb_slots)
+
+        def cb_decode(params, pools, carry, tables, key):
+            last, ntoks = carry[:s], carry[s:2 * s]
+            state = pools[mtp.entry]
+            k_verify, k_draft = jax.random.split(key)
+            both = lambda a, b: jnp.stack([a, b], 1).reshape(1, 2 * s)  # noqa: E731
+            logits, pools, hidden = forward_paged(
+                net, params, both(last, state["draft"]), pools, tables,
+                ntoks, with_hidden=True)
+            first, bonus, accepted, lp1, lp2 = verify_draft(
+                logits[0].reshape(s, 2, -1), state["draft"], state["q"],
+                k_verify, temperature, top_k, top_p)
+            with jax.named_scope(MTP_SCOPE):
+                logits, pools = draft_paged(
+                    net, params, hidden, both(first, bonus), pools, tables,
+                    ntoks)
+            logits = logits[0].reshape(s, 2, -1)
+            draft, lq, q = sample_logprob(
+                jnp.where(accepted[:, None], logits[:, 1], logits[:, 0]),
+                k_draft, temperature, top_k, top_p)
+            pools[mtp.entry] = {"draft": draft,
+                                "q": state["q"] if q is None else q}
+            count = jnp.where(ntoks > 0, 1 + accepted.astype(jnp.int32), 0)
+            rows = [jnp.where(accepted, bonus, first), ntoks + count, first,
+                    bonus, count, draft, _bits(lp1), _bits(lp2), _bits(lq)]
+            assert len(rows) == STEP_ROWS
+            if self._routed_layers:
+                rows.append(self._routed_counts(pools))
+            return jnp.concatenate(rows), pools
 
         return cb_decode
 
@@ -800,6 +945,14 @@ class InferenceEngine:
         return jax.eval_shape(lambda: init_pools(
             self.net, self.spec.cb_pool_blocks, self.spec.cb_block_len,
             self.serve_dtype, self.spec.cb_slots))
+
+    @property
+    def _cb_width(self) -> int:
+        """Length of the decode program's token argument and result: the
+        slots' tokens (a verify step's `STEP_ROWS` rows of them) and the
+        routing counts behind."""
+        rows = STEP_ROWS if self.drafts else 1
+        return rows * int(self.spec.cb_slots) + self._cb_tail
 
     def _cb_prefill_name(self, p_len: int) -> str:
         """What the compile and cost accounts (`obs.perf`) call the
@@ -869,13 +1022,14 @@ class InferenceEngine:
                 elif which == "decode":
                     fn = self._build_cb_decode()
                     s = spec.cb_slots
-                    tok = jax.ShapeDtypeStruct((s + self._cb_tail,),
-                                               jnp.int32)
-                    ntoks = jax.ShapeDtypeStruct((s,), jnp.int32)
-                    tables = jax.ShapeDtypeStruct(
-                        (s, spec.cb_blocks_per_slot), jnp.int32)
+                    tok = jax.ShapeDtypeStruct((self._cb_width,), jnp.int32)
+                    small = [tok, jax.ShapeDtypeStruct(
+                        (s, spec.cb_blocks_per_slot), jnp.int32)]
+                    if not self.drafts:  # else the rows held ride in `tok`
+                        small.insert(1, jax.ShapeDtypeStruct((s,),
+                                                             jnp.int32))
                     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-                        p_spec, pools, tok, ntoks, tables, rng).compile()
+                        p_spec, pools, *small, rng).compile()
                 else:
                     raise ValueError(f"unknown cb program {which!r}")
             self.stats.count("compiles")
@@ -938,12 +1092,19 @@ class InferenceEngine:
                 jnp.int32(1), row, jnp.zeros((2,), jnp.uint32))
         return pools
 
-    def fetch_cb_prefill(self, flying) -> int:
-        """The first sampled token of a dispatched prefill (waits)."""
+    def fetch_cb_prefill(self, flying):
+        """The first sampled token of a dispatched prefill (waits): an
+        int, or where the model drafts a `FirstToken`."""
         tok0, t0, width = flying
         t = time.perf_counter()
         with obs.span("engine.cb_prefill_fetch"):
-            tok0 = int(tok0)
+            if self.drafts:
+                got = np.asarray(tok0)
+                lps = got[2:].view(np.float32)
+                tok0 = FirstToken(int(got[0]), float(lps[0]), int(got[1]),
+                                  float(lps[1]))
+            else:
+                tok0 = int(tok0)
         now = time.perf_counter()
         self.cb_wait = (t, now)
         perf.observe_step(self._cb_prefill_name(width), now - t0)
@@ -952,26 +1113,14 @@ class InferenceEngine:
 
     def run_cb_decode(self, params, pools, tokens: np.ndarray,
                       ntoks: np.ndarray, tables: np.ndarray):
-        """One decode step for all S slots.  Returns ((S,) int32 next
-        tokens on host, new pools).  `pools` was donated."""
-        self._maybe_stall()
-        compiled = self._compile_cb("decode")
-        t0 = time.perf_counter()
+        """One decode step for all S slots: `dispatch_cb_decode` and
+        `fetch_cb_decode` under one span.  Returns ((S,) int32 next
+        tokens on host, or a `StepTokens` where the model drafts; new
+        pools).  `pools` was donated."""
         with obs.span("engine.cb_decode"):
-            with obs.span("engine.upload"):
-                args = self._cb_decode_args(tokens, ntoks, tables)
-            with obs.span("engine.dispatch"):
-                nxt, pools = compiled(params, pools, *args)
-                # on its way to the host as soon as the device has it
-                nxt.copy_to_host_async()
-            t = time.perf_counter()
-            with obs.span("engine.fetch"):
-                nxt = self._cb_tokens(np.asarray(nxt))
-        now = time.perf_counter()
-        self.cb_wait = (t, now)
-        self._cb_read_at = now
-        perf.observe_step("cb_decode", now - t0)
-        return nxt, pools
+            flying, pools = self._hand_over(params, pools, tokens, ntoks,
+                                            tables)
+            return self._fetch(flying), pools
 
     def _cb_decode_args(self, tokens, ntoks, tables):
         """The decode program's small inputs as the call takes them.
@@ -980,48 +1129,74 @@ class InferenceEngine:
         call's own transfer moves them: three `jnp.asarray` cost a
         step 0.8 ms of Python with the device idle (PERF.md 6, PR 28).
         The caller keeps them unchanged until the step is read, or
-        hands over copies."""
+        hands over copies.  Where the model drafts the rows each slot
+        holds travel in `tokens` (behind the host's tokens, or as the
+        step before left them on the device) and `ntoks` is not an
+        argument of the program."""
         if isinstance(tokens, np.ndarray):
-            if self._cb_tail:
+            behind = self._cb_width - tokens.shape[0]
+            if self.drafts:
                 tokens = np.concatenate(
-                    [tokens, np.zeros((self._cb_tail,), tokens.dtype)])
+                    [tokens, ntoks,
+                     np.zeros((behind - ntoks.shape[0],), np.int32)])
+            elif behind:
+                tokens = np.concatenate(
+                    [tokens, np.zeros((behind,), tokens.dtype)])
             tokens = np.asarray(tokens, np.int32)
-        return (tokens, np.asarray(ntoks, np.int32),
-                np.asarray(tables, np.int32), self._next_key())
+        args = [tokens, np.asarray(tables, np.int32), self._next_key()]
+        if not self.drafts:
+            args.insert(1, np.asarray(ntoks, np.int32))
+        return args
 
-    def _cb_tokens(self, nxt: np.ndarray) -> np.ndarray:
-        """A fetched step's (S,) tokens; the routing counts behind them
-        go to the stats."""
+    def _cb_tokens(self, nxt: np.ndarray):
+        """A fetched step's (S,) tokens, or its `StepTokens`; the
+        routing counts behind them go to the stats."""
+        s = self.spec.cb_slots
         if self._cb_tail:
-            s = self.spec.cb_slots
-            self.stats.observe_routing(int(nxt[s]), int(nxt[s + 1]),
+            tail = nxt[-self._cb_tail:]
+            self.stats.observe_routing(int(tail[0]), int(tail[1]),
                                        len(self._routed_layers),
-                                       int(nxt[s + 2:].sum()))
-            nxt = nxt[:s]
-        return nxt
+                                       int(tail[2:].sum()))
+        if not self.drafts:
+            return nxt[:s]
+        (_, ntoks, first, bonus, count, draft, lp1, lp2,
+         lq) = nxt[:STEP_ROWS * s].reshape(STEP_ROWS, s)
+        return StepTokens(np.stack([first, bonus], 1), count,
+                          np.stack([lp1, lp2], 1).view(np.float32), ntoks,
+                          draft, lq.view(np.float32))
+
+    def _hand_over(self, params, pools, tokens, ntoks, tables):
+        self._maybe_stall()
+        compiled = self._compile_cb("decode")
+        self._cb_flying_at.append(time.perf_counter())
+        with obs.span("engine.upload"):
+            args = self._cb_decode_args(tokens, ntoks, tables)
+        with obs.span("engine.dispatch"):
+            nxt, pools = compiled(params, pools, *args)
+            # on its way to the host as soon as the device has it
+            nxt.copy_to_host_async()
+            return nxt, pools
 
     def dispatch_cb_decode(self, params, pools, tokens, ntoks: np.ndarray,
                            tables: np.ndarray):
         """`run_cb_decode` up to the hand-over to the device: returns
         (the step's tokens as they lie on the device, new pools)
         without waiting.  `tokens` may be such a return of the step
-        before: then that step's tokens go in unread."""
-        self._maybe_stall()
-        compiled = self._compile_cb("decode")
-        self._cb_flying_at.append(time.perf_counter())
+        before: then that step's tokens go in unread (and, where the
+        model drafts, the rows each slot holds behind them: `ntoks` is
+        then the host's last knowledge and is not used)."""
         with obs.span("engine.cb_decode"):
-            with obs.span("engine.upload"):
-                args = self._cb_decode_args(tokens, ntoks, tables)
-            with obs.span("engine.dispatch"):
-                nxt, pools = compiled(params, pools, *args)
-                nxt.copy_to_host_async()
-                return nxt, pools
+            return self._hand_over(params, pools, tokens, ntoks, tables)
 
-    def fetch_cb_decode(self, flying) -> np.ndarray:
-        """The (S,) host tokens of a dispatched step (waits).  The
-        step's period goes to the step account `run_cb_decode` keeps:
-        from when the tokens before it were read, or from its own
-        hand-over if that was later, to now."""
+    def fetch_cb_decode(self, flying):
+        """The (S,) host tokens of a dispatched step, or its
+        `StepTokens` (waits)."""
+        return self._fetch(flying)
+
+    def _fetch(self, flying):
+        """The step's period goes to the step account: from when the
+        tokens before it were read, or from its own hand-over if that
+        was later, to now."""
         t = time.perf_counter()
         with obs.span("engine.fetch"):
             nxt = self._cb_tokens(np.asarray(flying))

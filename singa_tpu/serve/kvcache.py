@@ -54,6 +54,19 @@ so pool exhaustion can only ever surface as an admission decision
 (queue, then shed) — never as a mid-decode OOM or a deadlock between
 half-admitted requests.  The reservation is of the growing kind alone:
 a ring is the slot's already, whatever the request's length.
+
+Where the model drafts (a verify-and-draft step writes TWO rows a slot,
+positions n and n + 1, and the slot then advances by one or two:
+serve/engine.py) the same reservation holds: a live request has produced
+at most max_new - 1 tokens before a step, so its rows end at plen +
+max_new - 1, inside its blocks, and they are there before every step in
+flight because they were there at admission.  A rejected draft's row
+(n + 1) stays where it was written: the next step starts at n + 1 and
+writes over it before anything attends it.  The one step too many of a
+slot that has just retired may write past its rows: into the null block
+behind its table's real entries or, where the table row is full, into
+its own last block, which only an admission's prefill or decode steps
+will write again before they read it.
 """
 
 from __future__ import annotations

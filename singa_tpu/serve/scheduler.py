@@ -14,7 +14,13 @@ SLOTS instead:
            join the running batch on the next step;
   step     the ONE compiled fixed-slot-count decode program advances
            every active slot a token; inactive slots ride along
-           pointing at the null block (garbage out, masked, ignored);
+           pointing at the null block (garbage out, masked, ignored).
+           Where the model has a multi-token prediction module
+           (`engine.drafts`) the step verifies the slot's draft and
+           makes the next one, and a slot advances by ONE OR TWO
+           tokens: how far is known on the device first (it rides from
+           step to step there) and reaches the host with the tokens;
+           `_hand_out` is the one place that hands tokens to requests;
   retire   on EOS / max-new / deadline the slot's blocks return to
            the free pool immediately and the slot is free for the
            next admission that very step.
@@ -214,6 +220,12 @@ class _CBRequest:
     cancel_event: Optional[threading.Event] = None
     t_admit: float = 0.0
     produced: List[int] = field(default_factory=list)
+    # where the model drafts: the main model's log-probability of each
+    # produced token, and every draft made for this request as (the
+    # index in `produced` it stood for, its token, the module's
+    # log-probability of it)
+    logprobs: List[float] = field(default_factory=list)
+    drafts: List[tuple] = field(default_factory=list)
     # trace context captured at submit — the prefill runs on the
     # scheduler loop thread, so its span needs an explicit anchor to
     # land in the submitting request's trace
@@ -755,6 +767,10 @@ class ContinuousScheduler:
             admitted += 1
             self.stats.observe_cb_prefill(
                 req.plen, width, width in self.engine.cb_flash_widths)
+            if self.engine.drafts:
+                first, tok0 = tok0, tok0.token
+                req.logprobs.append(first.logprob)
+                req.drafts.append((1, first.draft, first.draft_logprob))
             self._slot_req[slot] = req
             self._active[slot] = True
             self._ntoks[slot] = req.plen
@@ -780,28 +796,22 @@ class ContinuousScheduler:
             nxt, self.kv.pools = self.engine.run_cb_decode(
                 params, self.kv.pools, self._last, self._ntoks, tables)
             self._lap_wait("handover", "wait")
-            now = time.monotonic()
-            with obs.span("scheduler.emit", slots=active):
-                for slot in np.flatnonzero(self._active):
-                    slot = int(slot)
-                    self._ntoks[slot] += 1
-                    tok = int(nxt[slot])
-                    self._last[slot] = tok
-                    req = self._slot_req[slot]
-                    req.produced.append(tok)
-                    req.ticket._emit(tok)
-                    self._maybe_retire(slot, tok, step_no, now)
-            self._lap("emit")
+            busy = np.flatnonzero(self._active)
+            if not self.engine.drafts:
+                self._ntoks[busy] += 1
+            self._hand_out(nxt, [(int(s), self._slot_req[s]) for s in busy],
+                           step_no)
 
     def _decode_ahead(self, params, step_no: int) -> None:
         """Every slot is busy: nothing can be admitted before one
         retires, so no request waits on this step, and it goes to the
         device BEFORE the step before it is read (its tokens go in as
-        they lie on the device).  The host's part of a step then runs
-        while the device works.  A slot that the step before retires
-        has made one token too many in this one: `_collect` drops it,
-        and what it wrote lies in blocks and state that an admission
-        overwrites."""
+        they lie on the device; where the model drafts, so do the rows
+        each slot holds, which the host does not know yet).  The host's
+        part of a step then runs while the device works.  A slot that
+        the step before retires has made one step too many in this one:
+        `_hand_out` drops its tokens, and what it wrote lies in blocks
+        and state that an admission overwrites."""
         before = self._flying
         # copies: the host's arrays change before the device has run
         tokens = self._last.copy() if before is None else before[0]
@@ -809,7 +819,8 @@ class ContinuousScheduler:
         self._lap("rest")
         nxt, self.kv.pools = self.engine.dispatch_cb_decode(
             params, self.kv.pools, tokens, ntoks, tables)
-        self._ntoks += 1
+        if not self.engine.drafts:
+            self._ntoks += 1
         self._flying = (nxt, list(self._slot_req))
         if before is not None:
             self._collect(step_no, COLLECT_BEHIND, before)
@@ -828,17 +839,56 @@ class ContinuousScheduler:
                 self._step_drained += 1
             nxt = self.engine.fetch_cb_decode(flying[0])
             self._lap_wait(_LAP_BEFORE_COLLECT[why], "wait")
-            now = time.monotonic()
-            with obs.span("scheduler.emit", slots=len(flying[1])):
-                for slot, req in enumerate(flying[1]):
-                    if req is not self._slot_req[slot]:
-                        continue       # retired since: a token too many
-                    tok = int(nxt[slot])
-                    self._last[slot] = tok
+            self._hand_out(nxt, list(enumerate(flying[1])), step_no)
+
+    def _hand_out(self, step, holders, step_no: int) -> None:
+        """THE emit loop: a fetched step's tokens to the requests that
+        held their slots when it went out (`holders`, (slot, request))
+        and still do; one a slot, or where the model drafts the one or
+        two the step yielded (`StepTokens`), each with its
+        log-probability, and the rows the slot holds now.  A request
+        that retires on its first of two tokens drops the second; one
+        retired since the step went out made a step too many."""
+        drafts = self.engine.drafts
+        now = time.monotonic()
+        # plain lists: an index into one costs a fifth of one into an array
+        if drafts:
+            tokens, counts = step.tokens.tolist(), step.count.tolist()
+            logprobs = step.logprobs.tolist()
+        else:
+            tokens = step.tolist()
+        live, last = self._slot_req, self._last
+        slots = emitted = accepted = 0
+        with obs.span("scheduler.emit", slots=len(holders)) as sp:
+            for slot, req in holders:
+                if req is not live[slot]:
+                    continue
+                slots += 1
+                if drafts:
+                    mine = tokens[slot][:counts[slot]]
+                    accepted += len(mine) - 1
+                    self._ntoks[slot] = step.ntoks[slot]
+                else:
+                    mine = (tokens[slot],)
+                for j, tok in enumerate(mine):
+                    last[slot] = tok
                     req.produced.append(tok)
+                    if drafts:
+                        req.logprobs.append(logprobs[slot][j])
                     req.ticket._emit(tok)
+                    emitted += 1
                     self._maybe_retire(slot, tok, step_no, now)
-            self._lap("emit")
+                    if live[slot] is not req:
+                        break
+                else:
+                    if drafts:
+                        req.drafts.append((len(req.produced),
+                                           int(step.draft[slot]),
+                                           float(step.draft_logprob[slot])))
+            sp.set(tokens=emitted)
+        self.stats.observe_cb_emit(slots, emitted,
+                                   accepted if drafts else None)
+        self._lap("emit")
 
     def _maybe_retire(self, slot: int, tok: int, step_no: int,
                       now: float) -> None:
@@ -887,9 +937,12 @@ class ContinuousScheduler:
         obs.emit_event("serve.cb_retire", corr=req.corr,
                        finish=finish, tokens=len(req.produced),
                        slot=slot, tenant=req.tenant)
-        req.ticket._resolve({"tokens": list(req.produced),
-                             "step": step_no, "finish": finish,
-                             "slots": self.spec.cb_slots})
+        result = {"tokens": list(req.produced), "step": step_no,
+                  "finish": finish, "slots": self.spec.cb_slots}
+        if self.engine.drafts:
+            result.update(logprobs=list(req.logprobs),
+                          drafts=list(req.drafts))
+        req.ticket._resolve(result)
 
     def _fail_step(self, e: BaseException) -> None:
         """A compiled call raised: fail every in-flight request, free

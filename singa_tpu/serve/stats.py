@@ -37,6 +37,13 @@ handler and tests see the same semantics:
     them was read) and `cb_collects_drained` (steps in flight read
     with none handed over behind them: a slot fell free, or an
     admission took it);
+  * `observe_cb_emit` is the emit loop's account: busy slots of the
+    fetched decode steps and the tokens they were handed
+    (`cb_tokens_emitted` / `cb_emit_slot_steps`: 1 without drafts, 1
+    to 2 with them), and where the model drafts the drafts verified and
+    accepted (`cb_drafts_made`, `cb_drafts_accepted`: a layer wrote
+    two cache rows a draft verified, and the next step writes over the
+    second where the draft was rejected).
   * `observe_cb_stall` is the loop thread's stall account: a LAP of a
     scheduler step (`serve/scheduler.py`, `STALL_S`) that took longer
     than any legitimate one adds to `cb_stalls`, its seconds to
@@ -170,6 +177,13 @@ class ServeStats:
         self.cb_routed_assignments = 0   # (token, held expert) pairs
         self.cb_routed_experts_touched = 0  # held experts some token chose
         self.cb_routed_max_load = 0      # the busiest held expert's pairs
+        # what the emit loop handed out (observe_cb_emit)
+        self.cb_emit_slot_steps = 0    # busy slots of fetched decode steps
+        self.cb_tokens_emitted = 0     # tokens they were handed
+        # where the model drafts (a verify-and-draft step, two rows a
+        # slot): a busy slot-step verifies one draft
+        self.cb_drafts_made = 0        # drafts verified
+        self.cb_drafts_accepted = 0    # of them, accepted (2 tokens)
         # batching
         self.batches = 0
         self.batched_requests = 0
@@ -304,6 +318,18 @@ class ServeStats:
                 self.cb_live_block_steps += int(live_blocks)
                 self.cb_window_block_steps += int(window_blocks)
             self._cb_t.append((time.monotonic(), int(active_slots)))
+
+    def observe_cb_emit(self, slots: int, tokens: int,
+                        accepted: Optional[int] = None) -> None:
+        """One fetched decode step handed out: `slots` busy slots got
+        `tokens` tokens; where the model drafts each of them verified a
+        draft and `accepted` of those were accepted (None: no drafts)."""
+        with self._lock:
+            self.cb_emit_slot_steps += int(slots)
+            self.cb_tokens_emitted += int(tokens)
+            if accepted is not None:
+                self.cb_drafts_made += int(slots)
+                self.cb_drafts_accepted += int(accepted)
 
     def observe_cb_stall(self, seconds: float, waited: bool) -> None:
         """One lap of a scheduler step that took `seconds`, longer than
@@ -490,6 +516,8 @@ class ServeStats:
                     "cb_live_block_steps", "cb_window_block_steps",
                     "cb_routed_layer_steps", "cb_routed_assignments",
                     "cb_routed_experts_touched", "cb_routed_max_load",
+                    "cb_emit_slot_steps", "cb_tokens_emitted",
+                    "cb_drafts_made", "cb_drafts_accepted",
                     "compiles", "reloads", "reload_failures",
                     "reloads_refused", "torn_polls",
                     "reload_poll_deaths")
@@ -601,6 +629,10 @@ class ServeStats:
                 "cb_routed_experts_touched":
                     self.cb_routed_experts_touched,
                 "cb_routed_max_load": self.cb_routed_max_load,
+                "cb_emit_slot_steps": self.cb_emit_slot_steps,
+                "cb_tokens_emitted": self.cb_tokens_emitted,
+                "cb_drafts_made": self.cb_drafts_made,
+                "cb_drafts_accepted": self.cb_drafts_accepted,
                 "consecutive_batch_failures":
                     self.consecutive_batch_failures,
                 "compiles": self.compiles,
