@@ -49,7 +49,7 @@ from ..ops import cca as cca_ops
 from ..ops import kda as kda_ops
 from ..ops import moe as moe_ops
 from ..ops.attention import NEG_INF
-from ..ops.paged_attention import paged_decode_attention
+from ..ops.paged_attention import extent_blocks, paged_decode_attention
 from .layers import Layer, LayerError, ParamSpec, register_layer
 from .seq_layers import (AttentionLayer, _declare_with_default, attend_cache,
                          paged_rows, write_token)
@@ -411,6 +411,14 @@ class MLALayer(Layer):
     def init_pool(self, num_slots: int, num_blocks: int, block_len: int,
                   dtype):
         return {"c": jnp.zeros((num_blocks, block_len, self.pool_row), dtype)}
+
+    def paged_extent(self, block_len: int, dtype, table_width: int) -> int:
+        """Consecutive blocks the paged kernel copies at once of this
+        layer's pool as `apply_paged` hands it over (one head, the
+        value a row's leading columns): what the serving cache's free
+        list has to deal in (serve/kvcache.py)."""
+        return extent_blocks((0, 1, block_len, self.pool_row), dtype,
+                             self.rank, table_width)
 
     def apply_cached(self, params, x, entry, pos, kmask=None, plen=None):
         t = x.shape[1]

@@ -33,7 +33,12 @@ KB while keys and values lay in two pools: PERF.md 6, PR 38).  So a
 chunk is a number of bytes (`chunk_positions`); a chunk's copies signal
 one DMA semaphore, which counts bytes, and are waited for together, by
 size, with no second walk of the table; and `start` issues a group of
-blocks in straight-line code before it loops.
+blocks in straight-line code before it loops.  A latent pool's block
+is 20 KB and cannot hide its descriptor either, but it has ONE head:
+consecutive blocks are consecutive rows of the pool and of the buffer
+alike, so where the table promises runs of `extent_blocks` consecutive
+blocks (serve/kvcache.py hands a latent net's blocks out so) `start`
+reads one table entry a run and brings it in one copy.
 
 A WINDOWED call (`window` W > 0, a sliding-window `kAttention` layer)
 attends positions max(0, ntoks[s] - W + 1)..ntoks[s] and its table row
@@ -93,6 +98,21 @@ _CHUNK_BYTES = 512 * 1024
 # CPU 1.4 -> 4.1 s), so `paged_decode_attention` gives it 1.
 _ISSUE_GROUP = 8
 
+# Bytes ONE copy brings of a one-head pool of latent rows, whose
+# consecutive blocks are consecutive rows of HBM and of the buffer
+# alike: `extent_blocks` of them, 8 at (1, 16, 640) bf16.  On the v5e
+# (tools/paged_kernel_bench.py; PERF.md 6, PR 41), at 1 / 2 / 4 / 8 /
+# 16 / 32 blocks a copy, us a call: 64 slots x 448 blocks under two
+# query rows of 128 heads (held by its operations), 9,702 live blocks,
+# 822 / 756 / 717 / 697 / 686 / 680, and with the table full 2,188 /
+# 1,990 / 1,872 / 1,817 / 1,787 / 1,770: 14.8 ns a descriptor removed,
+# seven eighths of them gone at 8; 96 slots x 128 blocks under one row
+# of 32 heads (held by its bytes), 2,835 live blocks, 156 / 145 / 144 /
+# 144 / 146 / 153: past 8 the extent that holds a short slot's horizon
+# reads more than its descriptors cost.  A shuffled table read a block
+# a copy takes what a run of blocks read a block a copy takes (822).
+_EXTENT_BYTES = 160 * 1024
+
 
 def key_heads(pool_shape, value_dim=None) -> int:
     """Hkv of a (num_blocks, heads, bl, D) pool: half its heads where a
@@ -113,6 +133,28 @@ def chunk_positions(pool_shape, dtype, value_dim=None):
     row = (key_heads(pool_shape, value_dim) * pool_shape[3]
            * jnp.dtype(dtype).itemsize)
     return 2 ** round(math.log2(_CHUNK_BYTES / row))
+
+
+def extent_blocks(pool_shape, dtype, value_dim, table_width) -> int:
+    """Consecutive pool blocks ONE copy brings of a (num_blocks, heads,
+    bl, D) pool read through a table `table_width` columns wide: the
+    extent the paged cache's free list deals in (serve/kvcache.py) and
+    the kernel copies, from the pool's shape alone so that the two
+    cannot disagree.  1 wherever a block has more than one head (the
+    buffer is head-major: a run of blocks is no one slab of it); for a
+    one-head pool whose rows hold keys and values (`value_dim` given)
+    the power of two whose bytes come nearest `_EXTENT_BYTES`, halved
+    until it divides a chunk's blocks and the table row."""
+    _, heads, bl, d = pool_shape
+    if heads != 1 or value_dim is None:
+        return 1
+    block = bl * d * jnp.dtype(dtype).itemsize
+    extent = 2 ** max(0, round(math.log2(_EXTENT_BYTES / block)))
+    cb = max(1, min(chunk_positions(pool_shape, dtype, value_dim) // bl,
+                    table_width))
+    while cb % extent or table_width % extent:
+        extent //= 2
+    return extent
 
 
 def ring_blocks(window: int, block_len: int) -> int:
@@ -174,7 +216,7 @@ def paged_attention_reference(q, pool, tables, ntoks, *, scale=None,
 
 
 def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
-            *, bl, cb, tw, scale, group, window, qrows=1):
+            *, bl, cb, tw, scale, group, window, qrows=1, extent=1):
     """Grid step s attends slot s.  `pool` lies in HBM; `into` is the
     buffer (2, heads, cb*bl, D): two chunks of cb blocks each, a
     block's (heads, bl, D) slab, its key heads and behind them its
@@ -185,7 +227,10 @@ def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
     the walk starts at the window's first block and the table row is a
     ring of `tw` columns.  With `qrows` R > 1 the slot's query heads are
     R rows of heads, row j at position ntoks + j: the walk goes to the
-    last row's horizon and row j's scores stop at its own."""
+    last row's horizon and row j's scores stop at its own.  With an
+    `extent` E > 1 the pool is one head's rows, (1, num_blocks * bl,
+    D), and a copy brings the E consecutive blocks that begin at a
+    table entry: E * bl rows of the pool to E * bl rows of the buffer."""
     s = pl.program_id(0)
     slots = pl.num_programs(0)
     span = cb * bl
@@ -218,10 +263,13 @@ def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
 
     def start(slot, chunk, buf, walked):
         """Start the copies of the chunk's live blocks, the slot's walk
-        being `walked` (its first block, its count), one a block; all
-        signal the semaphore of `buf`."""
+        being `walked` (its first block, its count), one a block (an
+        extent: the one that holds the horizon whole); all signal the
+        semaphore of `buf`."""
         first, of = walked
         live = jnp.minimum(of - chunk * cb, cb)
+        if extent > 1:
+            live = (live + (extent - 1)) // extent
         if window:
             # the chunk's first column of the ring; a block's own wraps
             # by a compare, not by a division of its own
@@ -230,14 +278,24 @@ def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
         else:
             entry = slot * tw + chunk * cb
 
+        run = extent * bl                   # rows a copy brings
+
         def block(c):
-            if window:
-                at = column + c
-                blk = tables_ref[entry + jnp.where(at >= tw, at - tw, at)]
+            if extent > 1:
+                # one table entry an extent: the blocks behind it are
+                # the next of the pool (serve/kvcache.py)
+                blk = tables_ref[entry + c * extent]
+                src = pool.at[:, pl.ds(pl.multiple_of(blk * bl, bl), run), :]
             else:
-                blk = tables_ref[entry + c]
-            rows = pl.ds(pl.multiple_of(c * bl, bl), bl)
-            pltpu.make_async_copy(pool.at[blk], into.at[buf, :, rows, :],
+                if window:
+                    at = column + c
+                    blk = tables_ref[entry
+                                     + jnp.where(at >= tw, at - tw, at)]
+                else:
+                    blk = tables_ref[entry + c]
+                src = pool.at[blk]
+            rows = pl.ds(pl.multiple_of(c * run, run), run)
+            pltpu.make_async_copy(src, into.at[buf, :, rows, :],
                                   sems.at[buf]).start()
 
         def issue(width):
@@ -327,12 +385,14 @@ def _kernel(ntoks_ref, tables_ref, q_ref, pool, o_ref, into, sems, base_ref,
         start(s + 1, 0, 1 - buf, walk(horizon(s + 1)))
 
     # the slot's last chunk, 1..cb live blocks: a wait a binary digit
-    # of their count
+    # of their count (of their extents', each of `extent` blocks)
     live = blocks - (chunks - 1) * cb
-    for bit in range(cb.bit_length()):
+    if extent > 1:
+        live = (live + (extent - 1)) // extent
+    for bit in range((cb // extent).bit_length()):
         @pl.when((live >> bit) & 1 == 1)
         def _():
-            wait(buf, 1 << bit)
+            wait(buf, extent << bit)
 
     _, l, acc = attend(chunks - 1, buf, carry, last=True)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
@@ -360,7 +420,8 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
     int32 and `ntoks` (S,) int32.  Returns (S, H, D) in q's dtype,
     equal to `paged_attention_reference` up to the order of the f32
     sums.  Reads ntoks[s] // bl + 1 blocks of slot s's row, each in one
-    copy, and nothing else of the pool.  `scale` multiplies the f32
+    copy, and nothing else of the pool (a latent pool: whole extents,
+    below).  `scale` multiplies the f32
     scores (default 1 / sqrt(D)).
 
     `value_dim`: the pool is (num_blocks, Hkv, bl, D) and holds both
@@ -379,6 +440,25 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
     H heads; row j stands at position ntoks[s] + j and attends
     positions 0..ntoks[s] + j, all R rows written before the call.  A
     block is still read once.  Not with a window.
+
+    A one-head pool of latent rows (`value_dim`, Hkv 1) is read an
+    EXTENT a copy, E = `extent_blocks` of the pool's shape and the
+    table's width: columns k E .. k E + E - 1 of a row's real part
+    have to be consecutive pool blocks (as `PagedKVCache` hands them
+    out; the kernel reads column k E alone), and the pool holds more
+    than E blocks: an extent behind the null block, and a null entry
+    reads blocks 0 .. E - 1.  The
+    extent that holds a slot's horizon is copied whole: its blocks are
+    the slot's own, and their rows past the horizon are masked as the
+    unwritten rows of a last block are (scores to NEG_INF, values to
+    0).  That reads half an extent a slot a call beyond the live
+    blocks, ~3 % of the bytes at the Pangu cell's mean of 2,000 rows a
+    slot and ~14 % at the Kimi cell's 450, and is the cheaper side:
+    the first call is held by its operations, not its bytes, the
+    second is 1.5 % of its step, and a call of the first kind issues
+    an eighth of the descriptors (PERF.md 6, PR 41).  The same
+    positions meet in the same chunks, so the f32 sums keep their
+    order.
 
     Compiled by Mosaic on the TPU, interpreted elsewhere
     (`ops.attention._on_tpu`)."""
@@ -401,12 +481,14 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
     interpret = not _attention._on_tpu()
     if not interpret:
         _check_tiling(q, pool, d if value_dim is None else value_dim)
+    extent = 1 if window else extent_blocks(pool.shape, pool.dtype,
+                                            value_dim, tables.shape[1])
     return singa_paged_decode(
         q, pool, tables, ntoks, interpret=interpret,
         chunk=chunk_positions(pool.shape, pool.dtype, value_dim),
         group=1 if interpret else _ISSUE_GROUP, value_dim=value_dim,
         scale=1.0 / math.sqrt(d) if scale is None else float(scale),
-        window=int(window), rows=int(rows))
+        window=int(window), rows=int(rows), extent=extent)
 
 
 # Jitted, so that the layers of one program share one trace and one
@@ -415,16 +497,36 @@ def paged_decode_attention(q, pool, tables, ntoks, *, scale=None,
 # of the row, that holds the kernel's time in a device trace.
 @functools.partial(jax.jit, static_argnames=("interpret", "chunk", "group",
                                              "scale", "value_dim", "window",
-                                             "rows"))
+                                             "rows", "extent"))
 def singa_paged_decode(q, pool, tables, ntoks, *, interpret, chunk, scale,
-                       value_dim=None, group=_ISSUE_GROUP, window=0, rows=1):
+                       value_dim=None, group=_ISSUE_GROUP, window=0, rows=1,
+                       extent=1):
     s, h, d = q.shape
-    _, heads, bl, _ = pool.shape
+    nb, heads, bl, _ = pool.shape
     hkv = key_heads(pool.shape, value_dim)
     tw = tables.shape[1]
     cb = max(1, min(chunk // bl, tw))
     groups = h // hkv
     vd = d if value_dim is None else value_dim
+    if extent > 1:
+        if heads != 1 or value_dim is None or window:
+            raise ValueError(
+                f"extents of {extent} blocks are copied of a one-head "
+                f"pool of latent rows under a growing table; the pool "
+                f"is {pool.shape}, window {window}")
+        if cb % extent or tw % extent:
+            raise ValueError(
+                f"an extent of {extent} blocks divides neither a chunk "
+                f"of {cb} nor a table row of {tw}")
+        if nb <= extent:
+            raise ValueError(
+                f"a pool of {nb} blocks holds no extent of {extent} "
+                f"behind its null block (and a null table entry reads "
+                f"blocks 0..{extent - 1})")
+        # one head: a run of blocks is a run of rows, of the pool as
+        # of the buffer
+        pool = pool.reshape(1, nb * bl, d)
+        group = min(group, cb // extent)
 
     def of_slot(width):
         return pl.BlockSpec((1, hkv, groups, width),
@@ -432,7 +534,7 @@ def singa_paged_decode(q, pool, tables, ntoks, *, interpret, chunk, scale,
 
     out = pl.pallas_call(
         functools.partial(_kernel, bl=bl, cb=cb, tw=tw, scale=scale,
-                          group=group, window=window,
+                          group=group, window=window, extent=extent,
                           **({"qrows": rows} if rows > 1 else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

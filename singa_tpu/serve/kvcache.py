@@ -19,7 +19,16 @@ the prefill program the table row and the slot behind it:
     slot returns its blocks to the free list immediately.  Slot memory
     is O(active tokens), where the static bucket path allocates each
     batch a contiguous cache at max_len (reads stay at Hkv width
-    exactly like `AttentionLayer.apply_cached`).
+    exactly like `AttentionLayer.apply_cached`).  The free list deals
+    in EXTENTS of `extent_blocks` consecutive blocks, the first at 1 +
+    k E: columns k E .. k E + E - 1 of a row's real part are
+    consecutive pool blocks, so the paged kernel brings an extent of a
+    one-head latent pool in ONE copy (`ops.paged_attention.
+    extent_blocks`, the one rule both sides read: 8 at a latent row of
+    640 bf16, 1 wherever a block holds several heads, and then this
+    class does block for block what it did before there were extents).
+    A row still lists EVERY block: the writes, the prefill's scatter
+    and the reference address blocks as they did.
   * rows per token in a RING OF BLOCKS PER SLOT — K and V of a
     `kAttention` layer with a window W: it needs a slot's last W
     positions and no more, so slot s owns `ring_blocks` = W / block_len
@@ -53,7 +62,12 @@ scheduler asks for ceil((plen + max_new) / block_len) blocks up front,
 so pool exhaustion can only ever surface as an admission decision
 (queue, then shed) — never as a mid-decode OOM or a deadlock between
 half-admitted requests.  The reservation is of the growing kind alone:
-a ring is the slot's already, whatever the request's length.
+a ring is the slot's already, whatever the request's length.  That a
+request's whole life is reserved at once is what makes extents free:
+`blocks_for` rounds the reservation up to whole extents (at most E - 1
+blocks more), the auto pool holds every slot's worst-case row, itself
+whole extents, and nothing ever grows a row block by block, so the
+free list never has to find a run among scraps.
 
 Where the model drafts (a verify-and-draft step writes TWO rows a slot,
 positions n and n + 1, and the slot then advances by one or two:
@@ -168,6 +182,16 @@ def state_bytes(net, block_len: int, dtype=jnp.float32) -> Dict[str, int]:
     return out
 
 
+def extent_of(net, block_len: int, dtype, table_width: int) -> int:
+    """Consecutive blocks the free list hands out together: the largest
+    extent the paged kernel copies of any of the net's pools under the
+    growing table (`layer.paged_extent`, which a layer has where its
+    pool's runs of blocks are runs of rows: kMLA), 1 where none does."""
+    return max([layer.paged_extent(block_len, dtype, table_width)
+                for _, layer in _stateful(net)
+                if hasattr(layer, "paged_extent")], default=1)
+
+
 def slot_behind_row(net) -> bool:
     """Whether a prefill has to be told its slot: some layer keeps a
     state or a ring per slot (its pools grow with the slots)."""
@@ -205,8 +229,19 @@ class PagedKVCache:
         # a layer with a state or a ring per slot: the prefill program
         # is told the slot behind the table row (`prefill_target`)
         self.per_slot_state = slot_behind_row(net)
-        # host bookkeeping: block 0 never enters the free list
-        self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
+        # blocks come and go in aligned runs of `extent_blocks`
+        self.extent_blocks = extent_of(net, self.block_len, dtype,
+                                       self.max_blocks_per_slot)
+        if self.num_blocks <= self.extent_blocks:
+            raise ValueError(
+                f"num_blocks {self.num_blocks} holds no extent of "
+                f"{self.extent_blocks} blocks behind the null block")
+        # host bookkeeping: the first block of every free extent; block
+        # 0 never enters the list, nor the blocks behind the last whole
+        # extent
+        self._free: List[int] = list(range(
+            self.usable_blocks - self.extent_blocks + 1, 0,
+            -self.extent_blocks))
         self._refcounts = np.zeros((self.num_blocks,), np.int32)
         self.tables = np.full((self.num_slots, self.max_blocks_per_slot),
                               NULL_BLOCK, np.int32)
@@ -215,24 +250,31 @@ class PagedKVCache:
     # -- capacity -----------------------------------------------------------
     @property
     def usable_blocks(self) -> int:
-        """Pool capacity excluding the null block."""
-        return self.num_blocks - 1
+        """Pool capacity in whole extents, excluding the null block."""
+        e = self.extent_blocks
+        return (self.num_blocks - 1) // e * e
 
     @property
     def free_blocks(self) -> int:
-        return len(self._free)
+        return len(self._free) * self.extent_blocks
 
     @property
     def blocks_in_use(self) -> int:
-        return self.usable_blocks - len(self._free)
+        return self.usable_blocks - self.free_blocks
 
     def blocks_for(self, total_tokens: int) -> int:
         """Blocks a sequence of `total_tokens` (prompt + generated)
-        needs — the conservative admission reservation."""
-        return -(-max(int(total_tokens), 1) // self.block_len)
+        holds — the conservative admission reservation, in whole
+        extents."""
+        blocks = -(-max(int(total_tokens), 1) // self.block_len)
+        return self._extents(blocks) * self.extent_blocks
+
+    def _extents(self, nblocks: int) -> int:
+        """Extents that hold `nblocks` blocks."""
+        return -(-int(nblocks) // self.extent_blocks)
 
     def can_admit(self, nblocks: int) -> bool:
-        return nblocks <= len(self._free)
+        return self._extents(nblocks) <= len(self._free)
 
     def ring_block(self, slot: int, position: int) -> int:
         """The pool block of a windowed layer that holds `position` of
@@ -244,9 +286,14 @@ class PagedKVCache:
         """Blocks one decode step's paged kernel walks over all slots,
         once a kind: `table` every slot's row up to its write position
         (an idle slot one block), `window` the blocks of its ring that
-        the window touches (0 where no layer has a window)."""
+        the window touches (0 where no layer has a window); `copies`
+        the extents of `table`, a descriptor each where the kernel
+        copies an extent."""
         last = np.asarray(ntoks) // self.block_len
-        out = {"table": int((last + 1).sum()), "window": 0}
+        table = int((last + 1).sum())
+        out = {"table": table, "window": 0,
+               "copies": table if self.extent_blocks == 1 else
+               int((last // self.extent_blocks + 1).sum())}
         if self.window:
             first = np.maximum(np.asarray(ntoks) - (self.window - 1),
                                0) // self.block_len
@@ -255,25 +302,28 @@ class PagedKVCache:
 
     # -- slot lifecycle -----------------------------------------------------
     def alloc(self, slot: int, nblocks: int) -> np.ndarray:
-        """Reserve `nblocks` blocks for `slot` (refcount 1 each) and
-        return the slot's full table row (real blocks first, null
-        padding after).  Raises RuntimeError when the pool cannot
-        cover the reservation — the scheduler checks `can_admit`
-        first, so reaching the raise is a bug, not backpressure."""
+        """Reserve `nblocks` blocks for `slot`, in whole extents
+        (refcount 1 each), and return the slot's full table row (real
+        blocks first, an extent's one after another, null padding
+        after).  Raises RuntimeError when the pool cannot cover the
+        reservation — the scheduler checks `can_admit` first, so
+        reaching the raise is a bug, not backpressure."""
         if slot in self._slot_blocks:
             raise RuntimeError(f"slot {slot} already holds blocks")
         if nblocks > self.max_blocks_per_slot:
             raise ValueError(
                 f"request needs {nblocks} blocks but a slot holds at "
                 f"most {self.max_blocks_per_slot}")
-        if nblocks > len(self._free):
+        if not self.can_admit(nblocks):
             raise RuntimeError(
                 f"block pool exhausted: need {nblocks}, "
-                f"{len(self._free)} free")
-        blocks = [self._free.pop() for _ in range(nblocks)]
+                f"{self.free_blocks} free")
+        firsts = [self._free.pop() for _ in range(self._extents(nblocks))]
+        blocks = [first + i for first in firsts
+                  for i in range(self.extent_blocks)]
         self._refcounts[blocks] += 1
         self.tables[slot] = NULL_BLOCK
-        self.tables[slot, :nblocks] = blocks
+        self.tables[slot, :len(blocks)] = blocks
         self._slot_blocks[slot] = blocks
         return self.tables[slot].copy()
 
@@ -288,12 +338,12 @@ class PagedKVCache:
 
     def free(self, slot: int) -> None:
         """Retire `slot`: drop each block's refcount and return
-        zero-refcount blocks to the free list immediately."""
+        zero-refcount extents to the free list immediately."""
         blocks = self._slot_blocks.pop(slot, None)
         if blocks is None:
             return
-        for b in blocks:
-            self._refcounts[b] -= 1
+        self._refcounts[blocks] -= 1
+        for b in blocks[::self.extent_blocks]:
             if self._refcounts[b] == 0:
                 self._free.append(b)
         self.tables[slot] = NULL_BLOCK
@@ -321,4 +371,5 @@ class PagedKVCache:
                 "num_slots": self.num_slots,
                 "max_blocks_per_slot": self.max_blocks_per_slot,
                 "window": self.window, "ring_blocks": self.ring_blocks,
+                "extent_blocks": self.extent_blocks,
                 "utilization": round(self.utilization(), 4)}

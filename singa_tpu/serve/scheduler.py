@@ -210,7 +210,6 @@ class _CBRequest:
     tokens: np.ndarray            # (plen,) int32
     plen: int
     max_new: int
-    nblocks: int                  # conservative reservation
     ticket: StreamTicket
     t_submit: float
     deadline: Optional[float]
@@ -219,6 +218,9 @@ class _CBRequest:
     tenant: str = "default"
     cancel_event: Optional[threading.Event] = None
     t_admit: float = 0.0
+    # the conservative reservation: what the cache holds for the
+    # request's whole life (`PagedKVCache.blocks_for`), once admitted
+    nblocks: int = 0
     produced: List[int] = field(default_factory=list)
     # where the model drafts: the main model's log-probability of each
     # produced token, and every draft made for this request as (the
@@ -325,6 +327,7 @@ class ContinuousScheduler:
             self.stats.gauge("cb_window_block_copy_bytes",
                              per["window_block_copy"])
             self.stats.gauge("cb_ring_blocks", self.kv.ring_blocks)
+            self.stats.gauge("cb_extent_blocks", self.kv.extent_blocks)
             # no request's first token waits on a program's first run
             self.kv.pools = self.engine.run_cb_prefill_rungs(
                 self.engine.params, self.kv.pools)
@@ -426,7 +429,6 @@ class ContinuousScheduler:
                     f"{spec.eos_id}")
             mn = mn - resume_from     # only the remainder decodes
             self.stats.count("resumed")
-        nblocks = -(-(int(arr.size) + mn) // int(spec.cb_block_len))
         deadline = qos.resolve_deadline(timeout, deadline,
                                         spec.request_timeout_s)
         now = time.monotonic()
@@ -444,7 +446,6 @@ class ContinuousScheduler:
         corr = obs.current_corr() or f"cbreq-{next(self._req_ids)}"
         link = obs.trace_context()
         req = _CBRequest(tokens=arr, plen=int(arr.size), max_new=mn,
-                         nblocks=nblocks,
                          ticket=StreamTicket(corr,
                                              first_index=resume_from),
                          t_submit=now, deadline=deadline, corr=corr,
@@ -609,7 +610,7 @@ class ContinuousScheduler:
                     # a slot fell free and nothing took it
                     self._collect(step_no, COLLECT_DRAIN)
                 active = int(self._active.sum())
-                walked = {"table": 0, "window": 0}
+                walked = {"table": 0, "window": 0, "copies": 0}
                 if active:
                     # what the decode program walks, once a kind of
                     # paged layer: every slot's table row up to its
@@ -625,7 +626,8 @@ class ContinuousScheduler:
                 self.stats.observe_cb_step(
                     int(self._active.sum()), self.kv.blocks_in_use,
                     walked["table"], walked["window"],
-                    ahead=self._step_ahead, drained=self._step_drained)
+                    ahead=self._step_ahead, drained=self._step_drained,
+                    copies=walked["copies"])
                 self.stats.gauge("cb_blocks_in_use", self.kv.blocks_in_use)
 
     def _expire_pending(self, now: float) -> None:
@@ -682,7 +684,9 @@ class ContinuousScheduler:
                             blocks_t.get(r.tenant, 0) + r.nblocks
                 req = None
                 for i, cand in enumerate(self._pending):
-                    if not self.kv.can_admit(cand.nblocks):
+                    # the cache reckons a reservation, rounding and all
+                    need = self.kv.blocks_for(cand.plen + cand.max_new)
+                    if not self.kv.can_admit(need):
                         # global pool pressure: the effective head
                         # waits, nothing overtakes it
                         return admitted
@@ -692,9 +696,10 @@ class ContinuousScheduler:
                         cand.tenant, self.kv.usable_blocks)
                     if slots_t.get(cand.tenant, 0) + 1 > squota or \
                             blocks_t.get(cand.tenant, 0) + \
-                            cand.nblocks > bquota:
+                            need > bquota:
                         continue  # ITS quota, not ours: step over
                     req = cand
+                    req.nblocks = need
                     del self._pending[i]
                     break
                 if req is None:

@@ -153,6 +153,8 @@ class ServeStats:
         self.cb_block_use_steps = 0    # sum of blocks in use per step
         self.cb_decode_steps = 0       # iterations that ran a decode
         self.cb_live_block_steps = 0   # sum of table blocks they walked
+        self.cb_block_copies = 0       # sum of the copies that brought
+                                       # them, a layer (an extent each)
         self.cb_window_block_steps = 0  # sum of ring blocks they walked
                                         # (layers with a window)
         self.cb_table_blocks = 0       # gauge: slots x blocks per slot
@@ -171,6 +173,8 @@ class ServeStats:
         self.cb_block_copy_bytes = 0         # gauge: under the table
         self.cb_window_block_copy_bytes = 0  # gauge: in a ring
         self.cb_ring_blocks = 0        # gauge: ring blocks a slot
+        self.cb_extent_blocks = 0      # gauge: consecutive blocks the
+                                       # free list deals in, a copy's
         # routing of the experts held here, summed over decode steps
         # and routed layers, busy slots only (engine.run_cb_decode)
         self.cb_routed_layer_steps = 0   # layers x steps counted
@@ -300,9 +304,11 @@ class ServeStats:
     def observe_cb_step(self, active_slots: int, blocks_in_use: int,
                         live_blocks: int = 0,
                         window_blocks: int = 0, ahead: int = 0,
-                        drained: int = 0) -> None:
+                        drained: int = 0, copies: int = 0) -> None:
         """`live_blocks`: table blocks the step's decode program walked
-        (0 for a step that ran none); `window_blocks`: ring blocks its
+        (0 for a step that ran none); `copies`: the descriptors one
+        table-kind call issued for them (as many, or an extent each);
+        `window_blocks`: ring blocks its
         windowed layers walked (`PagedKVCache.walked_blocks`);
         `ahead`: 1 where its decode step went to the device before the
         one before it was read; `drained`: steps in flight it read with
@@ -316,6 +322,7 @@ class ServeStats:
             if live_blocks:
                 self.cb_decode_steps += 1
                 self.cb_live_block_steps += int(live_blocks)
+                self.cb_block_copies += int(copies)
                 self.cb_window_block_steps += int(window_blocks)
             self._cb_t.append((time.monotonic(), int(active_slots)))
 
@@ -513,7 +520,8 @@ class ServeStats:
                     "cb_steps_ahead", "cb_collects_drained",
                     "cb_stalls", "cb_stall_seconds",
                     "cb_stall_wait_seconds",
-                    "cb_live_block_steps", "cb_window_block_steps",
+                    "cb_live_block_steps", "cb_block_copies",
+                    "cb_window_block_steps",
                     "cb_routed_layer_steps", "cb_routed_assignments",
                     "cb_routed_experts_touched", "cb_routed_max_load",
                     "cb_emit_slot_steps", "cb_tokens_emitted",
@@ -536,7 +544,8 @@ class ServeStats:
                   "cb_blocks_total",
                   "cb_slot_state_bytes", "cb_block_bytes",
                   "cb_window_block_bytes", "cb_ring_blocks",
-                  "cb_block_copy_bytes", "cb_window_block_copy_bytes",
+                  "cb_extent_blocks", "cb_block_copy_bytes",
+                  "cb_window_block_copy_bytes",
                   "cb_window_block_share")
 
         def collect():
@@ -622,7 +631,9 @@ class ServeStats:
                 "cb_window_block_copy_bytes":
                     self.cb_window_block_copy_bytes,
                 "cb_ring_blocks": self.cb_ring_blocks,
+                "cb_extent_blocks": self.cb_extent_blocks,
                 "cb_live_block_steps": self.cb_live_block_steps,
+                "cb_block_copies": self.cb_block_copies,
                 "cb_window_block_steps": self.cb_window_block_steps,
                 "cb_routed_layer_steps": self.cb_routed_layer_steps,
                 "cb_routed_assignments": self.cb_routed_assignments,

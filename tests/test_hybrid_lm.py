@@ -125,7 +125,9 @@ def _prefill_then_decode(net, params, seq, plen, slot, nslots=3):
     lg, cache = forward_cached(net, params, jnp.asarray(toks),
                                init_cache(net, 1, CAP, jnp.float32), 0,
                                plen=jnp.int32(plen))
-    pools = init_pools(net, nb + 1, BL, jnp.float32, nslots)
+    # a pool of whole extents, as the cache's is: the kernel copies the
+    # extent that holds a slot's horizon whole
+    pools = init_pools(net, table.shape[1] + 1, BL, jnp.float32, nslots)
     # the slot's last tenant left garbage behind
     pools = jax.tree_util.tree_map(
         lambda a: jnp.full_like(a, jnp.nan) if a.dtype == jnp.float32
@@ -179,9 +181,11 @@ def test_mla_absorbed_and_expanded_forms_agree(lm):
 
 def _engine(lm, slots):
     net, params, _ = lm
+    # a queued request waits out the decode program's compile, which a
+    # loaded test machine stretches past the default 5 s
     spec = ServeSpec(buckets=((1, CAP),), max_new_tokens=8, temperature=0.0,
                      eos_id=None, cb="on", cb_slots=slots, cb_block_len=BL,
-                     cb_prompt_cap=CAP)
+                     cb_prompt_cap=CAP, request_timeout_s=60.0)
     engine = InferenceEngine(net, spec, params=params,
                              log_fn=lambda *a, **k: None)
     engine.load()
@@ -232,7 +236,11 @@ def test_reused_slots_carry_nothing_of_their_last_tenant(lm):
     sched = ContinuousScheduler(engine, log_fn=lambda *a, **k: None)
     retire = sched._retire
 
+    pairs = []
+
     def poisoned(slot, finish, step_no):
+        held = sched.kv.tables[sched.kv.tables != 0]
+        pairs.extend(held.reshape(-1, 2).tolist())
         retire(slot, finish, step_no)
         sched.kv.pools = {
             n: ({k: v.at[slot].set(jnp.nan) for k, v in e.items()}
@@ -273,6 +281,13 @@ def test_reused_slots_carry_nothing_of_their_last_tenant(lm):
     assert st.cb_block_copy_bytes == per["block_copy"] == (
         BL * mla[0].pool_row * 4) == per["block"] // len(mla)
     assert st.cb_window_block_copy_bytes == per["window_block_copy"] == 0
+    # and their blocks come two a copy under this table of 6: every
+    # real pair of a row consecutive and aligned while the slots churned
+    # (`poisoned` looked after each retirement), a copy an extent
+    assert st.cb_extent_blocks == sched.kv.extent_blocks == 2
+    assert pairs and all(b == a + 1 and a % 2 == 1 for a, b in pairs)
+    assert (st.cb_live_block_steps / 2 <= st.cb_block_copies
+            < st.cb_live_block_steps)
 
 
 def test_cb_greedy_tokens_equal_generates(lm):

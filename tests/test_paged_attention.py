@@ -22,7 +22,7 @@ from singa_tpu.config.schema import LayerConfig, MLAConfig
 from singa_tpu.core.hybrid_layers import MLALayer
 from singa_tpu.core.net import build_net
 from singa_tpu.models.transformer import transformer_lm
-from singa_tpu.ops.paged_attention import (chunk_positions,
+from singa_tpu.ops.paged_attention import (chunk_positions, extent_blocks,
                                            paged_attention_reference,
                                            paged_decode_attention,
                                            ring_blocks, singa_paged_decode)
@@ -83,12 +83,16 @@ def _one_pool(sides):
 
 
 def _case(lengths, groups, dtype, seed, hkv=HKV, d=D, sides=2, bl=BL,
-          t=T):
+          t=T, extent=1, rows=1):
     """q, a clean and a poisoned copy of the pool (`sides` 2: a block's
     key heads and then its value heads; 1: latent rows), tables, ntoks,
     a slot a length.  The table is a shuffled (non-monotone) draw of
-    the pool's blocks; a slot's reservation ends somewhere at or after
-    its last live block and the row's tail is the null block.  In the
+    the pool's blocks, with an `extent` E > 1 of its aligned extents
+    (E consecutive blocks from 1 + k E, as `PagedKVCache` hands them
+    out); a slot's reservation ends somewhere at or after its last
+    live block (whole extents) and the row's tail is the null block.
+    With `rows` R > 1 q holds R rows of heads a slot and the slot's
+    last R - 1 positions beyond ntoks are written too.  In the
     poisoned copy every position no slot may see is nan (the key half,
     or the latent rows of both sides) or inf (the value half): blocks
     no live table entry names, the tail of each last live block, and
@@ -97,22 +101,23 @@ def _case(lengths, groups, dtype, seed, hkv=HKV, d=D, sides=2, bl=BL,
     rng = np.random.default_rng(seed)
     slots = len(lengths)
     nb = slots * t + 1
-    q = rng.standard_normal((slots, hkv * groups, d)).astype(np.float32)
+    q = rng.standard_normal((slots, hkv * groups * rows, d)).astype(
+        np.float32)
     pools = [rng.standard_normal((nb, hkv, bl, d)).astype(np.float32)
              for _ in range(sides)]
-    tables = rng.permutation(np.arange(1, nb)).reshape(slots, t).astype(
-        np.int32)
+    tables = (1 + rng.permutation((nb - 1) // extent)[:, None] * extent
+              + np.arange(extent)).reshape(slots, t).astype(np.int32)
     ntoks = np.zeros((slots,), np.int32)
     seen = np.zeros((nb, bl), bool)
-    seen[NULL_BLOCK, 0] = True
+    seen[NULL_BLOCK, :rows] = True
     for s, n in enumerate(lengths):
         if n is None:
             tables[s] = NULL_BLOCK
             continue
         ntoks[s] = n
-        live = n // bl + 1
-        tables[s, rng.integers(live, t + 1):] = NULL_BLOCK
-        at = np.arange(n + 1)
+        at = np.arange(n + rows)
+        live = -(-(at[-1] // bl + 1) // extent)
+        tables[s, rng.integers(live, t // extent + 1) * extent:] = NULL_BLOCK
         seen[tables[s, at // bl], at % bl] = True
     hide = ~seen[:, None, :, None]
     clean = _one_pool([np.where(hide, 0.0, a) for a in pools])
@@ -182,9 +187,13 @@ def test_kernel_matches_gather_reference_on_poisoned_pools(name, groups,
     elif groups == "latent":
         layer, params = mla
         params = {k: v.astype(dtype) for k, v in params.items()}
+        # a latent pool's table comes in the extents its shape gives
+        # (2 blocks of a table of 6 here)
+        extent = extent_blocks((1, 1, BL, MLA_ROW), dtype, layer.rank, T)
+        assert extent == 2
         q, clean, poisoned, tables, ntoks = _case(
             LENGTHS[name], layer.heads, dtype, seed=len(name), hkv=1,
-            d=MLA_ROW, sides=1)
+            d=MLA_ROW, sides=1, extent=extent)
         q = q[..., :layer.nope + layer.rope]
         _reference = functools.partial(_latent_reference, layer, params)
         _kernel = functools.partial(_latent_kernel, layer, params)
@@ -580,6 +589,170 @@ def test_waits_cover_the_copies_under_the_dma_model(sides):
     assert np.max(np.abs(np.asarray(got) - np.asarray(want))) <= 2e-6
 
 
+# -- extents: several consecutive blocks a copy --------------------------------
+
+# The four multi-head geometries of tools/paged_kernel_bench.py, as
+# (heads a block, block_len, D) and the table's width: an extent is one
+# block wherever a block holds several heads
+MANY_HEADS = {"dense": ((16, 16, 128), 80), "cca": ((4, 16, 128), 256),
+              "table": ((8, 16, 128), 512), "ring": ((8, 16, 128), 129)}
+
+
+@pytest.mark.parametrize("cell", list(MANY_HEADS))
+def test_an_extent_is_one_block_wherever_a_block_holds_several_heads(cell):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "paged_kernel_bench", "tools/paged_kernel_bench.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    g = bench.GEOMETRIES[cell]
+    shape, width = MANY_HEADS[cell]
+    assert (g.sides * g.hkv, g.bl, g.d) == shape and g.table == width
+    for dtype in (jnp.bfloat16, jnp.float32):
+        assert extent_blocks((4097,) + shape, dtype, None, width) == 1
+    # and whatever is said of its values: several heads are no one slab
+    assert extent_blocks((4097,) + shape, jnp.bfloat16, 128, width) == 1
+
+
+@pytest.mark.parametrize("width,dtype,want", [
+    (448, "bfloat16", 8), (128, "bfloat16", 8), (448, "float32", 4),
+    (4, "bfloat16", 4), (12, "bfloat16", 4), (7, "bfloat16", 1)],
+    ids=["pangu", "kimi", "f32", "narrow_table", "table_of_12", "odd_table"])
+def test_a_latent_pools_extent_follows_its_bytes_and_divides_what_it_must(
+        width, dtype, want):
+    """8 blocks of (1, 16, 640) bf16 are the 160 KB a copy is aimed at;
+    a float32 block is twice the bytes; an extent divides the table row
+    and a chunk's blocks, or the walk would copy past either."""
+    assert extent_blocks((4097, 1, 16, 640), jnp.dtype(dtype), 512,
+                         width) == want
+
+
+def _extent_kernel(extent, chunk, rows=1, group=1, interpret=True):
+    def kernel(q, pool, tables, ntoks):
+        return singa_paged_decode(
+            q, pool, tables, ntoks, interpret=interpret, chunk=chunk,
+            group=group, scale=0.3, value_dim=pool.shape[-1] // 2,
+            rows=rows, extent=extent)
+    return kernel
+
+
+@pytest.mark.parametrize("rows", [1, 2], ids=["one_row", "two_rows"])
+@pytest.mark.parametrize("shape", ["tiny", "one_extent_wide", "lane_true"])
+def test_extents_are_copied_whole_and_masked_past_the_horizon(shape, rows):
+    """A latent pool whose table comes in aligned extents, against the
+    gather: a horizon at EVERY position of the table (so at every
+    position of an extent, on and around the edges of blocks, extents
+    and chunks; with two rows the pair straddles each of them), a slot
+    whose row is all null blocks, a table one extent wide.  The extent
+    that holds a horizon is copied whole: what lies past the horizon in
+    it is poisoned here as everything else unseen."""
+    bl, d, t, extent, chunk, heads, dtype, tol = {
+        # chunks of two extents of two blocks, three chunks a row
+        "tiny": (4, 16, 12, 2, 4 * 4, 3, jnp.float32, 2e-6),
+        # the whole row one copy, and one chunk
+        "one_extent_wide": (4, 16, 4, 4, 4 * 4, 3, jnp.float32, 2e-6),
+        # the serving cells' latent block, (1, 16, 640) bf16: 8 a copy,
+        # 32 a chunk, a row of two chunks
+        "lane_true": (16, 640, 64, 8, 512, 2, jnp.bfloat16, 2e-2),
+    }[shape]
+    last = t * bl - rows
+    lengths = (list(range(last + 1)) if shape != "lane_true" else
+               [0, 15, 16, 127, 128, 129, 511, 512, 513, 640, last])
+    lengths.append(None)
+    q, clean, poisoned, tables, ntoks = _case(
+        lengths, heads, dtype, seed=rows, hkv=1, d=d, sides=1, bl=bl, t=t,
+        extent=extent, rows=rows)
+    kernel = _extent_kernel(extent, chunk, rows, group=2)
+    kw = dict(scale=0.3, value_dim=d // 2, rows=rows)
+    want = np.asarray(paged_attention_reference(
+        q, clean, tables, ntoks, **kw), np.float32)
+    got = np.asarray(kernel(q, poisoned, tables, ntoks), np.float32)
+    assert np.isfinite(got).all(), "the kernel attended past a horizon"
+    assert np.max(np.abs(got - want)) <= tol
+    # a block a copy reads the same table to the same sums: the same
+    # positions meet in the same chunks
+    blockwise = np.asarray(_extent_kernel(1, chunk, rows, group=2)(
+        q, poisoned, tables, ntoks), np.float32)
+    assert np.array_equal(got, blockwise)
+
+
+def test_an_extents_waits_cover_its_copies_under_the_dma_model():
+    """As `test_waits_cover_the_copies_under_the_dma_model`, a copy an
+    extent of two blocks: chunks of two extents, a last chunk of one
+    and of two live extents, the second one's horizon in its first and
+    in its second block, an inactive slot."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, extent = 8, 2
+    lengths = [1, BL, 2 * BL - 1, 2 * BL, 3 * BL, 4 * BL - 1, 4 * BL,
+               6 * BL + 1, t * BL - 1, None]
+    q, clean, poisoned, tables, ntoks = _case(
+        lengths, 3, jnp.float32, 5, hkv=1, sides=1, t=t, extent=extent)
+    got = _extent_kernel(extent, 4 * BL, interpret=pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True))(
+            q, poisoned, tables, ntoks)
+    want = paged_attention_reference(q, clean, tables, ntoks, scale=0.3,
+                                     value_dim=D // 2)
+    assert np.max(np.abs(np.asarray(got) - np.asarray(want))) <= 2e-6
+
+
+def test_one_copy_an_extent_and_a_multi_head_call_as_it_was():
+    """The kernel's jaxpr at an extent of E holds the `dma_start`s it
+    held (three places that start a chunk's copies), each of E blocks'
+    rows of the one-head pool; a multi-head call through
+    `paged_decode_attention` traces what an explicit extent of 1
+    traces, copy for copy and equation for equation."""
+    t, extent = 8, 4
+    q, pool, _, tables, ntoks = _case(
+        [3, 2 * BL, t * BL - 1], 3, jnp.float32, 2, hkv=1, sides=1, t=t,
+        extent=extent)
+    for e, rows_a_copy in ((extent, extent * BL), (1, None)):
+        starts = _copies(jax.make_jaxpr(_extent_kernel(e, 4 * BL))(
+            q, pool, tables, ntoks).jaxpr)
+        assert len(starts) == 3
+        for eqn in starts:
+            src, dst = eqn.invars[0].aval, eqn.invars[2].aval
+            if rows_a_copy is None:      # a block of the pool as it is
+                assert src.shape == pool.shape
+            else:                        # a run of rows of its one head
+                assert src.shape == (1, pool.shape[0] * BL, D)
+            assert dst.shape == (2, 1, 4 * BL, D)
+    # through the rule: a latent call copies extents, a table of 8
+    # blocks of 4 x 8 float32 whole
+    ruled = _copies(jax.make_jaxpr(functools.partial(
+        paged_decode_attention, scale=0.3, value_dim=D // 2))(
+            q, pool, tables, ntoks).jaxpr)
+    assert len(ruled) == 3
+    assert ruled[0].invars[0].aval.shape == (1, pool.shape[0] * BL, D)
+    many = _case(LENGTHS["mixed"], 2, jnp.float32, 1)
+    q, pool, _, tables, ntoks = many
+    through = jax.make_jaxpr(paged_decode_attention)(q, pool, tables, ntoks)
+    explicit = jax.make_jaxpr(functools.partial(
+        singa_paged_decode, interpret=True, group=1, scale=1 / np.sqrt(D),
+        chunk=chunk_positions(pool.shape, pool.dtype)))(
+            q, pool, tables, ntoks)
+    assert len(_copies(through.jaxpr)) == len(_copies(explicit.jaxpr)) == 3
+    assert str(through) == str(explicit)
+
+
+@pytest.mark.parametrize("bad,match", [
+    ({"extent": 4}, "one-head pool"),             # keys and values apart
+    ({"extent": 4, "sides": 1, "t": 6}, "divides neither"),
+    ({"extent": 8, "sides": 1, "t": 8, "slots": 0}, "holds no extent")],
+    ids=["many_heads", "a_table_it_does_not_divide", "a_pool_too_small"])
+def test_an_extent_the_pool_or_the_table_cannot_hold_is_refused(bad, match):
+    sides, t = bad.get("sides", 2), bad.get("t", 8)
+    q, pool, _, tables, ntoks = _case(
+        [3, 9], 2, jnp.float32, 0, sides=sides, t=t,
+        **({"hkv": 1} if sides == 1 else {}))
+    if "slots" in bad:
+        pool = pool[:4]
+    with pytest.raises(ValueError, match=match):
+        singa_paged_decode(
+            q, pool, tables, ntoks, interpret=True, chunk=8 * BL, scale=0.3,
+            value_dim=D if sides == 1 else None, extent=bad["extent"])
+
+
 @pytest.mark.parametrize("bad", [{"value_dim": 0}, {"value_dim": D + 1},
                                  {"heads": 3}],
                          ids=["none", "wider_than_a_row",
@@ -687,6 +860,11 @@ def test_cb_live_block_share_counts_what_the_kernel_walks():
         text = reg.render_prometheus()
     assert engine.stats.cb_decode_steps == steps
     assert engine.stats.cb_live_block_steps == walked
+    # a block a copy: keys and values hold several heads a block
+    assert engine.stats.cb_block_copies == walked
+    assert snap["cb_extent_blocks"] == 1
+    assert "singa_serve_cb_extent_blocks 1" in text
+    assert f"singa_serve_cb_block_copies_total {walked}" in text
     want = walked / (steps * slots * width)
     assert snap["cb_live_block_share"] == round(want, 4)
     assert f"singa_serve_cb_live_block_share {round(want, 4)}" in text

@@ -205,8 +205,10 @@ def test_the_allocator_reserves_per_kind(lm):
         == set(range(1, 3 * RING + 1))
     # what a step's kernel walks, once a kind: the table up to the write
     # position, of the ring what the window touches
+    # (a block a copy: its blocks hold several heads)
     assert kv.walked_blocks(np.array([0, 5, 40])) == {
-        "table": 1 + 2 + 11, "window": 1 + 2 + 3}     # 33..40: 3 blocks
+        "table": 1 + 2 + 11, "copies": 1 + 2 + 11,
+        "window": 1 + 2 + 3}                          # 33..40: 3 blocks
     assert kv.walked_blocks(np.array([0, 43, 0]))["window"] == 1 + 2 + 1
     # retiring returns the growing blocks; the ring was never taken
     kv.free(1)

@@ -1,6 +1,9 @@
 """What `cb_live_block_share`, `cb_prefill_fill_share`, the share of
-prefills through a flash-kernel rung and the bytes one copy of the paged
-kernel moves (`cb_block_copy_bytes`, a ring's `cb_window_block_copy_bytes`)
+prefills through a flash-kernel rung, the bytes of a block of the paged
+kernel's pools (`cb_block_copy_bytes`, a ring's
+`cb_window_block_copy_bytes`), the blocks one copy brings
+(`cb_extent_blocks`) and the copies a live block (`cb_block_copies` /
+`cb_live_block_steps`: 1.0 a block a copy, ~0.14 at extents of 8)
 read under one of the benchmark's serving cells (a builder's tool; the
 benchmark does not report the counters):
 
@@ -32,8 +35,13 @@ def stop(self, *args, **kwargs):
                              "cb_prefill_width_rows", "cb_slot_occupancy",
                              "cb_block_utilization", "cb_steps",
                              "cb_block_bytes", "cb_block_copy_bytes",
-                             "cb_window_block_copy_bytes")},
+                             "cb_window_block_copy_bytes",
+                             "cb_extent_blocks", "cb_live_block_steps",
+                             "cb_block_copies")},
         "cb_decode_steps": self.stats.cb_decode_steps,
+        "cb_copies_a_live_block": (
+            snap["cb_block_copies"] / snap["cb_live_block_steps"]
+            if snap["cb_live_block_steps"] else None),
         "cb_flash_prefill_share": (
             snap["cb_flash_prefills"] / snap["cb_prefills"]
             if snap["cb_prefills"] else None)}), flush=True)

@@ -1,12 +1,18 @@
 """Microbenchmark of the paged decode attention kernel on the chip (a
 builder's tool, not part of the benchmark): the kernel alone at the
 serving geometries (the dense cells' pool of keys and values, the Kimi
-cell's pool of latent rows, the ZAYA cell's narrow pool, the Trinity
-cell's pool under a growing table and under a ring read through a
-window), each under its slot mixes, against the gather formulation, as
-seconds a layer and as a share of the live bytes' time at the memory
-roofline, at the chunk and the issue group the kernel's rule gives and
-at fixed ones beside them, the bytes one copy moves on every row.
+cell's pool of latent rows and the Pangu cell's under two query rows
+of 128 heads, the ZAYA cell's narrow pool, the Trinity cell's pool
+under a growing table and under a ring read through a window), each
+under its slot mixes, against the gather formulation, as seconds a
+layer and as a share of the live bytes' time at the memory roofline,
+at the chunk, the issue group and the extent the kernel's rules give
+and at fixed ones beside them, the copies a call issues and the bytes
+one of them moves on every row.  A one-head geometry's table is what
+the paged cache hands out (serve/kvcache.py): aligned extents of
+consecutive blocks, here each slot's row one run, so that any extent
+can be tried; the row `kernel_scattered` reads a shuffled table a block
+a copy, as every call did before PR 41.
 `python tools/paged_kernel_bench.py [geometry ...]` prints one JSON
 line a measurement; fails off the TPU."""
 import functools
@@ -24,6 +30,7 @@ sys.path.insert(0, ".")
 from singa_tpu.ops import paged_attention as pa  # noqa: E402
 
 HBM_BYTES_S = 819e9
+MXU_FLOP_S = 197e12
 
 
 class Geometry(NamedTuple):
@@ -42,6 +49,7 @@ class Geometry(NamedTuple):
     scale: float
     value_dim: int
     window: int = 0         # > 0: `table` is the ring's width
+    rows: int = 1           # query rows a slot (`heads` a row)
 
 
 GEOMETRIES = {
@@ -50,6 +58,13 @@ GEOMETRIES = {
     # kimilinear-serve-l17-ep8: kMLA, rank 512 + rope 64 stored as 640
     "latent": Geometry(96, 32, 1, 640, 16, 128, 4, 1,
                        1 / math.sqrt(192), 512),
+    # openpangu-ultra-moe-serve-l5-ep32: kMLA at 128 heads, a verify
+    # step's two rows a slot, five layers and the module's one
+    "latent128x2": Geometry(64, 128, 1, 640, 16, 448, 6, 1,
+                            1 / math.sqrt(192), 512, rows=2),
+    # the same pool under one query row: T = a + b x rows
+    "latent128": Geometry(64, 128, 1, 640, 16, 448, 6, 1,
+                          1 / math.sqrt(192), 512),
     # zaya1-8b-serve-l16: kCCA
     "cca": Geometry(64, 8, 2, 128, 16, 256, 16, 2, 1 / math.sqrt(128), 128),
     # trinity-mini-serve-l16-ep8: kAttention, 4 full layers under the
@@ -67,6 +82,15 @@ def mixes(name, g, rng):
         agent = 300 + rng.exponential(2300, g.slots)
         return {"agent": np.minimum(agent, 8000).astype(np.int32),
                 "full": np.full(g.slots, 8191, np.int32)}
+    if name in ("latent128x2", "latent128"):
+        # the Pangu cell's full house: 64 slots of up to 7,168 rows, a
+        # mean of ~2,050 (the ledger's ~132 k live rows a call, PR 39);
+        # the same draw scaled to the 110,698 rows PR 39's builder read
+        # the kernel alone at
+        think = np.minimum(300 + rng.exponential(1850, g.slots), 7168)
+        return {"think": think.astype(np.int32),
+                "probe39": (think * (110698 / think.sum())).astype(np.int32),
+                "full": full - (g.rows - 1)}
     if name == "latent":
         # the Kimi cell's full house: cb_live_block_share 0.22
         return {"assist": rng.integers(150, 750, g.slots).astype(np.int32),
@@ -109,15 +133,22 @@ def bench(name, g):
     rng = np.random.default_rng(0)
     nb = g.slots * g.table + 1
     dt = jnp.bfloat16
-    q = jnp.asarray(rng.standard_normal((g.slots, g.heads, g.d)), dt)
+    q = jnp.asarray(
+        rng.standard_normal((g.slots, g.rows * g.heads, g.d)), dt)
     pool = jnp.asarray(
         rng.standard_normal((nb, g.sides * g.hkv, g.bl, g.d)), dt)
     copy_bytes = pool[0].size * pool.dtype.itemsize
-    tables = jnp.asarray(rng.permutation(np.arange(1, nb))
-                         .reshape(g.slots, g.table).astype(np.int32))
+    scattered = jnp.asarray(rng.permutation(np.arange(1, nb))
+                            .reshape(g.slots, g.table).astype(np.int32))
+    tables = scattered
     how = {"scale": g.scale}
     if g.sides == 1:
         how["value_dim"] = g.value_dim
+        # a slot's row one run of the pool's blocks, begun at 1 + k E
+        # for every E that divides the table
+        tables = jnp.arange(1, nb, dtype=jnp.int32).reshape(g.slots, g.table)
+    if g.rows > 1:
+        how["rows"] = g.rows
     if g.window:
         how["window"] = g.window
     gather = functools.partial(pa.paged_attention_reference, **how)
@@ -132,38 +163,61 @@ def bench(name, g):
     for mix, ntoks in mixes(name, g, rng).items():
         nt = jnp.asarray(ntoks)
         first = np.maximum(ntoks - g.window + 1, 0) // g.bl if g.window else 0
-        live = int(np.sum(ntoks // g.bl - first + 1))
+        # the walk goes to a slot's last query row
+        horizon = ntoks + g.rows - 1
+        live = int(np.sum(horizon // g.bl - first + 1))
         need = live * copy_bytes / HBM_BYTES_S
+        # scores over a row's D columns and values of `value_dim`, for
+        # every query head of every row, at each live block's positions
+        work = (live * g.bl * g.rows * g.heads * 2 * (g.d + g.value_dim)
+                / MXU_FLOP_S)
         ref = gather(q, pool, tables, nt)
         ruled = pa.chunk_positions(pool.shape, pool.dtype,
                                    how.get("value_dim"))
+        extent = 1 if g.window else pa.extent_blocks(
+            pool.shape, pool.dtype, how.get("value_dim"), g.table)
         fixed = functools.partial(pa.singa_paged_decode, interpret=False,
                                   **how)
-        rows = {"gather": (gather, None, None),
+        at = functools.partial(fixed, chunk=ruled)
+        rows = {"gather": (gather, None, None, 1),
                 "kernel": (functools.partial(pa.paged_decode_attention,
-                                             **how), ruled, pa._ISSUE_GROUP)}
+                                             **how), ruled, pa._ISSUE_GROUP,
+                           extent)}
         for pos in (128, 256, 512, 1024):
-            rows[f"kernel_{pos}"] = (functools.partial(fixed, chunk=pos),
-                                     pos, pa._ISSUE_GROUP)
+            e = math.gcd(extent, pos // g.bl)     # divides the chunk
+            rows[f"kernel_{pos}"] = (
+                functools.partial(fixed, chunk=pos, extent=e), pos,
+                pa._ISSUE_GROUP, e)
         for group in (4, 8, 16):
             rows[f"kernel_group_{group}"] = (functools.partial(
-                fixed, chunk=ruled, group=group), ruled, group)
-        for label, (fn, chunk, group) in rows.items():
-            one = jax.jit(fn)(q, pool, tables, nt)
+                at, group=group, extent=extent), ruled, group, extent)
+        if g.sides == 1 and g.hkv == 1 and not g.window:
+            for e in (1, 2, 4, 8, 16, 32):
+                rows[f"kernel_extent_{e}"] = (functools.partial(
+                    at, extent=e), ruled, pa._ISSUE_GROUP, e)
+            rows["kernel_scattered"] = (at, ruled, pa._ISSUE_GROUP, 1)
+        for label, (fn, chunk, group, e) in rows.items():
+            table = scattered if label == "kernel_scattered" else tables
+            want = ref if table is tables else gather(q, pool, table, nt)
+            one = jax.jit(fn)(q, pool, table, nt)
             err = float(jnp.max(jnp.abs(one.astype(jnp.float32)
-                                        - ref.astype(jnp.float32))))
-            sec = timed(chain(fn, g.layers), (q, pool, tables, nt),
+                                        - want.astype(jnp.float32))))
+            sec = timed(chain(fn, g.layers), (q, pool, table, nt),
                         g.layers)
+            copies = (live if e == 1
+                      else int(np.sum(horizon // (g.bl * e) + 1)))
             print(json.dumps({
                 "geometry": name, "mix": mix, "what": label,
                 "live_blocks": live,
                 "live_block_share": live / (g.slots * g.table),
                 "chunk_positions": chunk, "issue_group": group,
-                "copy_bytes": copy_bytes,
-                "copies_a_call": live,
+                "extent_blocks": e,
+                "copy_bytes": copy_bytes * e,
+                "copies_a_call": copies,
                 "us_a_layer": sec * 1e6,
                 f"ms_a_step_{g.layers}_layers": sec * g.layers * 1e3,
                 "roofline_share": need / sec,
+                "operations_share": work / sec,
                 "max_err_vs_gather": err}), flush=True)
 
 
