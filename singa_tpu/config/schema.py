@@ -250,6 +250,9 @@ class KDAConfig:
     head_dim: int = 64
     conv_kernel: int = 4
     epsilon: float = 1e-5        # of the output's per-head RMSNorm
+    # beta = 2 sigmoid(.) in place of sigmoid(.): the delta rule's
+    # transition I - beta k k^T then has eigenvalues in [-1, 1]
+    neg_eigval: bool = False
 
 
 @dataclass

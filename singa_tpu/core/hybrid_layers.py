@@ -96,7 +96,10 @@ class KDALayer(Layer):
 
     q~, k~, v~ = x Wq, x Wk, x Wv, each through a causal depthwise conv
     (kernel `conv_kernel`) and SiLU; per head q = l2norm(q~) / sqrt(D),
-    k = l2norm(k~), v = v~; beta = sigmoid(x Wbeta); log-decay per head
+    k = l2norm(k~), v = v~; beta = sigmoid(x Wbeta), or twice that with
+    `neg_eigval` (the transition I - beta k k^T then has eigenvalues in
+    [-1, 1]: a state can flip a direction as well as forget it), in the
+    one-token step, the scan and the chunked form alike; log-decay per head
     and key channel g = -exp(A_log) softplus(Wfb (Wfa x) + dt_bias);
     the delta rule of ops/kda.py; o = RMSNorm_D(o) * sigmoid(Wgb (Wga
     x)) per head, then Wo.  The recurrence, its decay and the norms are
@@ -109,6 +112,7 @@ class KDALayer(Layer):
         b, s, e = tuple(src_shapes[0])
         self.heads, self.head_dim = p.num_heads, p.head_dim
         self.conv_kernel, self.eps = p.conv_kernel, p.epsilon
+        self.beta_scale = 2.0 if p.neg_eigval else 1.0
         self.out_shape = (b, s, e)
         h, d = self.heads, self.head_dim
         hd, se, sd = h * d, 1.0 / math.sqrt(e), 1.0 / math.sqrt(d)
@@ -146,6 +150,8 @@ class KDALayer(Layer):
             jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
         q, k = norm(q) * d ** -0.5, norm(k)
         beta = jax.nn.sigmoid(_dot(x, params[self.w_beta]))
+        if self.beta_scale != 1.0:
+            beta = beta * self.beta_scale
         low = _dot(x, params[self.w_fa]).astype(x.dtype)
         f = _dot(low, params[self.w_fb]) + params[self.dt_bias].astype(
             jnp.float32)
@@ -202,6 +208,19 @@ class KDALayer(Layer):
             o, state = kda_ops.delta_rule_chunked(q, k, v, g, beta,
                                                   entry["S"], valid)
         return self._output(params, x, o), {"S": state, "conv": tails}
+
+    def apply_chunk(self, params, x, entry, row, slot, start, plen, piece):
+        """A chunk of a prompt that is prefilled in several
+        (`generate.forward_chunk`): x (1, T, E) goes on from slot
+        `slot`'s own state and tails as the chunk before left them
+        (zeros at `start` 0, whatever the slot's last tenant left), and
+        leaves them as they are after the chunk's last REAL row."""
+        here = lambda a: jnp.where(start == 0, jnp.zeros_like(a[:1]),  # noqa: E731
+                                   jax.lax.dynamic_index_in_dim(a, slot, 0))
+        out, state = self.apply_cached(
+            params, x, {"S": here(entry["S"]), "conv": here(entry["conv"])},
+            start, plen=plen)
+        return out, self.scatter_prefill(entry, state, row, slot)
 
     def apply_paged(self, params, x, entry, tables, ntoks):
         """x (1, S, E): slot s's token against slot s's state, stepped
@@ -566,6 +585,14 @@ class RoutedMoELayer(Layer):
             valid = jnp.broadcast_to(valid, (b, t)).reshape(b * t)
         out, _ = self._ffn(params, x.reshape(b * t, e), valid)
         return out.reshape(b, t, e), entry
+
+    def apply_chunk(self, params, x, entry, row, slot, start, plen, piece):
+        """A chunk of a prompt prefilled in several: no state to carry;
+        the chunk's routing counts over its real rows are left where a
+        decode step leaves its own."""
+        _, t, e = x.shape
+        out, counts = self._ffn(params, x.reshape(t, e), jnp.arange(t) < plen)
+        return out.reshape(1, t, e), {"routed": counts}
 
     def apply_paged(self, params, x, entry, tables, ntoks):
         busy = ntoks > 0

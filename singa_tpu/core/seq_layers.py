@@ -23,8 +23,10 @@ import jax.numpy as jnp
 
 from ..config.schema import ParamConfig
 from ..ops import moe as moe_ops
-from ..ops.attention import (attention_reference, expand_kv_heads,
-                             flash_attention, flash_prefill, rope)
+from ..ops.attention import (attention_reference, chunk_attention,
+                             expand_kv_heads, flash_attention, flash_part,
+                             flash_part_legal, flash_prefill,
+                             merge_attention, rope)
 from ..ops.paged_attention import paged_decode_attention, ring_blocks
 from .layers import Context, Layer, LayerError, register_layer
 
@@ -260,6 +262,52 @@ def _attend_dense(q, k_cache, v_cache, pos, kmask, window):
     return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
 
+def attend_prefix(q, k, v, pool, row, start, piece: int):
+    """A chunk's attention where the rows before it are in the paged
+    pool: q (1, H, T, D), the first at absolute position `start`
+    (traced, a multiple of `piece`), k / v (1, Hkv, T, D) the chunk's
+    own; `pool` (num_blocks, 2 * Hkv, block_len, D) holds rows
+    [0, start) of the sequence in the blocks `row` names (the slot's
+    whole table row).  Causal within the chunk, in full over the rows
+    before it, `piece` rows of the pool at a time: each part gives
+    (out, log-sum-exp) and `merge_attention` joins them, so the scores
+    that exist at once are one part's and the time follows `start`, not
+    the table's width.  A part goes through the flash forward kernel
+    (`ops.attention.flash_part`) where its shapes are legal there, and
+    through `chunk_attention`'s dense scores where not (the tiny test
+    sizes).  Returns (1, T, H * D) float32."""
+    _, heads, t, d = q.shape
+    hkv, bl = k.shape[1], pool.shape[2]
+    nb = piece // bl
+    flash = flash_part_legal(t, t, d, heads, hkv) and \
+        flash_part_legal(t, piece, d, heads, hkv)
+
+    def packed(a):                       # (1, n, rows, D) -> (1, rows, n D)
+        return a.transpose(0, 2, 1, 3).reshape(1, a.shape[2], -1)
+
+    def part(kk, vv, causal):
+        """(out (1, T, H, D), lse (1, T, H, 1)), both float32."""
+        if flash:
+            out, lse = flash_part(packed(q), packed(kk.astype(q.dtype)),
+                                  packed(vv.astype(q.dtype)), heads, hkv,
+                                  causal)
+            return (out.reshape(1, t, heads, d).astype(jnp.float32),
+                    lse[..., None])
+        out, lse = chunk_attention(q, expand_kv_heads(kk.astype(q.dtype), heads),
+                                   expand_kv_heads(vv.astype(q.dtype), heads),
+                                   causal, 0, 0)
+        return out.transpose(0, 2, 1, 3), lse.transpose(0, 2, 1, 3)
+
+    def before(i, carry):
+        blocks = pool[jax.lax.dynamic_slice_in_dim(row, i * nb, nb)]
+        rows = blocks.transpose(1, 0, 2, 3).reshape(1, 2 * hkv, piece, d)
+        return merge_attention(*carry,
+                               *part(rows[:, :hkv], rows[:, hkv:], False))
+
+    out, _ = jax.lax.fori_loop(0, start // piece, before, part(k, v, True))
+    return out.reshape(1, t, heads * d)
+
+
 @register_layer("kAttention")
 class AttentionLayer(Layer):
     """Multi-head (GQA) causal self-attention with RoPE.
@@ -318,9 +366,11 @@ class AttentionLayer(Layer):
                 ParamSpec(key, (self.head_dim,), 0, one)
                 for key in (self.q_norm, self.k_norm)]
         if self.window:
-            # the serving state is a ring per slot, not table blocks
+            # the serving state is a ring per slot, not table blocks;
+            # and a ring does not hold the rows before a chunk
             self.init_pool = self._init_ring
             self.scatter_prefill = self._scatter_ring
+            self.apply_chunk = None
 
     def _proj(self, params, key, x, ctx):
         w = params[key]
@@ -561,6 +611,27 @@ class AttentionLayer(Layer):
         return out, entry
 
 
+
+    def apply_chunk(self, params, x, entry, row, slot, start, plen, piece):
+        """A chunk of a prompt that is prefilled in several
+        (`generate.forward_chunk`): x (1, T, E) at positions `start` ..
+        start + T - 1, T whole blocks.  Its K/V rows go into the blocks
+        of the slot's table row `row` that hold those positions (pads
+        too: into reserved blocks a decode step writes before it reads,
+        or the null block), then `attend_prefix`: causal within the
+        chunk, in full over rows [0, start) of the pool.  `plen` is for
+        recurrent mixers, as in `apply_cached`."""
+        assert self.causal, f"{self.name}: decode requires causal attention"
+        pool = entry["kv"]
+        bl, t = pool.shape[2], x.shape[1]
+        q, k, v = self.qkv(params, x, start + jnp.arange(t), DECODE_CTX)
+        rows = paged_rows(k[0], v[0])                        # (2 Hkv, T, D)
+        blocks = rows.reshape(rows.shape[0], t // bl, bl, -1).transpose(
+            1, 0, 2, 3)
+        mine = jax.lax.dynamic_slice_in_dim(row, start // bl, t // bl)
+        out = attend_prefix(q, k, v, pool, row, start, piece)
+        pool = pool.at[mine].set(blocks.astype(pool.dtype))
+        return self._out(params, x, out, DECODE_CTX), {"kv": pool}
 
     def apply_paged(self, params, x, entry, tables, ntoks):
         """Single-token decode attention over a block/paged KV pool.

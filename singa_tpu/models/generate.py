@@ -49,6 +49,12 @@ Cache = Dict[str, CacheEntry]         # layer name -> its entry
 #       of these in one entry
 #   apply_paged(params, x, entry, tables, ntoks)  -> (out, entry)
 #   scatter_prefill(pool, cache, table_row, slot) -> pool
+#   apply_chunk(params, x, entry, row, slot, start, plen, piece)
+#       -> (out, entry)   optional: a chunk of a prompt that is
+#       prefilled in several, straight against the serving state (rows
+#       [0, start) of the slot are in it already; `forward_chunk`).  A
+#       layer without it keeps the model to prompts of one chunk
+#       (`unchunked_layers`)
 # `x` is the layer's source, or the list of them where the layer has
 # several; `out` is an array, or a dict of named outputs that the
 # layer's consumers read by name (`hybrid_layers.named_output`).
@@ -114,11 +120,14 @@ def mtp_module(net: NeuralNet) -> Optional[MTPModule]:
     return MTPModule(entry, frozenset(inside), last, embed, hidden)
 
 
-def _walk(net: NeuralNet, params, tokens, state: Cache, step, hidden=None):
+def _walk(net: NeuralNet, params, tokens, state: Cache, step, hidden=None,
+          head: bool = True):
     """The LM over `tokens` with `step(layer, full, x, entry)` ->
     (out, entry) at every layer that keeps state; every other layer
     runs its normal `apply`.  Returns (logits float32, new state, the
-    main stack's output where the net has a module).
+    main stack's output where the net has a module); without `head`,
+    what the head would project in the logits' place (`project_head`
+    makes logits of any rows of it).
 
     With `hidden` the walk is the MODULE's: `tokens` are, for every
     position, the token AFTER it; their embedding and `hidden` go into
@@ -127,7 +136,7 @@ def _walk(net: NeuralNet, params, tokens, state: Cache, step, hidden=None):
     full = net._resolve_params(params)
     outputs: Dict[str, Any] = {}
     new_state: Cache = dict(state)
-    head = head_src = None
+    want_logits, head, head_src = head, None, None
     module = mtp_module(net)
     drafting = hidden is not None
     if drafting:
@@ -166,12 +175,26 @@ def _walk(net: NeuralNet, params, tokens, state: Cache, step, hidden=None):
         raise ValueError("net has no kLMHead/kLMHeadLoss layer")
     if drafting:
         head_src = outputs[module.last]
+    logits = (_head_logits(head, full, head_src) if want_logits
+              else head_src)
+    return (logits, new_state,
+            None if module is None or drafting else outputs[module.hidden])
+
+
+def _head_logits(head, full, head_src):
     # the fused loss layer's projection is reused to emit logits
     logits = (head.apply(full, [head_src], DECODE_CTX)
               if head.cfg.type == "kLMHead"
               else head.project_logits(full, head_src))
-    return (logits.astype(jnp.float32), new_state,
-            None if module is None or drafting else outputs[module.hidden])
+    return logits.astype(jnp.float32)
+
+
+def project_head(net: NeuralNet, params, rows):
+    """Logits (B, T, V) float32 of `rows` (B, T, E) of what a walk
+    without `head` handed back."""
+    head = next(net.layers[n] for n in net.topo
+                if net.layers[n].cfg.type in ("kLMHead", "kLMHeadLoss"))
+    return _head_logits(head, net._resolve_params(params), rows)
 
 
 def forward_cached(net: NeuralNet, params, tokens: jnp.ndarray,
@@ -225,6 +248,46 @@ def forward_paged(net: NeuralNet, params, tokens: jnp.ndarray,
                 lambda layer, full, x, entry: layer.apply_paged(
                     full, x, entry, tables, ntoks))
     return out if with_hidden else out[:2]
+
+
+def unchunked_layers(net: NeuralNet) -> Tuple[str, ...]:
+    """What keeps the net to prompts of one chunk: the kinds of its
+    layers whose serving state a chunk at start > 0 cannot carry on
+    from (no `apply_chunk`: a latent cache, a ring, CCA's tails), and
+    the MTP module.  Empty where every layer can."""
+    kinds = []
+    for name in net.topo:
+        layer = net.layers[name]
+        if hasattr(layer, "init_pool") and \
+                getattr(layer, "apply_chunk", None) is None:
+            kind = layer.cfg.type
+            if getattr(layer, "window", 0):
+                kind += " with a window (a ring of blocks)"
+            if kind not in kinds:
+                kinds.append(kind)
+    if mtp_module(net) is not None and "kMTP" not in kinds:
+        kinds.append("kMTP")
+    return tuple(kinds)
+
+
+def forward_chunk(net: NeuralNet, params, tokens: jnp.ndarray, pools: Cache,
+                  row, slot, start, plen, piece: int):
+    """One chunk of a prompt that is prefilled in several, straight
+    against the serving state of slot `slot`: `tokens` (1, T) the
+    chunk's, right-padded, its first `plen` real (traced), the first at
+    absolute position `start` (traced; a multiple of `piece`, the widest
+    chunk).  `row` is the slot's WHOLE table row.  Rows [0, start) are
+    in the pools already: a recurrent layer goes on from the slot's own
+    state and tails (zeros at start 0), an attention layer writes the
+    chunk's K/V rows into the slot's blocks and attends them causally
+    and rows [0, start) in full, `piece` rows of the pool at a time.
+    Returns (what the head projects, (1, T, E); updated pools): the
+    head is the caller's, on the one row it needs (`project_head`)."""
+    out = _walk(net, params, tokens, pools,
+                lambda layer, full, x, entry: layer.apply_chunk(
+                    full, x, entry, row, slot, start, plen, piece),
+                head=False)
+    return out[:2]
 
 
 def draft_paged(net: NeuralNet, params, hidden, next_tokens, pools: Cache,

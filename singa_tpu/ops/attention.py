@@ -837,6 +837,49 @@ def singa_flash_prefill(q, k, v, *, num_heads, num_kv_heads, window,
                            vmem_limit_bytes=_PREFILL_VMEM_BYTES)[0]
 
 
+def flash_part_legal(sq: int, sk: int, head_dim: int, num_heads: int,
+                     num_kv_heads: int) -> bool:
+    """Whether `flash_part` takes queries of `sq` rows against keys of
+    `sk`: whole lane tiles of rows on both sides and a head the kernel
+    is legal at (`core.seq_layers.attend_cache`'s own conditions)."""
+    return (sq % 128 == 0 and sk % 128 == 0 and head_dim % 8 == 0
+            and num_heads % num_kv_heads == 0)
+
+
+def flash_part(q, k, v, num_heads: int, num_kv_heads: int, causal: bool):
+    """A chunk's queries q (B, Sq, H·D) against ONE PART of their keys,
+    k / v (B, Sk, Hkv·D), forward only: the chunk's own rows (`causal`,
+    Sq = Sk, the first query at the first key) or rows that all lie
+    before it (not causal).  Returns (out (B, Sq, H·D) normalised over
+    the part, natural log-sum-exp (B, Sq, H) float32): the pair
+    `merge_attention` joins, so that a chunk of a long prompt attends
+    its prefix a piece of the paged pool at a time and no array of
+    chunk x context scores exists (`core.seq_layers.attend_prefix`).
+    Blocks and heads a step as `flash_prefill` chooses them."""
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[-1] // num_heads
+    per_step = 1 if d % 128 == 0 else 0
+    held, held_kv = (1, 1) if per_step else (num_heads, num_kv_heads)
+    bq, _ = prefill_blocks(sq, held * d, held_kv * d, q.dtype.itemsize)
+    _, bk = prefill_blocks(sk, held * d, held_kv * d, q.dtype.itemsize)
+    return singa_flash_part(
+        q, k, v, num_heads=num_heads, num_kv_heads=num_kv_heads,
+        causal=bool(causal), block_q=bq, block_k=bk,
+        heads_per_step=per_step, interpret=not _on_tpu())
+
+
+# named as `singa_flash_prefill` is: the row of a device trace that
+# holds the chunk programs' attention
+@functools.partial(jax.jit, static_argnames=(
+    "num_heads", "num_kv_heads", "causal", "block_q", "block_k",
+    "heads_per_step", "interpret"))
+def singa_flash_part(q, k, v, *, num_heads, num_kv_heads, causal, block_q,
+                     block_k, heads_per_step, interpret):
+    return _packed_forward(q, k, v, num_heads, causal, block_q, block_k,
+                           interpret, num_kv_heads, 0, fold_scale=False,
+                           heads_per_step=heads_per_step,
+                           vmem_limit_bytes=_PREFILL_VMEM_BYTES)
+
+
 def flash_chunk(q, k, v, causal: bool,
                 interpret: Optional[bool] = None,
                 block_q: int = 512, block_k: int = 512):

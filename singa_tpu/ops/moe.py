@@ -137,6 +137,59 @@ def route_mlp_softmax(state, norm_scale, eps: float, layers, bias, k: int):
     return idx.astype(jnp.int32), jnp.take_along_axis(p, idx, axis=-1)
 
 
+def grouped_run(rows: int, num_held: int, experts_per_token: int) -> bool:
+    """Whether `held_experts_ffn` takes a run of `rows` rows in its
+    grouped form: a run past ROW_BLOCK where the dense walk would cost
+    more expert products a row than the routing asks for."""
+    return rows > ROW_BLOCK and num_held > experts_per_token
+
+
+def _grouped(x, local, held, weights, group_sizes, w_gate, w_up, w_down):
+    """`held_experts_ffn`'s grouped form.  local (T, k) the chosen
+    experts' places among the held ones, held (T, k) bool which of them
+    are held (and their row real), group_sizes (X,) assignments on each
+    held expert.
+
+    The grouped matmul itself visits the tiles of real groups only,
+    whatever the rows handed, but everything around it follows the rows
+    HANDED: the gather of the rows, the SiLU and the product on (m, F),
+    the (m, E) float32 result and its gather back (PERF.md 6, PR 43).
+    So the sorted rows are cut to HALF of the T k assignments where the
+    groups fit that: a router that spreads its choices puts held /
+    routed of them here, an eighth in the configurations that hold a
+    share of their experts.  ONE size whatever the routing, because a
+    size that followed it made a run's cost follow the weights' seed.
+    Past a half, all of them, exact always: a layer that holds EVERY
+    expert a token can choose (ZAYA's 16 of 16, top 1) always takes
+    that side."""
+    t, k = local.shape
+    n_held = w_gate.shape[0]
+    # an assignment's expert, `n_held` where it is on none held here:
+    # those sort behind every group, and no group's product reads them
+    order = jnp.argsort(jnp.where(held, local, n_held).reshape(t * k),
+                        stable=True)
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+
+    def first(m):
+        """The first `m` sorted assignments through their experts."""
+        rows = x[order[:m] // k]                             # (m, E)
+        dot = lambda a, w: jax.lax.ragged_dot(               # noqa: E731
+            a, w, group_sizes, preferred_element_type=jnp.float32)
+        hid = (jax.nn.silu(dot(rows, w_gate))
+               * dot(rows, w_up)).astype(x.dtype)
+        out = dot(hid, w_down)                               # (m, E) f32
+        # back to the tokens' order; what lies behind the last group is
+        # whatever the product left there, and is not read
+        out = out[jnp.minimum(back, m - 1)].reshape(t, k, -1)
+        return jnp.sum(jnp.where(held[:, :, None],
+                                 out * weights[:, :, None], 0.0), axis=1)
+
+    half = -(-t * k // 2)
+    return jax.lax.cond(jnp.sum(group_sizes) <= half,
+                        lambda: first(half), lambda: first(t * k))
+
+
 def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
                      valid=None, max_load: bool = False):
     """What the experts held here add for each token: expert j of the
@@ -149,13 +202,26 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
     assignments).  A token none of whose experts is held gets zeros; no
     token is dropped and no row depends on another.
 
-    Every row goes through every held expert, the pairs the router did
-    not choose weighted 0: with the experts' weights as the traffic and
-    a few rows an expert (a decode step, a balanced router) nothing is
-    saved by sorting, and the step costs the same whatever the routing.
-    A run longer than ROW_BLOCK rows is walked a block at a time, and
-    no block after the last one that holds a real row is walked: a
-    right-padded prompt costs its own length."""
+    Which of two forms computes it follows from the layer's own sizes
+    and the run's length, and is no setting (`grouped_run`):
+
+    * the DENSE WALK, for a run of at most ROW_BLOCK rows (a decode
+      step) and wherever this process holds no more experts than a
+      token chooses: every row goes through every held expert, the
+      pairs the router did not choose weighted 0.  With the experts'
+      weights as the traffic and a few rows an expert nothing is saved
+      by sorting, and the step costs the same whatever the routing.
+      A longer run is walked ROW_BLOCK rows at a time, and no block
+      after the last one that holds a real row is walked.
+    * the GROUPED form, for a longer run (a prefill, a chunk of one)
+      where more experts are held than a token chooses: the dense walk
+      costs `num_held` expert products a row, this one the row's own
+      assignments on held experts, at most `experts_per_token`.  The
+      (token, expert) assignments are sorted by expert, those on no
+      held expert (and every pad's) last, and each held expert's run of
+      rows is multiplied by that expert's weights
+      (`jax.lax.ragged_dot`: on the TPU a Mosaic grouped matmul).  No
+      capacity, no dropped token, float32 sums, the same `counts`."""
     t, e = x.shape
     n_held = w_gate.shape[0]
     local = idx - first                                      # (T, k)
@@ -169,6 +235,9 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
     if max_load:
         counts.append(jnp.max(per_expert))
     counts = jnp.stack(counts).astype(jnp.int32)
+    if grouped_run(t, n_held, idx.shape[1]):
+        return _grouped(x, local, held, weights, per_expert.astype(jnp.int32),
+                        w_gate, w_up, w_down), counts
 
     def through(rows, row_combine):
         gate = jnp.einsum("te,xef->txf", rows, w_gate,

@@ -51,10 +51,12 @@ import numpy as np
 from .. import obs
 from ..obs import perf
 from ..models.generate import (_sample, draft_cached, draft_paged,
-                               forward_cached, forward_paged, init_cache,
-                               mtp_module, sample_logprob, scatter_prefill,
-                               verify_draft)
-from ..ops.attention import singa_flash_prefill
+                               forward_cached, forward_chunk, forward_paged,
+                               init_cache, mtp_module, project_head,
+                               sample_logprob, scatter_prefill,
+                               unchunked_layers, verify_draft)
+from ..ops.attention import singa_flash_part, singa_flash_prefill
+from ..ops.moe import grouped_run
 from ..utils import faults
 from ..utils.checkpoint import CheckpointManager
 from .kvcache import init_pools, slot_behind_row
@@ -112,6 +114,10 @@ class ServeSpec:
     cb_blocks: int = 0        # pool size incl. null block; 0 = auto
     cb_prompt_cap: int = 0    # longest admissible prompt; 0 = widest
                               # bucket prompt_len
+    cb_prefill_rung: int = 0  # widest compiled prefill; 0 = the cap.  A
+                              # cap past it is served in chunks of it
+                              # (`cb_chunks`), where every layer of the
+                              # model can carry a chunk on
     # model family this engine serves: half of the (family, step)
     # serving fingerprint.  Engines advertise it on /healthz, the
     # router dispatches a request's `model` onto matching members
@@ -153,9 +159,10 @@ class ServeSpec:
                              f"{self.cb!r}")
         if int(self.cb_slots) < 1 or int(self.cb_block_len) < 1:
             raise ValueError("cb_slots and cb_block_len must be >= 1")
-        if int(self.cb_blocks) < 0 or int(self.cb_prompt_cap) < 0:
-            raise ValueError("cb_blocks and cb_prompt_cap must be "
-                             ">= 0 (0 = auto)")
+        if min(int(self.cb_blocks), int(self.cb_prompt_cap),
+               int(self.cb_prefill_rung)) < 0:
+            raise ValueError("cb_blocks, cb_prompt_cap and cb_prefill_rung "
+                             "must be >= 0 (0 = auto)")
         if float(self.stall_fault_s) < 0:
             raise ValueError(f"stall_fault_s must be >= 0, got "
                              f"{self.stall_fault_s}")
@@ -187,11 +194,34 @@ class ServeSpec:
 
     @property
     def cb_prefill_len(self) -> int:
-        """Compiled prefill width P: the prompt cap rounded UP to a
-        block multiple (prefill scatters whole blocks)."""
-        cap = int(self.cb_prompt_cap) or self.max_prompt_len
+        """The WIDEST compiled prefill P, rounded UP to a block multiple
+        (prefill scatters whole blocks): `cb_prefill_rung` where that is
+        set and under the cap, else the prompt cap `cb_max_prompt_len`
+        itself, and then the two are one number.  A prompt no longer
+        than P is prefilled whole, a longer one (up to the cap) in
+        chunks of P (`cb_chunks`)."""
+        cap = self.cb_max_prompt_len
+        if int(self.cb_prefill_rung):
+            cap = min(cap, int(self.cb_prefill_rung))
         bl = int(self.cb_block_len)
         return -(-cap // bl) * bl
+
+    @property
+    def cb_chunked(self) -> bool:
+        """Whether the cap lies past the widest compiled prefill."""
+        return self.cb_max_prompt_len > self.cb_prefill_len
+
+    def cb_chunks(self, plen: int) -> Tuple[Tuple[int, int, int], ...]:
+        """(start, real rows, width) of the chunks a prompt of `plen`
+        tokens is prefilled in where the engine chunks: whole chunks of
+        the widest rung, then the rest at the narrowest rung that holds
+        it (a prompt within the widest rung: that one chunk)."""
+        wide = self.cb_prefill_len
+        whole, rest = divmod(int(plen), wide)
+        out = [(i * wide, wide, wide) for i in range(whole)]
+        if rest:
+            out.append((whole * wide, rest, self.cb_prefill_width(rest)))
+        return tuple(out)
 
     @property
     def cb_prefill_widths(self) -> Tuple[int, ...]:
@@ -213,20 +243,27 @@ class ServeSpec:
         for width in self.cb_prefill_widths:
             if width >= plen:
                 return width
-        raise ValueError(f"prompt length {plen} exceeds the widest "
-                         f"prefill program ({self.cb_prefill_len})")
+        raise ValueError(
+            f"prompt length {plen} exceeds the widest prefill program "
+            f"({self.cb_prefill_len} rows); the prompt cap is "
+            f"{self.cb_max_prompt_len}, and a prompt between the two goes "
+            f"in chunks (cb_chunks)")
 
     @property
     def cb_max_prompt_len(self) -> int:
-        """Longest admissible prompt under cb (fail-fast bound)."""
+        """The prompt CAP: the longest prompt `submit` admits under cb
+        (fail-fast bound).  It may lie past the widest compiled prefill
+        `cb_prefill_len`; the tables and the pool are sized by it."""
         return int(self.cb_prompt_cap) or self.max_prompt_len
 
     @property
     def cb_blocks_per_slot(self) -> int:
         """Table width T: worst-case blocks one slot can ever hold
-        (full prefill + a full generation)."""
-        bl = int(self.cb_block_len)
-        return -(-(self.cb_prefill_len + int(self.max_new_tokens)) // bl)
+        (full prefill + a full generation).  A prompt in chunks writes
+        whole chunks, so the cap counts in whole widest rungs."""
+        bl, wide = int(self.cb_block_len), self.cb_prefill_len
+        span = -(-self.cb_max_prompt_len // wide) * wide
+        return -(-(span + int(self.max_new_tokens)) // bl)
 
     @property
     def cb_pool_blocks(self) -> int:
@@ -384,6 +421,14 @@ class InferenceEngine:
         # the prefill hands back a first draft, the decode program is
         # the verify-and-draft step (two rows a slot, one or two tokens)
         self._mtp = mtp_module(net)
+        # the kinds of layers that keep this model to prompts of ONE
+        # chunk, whatever the spec's cap (`cb_prompt_limit`)
+        self.cb_unchunked = unchunked_layers(net)
+        # (experts held, experts a token chooses) of every routed layer:
+        # what says which runs it takes grouped (`grouped_row_slots`)
+        self._routed_sizes = tuple(
+            (layer.n_held, layer.k) for layer in
+            (net.layers[name] for name in self._routed_layers))
         self.stats = stats if stats is not None else ServeStats()
         self.log = log_fn
         self.ckpt = (CheckpointManager(workspace, log_fn=log_fn)
@@ -455,6 +500,32 @@ class InferenceEngine:
         net has an MTP module): a slot then yields one or two tokens a
         step, with their log-probabilities (docs/SERVING.md)."""
         return self._mtp is not None
+
+    @property
+    def chunks_prompts(self) -> bool:
+        """Whether a prompt past the widest compiled prefill is served,
+        in chunks: the spec's cap lies past it and every layer of the
+        model can carry a chunk on (docs/SERVING.md).  Such an engine
+        has ONE ladder, the chunk programs `cb_chunk_<width>`, and every
+        prompt takes it: one within the widest rung is a single last
+        chunk at start 0.  An engine that does not chunk keeps the
+        whole-prompt programs `cb_prefill_<width>` and no others."""
+        return self.spec.cb_chunked and not self.cb_unchunked
+
+    @property
+    def cb_prompt_limit(self) -> int:
+        """The longest prompt this engine serves: the spec's cap, or the
+        widest compiled prefill where the model cannot go in chunks."""
+        spec = self.spec
+        return (spec.cb_max_prompt_len if self.chunks_prompts
+                else min(spec.cb_max_prompt_len, spec.cb_prefill_len))
+
+    def grouped_row_slots(self, width: int, rows: int) -> int:
+        """Expert products the dense walk would cost `rows` real rows of
+        a run `width` wide, over the routed layers that take such a run
+        in the grouped form (0 where none does)."""
+        return rows * sum(held for held, k in self._routed_sizes
+                          if grouped_run(width, held, k))
 
     def note_poll_death(self) -> int:
         self._poll_death_streak += 1
@@ -842,6 +913,38 @@ class InferenceEngine:
 
         return cb_prefill
 
+    def _build_cb_chunk(self, p_len: int):
+        """A chunk of a prompt (all of one that a rung holds), at fixed
+        (1, P), P one rung of the ladder: `forward_chunk` straight
+        against the pools (the slot's own state and tails, the rows
+        already in its blocks), `meta` = [real rows, start, last].  The
+        head runs on the last real row only, and only in the prompt's
+        last chunk; any other chunk's token is 0.  Returns ([token, the
+        chunk's routing counts], pools)."""
+        net, spec = self.net, self.spec
+        piece = spec.cb_prefill_len
+        temperature, top_k, top_p = (float(spec.temperature),
+                                     int(spec.top_k), float(spec.top_p))
+
+        def cb_chunk(params, pools, tokens, meta, row, key):
+            rows, start, last = meta[0], meta[1], meta[2]
+            hidden, pools = forward_chunk(net, params, tokens, pools,
+                                          row[:-1], row[-1], start, rows,
+                                          piece)
+
+            def first_token():
+                at = jax.lax.dynamic_slice_in_dim(hidden, rows - 1, 1, axis=1)
+                return _sample(project_head(net, params, at)[0], key,
+                               temperature, top_k, top_p)[0].astype(jnp.int32)
+
+            out = [jax.lax.cond(last > 0, first_token,
+                                lambda: jnp.int32(0))[None]]
+            if self._routed_layers:
+                out.append(self._routed_counts(pools))
+            return jnp.concatenate(out), pools
+
+        return cb_chunk
+
     def _build_cb_decode(self):
         """ONE compiled decode step at fixed slot count S: every
         active slot advances one token against its paged blocks
@@ -980,6 +1083,9 @@ class InferenceEngine:
             if p_len is None:
                 p_len = spec.cb_prefill_len
             name = self._cb_prefill_name(p_len)
+        elif which == "chunk":
+            self._cb_prefill_name(p_len)          # a rung, or it raises
+            name = f"cb_chunk_{p_len}"
         key = (name, spec.cb_slots, spec.cb_blocks_per_slot)
         got = self._compiled.get(key)
         if got is not None:
@@ -1017,6 +1123,17 @@ class InferenceEngine:
                         p_spec, pools, tok, plen, row, rng)
                     # what `attend_cache` took at this rung's shapes
                     if singa_flash_prefill.__name__ in lowered.as_text():
+                        self.cb_flash_widths.add(p_len)
+                    compiled = lowered.compile()
+                elif which == "chunk":
+                    fn = self._build_cb_chunk(p_len)
+                    lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+                        p_spec, pools,
+                        jax.ShapeDtypeStruct((1, p_len), jnp.int32),
+                        jax.ShapeDtypeStruct((3,), jnp.int32),
+                        jax.ShapeDtypeStruct(
+                            (spec.cb_blocks_per_slot + 1,), jnp.int32), rng)
+                    if singa_flash_part.__name__ in lowered.as_text():
                         self.cb_flash_widths.add(p_len)
                     compiled = lowered.compile()
                 elif which == "decode":
@@ -1074,6 +1191,45 @@ class InferenceEngine:
             tok0.copy_to_host_async()
         return (tok0, t0, width), pools
 
+    def dispatch_cb_chunk(self, params, pools, tokens: np.ndarray,
+                          rows: int, start: int, last: bool,
+                          row: np.ndarray):
+        """One chunk of a prompt that is prefilled in several, handed to
+        the device: `tokens` (1, P) int32 the chunk's, RIGHT-padded to a
+        rung P, its first `rows` real, the first at position `start` of
+        the prompt; `row` what `PagedKVCache.chunk_target` gives (the
+        slot's whole table row, the slot's index behind it).  `last`:
+        the prompt ends in this chunk, and its token is the request's
+        first.  Returns (what `fetch_cb_chunk` takes, new pools) without
+        waiting; `pools` was donated."""
+        self._maybe_stall()
+        width = int(tokens.shape[1])
+        compiled = self._compile_cb("chunk", width)
+        t0 = time.perf_counter()
+        with obs.span("engine.cb_prefill", width=width, start=int(start),
+                      rows=int(rows)):
+            out, pools = compiled(
+                params, pools, np.asarray(tokens, np.int32),
+                np.array([rows, start, int(bool(last))], np.int32),
+                np.asarray(row, np.int32), self._next_key())
+            out.copy_to_host_async()
+        return (out, t0, width, bool(last)), pools
+
+    def fetch_cb_chunk(self, flying) -> Tuple[int, int]:
+        """(the chunk's token, 0 but for a prompt's last chunk; the
+        assignments of its real rows that fell on held experts, layers
+        summed) of a dispatched chunk (waits)."""
+        out, t0, width, last = flying
+        t = time.perf_counter()
+        with obs.span("engine.cb_prefill_fetch"):
+            got = np.asarray(out)
+        now = time.perf_counter()
+        self.cb_wait = (t, now)
+        if last:       # the one chunk nothing else ran behind
+            perf.observe_step(f"cb_chunk_{width}", now - t0)
+            perf.mark_serving_ready()
+        return int(got[0]), int(got[1]) if got.shape[0] > 1 else 0
+
     def run_cb_prefill_rungs(self, params, pools):
         """Every rung of the ladder run once, on pools no request
         holds yet: a program's first run on a device costs more than
@@ -1085,6 +1241,13 @@ class InferenceEngine:
         pools (`pools` was donated)."""
         spec = self.spec
         for width in spec.cb_prefill_widths:
+            if self.chunks_prompts:      # a last chunk of one row at 0
+                _, pools = self._compile_cb("chunk", width)(
+                    params, pools, jnp.zeros((1, width), jnp.int32),
+                    jnp.array([1, 0, 1], jnp.int32),
+                    jnp.zeros((spec.cb_blocks_per_slot + 1,), jnp.int32),
+                    jnp.zeros((2,), jnp.uint32))
+                continue
             row = jnp.zeros((width // spec.cb_block_len
                              + int(self._per_slot_state),), jnp.int32)
             _, pools = self._compile_cb("prefill", width)(
@@ -1261,8 +1424,12 @@ class InferenceEngine:
                 # says; predict stays on buckets.  One after another: on
                 # a TPU their compiles, and the persistent cache's
                 # fetches, run slower side by side (PERF.md 6, PR 28)
+                # (an engine that chunks sends EVERY prompt through the
+                # chunk programs, a short one as a single last chunk:
+                # one ladder either way)
+                which = "chunk" if self.chunks_prompts else "prefill"
                 for width in self.spec.cb_prefill_widths:
-                    self._compile_cb("prefill", width)
+                    self._compile_cb(which, width)
                 self._compile_cb("decode")
                 continue
             for b, p in self.spec.buckets:

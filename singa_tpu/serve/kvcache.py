@@ -147,7 +147,15 @@ def state_bytes(net, block_len: int, dtype=jnp.float32) -> Dict[str, int]:
     the paged kernel moves, a block of one layer's pool: `block_copy`
     under a table, `window_block_copy` in a ring (the widest layer's,
     should they differ).  Read off each layer's pool shapes at two
-    sizes, so a layer whose entry holds two kinds adds to both."""
+    sizes, so a layer whose entry holds two kinds adds to both.
+
+    A slot IN PREFILL (`ContinuousScheduler._prefill_chunk`) holds the
+    same and no more: its `slot` bytes and the blocks reserved for its
+    whole prompt and answer, all through its chunks, while it takes part
+    in no decode step; a chunk works straight on the pools.  (A
+    whole-prompt prefill builds a contiguous cache beside them and
+    scatters it: a transient of its program, counted with the program's
+    temporaries and not here.)"""
     import jax
     from ..ops.paged_attention import ring_blocks
 
@@ -336,6 +344,13 @@ class PagedKVCache:
         return np.append(row, slot).astype(np.int32) \
             if self.per_slot_state else row.copy()
 
+    def chunk_target(self, slot: int) -> np.ndarray:
+        """Where a chunk of a prompt that is prefilled in several reads
+        and writes slot `slot`'s state, as the chunk program takes it:
+        the slot's WHOLE table row (the chunk's own blocks and those of
+        the rows before it) and the slot's index behind it."""
+        return np.append(self.tables[slot], slot).astype(np.int32)
+
     def free(self, slot: int) -> None:
         """Retire `slot`: drop each block's refcount and return
         zero-refcount extents to the free list immediately."""
@@ -353,10 +368,16 @@ class PagedKVCache:
             self.free(slot)
 
     # -- reads --------------------------------------------------------------
-    def table_array(self) -> np.ndarray:
+    def table_array(self, hide=None) -> np.ndarray:
         """Copy of the (num_slots, max_blocks_per_slot) int32 block
-        table for upload to the compiled decode program."""
-        return self.tables.copy()
+        table for upload to the compiled decode program.  Slot `hide`
+        (one in prefill, which takes no part in the step) shows the null
+        block throughout: the step's write for an idle slot lands at
+        position 0 of its row, and that slot's row holds its prompt."""
+        tables = self.tables.copy()
+        if hide is not None:
+            tables[hide] = NULL_BLOCK
+        return tables
 
     def utilization(self) -> float:
         return (self.blocks_in_use / self.usable_blocks

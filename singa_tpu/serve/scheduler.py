@@ -25,6 +25,23 @@ SLOTS instead:
            the free pool immediately and the slot is free for the
            next admission that very step.
 
+Where `engine.chunks_prompts` (the cap lies past the widest compiled
+prefill, `ServeSpec.cb_prefill_len`) a prompt is admitted the same way,
+its blocks reserved whole before its first chunk, and then PREFILLED IN
+CHUNKS over several steps (`_prefill_chunk`); one within the widest
+rung is a single last chunk, by the same programs.  The rule is fixed here
+and is no setting: while a prompt is in prefill one chunk goes out,
+then one decode step of the running slots, and so on; ONE prompt is in
+prefill at a time, and nothing is admitted behind it meanwhile (FIFO
+holds).  Its slot is not active: it is not in a decode step's busy
+set, the step sees the null block in its table row
+(`PagedKVCache.table_array(hide=)`), and its state, tails and K/V rows
+are written by its own chunks only.  A chunk but the last is read back
+when the next one's turn comes (it ran while the decode step did); the
+last is waited for as a whole-prompt prefill is, and the slot joins the
+batch.  A cancel or a deadline between two chunks frees the blocks; the
+state needs nothing (a first chunk starts from zeros).
+
 Control plane vs data plane ("RPC Considered Harmful"): everything in
 this file is host-side numpy bookkeeping; device work is exactly one
 compiled-program invocation per prefill and one per decode step, both
@@ -234,6 +251,18 @@ class _CBRequest:
     link: Any = None
 
 
+@dataclass
+class _Prefill:
+    """The one prompt that is in prefill over several steps."""
+    req: _CBRequest
+    slot: int
+    chunks: tuple                 # (start, real rows, width) of each
+    trace: tuple                  # (trace id, parent) of its spans
+    queued: float                 # seconds it waited for admission
+    done: int = 0                 # chunks handed over
+    flying: Any = None            # the last of them, not yet read
+
+
 class ContinuousScheduler:
     """See module docstring.  One daemon loop thread; `submit` is
     called from any number of frontend threads."""
@@ -275,6 +304,8 @@ class ContinuousScheduler:
         # yet: (its tokens on the device, who held each slot then).
         # Only a step in which every slot was busy is left so
         self._flying: Optional[tuple] = None
+        # the prompt that is prefilled in chunks, if one is
+        self._prefilling: Optional[_Prefill] = None
         # the stall account (module docstring): the laps since the step
         # before was accounted, when the last one closed, whether one
         # of them was long; and how this step's decode went out
@@ -352,6 +383,9 @@ class ContinuousScheduler:
             self.stats.count("failed")
             r.ticket._fail(RuntimeError("server shutting down"))
         self._flying = None
+        if self._prefilling is not None:
+            self.stats.count("failed")
+            self._drop_prefill(RuntimeError("server shutting down"))
         for s, r in enumerate(self._slot_req):
             if r is not None:
                 self._retire(s, "shutdown", self.engine.params_step)
@@ -398,6 +432,15 @@ class ContinuousScheduler:
             raise ValueError(
                 f"prompt length {arr.size} exceeds the cb prompt cap "
                 f"({spec.cb_max_prompt_len}); not servable")
+        if arr.size > self.engine.cb_prompt_limit:
+            self.stats.count("rejected")
+            raise ValueError(
+                f"prompt length {arr.size} exceeds the widest prefill "
+                f"program ({spec.cb_prefill_len} rows), and a longer "
+                f"prompt goes in chunks only where every layer can carry "
+                f"a chunk on: this model's "
+                f"{', '.join(self.engine.cb_unchunked)} cannot; not "
+                f"servable")
         mn = int(max_new) if max_new is not None else \
             int(spec.max_new_tokens)
         if mn < 1:
@@ -526,7 +569,8 @@ class ContinuousScheduler:
             with self._cv:
                 idle = False
                 while (not self._pending and not self._active.any()
-                       and self._flying is None and not self._stop):
+                       and self._flying is None
+                       and self._prefilling is None and not self._stop):
                     idle = True
                     # the profiler's alone: an idle server's twenty
                     # waits a second would fill a session's tracer
@@ -601,11 +645,16 @@ class ContinuousScheduler:
             try:
                 # an unlocked peek: a submit that lands just after it
                 # is admitted by the next step, as it always was
-                if self._pending and not self._active.all():
+                if (self._pending and self._prefilling is None
+                        and not self._active.all()):
                     with obs.span("scheduler.admit_pending"):
                         admitted = self._admit_pending(params, step_no)
                     if admitted:
                         self.stats.count("cb_admit_steps")
+                if self._prefilling is not None:
+                    # an admission's hand-over, a chunk a step
+                    with obs.span("scheduler.admit_pending"):
+                        self._prefill_chunk(params, step_no)
                 if self._flying is not None and not self._active.all():
                     # a slot fell free and nothing took it
                     self._collect(step_no, COLLECT_DRAIN)
@@ -669,7 +718,8 @@ class ContinuousScheduler:
         while True:
             free = np.flatnonzero(~self._active)
             with self._cv:
-                if not self._pending or free.size == 0:
+                if (not self._pending or free.size == 0
+                        or self._prefilling is not None):
                     return admitted
                 # per-tenant occupancy among the ACTIVE slots (slot
                 # count + conservative block reservations), once per
@@ -734,6 +784,14 @@ class ContinuousScheduler:
                     queued, corr=req.corr, trace=trace_id,
                     parent=parent, plen=req.plen, tenant=req.tenant)
             self.kv.alloc(slot, req.nblocks)
+            if self.engine.chunks_prompts:
+                # by the chunk programs, from this step on (a prompt
+                # within the widest rung is one last chunk), and
+                # nothing is admitted behind it meanwhile
+                self._prefilling = _Prefill(
+                    req, slot, spec.cb_chunks(req.plen),
+                    (trace_id, parent), queued)
+                continue
             # the prompt's own rung of the prefill ladder
             width = spec.cb_prefill_width(req.plen)
             toks = np.zeros((1, width), np.int32)
@@ -776,15 +834,97 @@ class ContinuousScheduler:
                 first, tok0 = tok0, tok0.token
                 req.logprobs.append(first.logprob)
                 req.drafts.append((1, first.draft, first.draft_logprob))
-            self._slot_req[slot] = req
-            self._active[slot] = True
-            self._ntoks[slot] = req.plen
-            self._last[slot] = tok0
-            req.produced.append(tok0)
-            req.ticket._emit(tok0)
+            self._join(slot, req, tok0, step_no)
+
+    def _join(self, slot: int, req: _CBRequest, tok0: int,
+              step_no: int) -> None:
+        """A prefilled request takes its slot in the running batch and
+        is handed its first token."""
+        self._slot_req[slot] = req
+        self._active[slot] = True
+        self._ntoks[slot] = req.plen
+        self._last[slot] = tok0
+        req.produced.append(tok0)
+        req.ticket._emit(tok0)
+        now = time.monotonic()
+        self.stats.observe_ttft(now - req.t_submit)
+        self._maybe_retire(slot, tok0, step_no, now)
+
+    def _prefill_chunk(self, params, step_no: int) -> None:
+        """The step's one chunk of the prompt in prefill (module
+        docstring): the chunk before is read (it ran while the decode
+        step did), the next goes out; the last is waited for, and the
+        request joins the batch."""
+        pf, engine = self._prefilling, self.engine
+        req, slot = pf.req, pf.slot
+        try:
+            if pf.flying is not None:
+                flying, pf.flying = pf.flying, None
+                self._chunk_read(pf, engine.fetch_cb_chunk(flying)[1])
             now = time.monotonic()
-            self.stats.observe_ttft(now - req.t_submit)
-            self._maybe_retire(slot, tok0, step_no, now)
+            if req.cancel_event is not None and req.cancel_event.is_set():
+                self.stats.count("cancelled")
+                return self._drop_prefill(Cancelled(
+                    f"cancelled by caller after {pf.done} of "
+                    f"{len(pf.chunks)} prefill chunks"))
+            if req.deadline is not None and now >= req.deadline:
+                self.stats.count("expired")
+                return self._drop_prefill(DeadlineExpired(
+                    f"deadline passed after {pf.done} of "
+                    f"{len(pf.chunks)} prefill chunks"))
+            start, rows, width = pf.chunks[pf.done]
+            last = pf.done == len(pf.chunks) - 1
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :rows] = req.tokens[start:start + rows]
+            attrs = {"queue_ms": pf.queued * 1e3} if pf.done == 0 else {}
+            self._lap("rest")
+            with obs.span("scheduler.prefill", corr=req.corr,
+                          trace=pf.trace[0], parent=pf.trace[1], slot=slot,
+                          plen=req.plen, width=width, start=start,
+                          chunk=pf.done, of=len(pf.chunks), **attrs):
+                flying, self.kv.pools = engine.dispatch_cb_chunk(
+                    params, self.kv.pools, toks, rows, start, last,
+                    self.kv.chunk_target(slot))
+                pf.done += 1
+                if self._flying is not None:
+                    # behind the step in flight, as an admission's prefill
+                    self._collect(step_no, COLLECT_ADMIT)
+                if not last:
+                    pf.flying = flying
+                    return self._lap("prefill")
+                tok0, grouped = engine.fetch_cb_chunk(flying)
+                self._lap_wait("prefill", "first_token")
+        except Exception as e:  # noqa: BLE001 — fail req, keep going
+            self.stats.count("failed")
+            self.stats.observe_batch_failure()
+            self.log(f"warning: cb prefill chunk failed "
+                     f"({type(e).__name__}: {e}); request {req.corr} "
+                     f"failed, server continues")
+            return self._drop_prefill(RuntimeError(f"prefill failed: {e}"))
+        self._chunk_read(pf, grouped)
+        self._prefilling = None
+        self.stats.count("cb_chunked_prompts")
+        self.stats.count("cb_admit_steps")
+        self.stats.observe_cb_prefill(
+            req.plen, sum(c[2] for c in pf.chunks),
+            all(c[2] in engine.cb_flash_widths for c in pf.chunks))
+        self._join(slot, req, tok0, step_no)
+
+    def _chunk_read(self, pf: _Prefill, grouped_rows: int) -> None:
+        """The account of the chunk of `pf` that was just read back."""
+        start, rows, width = pf.chunks[pf.done - 1]
+        slots = self.engine.grouped_row_slots(width, rows)
+        # a chunk the dense walk took multiplied every row by every expert
+        self.stats.observe_cb_chunk(rows, start,
+                                    grouped_rows if slots else 0, slots)
+
+    def _drop_prefill(self, exc: BaseException) -> None:
+        """The prompt in prefill is given up between two chunks: its
+        blocks go back; its state needs nothing (a first chunk starts
+        from zeros)."""
+        pf, self._prefilling = self._prefilling, None
+        self.kv.free(pf.slot)
+        pf.req.ticket._fail(exc)
 
     def _decode_step(self, params, step_no: int, active: int) -> None:
         full = active == len(self._active)
@@ -796,7 +936,10 @@ class ContinuousScheduler:
             if full:
                 self._decode_ahead(params, step_no)
                 return
-            tables = self.kv.table_array()
+            pf = self._prefilling
+            tables = self.kv.table_array(hide=None if pf is None else pf.slot)
+            if pf is not None and pf.done:
+                self.stats.count("cb_steps_between_chunks")
             self._lap("rest")
             nxt, self.kv.pools = self.engine.run_cb_decode(
                 params, self.kv.pools, self._last, self._ntoks, tables)
@@ -956,6 +1099,10 @@ class ContinuousScheduler:
         n = int(self._active.sum())
         self._flying = None
         self._account_laps()
+        if self._prefilling is not None:
+            # its chunks wrote the pools the failed call held
+            n += 1
+            self._drop_prefill(RuntimeError(f"decode step failed: {e}"))
         self.stats.count("failed", n)
         self.stats.observe_batch_failure()
         self.log(f"warning: cb decode step failed "

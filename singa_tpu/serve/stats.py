@@ -53,7 +53,14 @@ handler and tests see the same semantics:
     rows the prefill programs ran: how much of each prompt's rung of
     the ladder, `ServeSpec.cb_prefill_widths`, was real) and
     `cb_flash_prefills` (of `cb_prefills`, those whose rung's attention
-    is the flash forward kernel).
+    is the flash forward kernel);
+  * `observe_cb_chunk` is the account of a prompt in an engine that
+    chunks (`InferenceEngine.chunks_prompts`), a chunk at a time: `cb_prefill_chunks`,
+    `cb_chunk_tokens`, `cb_prefix_rows` (rows of the pool the chunks'
+    attention read), `cb_grouped_rows` / `cb_grouped_row_slots` (expert
+    products the grouped form made over those the dense walk would
+    have); the scheduler counts `cb_chunked_prompts` and
+    `cb_steps_between_chunks` itself.
 
 `register_into(registry)` additionally exposes every snapshot field
 through an `obs.MetricsRegistry` pull-time collector (the /metrics
@@ -137,6 +144,18 @@ class ServeStats:
         self.cb_prefill_rows = 0      # prompt tokens they held
         self.cb_prefill_width_rows = 0  # rows their programs ran: each
                                         # prompt's rung of the ladder
+        # where the engine chunks (observe_cb_chunk; docs/SERVING.md)
+        self.cb_chunked_prompts = 0   # prompts by the chunk programs, whole
+        self.cb_prefill_chunks = 0    # chunks handed to the device
+        self.cb_chunk_tokens = 0      # prompt tokens they held
+        self.cb_prefix_rows = 0       # rows of the pool their attention
+                                      # read: each chunk's start, once
+        self.cb_steps_between_chunks = 0  # decode steps that went out
+                                      # while a prompt was in prefill
+        self.cb_grouped_rows = 0      # their rows multiplied by a held
+                                      # expert's weights (grouped form)
+        self.cb_grouped_row_slots = 0  # rows x held experts: what the
+                                      # dense walk would have multiplied
         self.cb_admit_steps = 0       # iterations that admitted >= 1:
                                       # each held every slot for its
                                       # prefills before decoding
@@ -300,6 +319,20 @@ class ServeStats:
             self.cb_flash_prefills += bool(flash)
             self.cb_prefill_rows += int(plen)
             self.cb_prefill_width_rows += int(width)
+
+    def observe_cb_chunk(self, rows: int, start: int, grouped_rows: int,
+                         grouped_row_slots: int) -> None:
+        """One chunk of a prompt that is prefilled in several, read
+        back: `rows` prompt tokens at positions `start` .. (its
+        attention read `start` rows of the pool), of whose assignments
+        `grouped_rows` fell on held experts where the dense walk would
+        have multiplied `grouped_row_slots`."""
+        with self._lock:
+            self.cb_prefill_chunks += 1
+            self.cb_chunk_tokens += int(rows)
+            self.cb_prefix_rows += int(start)
+            self.cb_grouped_rows += int(grouped_rows)
+            self.cb_grouped_row_slots += int(grouped_row_slots)
 
     def observe_cb_step(self, active_slots: int, blocks_in_use: int,
                         live_blocks: int = 0,
@@ -516,7 +549,11 @@ class ServeStats:
                     "generated_tokens", "batches",
                     "batched_requests", "batch_slots", "cb_steps",
                     "cb_prefills", "cb_flash_prefills", "cb_prefill_rows",
-                    "cb_prefill_width_rows", "cb_admit_steps",
+                    "cb_prefill_width_rows", "cb_chunked_prompts",
+                    "cb_prefill_chunks", "cb_chunk_tokens",
+                    "cb_prefix_rows", "cb_steps_between_chunks",
+                    "cb_grouped_rows", "cb_grouped_row_slots",
+                    "cb_admit_steps",
                     "cb_steps_ahead", "cb_collects_drained",
                     "cb_stalls", "cb_stall_seconds",
                     "cb_stall_wait_seconds",
@@ -615,6 +652,13 @@ class ServeStats:
                 "cb_flash_prefills": self.cb_flash_prefills,
                 "cb_prefill_rows": self.cb_prefill_rows,
                 "cb_prefill_width_rows": self.cb_prefill_width_rows,
+                "cb_chunked_prompts": self.cb_chunked_prompts,
+                "cb_prefill_chunks": self.cb_prefill_chunks,
+                "cb_chunk_tokens": self.cb_chunk_tokens,
+                "cb_prefix_rows": self.cb_prefix_rows,
+                "cb_steps_between_chunks": self.cb_steps_between_chunks,
+                "cb_grouped_rows": self.cb_grouped_rows,
+                "cb_grouped_row_slots": self.cb_grouped_row_slots,
                 "cb_admit_steps": self.cb_admit_steps,
                 "cb_steps_ahead": self.cb_steps_ahead,
                 "cb_collects_drained": self.cb_collects_drained,

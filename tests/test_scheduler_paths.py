@@ -65,14 +65,23 @@ def _prompts(seed, plens):
     return [rng.integers(1, VOCAB, p).astype(np.int32) for p in plens]
 
 
-def _serve(engine, prompts, news):
+def _serve(engine, prompts, news, drained=False):
     """Every request queued before the loop starts: as many as slots
-    make a full house from the first step."""
+    make a full house from the first step.  `drained`: the loop is
+    stopped only once it has read the step it still has in flight (a
+    full house's last step, one too many, goes out before the requests
+    resolve; whether the loop's next turn reads it or `stop()` gets
+    there first is a race the machine's load decides)."""
     sched = ContinuousScheduler(engine, log_fn=lambda s: None)
     try:
         tickets = [sched.submit(p, max_new=n) for p, n in zip(prompts, news)]
         sched.start()
-        return [t.wait(timeout=300)["tokens"] for t in tickets]
+        tokens = [t.wait(timeout=300)["tokens"] for t in tickets]
+        give_up = time.monotonic() + 60.0
+        while drained and sched._flying is not None \
+                and time.monotonic() < give_up:
+            time.sleep(0.001)
+        return tokens
     finally:
         sched.stop()
 
@@ -337,7 +346,7 @@ def test_a_full_houses_step_period_reaches_the_cost_account(lm):
     perf.observe_step = spy
     try:
         t0 = time.perf_counter()
-        _serve(engine, _prompts(1, (4, 6)), [NEW, NEW])
+        _serve(engine, _prompts(1, (4, 6)), [NEW, NEW], drained=True)
         wall = time.perf_counter() - t0
     finally:
         perf.observe_step = real
