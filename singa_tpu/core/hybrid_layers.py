@@ -546,14 +546,17 @@ class RoutedMoELayer(Layer):
         self.router_bias = _declare_const(self, "router_bias",
                                           (self.n_routed,), 0.0)
 
-    def _ffn(self, params, x, valid):
-        """x (T, E) -> (out (T, E), counts int32 (3,))."""
+    def _ffn(self, params, x, valid, tile_rows=False):
+        """x (T, E) -> (out (T, E), counts int32 (3,); with `tile_rows`
+        a run that goes grouped adds a fourth, the rows of the tiles its
+        products visited)."""
         idx, weights = moe_ops.route_sigmoid(
             x, params[self.router], params[self.router_bias], self.k,
             self.renormalize, self.scale)
         y, counts = moe_ops.held_experts_ffn(
             x, idx, weights, params[self.w_gate], params[self.w_up],
-            params[self.w_down], self.first, valid, max_load=True)
+            params[self.w_down], self.first, valid, max_load=True,
+            tile_rows=tile_rows)
         if self.shared is not None:
             gate, up, down = (params[w] for w in self.shared)
             hid = (jax.nn.silu(_dot(x, gate)) * _dot(x, up)).astype(x.dtype)
@@ -589,9 +592,12 @@ class RoutedMoELayer(Layer):
     def apply_chunk(self, params, x, entry, row, slot, start, plen, piece):
         """A chunk of a prompt prefilled in several: no state to carry;
         the chunk's routing counts over its real rows are left where a
-        decode step leaves its own."""
+        decode step leaves its own, and behind them, where the chunk
+        went grouped, the rows of the tiles its products visited (the
+        chunk program takes that one off again: `_chunk_counts`)."""
         _, t, e = x.shape
-        out, counts = self._ffn(params, x.reshape(t, e), jnp.arange(t) < plen)
+        out, counts = self._ffn(params, x.reshape(t, e),
+                                jnp.arange(t) < plen, tile_rows=True)
         return out.reshape(1, t, e), {"routed": counts}
 
     def apply_paged(self, params, x, entry, tables, ntoks):

@@ -18,7 +18,9 @@ per expert, scaled by n_experts).
 Below `moe_ffn`, the serving form of a sparse layer that is told which
 experts it holds (`route_sigmoid`, `held_experts_ffn`; the `kRoutedMoE`
 layer of core/hybrid_layers.py): it routes over ALL experts, computes
-the held ones' part, and has no capacity and no dropped token.
+the held ones' part, and has no capacity and no dropped token.  A
+long run's rows go through their experts sorted, by the grouped matmul
+of ops/grouped_matmul.py (`_grouped`).
 """
 
 from __future__ import annotations
@@ -27,6 +29,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from .grouped_matmul import grouped_matmul
 
 
 def moe_ffn(x: jnp.ndarray, params: Dict[str, jnp.ndarray], k: int = 2,
@@ -148,20 +152,31 @@ def _grouped(x, local, held, weights, group_sizes, w_gate, w_up, w_down):
     """`held_experts_ffn`'s grouped form.  local (T, k) the chosen
     experts' places among the held ones, held (T, k) bool which of them
     are held (and their row real), group_sizes (X,) assignments on each
-    held expert.
+    held expert.  Returns (y (T, E) float32, the rows of the tiles the
+    products' schedules visited, int32).
 
-    The grouped matmul itself visits the tiles of real groups only,
-    whatever the rows handed, but everything around it follows the rows
-    HANDED: the gather of the rows, the SiLU and the product on (m, F),
-    the (m, E) float32 result and its gather back (PERF.md 6, PR 43).
-    So the sorted rows are cut to HALF of the T k assignments where the
-    groups fit that: a router that spreads its choices puts held /
-    routed of them here, an eighth in the configurations that hold a
-    share of their experts.  ONE size whatever the routing, because a
-    size that followed it made a run's cost follow the weights' seed.
-    Past a half, all of them, exact always: a layer that holds EVERY
-    expert a token can choose (ZAYA's 16 of 16, top 1) always takes
-    that side."""
+    The three products are `ops/grouped_matmul.py`'s: a Pallas grouped
+    matmul whose 128-row tiles span group boundaries, one visit a
+    (tile, group) pair that shares a row.  It visits the tiles of real
+    groups only, whatever the rows handed, but everything around it
+    follows the rows HANDED: the gather of the rows, the SiLU and the
+    product on (m, F), the (m, E) float32 result and its gather back
+    (PERF.md 6, PR 43).
+    So the sorted rows go through HALF of the T k assignments at a
+    time, and the second half only where the groups do not fit the
+    first: a router that spreads its choices puts held / routed of them
+    here, an eighth in the configurations that hold a share of their
+    experts.  ONE size whatever the routing, because a size that
+    followed it made a run's cost follow the weights' seed.  A layer
+    that holds EVERY expert a token can choose (ZAYA's 16 of 16, top 1)
+    takes both halves wherever more than half its rows are real; an
+    expert's weights are still read once, by the half that holds its
+    rows (twice where its rows lie across the middle).
+
+    The first half's products stand OUTSIDE the `cond`, which it needs
+    either way: a Mosaic call in a `cond` branch carries no name of
+    ours in a device trace (`tpu_custom_call`), one in the main
+    computation its jitted function's (PERF.md 6, PR 45)."""
     t, k = local.shape
     n_held = w_gate.shape[0]
     # an assignment's expert, `n_held` where it is on none held here:
@@ -169,29 +184,39 @@ def _grouped(x, local, held, weights, group_sizes, w_gate, w_up, w_down):
     order = jnp.argsort(jnp.where(held, local, n_held).reshape(t * k),
                         stable=True)
     back = jnp.zeros((t * k,), jnp.int32).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
+        jnp.arange(t * k, dtype=jnp.int32)).reshape(t, k)
+    ends = jnp.cumsum(group_sizes)
 
-    def first(m):
-        """The first `m` sorted assignments through their experts."""
-        rows = x[order[:m] // k]                             # (m, E)
-        dot = lambda a, w: jax.lax.ragged_dot(               # noqa: E731
-            a, w, group_sizes, preferred_element_type=jnp.float32)
-        hid = (jax.nn.silu(dot(rows, w_gate))
-               * dot(rows, w_up)).astype(x.dtype)
-        out = dot(hid, w_down)                               # (m, E) f32
+    def run(lo, m):
+        """Sorted assignments [lo, lo + m) through their experts."""
+        sizes = jnp.diff(jnp.clip(ends, lo, lo + m), prepend=lo)
+        rows = x[order[lo:lo + m] // k]                      # (m, E)
+        gate, visited = grouped_matmul(rows, w_gate, sizes)
+        up, visited_up = grouped_matmul(rows, w_up, sizes)
+        hid = (jax.nn.silu(gate) * up).astype(x.dtype)
+        out, visited_down = grouped_matmul(hid, w_down, sizes)
         # back to the tokens' order; what lies behind the last group is
-        # whatever the product left there, and is not read
-        out = out[jnp.minimum(back, m - 1)].reshape(t, k, -1)
-        return jnp.sum(jnp.where(held[:, :, None],
-                                 out * weights[:, :, None], 0.0), axis=1)
+        # whatever the product left there (it writes no row there), and
+        # is not read
+        mine = held & (back >= lo) & (back < lo + m)
+        out = out[jnp.clip(back - lo, 0, m - 1)]             # (T, k, E)
+        return (jnp.sum(jnp.where(mine[:, :, None],
+                                  out * weights[:, :, None], 0.0), axis=1),
+                visited + visited_up + visited_down)
 
     half = -(-t * k // 2)
-    return jax.lax.cond(jnp.sum(group_sizes) <= half,
-                        lambda: first(half), lambda: first(t * k))
+    y, visited = run(0, half)
+
+    def rest():
+        more, visited_more = run(half, t * k - half)
+        return y + more, visited + visited_more
+
+    return jax.lax.cond(ends[-1] > half, rest, lambda: (y, visited))
 
 
 def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
-                     valid=None, max_load: bool = False):
+                     valid=None, max_load: bool = False,
+                     tile_rows: bool = False):
     """What the experts held here add for each token: expert j of the
     stacked weights is routed expert `first + j`.  x (T, E); idx,
     weights (T, k) from the router over ALL experts; w_gate, w_up
@@ -199,8 +224,11 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
     routed nowhere).  Returns (y (T, E) float32, counts int32 (2,):
     assignments that fell on held experts, held experts some token
     chose; with `max_load` a third, the busiest held expert's
-    assignments).  A token none of whose experts is held gets zeros; no
-    token is dropped and no row depends on another.
+    assignments; with `tile_rows` the grouped form adds one more, LAST:
+    the rows of the tiles its three products visited, of which the
+    first count x 3 were some expert's own).  A token none of whose
+    experts is held gets zeros; no token is dropped and no row depends
+    on another.
 
     Which of two forms computes it follows from the layer's own sizes
     and the run's length, and is no setting (`grouped_run`):
@@ -220,8 +248,10 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
       (token, expert) assignments are sorted by expert, those on no
       held expert (and every pad's) last, and each held expert's run of
       rows is multiplied by that expert's weights
-      (`jax.lax.ragged_dot`: on the TPU a Mosaic grouped matmul).  No
-      capacity, no dropped token, float32 sums, the same `counts`."""
+      (`ops/grouped_matmul.py`: a Pallas kernel, compiled by Mosaic on
+      the TPU and interpreted elsewhere, whose 128-row tiles span the
+      experts' boundaries).  No capacity, no dropped token, float32
+      sums, the same `counts` and the tiles' rows behind them."""
     t, e = x.shape
     n_held = w_gate.shape[0]
     local = idx - first                                      # (T, k)
@@ -236,8 +266,12 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down, first: int,
         counts.append(jnp.max(per_expert))
     counts = jnp.stack(counts).astype(jnp.int32)
     if grouped_run(t, n_held, idx.shape[1]):
-        return _grouped(x, local, held, weights, per_expert.astype(jnp.int32),
-                        w_gate, w_up, w_down), counts
+        y, visited = _grouped(x, local, held, weights,
+                              per_expert.astype(jnp.int32),
+                              w_gate, w_up, w_down)
+        if tile_rows:
+            counts = jnp.concatenate([counts, visited[None]])
+        return y, counts
 
     def through(rows, row_combine):
         gate = jnp.einsum("te,xef->txf", rows, w_gate,

@@ -413,6 +413,7 @@ class InferenceEngine:
                       lambda: init_pools(net, 2, 1, jnp.float32, 1)).items()
                   if "routed" in entry}
         self._routed_layers = tuple(routed)
+        self._routed_widths = routed
         # how many routing counts ride behind a decode step's tokens:
         # assignments, experts touched and, where a layer counts it,
         # the busiest expert's assignments
@@ -920,7 +921,8 @@ class InferenceEngine:
         already in its blocks), `meta` = [real rows, start, last].  The
         head runs on the last real row only, and only in the prompt's
         last chunk; any other chunk's token is 0.  Returns ([token, the
-        chunk's routing counts], pools)."""
+        chunk's routing counts, where it went grouped its tiles' rows],
+        pools)."""
         net, spec = self.net, self.spec
         piece = spec.cb_prefill_len
         temperature, top_k, top_p = (float(spec.temperature),
@@ -940,10 +942,29 @@ class InferenceEngine:
             out = [jax.lax.cond(last > 0, first_token,
                                 lambda: jnp.int32(0))[None]]
             if self._routed_layers:
-                out.append(self._routed_counts(pools))
+                counts, pools = self._chunk_counts(pools)
+                out.append(counts)
             return jnp.concatenate(out), pools
 
         return cb_chunk
+
+    def _chunk_counts(self, pools):
+        """(A chunk's routing counts summed over the routed layers, the
+        pools with every layer's counts as wide as a decode step leaves
+        them).  A layer whose chunk went grouped left the rows of the
+        tiles its products visited behind its counts: their sum rides
+        LAST, in the program of a width some layer takes grouped and in
+        no other."""
+        pools, tiles = dict(pools), []
+        for name in self._routed_layers:
+            counts, width = pools[name]["routed"], self._routed_widths[name]
+            if counts.shape[0] > width:
+                tiles.append(counts[width])
+                pools[name] = {**pools[name], "routed": counts[:width]}
+        counts = self._routed_counts(pools)
+        if tiles:
+            counts = jnp.concatenate([counts, sum(tiles)[None]])
+        return counts, pools
 
     def _build_cb_decode(self):
         """ONE compiled decode step at fixed slot count S: every
@@ -1215,10 +1236,11 @@ class InferenceEngine:
             out.copy_to_host_async()
         return (out, t0, width, bool(last)), pools
 
-    def fetch_cb_chunk(self, flying) -> Tuple[int, int]:
+    def fetch_cb_chunk(self, flying) -> Tuple[int, int, int]:
         """(the chunk's token, 0 but for a prompt's last chunk; the
         assignments of its real rows that fell on held experts, layers
-        summed) of a dispatched chunk (waits)."""
+        summed; the rows of the tiles its grouped products visited, 0
+        where it took the dense walk) of a dispatched chunk (waits)."""
         out, t0, width, last = flying
         t = time.perf_counter()
         with obs.span("engine.cb_prefill_fetch"):
@@ -1228,7 +1250,9 @@ class InferenceEngine:
         if last:       # the one chunk nothing else ran behind
             perf.observe_step(f"cb_chunk_{width}", now - t0)
             perf.mark_serving_ready()
-        return int(got[0]), int(got[1]) if got.shape[0] > 1 else 0
+        tiles = 1 + self._cb_tail
+        return (int(got[0]), int(got[1]) if got.shape[0] > 1 else 0,
+                int(got[tiles]) if got.shape[0] > tiles else 0)
 
     def run_cb_prefill_rungs(self, params, pools):
         """Every rung of the ladder run once, on pools no request

@@ -860,7 +860,7 @@ class ContinuousScheduler:
         try:
             if pf.flying is not None:
                 flying, pf.flying = pf.flying, None
-                self._chunk_read(pf, engine.fetch_cb_chunk(flying)[1])
+                self._chunk_read(pf, *engine.fetch_cb_chunk(flying)[1:])
             now = time.monotonic()
             if req.cancel_event is not None and req.cancel_event.is_set():
                 self.stats.count("cancelled")
@@ -892,7 +892,7 @@ class ContinuousScheduler:
                 if not last:
                     pf.flying = flying
                     return self._lap("prefill")
-                tok0, grouped = engine.fetch_cb_chunk(flying)
+                tok0, *grouped = engine.fetch_cb_chunk(flying)
                 self._lap_wait("prefill", "first_token")
         except Exception as e:  # noqa: BLE001 — fail req, keep going
             self.stats.count("failed")
@@ -901,7 +901,7 @@ class ContinuousScheduler:
                      f"({type(e).__name__}: {e}); request {req.corr} "
                      f"failed, server continues")
             return self._drop_prefill(RuntimeError(f"prefill failed: {e}"))
-        self._chunk_read(pf, grouped)
+        self._chunk_read(pf, *grouped)
         self._prefilling = None
         self.stats.count("cb_chunked_prompts")
         self.stats.count("cb_admit_steps")
@@ -910,13 +910,15 @@ class ContinuousScheduler:
             all(c[2] in engine.cb_flash_widths for c in pf.chunks))
         self._join(slot, req, tok0, step_no)
 
-    def _chunk_read(self, pf: _Prefill, grouped_rows: int) -> None:
+    def _chunk_read(self, pf: _Prefill, grouped_rows: int,
+                    tile_rows: int) -> None:
         """The account of the chunk of `pf` that was just read back."""
         start, rows, width = pf.chunks[pf.done - 1]
         slots = self.engine.grouped_row_slots(width, rows)
         # a chunk the dense walk took multiplied every row by every expert
         self.stats.observe_cb_chunk(rows, start,
-                                    grouped_rows if slots else 0, slots)
+                                    grouped_rows if slots else 0, slots,
+                                    tile_rows)
 
     def _drop_prefill(self, exc: BaseException) -> None:
         """The prompt in prefill is given up between two chunks: its

@@ -59,8 +59,10 @@ handler and tests see the same semantics:
     `cb_chunk_tokens`, `cb_prefix_rows` (rows of the pool the chunks'
     attention read), `cb_grouped_rows` / `cb_grouped_row_slots` (expert
     products the grouped form made over those the dense walk would
-    have); the scheduler counts `cb_chunked_prompts` and
-    `cb_steps_between_chunks` itself.
+    have), `cb_grouped_tile_rows` (rows of the tiles the grouped
+    matmul's schedule visited, three products a layer: `cb_grouped_rows`
+    x 3 over it is the tiles' fill); the scheduler counts
+    `cb_chunked_prompts` and `cb_steps_between_chunks` itself.
 
 `register_into(registry)` additionally exposes every snapshot field
 through an `obs.MetricsRegistry` pull-time collector (the /metrics
@@ -156,6 +158,8 @@ class ServeStats:
                                       # expert's weights (grouped form)
         self.cb_grouped_row_slots = 0  # rows x held experts: what the
                                       # dense walk would have multiplied
+        self.cb_grouped_tile_rows = 0  # rows of the tiles the grouped
+                                      # matmul visited, products summed
         self.cb_admit_steps = 0       # iterations that admitted >= 1:
                                       # each held every slot for its
                                       # prefills before decoding
@@ -321,18 +325,21 @@ class ServeStats:
             self.cb_prefill_width_rows += int(width)
 
     def observe_cb_chunk(self, rows: int, start: int, grouped_rows: int,
-                         grouped_row_slots: int) -> None:
+                         grouped_row_slots: int,
+                         grouped_tile_rows: int = 0) -> None:
         """One chunk of a prompt that is prefilled in several, read
         back: `rows` prompt tokens at positions `start` .. (its
         attention read `start` rows of the pool), of whose assignments
         `grouped_rows` fell on held experts where the dense walk would
-        have multiplied `grouped_row_slots`."""
+        have multiplied `grouped_row_slots`, in tiles of
+        `grouped_tile_rows` rows over the layers' three products."""
         with self._lock:
             self.cb_prefill_chunks += 1
             self.cb_chunk_tokens += int(rows)
             self.cb_prefix_rows += int(start)
             self.cb_grouped_rows += int(grouped_rows)
             self.cb_grouped_row_slots += int(grouped_row_slots)
+            self.cb_grouped_tile_rows += int(grouped_tile_rows)
 
     def observe_cb_step(self, active_slots: int, blocks_in_use: int,
                         live_blocks: int = 0,
@@ -553,7 +560,7 @@ class ServeStats:
                     "cb_prefill_chunks", "cb_chunk_tokens",
                     "cb_prefix_rows", "cb_steps_between_chunks",
                     "cb_grouped_rows", "cb_grouped_row_slots",
-                    "cb_admit_steps",
+                    "cb_grouped_tile_rows", "cb_admit_steps",
                     "cb_steps_ahead", "cb_collects_drained",
                     "cb_stalls", "cb_stall_seconds",
                     "cb_stall_wait_seconds",
@@ -659,6 +666,7 @@ class ServeStats:
                 "cb_steps_between_chunks": self.cb_steps_between_chunks,
                 "cb_grouped_rows": self.cb_grouped_rows,
                 "cb_grouped_row_slots": self.cb_grouped_row_slots,
+                "cb_grouped_tile_rows": self.cb_grouped_tile_rows,
                 "cb_admit_steps": self.cb_admit_steps,
                 "cb_steps_ahead": self.cb_steps_ahead,
                 "cb_collects_drained": self.cb_collects_drained,

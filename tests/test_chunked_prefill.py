@@ -418,3 +418,47 @@ def test_grouped_rows_are_counted_where_a_chunk_takes_the_grouped_form(
     monkeypatch.setattr(moe_ops, "ROW_BLOCK", 4)
     assert engine.grouped_row_slots(16, 10) == 10 * 4 * 4   # 4 layers x 4
     assert engine.grouped_row_slots(4, 3) == 0
+
+
+def test_the_grouped_chunks_tiles_are_counted(lm, monkeypatch):
+    """An engine whose 16-row chunks go grouped (ROW_BLOCK 4): a chunk's
+    program hands back the rows of the tiles its products visited behind
+    its routing counts, `cb_grouped_tile_rows` sums them, the pools keep
+    their three counts a layer, and the tokens are the dense walk's."""
+    from singa_tpu.ops import moe as moe_ops
+    net, params = lm
+    monkeypatch.setattr(moe_ops, "ROW_BLOCK", 4)
+    eng = InferenceEngine(net, _spec(slots=2), params=params,
+                          log_fn=lambda *a: None)
+    assert eng.spec.cb_prefill_widths == (8, 16)
+    read, seen = eng.fetch_cb_chunk, []
+
+    def fetch(flying):
+        seen.append(np.asarray(flying[0]))
+        return read(flying)
+
+    eng.fetch_cb_chunk = fetch
+    prompt = _prompt(5, 40)
+    sched = ContinuousScheduler(eng, log_fn=lambda *a: None)
+    try:
+        ticket = sched.submit(prompt, max_new=3)
+        sched.start()
+        toks = ticket.wait(timeout=300)["tokens"]
+        pools = sched.kv.pools
+    finally:
+        sched.stop()
+    assert toks == _greedy(lm, prompt, 3)
+    # [token, assignments, experts touched, busiest, tiles' rows]
+    assert [a.shape for a in seen] == [(5,)] * 3
+    assert all(entry["routed"].shape == (3,)
+               for entry in pools.values() if "routed" in entry)
+    d = eng.stats.snapshot()
+    assert d["cb_grouped_row_slots"] == 40 * 4 * 4
+    assert d["cb_grouped_rows"] == sum(int(a[1]) for a in seen) > 0
+    assert d["cb_grouped_tile_rows"] == sum(int(a[4]) for a in seen)
+    for a, width in zip(seen, (16, 16, 8)):
+        # 2 x width assignments a layer, `width` of them handed at a
+        # time: one tile of the rows handed, a visit an expert touched
+        # (two where its rows lie across the middle), three products
+        assert 3 * width * a[2] <= a[4] <= 3 * width * (a[2] + 4)
+        assert a[4] % (3 * width) == 0 and 3 * a[1] <= a[4]

@@ -2,8 +2,10 @@
 prefills through a flash-kernel rung, the bytes of a block of the paged
 kernel's pools (`cb_block_copy_bytes`, a ring's
 `cb_window_block_copy_bytes`), the blocks one copy brings
-(`cb_extent_blocks`) and the copies a live block (`cb_block_copies` /
-`cb_live_block_steps`: 1.0 a block a copy, ~0.14 at extents of 8)
+(`cb_extent_blocks`), the copies a live block (`cb_block_copies` /
+`cb_live_block_steps`: 1.0 a block a copy, ~0.14 at extents of 8) and
+the fill of the grouped matmul's tiles under a cell that chunks
+(`cb_grouped_rows` x 3 / `cb_grouped_tile_rows`)
 read under one of the benchmark's serving cells (a builder's tool; the
 benchmark does not report the counters):
 
@@ -37,11 +39,15 @@ def stop(self, *args, **kwargs):
                              "cb_block_bytes", "cb_block_copy_bytes",
                              "cb_window_block_copy_bytes",
                              "cb_extent_blocks", "cb_live_block_steps",
-                             "cb_block_copies")},
+                             "cb_block_copies", "cb_grouped_rows",
+                             "cb_grouped_tile_rows")},
         "cb_decode_steps": self.stats.cb_decode_steps,
         "cb_copies_a_live_block": (
             snap["cb_block_copies"] / snap["cb_live_block_steps"]
             if snap["cb_live_block_steps"] else None),
+        "cb_grouped_tile_fill": (
+            3 * snap["cb_grouped_rows"] / snap["cb_grouped_tile_rows"]
+            if snap["cb_grouped_tile_rows"] else None),
         "cb_flash_prefill_share": (
             snap["cb_flash_prefills"] / snap["cb_prefills"]
             if snap["cb_prefills"] else None)}), flush=True)
