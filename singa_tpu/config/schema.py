@@ -499,8 +499,7 @@ class ModelConfig:
     # (see Trainer._compiler_options): "auto" applies it when the net's
     # widest conv has >= 96 filters (the raised budget HANGS LeNet-scale
     # compiles, which is why auto exists), "on" forces it, "off"
-    # disables it.  The SINGA_TPU_SCOPED_VMEM env var (same values)
-    # overrides this field.
+    # disables it.
     scoped_vmem: str = "auto"         # auto | on | off
 
     def __post_init__(self):

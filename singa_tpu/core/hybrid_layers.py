@@ -397,24 +397,6 @@ class MLALayer(Layer):
                        preferred_element_type=jnp.float32)
         return o.reshape(o.shape[0], -1).astype(o_lat.dtype)
 
-    def _attend_absorbed(self, params, q, lat, allowed):
-        """q (N, H, .) one token a row against its latent rows lat
-        (N, L, rank + rope, or wider with zeros behind); allowed (N, L)
-        bool.  The decode step's sums over a dense table: what
-        `apply_paged` ran before the paged kernel took the middle, kept
-        as the oracle the tests hold the kernel to."""
-        sc = jnp.einsum("nhr,nlr->nhl",
-                        self._absorb_query(params, q, lat.shape[-1]), lat,
-                        preferred_element_type=jnp.float32)
-        sc = sc / math.sqrt(self.nope + self.rope)
-        sc = jnp.where(allowed[:, None, :], sc, NEG_INF)
-        p = jax.nn.softmax(sc, axis=-1)
-        # 0 * (inf | nan) is nan: rows the mask hides may hold anything
-        c = jnp.where(allowed[:, :, None], lat[..., :self.rank], 0)
-        o_lat = jnp.einsum("nhl,nlr->nhr", p.astype(c.dtype), c,
-                           preferred_element_type=jnp.float32)
-        return self._expand_output(params, o_lat.astype(q.dtype))
-
     def apply(self, params, srcs, ctx):
         x = srcs[0]
         t = x.shape[1]

@@ -17,7 +17,6 @@ Cadence semantics preserved from ModelProto (model.proto:2-47):
 
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
@@ -31,6 +30,10 @@ from ..config.schema import ModelConfig
 from ..utils import faults
 from .net import NeuralNet, build_net
 from .updater import Updater, make_updater
+
+#: chunks the DeviceFeeder stages ahead where `feeder_depth` is not
+#: given (docs/PERFORMANCE.md)
+FEEDER_DEPTH = 2
 
 
 @dataclass
@@ -283,15 +286,13 @@ class Trainer:
         if not _on_tpu():
             return None
         # Escape hatch (VERDICT r2 item 9): ModelProto scoped_vmem
-        # (auto|on|off) selects the policy; SINGA_TPU_SCOPED_VMEM env
-        # overrides it, so a user whose net trips the auto heuristic
-        # either way is never at the mercy of the filter-count proxy.
-        mode = os.environ.get("SINGA_TPU_SCOPED_VMEM",
-                              getattr(self.cfg, "scoped_vmem", "auto"))
+        # (auto|on|off) selects the policy, so a user whose net trips
+        # the auto heuristic either way is never at the mercy of the
+        # filter-count proxy.
+        mode = getattr(self.cfg, "scoped_vmem", "auto")
         if mode not in ("auto", "on", "off"):
             raise ValueError(
-                f"SINGA_TPU_SCOPED_VMEM must be auto|on|off, got "
-                f"{mode!r}")
+                f"scoped_vmem must be auto|on|off, got {mode!r}")
         if mode == "off":
             return None
         # Budgets are per FAMILY: attention-family nets take the modest
@@ -402,13 +403,8 @@ class Trainer:
                         f"stacked=True needs a leading {nsteps}-axis on "
                         f"every batch leaf; got shapes {bad}")
             xs = (steps, batches if stacked else None, poison)
-            # SINGA_TPU_SCAN_UNROLL replicates the step body in the
-            # compiled loop (lax.scan unroll), trading compile time and
-            # program size for fewer loop-iteration boundaries
-            unroll = int(os.environ.get("SINGA_TPU_SCAN_UNROLL", "1"))
             (params, opt_state), metrics = jax.lax.scan(
-                body, (params, opt_state), xs, length=nsteps,
-                unroll=max(1, unroll))
+                body, (params, opt_state), xs, length=nsteps)
             return params, opt_state, metrics
 
         self.train_steps = jax.jit(train_scan, static_argnums=(5, 6),
@@ -536,26 +532,6 @@ class Trainer:
         return place_chunk(self.mesh, stacked,
                            seq_axis=("seq" if self._uses_sp else None))
 
-    @staticmethod
-    def _feeder_on(feeder: Optional[bool]) -> bool:
-        """Overlapped feed is ON by default for chunked loops; an
-        explicit argument wins, then SINGA_TPU_FEEDER=0/1."""
-        if feeder is not None:
-            return bool(feeder)
-        return os.environ.get("SINGA_TPU_FEEDER", "1") != "0"
-
-    @staticmethod
-    def _feeder_depth(depth: int = 0) -> int:
-        """Staged-chunks-ahead bound (argument, then
-        SINGA_TPU_FEEDER_DEPTH, default 2 — docs/PERFORMANCE.md)."""
-        if depth and depth > 0:
-            return int(depth)
-        try:
-            return max(1, int(os.environ.get("SINGA_TPU_FEEDER_DEPTH",
-                                             "2")))
-        except ValueError:
-            return 2
-
     def _chunk_plan(self, start_step: int, scan_chunk: int):
         """Deterministic (start, length) chunk descriptors covering
         [start_step, train_steps) with the SAME cadence cuts the run
@@ -598,7 +574,7 @@ class Trainer:
         DeviceFeeder (staging overlaps the previous chunk's eval scan);
         the remainder and custom step_fns dispatch per batch.  Chunks
         and single batches both land sharded under the trainer's mesh.
-        `feeder=False` (or SINGA_TPU_FEEDER=0) stages inline instead."""
+        `feeder=False` stages inline instead (None: the feeder)."""
         perf = Performance()
         steps = max(steps, 1)
         scan_fn = getattr(self, "_eval_scans", {}).get(id(step_fn))
@@ -609,13 +585,13 @@ class Trainer:
                 for i in range(chunk):
                     perf.update({k: v[i] for k, v in ms.items()})
             nchunks = steps // chunk
-            if self._feeder_on(feeder) and nchunks > 0:
+            if (feeder is None or feeder) and nchunks > 0:
                 from ..data.feed import DeviceFeeder
                 fd = DeviceFeeder(
                     data_iter, ((i * chunk, chunk)
                                 for i in range(nchunks)),
                     place=self._chunk_place,
-                    depth=self._feeder_depth(), capacity=chunk)
+                    depth=FEEDER_DEPTH, capacity=chunk)
                 try:
                     for _ in range(nchunks):
                         eat(jax.device_get(
@@ -702,11 +678,10 @@ class Trainer:
         stay on device in a small ring, drained only at display/eval/
         checkpoint boundaries — the host never blocks on data or
         metrics between chunks (docs/PERFORMANCE.md).  `feeder=False`
-        (or SINGA_TPU_FEEDER=0) selects the synchronous fallback, which
-        stages inline through the SAME sharded placement helper;
-        `feeder_depth` (or SINGA_TPU_FEEDER_DEPTH) bounds how many
-        chunks the feeder runs ahead.  Both paths produce bit-identical
-        trajectories (tests/test_feed.py).
+        selects the synchronous fallback, which stages inline through
+        the SAME sharded placement helper; `feeder_depth` bounds how
+        many chunks the feeder runs ahead (0: FEEDER_DEPTH).  Both
+        paths produce bit-identical trajectories (tests/test_feed.py).
 
         Preemption safety (the failure-recovery story the reference
         lacks, SURVEY.md §5 — any process death hangs its job): while a
@@ -734,12 +709,14 @@ class Trainer:
         step = start_step
         chunked = bool(scan_chunk and scan_chunk > 1)
         fd = stager = None
-        if chunked and self._feeder_on(feeder):
+        depth = (int(feeder_depth) if feeder_depth and feeder_depth > 0
+                 else FEEDER_DEPTH)
+        if chunked and (feeder is None or feeder):
             from ..data.feed import DeviceFeeder
             fd = DeviceFeeder(train_iter,
                               self._chunk_plan(start_step, scan_chunk),
                               place=self._chunk_place,
-                              depth=self._feeder_depth(feeder_depth),
+                              depth=depth,
                               capacity=scan_chunk)
         elif chunked:
             from ..data.feed import ChunkStager
@@ -752,8 +729,7 @@ class Trainer:
         # dispatches (and their staged input buffers) instead of letting
         # the host race arbitrarily far ahead.  Without it the ring is 1
         # (the synchronous per-chunk fetch, exactly the old loop).
-        ring = (self._feeder_depth(feeder_depth) + 1
-                if fd is not None else 1)
+        ring = depth + 1 if fd is not None else 1
         pending: List[tuple] = []
         staged_credit = [0.0]   # feeder stage_seconds already reported
         last_dbg = [None]       # newest single-batch view (debug/profile)

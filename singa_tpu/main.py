@@ -103,12 +103,11 @@ def make_argparser() -> argparse.ArgumentParser:
                          "chunked loop: a background thread stages the "
                          "next chunk (stack + sharded device_put) while "
                          "the current one trains (auto = on when "
-                         "scan_chunk > 1 unless SINGA_TPU_FEEDER=0; "
-                         "see docs/PERFORMANCE.md)")
+                         "scan_chunk > 1; see docs/PERFORMANCE.md)")
     ap.add_argument("--feeder_depth", "--feeder-depth", type=int,
                     dest="feeder_depth", default=0,
                     help="staged chunks the feeder may run ahead "
-                         "(0 = SINGA_TPU_FEEDER_DEPTH or 2)")
+                         "(0 = 2)")
     _add_obs_flags(ap)
     return ap
 
@@ -930,8 +929,7 @@ def _run(args) -> int:
             "(set --workspace or ClusterProto.workspace); "
             "starting from scratch")
 
-    # auto → None: Trainer.run resolves via SINGA_TPU_FEEDER (default on
-    # for chunked loops)
+    # auto → None: Trainer.run's default (on for chunked loops)
     feeder_flag = {"auto": None, "on": True, "off": False}[args.feeder]
     if args.feeder == "on" and args.scan_chunk <= 1:
         log("warning: --feeder on has no effect without "

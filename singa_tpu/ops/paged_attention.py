@@ -52,8 +52,8 @@ kernel it lowered to before there was one.
 `paged_attention_reference` is the plain-jnp gather of every slot's
 whole table, the formulation the serving engine ran before the kernel:
 it materialises (S, T, 2 * Hkv, block_len, D) and is kept only as
-the oracle the tests compare the kernel against (kMLA's is its own
-`MLALayer._attend_absorbed` over the gathered rows) and as the row
+the oracle the tests compare the kernel against (kMLA's is
+`tests/oracles.py:attend_absorbed` over the gathered rows) and as the row
 `tools/paged_kernel_bench.py` times the kernel beside.
 """
 

@@ -3,70 +3,30 @@
 
 One `ServeStats` instance is shared by the `InferenceEngine` (compile /
 reload accounting), the `MicroBatcher` (admission / batching / latency),
-and the `InferenceServer` (the /stats endpoint).  All mutation goes
-through the lock; `snapshot()` is the single read surface, so the HTTP
-handler and tests see the same semantics:
+the `ContinuousScheduler` (the `cb_*` account of its steps) and the
+`InferenceServer` (the /stats endpoint).  All mutation goes through the
+lock; `snapshot()` is the single read surface, so the HTTP handler and
+tests see the same semantics.
+
+Every tally and every gauge is declared ONCE, in `TALLIES` below: its
+name, its kind and what it means.  The zeros of `__init__`, the plain
+part of `snapshot()` and the counters and gauges of `register_into`
+(the /metrics Prometheus endpoint) are read from that table, so a new
+counter is one line there and its increment in an `observe_*` method
+(the scheduler moves `cb_chunked_prompts`, `cb_steps_between_chunks`
+and `cb_admit_steps` itself, through `count()`).  What the table cannot
+say in a line:
 
   * latency quantiles (p50/p95) come from a bounded reservoir of the
     most recent completions — a serving dashboard number, not an exact
     all-time percentile;
-  * `occupancy` is real requests / bucket batch slots averaged over
-    dispatched micro-batches (1.0 = every padded slot carried a real
-    request);
-  * `qps` is completed requests over the stats object's lifetime
-    (decays on an idle server — a health dashboard should read
-    `qps_recent`, completions within the last `qps_window_s` seconds,
-    next to `uptime_s`);
-  * `compiles` counts engine program compilations — a warmed server
-    must hold this constant (the zero-recompile acceptance gate);
-  * `observe_request` splits each completion's total latency into
-    queue-wait vs service time and records generated tokens + tok/s
-    (p50/p95 of each in `snapshot()`) — the attribution a bare
-    end-to-end percentile can't give;
-  * `observe_cb_step` feeds the continuous-batching occupancy pair:
-    `cb_slot_occupancy` (active slots / compiled slots, averaged over
-    scheduler steps) and `cb_block_utilization` (KV blocks in use /
-    pool size), and `cb_live_block_share` (table blocks the paged
-    attention kernel walked / slots x table width, averaged over the
-    steps that decoded: how much of what a whole-table read would
-    touch this traffic keeps live), and `cb_window_block_share` (ring
-    blocks the windowed layers' walk read / table blocks the growing
-    layers' walk read: what of a context a window still reads);
-  * `observe_cb_step` also counts how a step went to the device:
-    `cb_steps_ahead` (decode steps handed over before the step before
-    them was read) and `cb_collects_drained` (steps in flight read
-    with none handed over behind them: a slot fell free, or an
-    admission took it);
-  * `observe_cb_emit` is the emit loop's account: busy slots of the
-    fetched decode steps and the tokens they were handed
-    (`cb_tokens_emitted` / `cb_emit_slot_steps`: 1 without drafts, 1
-    to 2 with them), and where the model drafts the drafts verified and
-    accepted (`cb_drafts_made`, `cb_drafts_accepted`: a layer wrote
-    two cache rows a draft verified, and the next step writes over the
-    second where the draft was rejected).
-  * `observe_cb_stall` is the loop thread's stall account: a LAP of a
-    scheduler step (`serve/scheduler.py`, `STALL_S`) that took longer
-    than any legitimate one adds to `cb_stalls`, its seconds to
-    `cb_stall_seconds`, and to `cb_stall_wait_seconds` too where the
-    lap was a wait on the device or the runtime;
-  * `observe_cb_prefill` feeds `cb_prefill_fill_share` (prompt tokens /
-    rows the prefill programs ran: how much of each prompt's rung of
-    the ladder, `ServeSpec.cb_prefill_widths`, was real) and
-    `cb_flash_prefills` (of `cb_prefills`, those whose rung's attention
-    is the flash forward kernel);
-  * `observe_cb_chunk` is the account of a prompt in an engine that
-    chunks (`InferenceEngine.chunks_prompts`), a chunk at a time: `cb_prefill_chunks`,
-    `cb_chunk_tokens`, `cb_prefix_rows` (rows of the pool the chunks'
-    attention read), `cb_grouped_rows` / `cb_grouped_row_slots` (expert
-    products the grouped form made over those the dense walk would
-    have), `cb_grouped_tile_rows` (rows of the tiles the grouped
-    matmul's schedule visited, three products a layer: `cb_grouped_rows`
-    x 3 over it is the tiles' fill); the scheduler counts
-    `cb_chunked_prompts` and `cb_steps_between_chunks` itself.
-
-`register_into(registry)` additionally exposes every snapshot field
-through an `obs.MetricsRegistry` pull-time collector (the /metrics
-Prometheus endpoint) without changing any of the above.
+  * `qps` decays on an idle server — a health dashboard should read
+    `qps_recent` next to `uptime_s`;
+  * `compiles` is the zero-recompile acceptance gate: a warmed server
+    must hold it constant;
+  * where the model drafts, a layer wrote two cache rows a draft
+    verified, and the next step writes over the second where the draft
+    was rejected (`cb_drafts_made`, `cb_drafts_accepted`).
 """
 
 from __future__ import annotations
@@ -74,9 +34,199 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 from .tenancy import TenantCounts
+
+
+COUNTER, GAUGE, INTERNAL, DERIVED = "counter", "gauge", "internal", "derived"
+
+
+class Tally(NamedTuple):
+    """One line of `ServeStats`' accounting.  COUNTER and GAUGE are plain
+    attributes, zeroed by `__init__`, moved under the lock, copied by
+    `snapshot()` in the table's order and exported to /metrics as
+    `<prefix>_<name>_total` / `<prefix>_<name>`; INTERNAL is such an
+    attribute on neither surface, feeding the gauge its meaning names;
+    DERIVED is a gauge `snapshot()` computes (no attribute; /metrics
+    leaves it out while None).  `zero`: 0.0 marks a tally of seconds,
+    rounded to the microsecond in `snapshot()`.  `late`: /metrics lists
+    the gauges in the table's order, those with `late` behind the rest
+    in its order: the endpoint's order was never /stats'."""
+    name: str
+    kind: str
+    meaning: str
+    zero: Any = 0
+    late: int = 0
+
+
+TALLIES = (
+    # admission / completion
+    Tally("submitted", COUNTER, "requests handed to submit()"),
+    Tally("completed", COUNTER, "requests answered (observe_latency)"),
+    Tally("failed", COUNTER, "engine / batch errors surfaced to requests"),
+    Tally("expired", COUNTER, "deadline passed before dispatch"),
+    Tally("expired_on_arrival", COUNTER,
+          "dead on arrival: never queued, never prefilled (serve/qos.py)"),
+    Tally("cancelled", COUNTER, "cancelled by the caller (hedge loser)"),
+    Tally("shed", COUNTER, "admission rejected (queue full / fault)"),
+    Tally("shed_interactive", COUNTER, "of `shed`, by class (brownout)"),
+    Tally("shed_batch", COUNTER, "of `shed`, by class"),
+    Tally("shed_best_effort", COUNTER, "of `shed`, by class"),
+    Tally("rejected", COUNTER, "never-servable request (fast 400)"),
+    Tally("resumed", COUNTER, "admitted with a resume_from prefix"),
+    Tally("queue_depth", GAUGE, "requests waiting right now"),
+    Tally("generated_tokens", COUNTER, "tokens of completed requests"),
+    # micro-batching (serve/batcher.py)
+    Tally("batches", COUNTER, "micro-batches dispatched"),
+    Tally("batched_requests", COUNTER, "real requests they carried"),
+    Tally("batch_slots", COUNTER, "sum of their buckets' batch sizes"),
+    # continuous batching (serve/scheduler.py)
+    Tally("cb_steps", COUNTER, "scheduler iterations run"),
+    Tally("cb_prefills", COUNTER, "prefills that ran to their end"),
+    Tally("cb_flash_prefills", COUNTER, "of them, by a flash-attention rung"),
+    Tally("cb_prefill_rows", COUNTER, "prompt tokens they held"),
+    Tally("cb_prefill_width_rows", COUNTER,
+          "rows their programs ran: each prompt's rung of the ladder"),
+    # where the engine chunks (observe_cb_chunk; docs/SERVING.md)
+    Tally("cb_chunked_prompts", COUNTER, "prompts prefilled whole"),
+    Tally("cb_prefill_chunks", COUNTER, "chunks handed to the device"),
+    Tally("cb_chunk_tokens", COUNTER, "prompt tokens they held"),
+    Tally("cb_prefix_rows", COUNTER, "pool rows their attention read"),
+    Tally("cb_steps_between_chunks", COUNTER,
+          "decode steps that went out while a prompt was in prefill"),
+    Tally("cb_grouped_rows", COUNTER,
+          "their rows multiplied by a held expert's weights (grouped form)"),
+    Tally("cb_grouped_row_slots", COUNTER,
+          "rows x held experts: what the dense walk would have multiplied"),
+    Tally("cb_grouped_tile_rows", COUNTER,
+          "rows of the tiles the grouped matmul visited, three products "
+          "a layer: cb_grouped_rows x 3 over it is the tiles' fill"),
+    Tally("cb_admit_steps", COUNTER,
+          "iterations that admitted: each held every slot for its prefills"),
+    Tally("cb_steps_ahead", COUNTER,
+          "decode steps handed over before the step before them was read"),
+    Tally("cb_collects_drained", COUNTER,
+          "steps in flight read with none handed over behind them"),
+    # the loop thread's stall account (observe_cb_stall)
+    Tally("cb_stalls", COUNTER,
+          "laps of a step over STALL_S: longer than any legitimate one"),
+    Tally("cb_stall_seconds", COUNTER, "their seconds", zero=0.0),
+    Tally("cb_stall_wait_seconds", COUNTER,
+          "of them, inside waits on the device or the runtime", zero=0.0),
+    Tally("cb_blocks_in_use", GAUGE, "blocks held right now", late=1),
+    Tally("cb_blocks_total", GAUGE, "usable pool blocks", late=1),
+    # what one slot's fixed state and one paged block cost, over all
+    # layers (serve/kvcache.py state_bytes): with the slots in use and
+    # the live blocks, the bytes a decode step has to move
+    Tally("cb_slot_state_bytes", GAUGE, "one slot's fixed state", late=1),
+    Tally("cb_block_bytes", GAUGE, "a growing block", late=1),
+    Tally("cb_window_block_bytes", GAUGE,
+          "a ring block, over the layers with a window", late=1),
+    # what ONE copy of the paged kernel moves: a block of one layer's
+    # pool, keys and values together (0: no such layer)
+    Tally("cb_block_copy_bytes", GAUGE, "under the table", late=3),
+    Tally("cb_window_block_copy_bytes", GAUGE, "in a ring", late=3),
+    Tally("cb_ring_blocks", GAUGE, "ring blocks a slot", late=2),
+    Tally("cb_extent_blocks", GAUGE,
+          "consecutive blocks the free list deals in, a copy's", late=2),
+    Tally("cb_live_block_steps", COUNTER, "table blocks decode steps walked"),
+    Tally("cb_block_copies", COUNTER,
+          "sum of the copies that brought them, a layer (an extent each)"),
+    Tally("cb_window_block_steps", COUNTER,
+          "sum of ring blocks they walked (layers with a window)"),
+    # routing of the experts held here, summed over decode steps and
+    # routed layers, busy slots only (observe_routing)
+    Tally("cb_routed_layer_steps", COUNTER, "layers x steps counted"),
+    Tally("cb_routed_assignments", COUNTER, "(token, held expert) pairs"),
+    Tally("cb_routed_experts_touched", COUNTER, "held experts chosen"),
+    Tally("cb_routed_max_load", COUNTER, "the busiest held expert's pairs"),
+    # what the emit loop handed out (observe_cb_emit)
+    Tally("cb_emit_slot_steps", COUNTER, "busy slots of fetched steps"),
+    Tally("cb_tokens_emitted", COUNTER,
+          "tokens they were handed: 1 each without drafts, 1 to 2 with"),
+    Tally("cb_drafts_made", COUNTER, "drafts verified, one a busy slot-step"),
+    Tally("cb_drafts_accepted", COUNTER, "of them, accepted (2 tokens)"),
+    Tally("consecutive_batch_failures", GAUGE,
+          "batches failed in a row: /healthz degrades past degraded_after"),
+    # engine
+    Tally("compiles", COUNTER, "engine program compilations"),
+    Tally("reloads", COUNTER, "hot reloads that took new params"),
+    Tally("reload_failures", COUNTER, "restore raised: kept old params"),
+    Tally("reloads_refused", COUNTER, "nothing newer / unhealthy"),
+    Tally("torn_polls", COUNTER, "poll raced a live writer: no change"),
+    Tally("reload_poll_deaths", COUNTER,
+          "poll daemon died on an unexpected exception (then restarted)"),
+    # sums and sizes behind the cb shares
+    Tally("cb_active_slot_steps", INTERNAL,
+          "sum of active slots per step (cb_slot_occupancy)"),
+    Tally("cb_block_use_steps", INTERNAL,
+          "sum of blocks in use per step (cb_block_utilization)"),
+    Tally("cb_decode_steps", INTERNAL,
+          "iterations that ran a decode (cb_live_block_share)"),
+    Tally("cb_table_blocks", INTERNAL,
+          "slots x blocks per slot, set once (cb_live_block_share)"),
+    Tally("cb_slot_capacity", INTERNAL,
+          "compiled slot count S, set once (cb_slot_occupancy)"),
+    # what snapshot() computes, each by the method or loop named
+    Tally("qps", DERIVED, "completions over the object's lifetime: qps()"),
+    Tally("qps_recent", DERIVED, "completions in the last qps_window_s"),
+    Tally("uptime_s", DERIVED, "seconds since construction"),
+    Tally("p50_latency_ms", DERIVED, "latency_quantile(), recent reservoir"),
+    Tally("p95_latency_ms", DERIVED, "latency_quantile()"),
+    Tally("p99_latency_ms", DERIVED, "latency_quantile()"),
+    Tally("shed_rate_recent", DERIVED, "windowed(): sheds / attempts"),
+    Tally("p95_latency_recent_ms", DERIVED, "windowed()"),
+    Tally("p99_latency_recent_ms", DERIVED, "windowed()"),
+    Tally("p50_queue_wait_ms", DERIVED, "split_quantile(): time queued"),
+    Tally("p95_queue_wait_ms", DERIVED, "split_quantile()"),
+    Tally("p50_service_ms", DERIVED, "split_quantile(): being served"),
+    Tally("p95_service_ms", DERIVED, "split_quantile()"),
+    Tally("p50_ttft_ms", DERIVED, "split_quantile(): submit to first token"),
+    Tally("p95_ttft_ms", DERIVED, "split_quantile()"),
+    Tally("p50_tokens_per_s", DERIVED, "split_quantile(): tokens / service"),
+    Tally("p95_tokens_per_s", DERIVED, "split_quantile()"),
+    Tally("batch_occupancy", DERIVED,
+          "real requests / the batch slots dispatched (1.0: no padding)"),
+    Tally("cb_slot_occupancy", DERIVED,
+          "active slots / compiled slots averaged over scheduler steps"),
+    Tally("cb_slot_occupancy_recent", DERIVED,
+          "the same, time-weighted over a trailing window"),
+    Tally("cb_block_utilization", DERIVED,
+          "KV blocks in use / pool size averaged over steps"),
+    Tally("cb_live_block_share", DERIVED,
+          "table blocks the paged kernel walked / slots x table width over "
+          "the steps that decoded: what of a whole-table read stays live"),
+    Tally("cb_prefill_fill_share", DERIVED,
+          "prompt tokens / rows of each prompt's rung of cb_prefill_widths"),
+    Tally("cb_window_block_share", DERIVED,
+          "ring blocks the windowed walk read / table blocks the growing "
+          "walk read: under 1 once contexts pass the window", late=4),
+)
+
+_ZEROS = tuple((t.name, t.zero) for t in TALLIES if t.kind != DERIVED)
+# (name, is a tally of seconds) of what snapshot() copies
+_PLAIN = tuple((t.name, isinstance(t.zero, float)) for t in TALLIES
+               if t.kind in (COUNTER, GAUGE))
+_COUNTERS = tuple(t.name for t in TALLIES if t.kind == COUNTER)
+_GAUGES = tuple(t.name for t in sorted(
+    (t for t in TALLIES if t.kind in (GAUGE, DERIVED)),
+    key=lambda t: t.late))
+
+
+def _nearest_rank(ordered, q: float):
+    """The value at quantile `q` of a sorted list; None of an empty one."""
+    if not ordered:
+        return None
+    return ordered[min(int(q * len(ordered)), len(ordered) - 1)]
+
+
+def _rounded(v: Optional[float], digits: int, scale: float = 1.0):
+    return None if v is None else round(v * scale, digits)
+
+
+def _share(part, whole) -> Optional[float]:
+    return round(part / whole, 4) if whole else None
 
 
 class ServeStats:
@@ -91,144 +241,33 @@ class ServeStats:
         # register_into.  Callers pass registry-FOLDED labels.
         self.tenants = TenantCounts(
             ("submitted", "completed", "shed"))
-        self._latencies: deque = deque(maxlen=max(int(latency_window), 1))
+        window = max(int(latency_window), 1)
+        self._latencies: deque = deque(maxlen=window)
         # the total-latency split (observe_request): time in queue
         # before dispatch/admission vs time being served, plus the
         # per-request generated-token count and tok/s — the
         # attribution a bare p50/p95 gap lacks
-        self._queue_waits: deque = deque(
-            maxlen=max(int(latency_window), 1))
-        self._services: deque = deque(maxlen=max(int(latency_window), 1))
+        self._queue_waits: deque = deque(maxlen=window)
+        self._services: deque = deque(maxlen=window)
         # submit -> first token, one sample a request (observe_ttft)
-        self._ttfts: deque = deque(maxlen=max(int(latency_window), 1))
-        self._tok_rates: deque = deque(maxlen=max(int(latency_window), 1))
+        self._ttfts: deque = deque(maxlen=window)
+        self._tok_rates: deque = deque(maxlen=window)
         # completion timestamps for the windowed QPS (bounded: at most
         # latency_window recent completions contribute)
         self.qps_window_s = max(float(qps_window_s), 0.001)
-        self._completions: deque = deque(
-            maxlen=max(int(latency_window), 1))
+        self._completions: deque = deque(maxlen=window)
         # timestamped reservoirs for the windowed() view (autoscaler
         # control inputs): (stamp, latency) per completion, stamps per
         # shed
-        self._timed_lats: deque = deque(
-            maxlen=max(int(latency_window), 1))
-        self._shed_t: deque = deque(maxlen=max(int(latency_window), 1))
+        self._timed_lats: deque = deque(maxlen=window)
+        self._shed_t: deque = deque(maxlen=window)
         # (stamp, active_slots) per scheduler step: the lifetime
         # cb_slot_occupancy average can't fall after the scheduler
         # idles (no steps, no new samples), so the autoscaler reads
         # occupancy over a trailing window instead
         self._cb_t: deque = deque(maxlen=8192)
-        # admission / completion
-        self.submitted = 0
-        self.completed = 0
-        self.failed = 0          # engine/batch errors surfaced to requests
-        self.expired = 0         # deadline passed before dispatch
-        self.expired_on_arrival = 0  # dead on arrival: never queued,
-                                     # never prefilled — zero engine
-                                     # steps burned (serve/qos.py)
-        self.cancelled = 0       # cancelled by the caller (hedge loser)
-        self.shed = 0            # admission rejected (queue full / fault)
-        # per-class brownout accounting (every class shed also counts
-        # in `shed`; these split it by priority)
-        self.shed_interactive = 0
-        self.shed_batch = 0
-        self.shed_best_effort = 0
-        self.rejected = 0        # never-servable request (fast 400)
-        self.resumed = 0         # admissions that re-entered with a
-                                 # resume_from prefix (stream failover)
-        self.queue_depth = 0     # gauge: requests waiting right now
-        self.generated_tokens = 0
-        # continuous batching (serve/scheduler.py)
-        self.cb_steps = 0             # scheduler iterations run
-        self.cb_prefills = 0          # prefills that ran to their end
-        self.cb_flash_prefills = 0    # of them, through a rung whose
-                                      # attention is the flash kernel
-        self.cb_prefill_rows = 0      # prompt tokens they held
-        self.cb_prefill_width_rows = 0  # rows their programs ran: each
-                                        # prompt's rung of the ladder
-        # where the engine chunks (observe_cb_chunk; docs/SERVING.md)
-        self.cb_chunked_prompts = 0   # prompts by the chunk programs, whole
-        self.cb_prefill_chunks = 0    # chunks handed to the device
-        self.cb_chunk_tokens = 0      # prompt tokens they held
-        self.cb_prefix_rows = 0       # rows of the pool their attention
-                                      # read: each chunk's start, once
-        self.cb_steps_between_chunks = 0  # decode steps that went out
-                                      # while a prompt was in prefill
-        self.cb_grouped_rows = 0      # their rows multiplied by a held
-                                      # expert's weights (grouped form)
-        self.cb_grouped_row_slots = 0  # rows x held experts: what the
-                                      # dense walk would have multiplied
-        self.cb_grouped_tile_rows = 0  # rows of the tiles the grouped
-                                      # matmul visited, products summed
-        self.cb_admit_steps = 0       # iterations that admitted >= 1:
-                                      # each held every slot for its
-                                      # prefills before decoding
-        self.cb_steps_ahead = 0       # decode steps handed over before
-                                      # the step before them was read
-        self.cb_collects_drained = 0  # steps in flight read with none
-                                      # handed over behind them
-        # the loop thread's stall account (observe_cb_stall)
-        self.cb_stalls = 0            # laps of a step over STALL_S
-        self.cb_stall_seconds = 0.0   # their seconds
-        self.cb_stall_wait_seconds = 0.0  # of them, inside waits on
-                                          # the device or the runtime
-        self.cb_active_slot_steps = 0  # sum of active slots per step
-        self.cb_block_use_steps = 0    # sum of blocks in use per step
-        self.cb_decode_steps = 0       # iterations that ran a decode
-        self.cb_live_block_steps = 0   # sum of table blocks they walked
-        self.cb_block_copies = 0       # sum of the copies that brought
-                                       # them, a layer (an extent each)
-        self.cb_window_block_steps = 0  # sum of ring blocks they walked
-                                        # (layers with a window)
-        self.cb_table_blocks = 0       # gauge: slots x blocks per slot
-        self.cb_slot_capacity = 0      # gauge: compiled slot count S
-        self.cb_blocks_total = 0       # gauge: usable pool blocks
-        self.cb_blocks_in_use = 0      # gauge: blocks held right now
-        # what one slot's fixed state and one paged block cost, over all
-        # layers (serve/kvcache.py state_bytes): with the slots in use
-        # and the live blocks, the bytes a decode step has to move
-        self.cb_slot_state_bytes = 0   # gauge
-        self.cb_block_bytes = 0        # gauge: a growing block
-        self.cb_window_block_bytes = 0  # gauge: a ring block, over the
-                                        # layers with a window
-        # what ONE copy of the paged kernel moves: a block of one
-        # layer's pool, keys and values together (0: no such layer)
-        self.cb_block_copy_bytes = 0         # gauge: under the table
-        self.cb_window_block_copy_bytes = 0  # gauge: in a ring
-        self.cb_ring_blocks = 0        # gauge: ring blocks a slot
-        self.cb_extent_blocks = 0      # gauge: consecutive blocks the
-                                       # free list deals in, a copy's
-        # routing of the experts held here, summed over decode steps
-        # and routed layers, busy slots only (engine.run_cb_decode)
-        self.cb_routed_layer_steps = 0   # layers x steps counted
-        self.cb_routed_assignments = 0   # (token, held expert) pairs
-        self.cb_routed_experts_touched = 0  # held experts some token chose
-        self.cb_routed_max_load = 0      # the busiest held expert's pairs
-        # what the emit loop handed out (observe_cb_emit)
-        self.cb_emit_slot_steps = 0    # busy slots of fetched decode steps
-        self.cb_tokens_emitted = 0     # tokens they were handed
-        # where the model drafts (a verify-and-draft step, two rows a
-        # slot): a busy slot-step verifies one draft
-        self.cb_drafts_made = 0        # drafts verified
-        self.cb_drafts_accepted = 0    # of them, accepted (2 tokens)
-        # batching
-        self.batches = 0
-        self.batched_requests = 0
-        self.batch_slots = 0     # sum of bucket batch sizes dispatched
-        # gauge: dispatched batches failed in a row (reset by any
-        # successful batch) — the wedged-engine signal /healthz
-        # degrades on once it crosses ServeSpec.degraded_after
-        self.consecutive_batch_failures = 0
-        # engine
-        self.compiles = 0
-        self.reloads = 0
-        self.reload_failures = 0   # restore raised → kept old params
-        self.reloads_refused = 0   # nothing newer / unhealthy walk-back
-        self.torn_polls = 0        # poll raced a live writer → no change
-        self.reload_poll_deaths = 0  # poll daemon died on an
-                                     # unexpected exception (restarted
-                                     # under Backoff; /healthz degrades
-                                     # on a persistent streak)
+        for name, zero in _ZEROS:
+            setattr(self, name, zero)
         # real Prometheus histograms (cumulative buckets + _sum/_count)
         # created by register_into(); None until then so the hot path
         # costs one attribute check when /metrics is not wired
@@ -407,10 +446,7 @@ class ServeStats:
         completion."""
         with self._lock:
             lats = sorted(self._latencies)
-        if not lats:
-            return None
-        idx = min(int(q * len(lats)), len(lats) - 1)
-        return lats[idx]
+        return _nearest_rank(lats, q)
 
     def split_quantile(self, kind: str, q: float) -> Optional[float]:
         """Nearest-rank quantile over one of the observe_request
@@ -421,18 +457,7 @@ class ServeStats:
                "tokens_per_s": self._tok_rates}[kind]
         with self._lock:
             vals = sorted(src)
-        if not vals:
-            return None
-        return vals[min(int(q * len(vals)), len(vals) - 1)]
-
-    def cb_slot_occupancy(self) -> Optional[float]:
-        """Active slots / compiled slots averaged over scheduler
-        steps (the cb sibling of `occupancy`)."""
-        with self._lock:
-            if self.cb_steps == 0 or self.cb_slot_capacity == 0:
-                return None
-            return self.cb_active_slot_steps / (
-                self.cb_steps * self.cb_slot_capacity)
+        return _nearest_rank(vals, q)
 
     def cb_slot_occupancy_recent(
             self, window_s: float = 5.0) -> Optional[float]:
@@ -463,26 +488,6 @@ class ServeStats:
             busy += a * min(max(t - prev, 0.0), 0.25)
             prev = t
         return min(busy / (window * capacity), 1.0)
-
-    def cb_block_utilization(self) -> Optional[float]:
-        with self._lock:
-            if self.cb_steps == 0 or self.cb_blocks_total == 0:
-                return None
-            return self.cb_block_use_steps / (
-                self.cb_steps * self.cb_blocks_total)
-
-    def cb_prefill_fill_share(self) -> Optional[float]:
-        with self._lock:
-            if self.cb_prefill_width_rows == 0:
-                return None
-            return self.cb_prefill_rows / self.cb_prefill_width_rows
-
-    def cb_live_block_share(self) -> Optional[float]:
-        with self._lock:
-            if self.cb_decode_steps == 0 or self.cb_table_blocks == 0:
-                return None
-            return self.cb_live_block_steps / (
-                self.cb_decode_steps * self.cb_table_blocks)
 
     def occupancy(self) -> Optional[float]:
         with self._lock:
@@ -525,10 +530,7 @@ class ServeStats:
             lats = sorted(l for t, l in self._timed_lats if t >= cut)
 
         def q(frac):
-            if not lats:
-                return None
-            return round(
-                lats[min(int(frac * len(lats)), len(lats) - 1)] * 1e3, 3)
+            return _rounded(_nearest_rank(lats, frac), 3, 1e3)
         return {
             "window_s": round(window, 3),
             "completed": len(lats),
@@ -549,57 +551,14 @@ class ServeStats:
         agree by construction."""
         from ..obs.metrics import Sample
 
-        counters = ("submitted", "completed", "failed", "expired",
-                    "expired_on_arrival", "cancelled", "shed",
-                    "shed_interactive", "shed_batch",
-                    "shed_best_effort", "rejected", "resumed",
-                    "generated_tokens", "batches",
-                    "batched_requests", "batch_slots", "cb_steps",
-                    "cb_prefills", "cb_flash_prefills", "cb_prefill_rows",
-                    "cb_prefill_width_rows", "cb_chunked_prompts",
-                    "cb_prefill_chunks", "cb_chunk_tokens",
-                    "cb_prefix_rows", "cb_steps_between_chunks",
-                    "cb_grouped_rows", "cb_grouped_row_slots",
-                    "cb_grouped_tile_rows", "cb_admit_steps",
-                    "cb_steps_ahead", "cb_collects_drained",
-                    "cb_stalls", "cb_stall_seconds",
-                    "cb_stall_wait_seconds",
-                    "cb_live_block_steps", "cb_block_copies",
-                    "cb_window_block_steps",
-                    "cb_routed_layer_steps", "cb_routed_assignments",
-                    "cb_routed_experts_touched", "cb_routed_max_load",
-                    "cb_emit_slot_steps", "cb_tokens_emitted",
-                    "cb_drafts_made", "cb_drafts_accepted",
-                    "compiles", "reloads", "reload_failures",
-                    "reloads_refused", "torn_polls",
-                    "reload_poll_deaths")
-        gauges = ("queue_depth", "consecutive_batch_failures", "qps",
-                  "qps_recent", "uptime_s", "p50_latency_ms",
-                  "p95_latency_ms", "p99_latency_ms",
-                  "shed_rate_recent", "p95_latency_recent_ms",
-                  "p99_latency_recent_ms", "p50_queue_wait_ms",
-                  "p95_queue_wait_ms", "p50_service_ms",
-                  "p95_service_ms", "p50_ttft_ms", "p95_ttft_ms",
-                  "p50_tokens_per_s",
-                  "p95_tokens_per_s", "batch_occupancy",
-                  "cb_slot_occupancy", "cb_slot_occupancy_recent",
-                  "cb_block_utilization", "cb_live_block_share",
-                  "cb_prefill_fill_share", "cb_blocks_in_use",
-                  "cb_blocks_total",
-                  "cb_slot_state_bytes", "cb_block_bytes",
-                  "cb_window_block_bytes", "cb_ring_blocks",
-                  "cb_extent_blocks", "cb_block_copy_bytes",
-                  "cb_window_block_copy_bytes",
-                  "cb_window_block_share")
-
         def collect():
             snap = self.snapshot()
             out = [Sample(f"{prefix}_{k}_total", "counter",
                           f"serving counter {k!r}", float(snap[k]))
-                   for k in counters]
+                   for k in _COUNTERS]
             out += [Sample(f"{prefix}_{k}", "gauge",
                            f"serving gauge {k!r}", float(snap[k]))
-                    for k in gauges if snap.get(k) is not None]
+                    for k in _GAUGES if snap.get(k) is not None]
             return out
 
         registry.register_collector(collect)
@@ -625,85 +584,31 @@ class ServeStats:
             "submit to first token (continuous batching)")
 
     def snapshot(self) -> Dict[str, Any]:
-        """JSON-ready view for /stats."""
-        p50, p95, p99 = (self.latency_quantile(0.50),
-                         self.latency_quantile(0.95),
-                         self.latency_quantile(0.99))
-        occ = self.occupancy()
-        cb_occ = self.cb_slot_occupancy()
+        """JSON-ready view for /stats: the table's counters and gauges
+        in its order, then what is computed from them."""
         cb_occ_recent = self.cb_slot_occupancy_recent()
-        cb_util = self.cb_block_utilization()
-        cb_live = self.cb_live_block_share()
-        cb_fill = self.cb_prefill_fill_share()
         with self._lock:
-            out = {
-                "submitted": self.submitted,
-                "completed": self.completed,
-                "failed": self.failed,
-                "expired": self.expired,
-                "expired_on_arrival": self.expired_on_arrival,
-                "cancelled": self.cancelled,
-                "shed": self.shed,
-                "shed_interactive": self.shed_interactive,
-                "shed_batch": self.shed_batch,
-                "shed_best_effort": self.shed_best_effort,
-                "rejected": self.rejected,
-                "resumed": self.resumed,
-                "queue_depth": self.queue_depth,
-                "generated_tokens": self.generated_tokens,
-                "batches": self.batches,
-                "batched_requests": self.batched_requests,
-                "batch_slots": self.batch_slots,
-                "cb_steps": self.cb_steps,
-                "cb_prefills": self.cb_prefills,
-                "cb_flash_prefills": self.cb_flash_prefills,
-                "cb_prefill_rows": self.cb_prefill_rows,
-                "cb_prefill_width_rows": self.cb_prefill_width_rows,
-                "cb_chunked_prompts": self.cb_chunked_prompts,
-                "cb_prefill_chunks": self.cb_prefill_chunks,
-                "cb_chunk_tokens": self.cb_chunk_tokens,
-                "cb_prefix_rows": self.cb_prefix_rows,
-                "cb_steps_between_chunks": self.cb_steps_between_chunks,
-                "cb_grouped_rows": self.cb_grouped_rows,
-                "cb_grouped_row_slots": self.cb_grouped_row_slots,
-                "cb_grouped_tile_rows": self.cb_grouped_tile_rows,
-                "cb_admit_steps": self.cb_admit_steps,
-                "cb_steps_ahead": self.cb_steps_ahead,
-                "cb_collects_drained": self.cb_collects_drained,
-                "cb_stalls": self.cb_stalls,
-                "cb_stall_seconds": round(self.cb_stall_seconds, 6),
-                "cb_stall_wait_seconds":
-                    round(self.cb_stall_wait_seconds, 6),
-                "cb_blocks_in_use": self.cb_blocks_in_use,
-                "cb_blocks_total": self.cb_blocks_total,
-                "cb_slot_state_bytes": self.cb_slot_state_bytes,
-                "cb_block_bytes": self.cb_block_bytes,
-                "cb_window_block_bytes": self.cb_window_block_bytes,
-                "cb_block_copy_bytes": self.cb_block_copy_bytes,
-                "cb_window_block_copy_bytes":
-                    self.cb_window_block_copy_bytes,
-                "cb_ring_blocks": self.cb_ring_blocks,
-                "cb_extent_blocks": self.cb_extent_blocks,
-                "cb_live_block_steps": self.cb_live_block_steps,
-                "cb_block_copies": self.cb_block_copies,
-                "cb_window_block_steps": self.cb_window_block_steps,
-                "cb_routed_layer_steps": self.cb_routed_layer_steps,
-                "cb_routed_assignments": self.cb_routed_assignments,
-                "cb_routed_experts_touched":
-                    self.cb_routed_experts_touched,
-                "cb_routed_max_load": self.cb_routed_max_load,
-                "cb_emit_slot_steps": self.cb_emit_slot_steps,
-                "cb_tokens_emitted": self.cb_tokens_emitted,
-                "cb_drafts_made": self.cb_drafts_made,
-                "cb_drafts_accepted": self.cb_drafts_accepted,
-                "consecutive_batch_failures":
-                    self.consecutive_batch_failures,
-                "compiles": self.compiles,
-                "reloads": self.reloads,
-                "reload_failures": self.reload_failures,
-                "reloads_refused": self.reloads_refused,
-                "torn_polls": self.torn_polls,
-                "reload_poll_deaths": self.reload_poll_deaths,
+            out = {name: (round(getattr(self, name), 6) if seconds
+                          else getattr(self, name))
+                   for name, seconds in _PLAIN}
+            shares = {
+                "batch_occupancy": _share(self.batched_requests,
+                                          self.batch_slots),
+                "cb_slot_occupancy": _share(
+                    self.cb_active_slot_steps,
+                    self.cb_steps * self.cb_slot_capacity),
+                "cb_slot_occupancy_recent": _rounded(cb_occ_recent, 4),
+                "cb_block_utilization": _share(
+                    self.cb_block_use_steps,
+                    self.cb_steps * self.cb_blocks_total),
+                "cb_live_block_share": _share(
+                    self.cb_live_block_steps,
+                    self.cb_decode_steps * self.cb_table_blocks),
+                "cb_prefill_fill_share": _share(
+                    self.cb_prefill_rows, self.cb_prefill_width_rows),
+                "cb_window_block_share": _share(
+                    self.cb_window_block_steps, self.cb_live_block_steps)
+                if self.cb_ring_blocks else None,
             }
         out["qps"] = round(self.qps(), 3)
         out["qps_recent"] = round(self.qps_recent(), 3)
@@ -712,42 +617,16 @@ class ServeStats:
         out["p95_latency_recent_ms"] = win["p95_latency_ms"]
         out["p99_latency_recent_ms"] = win["p99_latency_ms"]
         out["uptime_s"] = round(self.uptime_s(), 3)
-        out["p50_latency_ms"] = (round(p50 * 1e3, 3)
-                                 if p50 is not None else None)
-        out["p95_latency_ms"] = (round(p95 * 1e3, 3)
-                                 if p95 is not None else None)
-        out["p99_latency_ms"] = (round(p99 * 1e3, 3)
-                                 if p99 is not None else None)
-        for kind, label in (("queue_wait", "queue_wait_ms"),
-                            ("service", "service_ms"),
-                            ("ttft", "ttft_ms")):
+        for q, pre in ((0.50, "p50"), (0.95, "p95"), (0.99, "p99")):
+            out[f"{pre}_latency_ms"] = _rounded(
+                self.latency_quantile(q), 3, 1e3)
+        for kind, label, scale in (("queue_wait", "queue_wait_ms", 1e3),
+                                   ("service", "service_ms", 1e3),
+                                   ("ttft", "ttft_ms", 1e3),
+                                   ("tokens_per_s", "tokens_per_s", 1.0)):
             for q, pre in ((0.50, "p50"), (0.95, "p95")):
-                v = self.split_quantile(kind, q)
-                out[f"{pre}_{label}"] = (round(v * 1e3, 3)
-                                         if v is not None else None)
-        for q, pre in ((0.50, "p50"), (0.95, "p95")):
-            v = self.split_quantile("tokens_per_s", q)
-            out[f"{pre}_tokens_per_s"] = (round(v, 3)
-                                          if v is not None else None)
-        out["batch_occupancy"] = (round(occ, 4) if occ is not None
-                                  else None)
-        out["cb_slot_occupancy"] = (round(cb_occ, 4)
-                                    if cb_occ is not None else None)
-        out["cb_slot_occupancy_recent"] = (
-            round(cb_occ_recent, 4)
-            if cb_occ_recent is not None else None)
-        out["cb_block_utilization"] = (round(cb_util, 4)
-                                       if cb_util is not None else None)
-        out["cb_live_block_share"] = (round(cb_live, 4)
-                                      if cb_live is not None else None)
-        out["cb_prefill_fill_share"] = (round(cb_fill, 4)
-                                        if cb_fill is not None else None)
-        # of the blocks a growing table's walk reads, what a window's
-        # reads: under 1 once contexts pass the window
-        out["cb_window_block_share"] = (
-            round(out["cb_window_block_steps"]
-                  / out["cb_live_block_steps"], 4)
-            if out["cb_ring_blocks"] and out["cb_live_block_steps"]
-            else None)
+                out[f"{pre}_{label}"] = _rounded(
+                    self.split_quantile(kind, q), 3, scale)
+        out.update(shares)
         out["by_tenant"] = self.tenants.snapshot()
         return out

@@ -17,6 +17,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from oracles import attend_absorbed  # noqa: E402
 from benchmark import harness, kimi_weights  # noqa: E402
 from benchmark.reference import kimi_linear  # noqa: E402
 from benchmark.runners import serve_kimi  # noqa: E402
@@ -245,7 +246,7 @@ def test_mla_absorbed_and_expanded_forms_agree(lm):
     q, lat = layer._project(full, x)
     allowed = jnp.asarray(rng.uniform(size=(3, 9)) < 0.7).at[:, 0].set(True)
     wide = layer._attend_expanded(full, q[:, -1:], lat, allowed[:, None, :])
-    narrow = layer._attend_absorbed(full, q[:, -1], lat, allowed)
+    narrow = attend_absorbed(layer, full, q[:, -1], lat, allowed)
     np.testing.assert_allclose(narrow, wide[:, 0], rtol=1e-4, atol=1e-5)
 
 
@@ -269,7 +270,7 @@ def test_decode_program_never_materialises_the_gathered_latent_tables(lm):
     whole table row of latent rows (S, T * bl, row), is in no value of
     the lowered cb decode program: the paged kernel (interpreted here:
     a `while`) reads the pool through the table.  The old formulation's
-    lowering, `_attend_absorbed` over the gathered table, is the same
+    lowering, `oracles.attend_absorbed` over the gathered table, is the same
     search's control."""
     net, _, _ = lm
     engine = _engine(lm, 3)
@@ -288,7 +289,7 @@ def test_decode_program_never_materialises_the_gathered_latent_tables(lm):
     def gather_formulation(params, q, pool, tables, ntoks):
         mine = pool[tables].reshape(s, t * bl, layer.pool_row)
         allowed = jnp.arange(t * bl)[None, :] <= ntoks[:, None]
-        return layer._attend_absorbed(params, q, mine, allowed)
+        return attend_absorbed(layer, params, q, mine, allowed)
 
     f32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32)  # noqa: E731
     control = jax.jit(gather_formulation).lower(

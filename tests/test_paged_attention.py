@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from oracles import attend_absorbed
 from singa_tpu.config.schema import LayerConfig, MLAConfig
 from singa_tpu.core.hybrid_layers import MLALayer
 from singa_tpu.core.net import build_net
@@ -150,13 +151,13 @@ def mla():
 
 
 def _latent_reference(layer, params, q, pool, tables, ntoks):
-    """`MLALayer._attend_absorbed` over every slot's gathered table:
+    """`oracles.attend_absorbed` over every slot's gathered table:
     what `apply_paged` ran before the kernel.  Returns the attended
     latents' expansion, (S, H * vdim)."""
     s, t = tables.shape
     rows = pool[tables][:, :, 0].reshape(s, t * BL, pool.shape[-1])
     allowed = jnp.arange(t * BL)[None, :] <= ntoks[:, None]
-    return layer._attend_absorbed(params, q, rows.astype(q.dtype), allowed)
+    return attend_absorbed(layer, params, q, rows.astype(q.dtype), allowed)
 
 
 def _latent_kernel(layer, params, q, pool, tables, ntoks):

@@ -18,6 +18,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from oracles import attend_absorbed  # noqa: E402
 from benchmark import harness, pangu_weights  # noqa: E402
 from benchmark.reference import pangu  # noqa: E402
 from benchmark.runners import serve_pangu  # noqa: E402
@@ -222,7 +223,7 @@ def test_a_rejected_row_is_overwritten_and_never_attended(lm):
 
 @pytest.mark.parametrize("ntoks", [[0, 3, 4, 9], [7, 8, 15, 1]])
 def test_two_query_rows_a_slot_are_the_absorbed_sums(lm, ntoks):
-    """`paged_decode_attention(rows=2)` against `_attend_absorbed` over
+    """`paged_decode_attention(rows=2)` against `attend_absorbed` over
     the gathered rows, row j up to position ntoks + j, and against the
     gather formulation; lengths that put the pair in one block, at a
     block's end and across two."""
@@ -250,7 +251,7 @@ def test_two_query_rows_a_slot_are_the_absorbed_sums(lm, ntoks):
     lat = pool[tables].reshape(s, t * BL, -1)
     for j in range(2):
         allowed = jnp.arange(t * BL)[None, :] <= (ntoks + j)[:, None]
-        want = layer._attend_absorbed(full, q[:, j], lat, allowed)
+        want = attend_absorbed(layer, full, q[:, j], lat, allowed)
         mine = layer._expand_output(
             full, got.reshape(s, 2, layer.heads, -1)[:, j])
         np.testing.assert_allclose(mine, want, rtol=2e-4, atol=2e-4)

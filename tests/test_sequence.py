@@ -54,15 +54,6 @@ def test_ring_attention_matches_reference(causal):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_ring_attention_grad():
-    q, k, v = _qkv(1, 4, 128, 16)
-    mesh = make_mesh(seq=8)
-    g1 = jax.grad(lambda q: ring_attention(q, k, v, mesh, "seq", True).sum())(q)
-    g2 = jax.grad(lambda q: attention_reference(q, k, v, True).sum())(q)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                               rtol=1e-4, atol=1e-5)
-
-
 @pytest.mark.parametrize("causal", [False, True])
 def test_ulysses_matches_reference(causal):
     q, k, v = _qkv()
@@ -471,28 +462,6 @@ def test_attention_layer_gqa_packed_matches_strided():
                                    rtol=1e-3, atol=1e-5, err_msg=k)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_flash_and_blockwise_paths_agree(causal):
-    """Both ring local-step implementations — the Pallas flash unrolled
-    rotation (use_flash=True) and the XLA blockwise scan fallback — must
-    match the dense reference and each other, gradients included."""
-    q, k, v = _qkv(1, 4, 256, 16)
-    mesh = make_mesh(seq=8)
-    of = ring_attention(q, k, v, mesh, "seq", causal, use_flash=True)
-    ob = ring_attention(q, k, v, mesh, "seq", causal, use_flash=False)
-    ref = attention_reference(q, k, v, causal)
-    np.testing.assert_allclose(np.asarray(of), np.asarray(ref),
-                               rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(of), np.asarray(ob),
-                               rtol=1e-4, atol=1e-5)
-    gf = jax.grad(lambda k: ring_attention(
-        q, k, v, mesh, "seq", causal, use_flash=True).sum())(k)
-    gr = jax.grad(lambda k: attention_reference(
-        q, k, v, causal).sum())(k)
-    np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                               rtol=1e-4, atol=1e-5)
-
-
 def _count_packed_traces(monkeypatch):
     """Count traces of the packed forward during jit tracing — proof the
     packed kernel path (not the strided fallback) is the one compiled."""
@@ -606,26 +575,6 @@ def _gqa_ref(q, k, v, causal):
     from singa_tpu.ops.attention import expand_kv_heads
     return attention_reference(q, expand_kv_heads(k, q.shape[1]),
                                expand_kv_heads(v, q.shape[1]), causal)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_gqa_unexpanded_kv(causal):
-    """Ring accepts (B, Hkv, S, D) k/v directly: forward parity vs the
-    dense reference on expanded heads, plus q AND k gradients (the k
-    grad flows through ppermute rotations at Hkv width)."""
-    q, k, v = _gqa_qkv()
-    mesh = make_mesh(seq=8)
-    out = ring_attention(q, k, v, mesh, "seq", causal)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(_gqa_ref(q, k, v, causal)),
-                               rtol=1e-4, atol=1e-5)
-    g1 = jax.grad(lambda q, k: ring_attention(
-        q, k, v, mesh, "seq", causal).sum(), argnums=(0, 1))(q, k)
-    g2 = jax.grad(lambda q, k: _gqa_ref(q, k, v, causal).sum(),
-                  argnums=(0, 1))(q, k)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("seq_size,native", [(2, True), (8, False)])

@@ -393,3 +393,152 @@ def test_stats_snapshot_fields():
     assert snap["p50_latency_ms"] == 2.0
     assert snap["p95_latency_ms"] == 100.0
     assert snap["qps"] > 0
+
+
+# What /stats and /metrics showed before `ServeStats` read its names from
+# one table (singa_tpu/serve/stats.py TALLIES), taken from that tree: the
+# table is held to both, order included.
+_SNAPSHOT_KEYS = (
+    "submitted", "completed", "failed", "expired", "expired_on_arrival",
+    "cancelled", "shed", "shed_interactive", "shed_batch",
+    "shed_best_effort", "rejected", "resumed", "queue_depth",
+    "generated_tokens", "batches", "batched_requests", "batch_slots",
+    "cb_steps", "cb_prefills", "cb_flash_prefills", "cb_prefill_rows",
+    "cb_prefill_width_rows", "cb_chunked_prompts", "cb_prefill_chunks",
+    "cb_chunk_tokens", "cb_prefix_rows", "cb_steps_between_chunks",
+    "cb_grouped_rows", "cb_grouped_row_slots", "cb_grouped_tile_rows",
+    "cb_admit_steps", "cb_steps_ahead", "cb_collects_drained",
+    "cb_stalls", "cb_stall_seconds", "cb_stall_wait_seconds",
+    "cb_blocks_in_use", "cb_blocks_total", "cb_slot_state_bytes",
+    "cb_block_bytes", "cb_window_block_bytes", "cb_block_copy_bytes",
+    "cb_window_block_copy_bytes", "cb_ring_blocks", "cb_extent_blocks",
+    "cb_live_block_steps", "cb_block_copies", "cb_window_block_steps",
+    "cb_routed_layer_steps", "cb_routed_assignments",
+    "cb_routed_experts_touched", "cb_routed_max_load",
+    "cb_emit_slot_steps", "cb_tokens_emitted", "cb_drafts_made",
+    "cb_drafts_accepted", "consecutive_batch_failures", "compiles",
+    "reloads", "reload_failures", "reloads_refused", "torn_polls",
+    "reload_poll_deaths", "qps", "qps_recent", "shed_rate_recent",
+    "p95_latency_recent_ms", "p99_latency_recent_ms", "uptime_s",
+    "p50_latency_ms", "p95_latency_ms", "p99_latency_ms",
+    "p50_queue_wait_ms", "p95_queue_wait_ms", "p50_service_ms",
+    "p95_service_ms", "p50_ttft_ms", "p95_ttft_ms", "p50_tokens_per_s",
+    "p95_tokens_per_s", "batch_occupancy", "cb_slot_occupancy",
+    "cb_slot_occupancy_recent", "cb_block_utilization",
+    "cb_live_block_share", "cb_prefill_fill_share",
+    "cb_window_block_share", "by_tenant")
+_METRIC_COUNTERS = (
+    "submitted", "completed", "failed", "expired", "expired_on_arrival",
+    "cancelled", "shed", "shed_interactive", "shed_batch",
+    "shed_best_effort", "rejected", "resumed", "generated_tokens",
+    "batches", "batched_requests", "batch_slots", "cb_steps",
+    "cb_prefills", "cb_flash_prefills", "cb_prefill_rows",
+    "cb_prefill_width_rows", "cb_chunked_prompts", "cb_prefill_chunks",
+    "cb_chunk_tokens", "cb_prefix_rows", "cb_steps_between_chunks",
+    "cb_grouped_rows", "cb_grouped_row_slots", "cb_grouped_tile_rows",
+    "cb_admit_steps", "cb_steps_ahead", "cb_collects_drained",
+    "cb_stalls", "cb_stall_seconds", "cb_stall_wait_seconds",
+    "cb_live_block_steps", "cb_block_copies", "cb_window_block_steps",
+    "cb_routed_layer_steps", "cb_routed_assignments",
+    "cb_routed_experts_touched", "cb_routed_max_load",
+    "cb_emit_slot_steps", "cb_tokens_emitted", "cb_drafts_made",
+    "cb_drafts_accepted", "compiles", "reloads", "reload_failures",
+    "reloads_refused", "torn_polls", "reload_poll_deaths")
+_METRIC_GAUGES = (
+    "queue_depth", "consecutive_batch_failures", "qps", "qps_recent",
+    "uptime_s", "p50_latency_ms", "p95_latency_ms", "p99_latency_ms",
+    "shed_rate_recent", "p95_latency_recent_ms",
+    "p99_latency_recent_ms", "p50_queue_wait_ms", "p95_queue_wait_ms",
+    "p50_service_ms", "p95_service_ms", "p50_ttft_ms", "p95_ttft_ms",
+    "p50_tokens_per_s", "p95_tokens_per_s", "batch_occupancy",
+    "cb_slot_occupancy", "cb_slot_occupancy_recent",
+    "cb_block_utilization", "cb_live_block_share",
+    "cb_prefill_fill_share", "cb_blocks_in_use", "cb_blocks_total",
+    "cb_slot_state_bytes", "cb_block_bytes", "cb_window_block_bytes",
+    "cb_ring_blocks", "cb_extent_blocks", "cb_block_copy_bytes",
+    "cb_window_block_copy_bytes", "cb_window_block_share")
+_METRIC_SAMPLES = (
+    [("singa_serve_request_latency_seconds", "histogram",
+      "end-to-end request latency on this engine"),
+     ("singa_serve_queue_wait_seconds", "histogram",
+      "time queued before dispatch/admission"),
+     ("singa_serve_service_seconds", "histogram",
+      "time being served after dispatch"),
+     ("singa_serve_ttft_seconds", "histogram",
+      "submit to first token (continuous batching)")]
+    + [(f"singa_serve_{k}_total", "counter", f"serving counter {k!r}")
+       for k in _METRIC_COUNTERS]
+    + [(f"singa_serve_{k}", "gauge", f"serving gauge {k!r}")
+       for k in _METRIC_GAUGES]
+    + [(f"singa_tenant_{k}_total", "counter", f"per-tenant counter {k!r}")
+       for k in ("submitted", "completed", "shed")])
+
+
+def _driven_stats():
+    """A `ServeStats` every gauge of which has a value (a gauge that is
+    None is left out of /metrics), and the registry it registered into."""
+    from singa_tpu.obs import MetricsRegistry
+    st, registry = ServeStats(), MetricsRegistry()
+    st.register_into(registry)
+    for field, value in (("cb_slot_capacity", 4), ("cb_table_blocks", 32),
+                         ("cb_blocks_total", 64), ("cb_ring_blocks", 3)):
+        st.gauge(field, value)
+    st.count("shed")
+    st.observe_batch(3, 4)
+    st.observe_latency(0.25)
+    st.observe_request(0.125, 0.375, 12)
+    st.observe_ttft(0.03125)
+    st.observe_cb_prefill(100, 256, flash=True)
+    st.observe_cb_step(3, 7, live_blocks=11, window_blocks=5, copies=6)
+    st.tenants.count("submitted", "acme")
+    return st, registry
+
+
+def _metric_heads(registry):
+    """(name, type, help) of every /metrics sample, as the endpoint
+    lists them."""
+    heads, helps = [], {}
+    for line in registry.render_prometheus().splitlines():
+        if line.startswith("# HELP "):
+            name, text = line[len("# HELP "):].split(" ", 1)
+            helps[name] = text
+        elif line.startswith("# TYPE "):
+            name, kind = line[len("# TYPE "):].split(" ", 1)
+            heads.append((name, kind, helps.get(name, "")))
+    return heads
+
+
+def test_stats_surfaces_are_what_they_were():
+    assert tuple(ServeStats().snapshot()) == _SNAPSHOT_KEYS
+    st, registry = _driven_stats()
+    assert tuple(st.snapshot()) == _SNAPSHOT_KEYS
+    assert _metric_heads(registry) == _METRIC_SAMPLES
+
+
+@pytest.mark.parametrize("kind", ["counter", "gauge", "internal", "derived"])
+def test_stats_table_says_where_each_name_shows(kind):
+    """Every name of the table is where its kind says, and nothing is
+    there beside the table: an attribute `__init__` zeroes, a key of
+    `snapshot()`, a /metrics sample of that type."""
+    from singa_tpu.serve.stats import TALLIES
+    declared = [t.name for t in TALLIES if t.kind == kind]
+    assert len(set(declared)) == len(declared)
+    assert all(t.meaning for t in TALLIES)
+    attrs = {k for k, v in vars(ServeStats()).items()
+             if not k.startswith("_") and isinstance(v, (int, float))
+             } - {"qps_window_s"}
+    st, registry = _driven_stats()
+    keys = set(st.snapshot()) - {"by_tenant"}
+    types = {name: mtype for name, mtype, _ in _metric_heads(registry)
+             if name.startswith("singa_serve_") and mtype != "histogram"}
+    counters = {k for k in attrs | keys
+                if types.get(f"singa_serve_{k}_total") == "counter"}
+    gauges = {k for k in attrs | keys
+              if types.get(f"singa_serve_{k}") == "gauge"}
+    assert len(counters) + len(gauges) == len(types)
+    found = {"counter": counters & attrs & keys,
+             "gauge": gauges & attrs & keys,
+             "internal": attrs - keys - counters - gauges,
+             "derived": (keys - attrs) & gauges}[kind]
+    assert found == set(declared)
+    assert attrs | keys == {t.name for t in TALLIES}
